@@ -1,6 +1,6 @@
 PY ?= python
 
-.PHONY: check test test-fast native bench flush-bench flush-bench-smoke loadsst-bench load-sst-smoke soak-bench repl-bench-smoke transport-bench-smoke macro-bench macro-bench-smoke macro-bench-move-smoke macro-bench-sched-ab macro-bench-hot-shift macro-bench-cdc fleet-bench fleet-smoke metrics-smoke compaction-bench compaction-bench-smoke compaction-remote-bench compaction-remote-smoke stream-merge-bench stream-merge-smoke overload-bench overload-smoke chaos-smoke chaos-failover-smoke reshard-smoke rebalance-smoke cdc-smoke clean
+.PHONY: check test test-fast native chip-smoke flush-bench flush-bench-smoke loadsst-bench load-sst-smoke soak-bench repl-bench-smoke transport-bench-smoke macro-bench macro-bench-smoke macro-bench-move-smoke macro-bench-sched-ab macro-bench-hot-shift macro-bench-cdc fleet-bench fleet-smoke metrics-smoke compaction-bench compaction-bench-smoke compaction-remote-bench compaction-remote-smoke stream-merge-bench stream-merge-smoke overload-bench overload-smoke chaos-smoke chaos-failover-smoke reshard-smoke rebalance-smoke cdc-smoke clean
 
 # rstpu-check: the three-pass static suite (lock-order/blocking-under-
 # lock, event-loop blocking, failpoint/span/stats registries) over
@@ -15,15 +15,19 @@ test:
 	$(PY) -m pytest tests/ -q
 
 # parallel across cores (pytest-xdist); per-process jax compiles also hit
-# the persistent XLA cache set up in tests/conftest.py
+# the persistent compile cache (rocksplicator_tpu/tpu/compile_cache.py)
 test-fast:
 	$(PY) -m pytest tests/ -q -n auto
 
 native:
 	$(MAKE) -C rocksplicator_tpu/storage/native
 
-bench:
-	$(PY) bench.py
+# the device path end to end on ONE chip, one process: bulk-load ->
+# device compaction -> reads vs a dict model. Exits nonzero without a
+# TPU (there is no CPU fallback); from the sandbox: chiprun -- python
+# chip_smoke.py
+chip-smoke:
+	$(PY) chip_smoke.py
 
 # round-9 engine microbench: flush / host-compaction / block-cache A/B
 # at the PERF.md 200k-entry methodology

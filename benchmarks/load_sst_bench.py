@@ -32,12 +32,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# persistent XLA compile cache (tests/conftest.py does the same): the TPU
-# config's kernel compiles are identical run to run — warm runs measure
-# the pipeline, not the compiler
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/rstpu_test_xla_cache")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1.0")
-
 from rocksplicator_tpu.admin import AdminHandler
 # Warm the engine's lazily-imported kernel deps (ops → jax, ~1.5 s) before
 # any timed region: a serving node has them loaded; without this the first
@@ -45,14 +39,6 @@ from rocksplicator_tpu.admin import AdminHandler
 # concurrently-admitted shard blocks on the same import lock.
 import rocksplicator_tpu.ops  # noqa: F401
 
-try:  # jax < 0.5 ignores the cache env vars; set the config directly
-    import jax
-
-    jax.config.update("jax_compilation_cache_dir",
-                      os.environ["JAX_COMPILATION_CACHE_DIR"])
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-except Exception:
-    pass
 from rocksplicator_tpu.observability.collector import SpanCollector, render_trace
 from rocksplicator_tpu.replication import Replicator
 from rocksplicator_tpu.rpc import IoLoop, RpcClientPool, RpcServer
@@ -210,7 +196,15 @@ def main(argv=None) -> int:
     configs = {"cpu": {}, "tpu": {"tpu_compaction": True}}
     runs = {}
     results = {}
-    for label in [c.strip() for c in args.configs.split(",") if c.strip()]:
+    labels = [c.strip() for c in args.configs.split(",") if c.strip()]
+    if "tpu" in labels:
+        # the tpu config's kernel compiles are identical run to run —
+        # warm runs measure the pipeline, not the compiler
+        from rocksplicator_tpu.tpu.compile_cache import \
+            configure_compile_cache
+
+        log(f"compile cache: {configure_compile_cache()}")
+    for label in labels:
         run = run_load(
             configs[label], store_uri, args.shards, args.keys_per_shard,
             args.write_frac, label, os.path.join(tmp, f"dbs-{label}"),

@@ -168,6 +168,11 @@ class AdminHandler:
             else default_sst_loading_concurrency()
         )
         self._tpu_compaction = tpu_compaction
+        if tpu_compaction:
+            # before the first kernel compile of this process
+            from ..tpu.compile_cache import configure_compile_cache
+
+            configure_compile_cache()
         self._batch_compactor = BatchCompactor(
             use_tpu=tpu_compaction, compact_parallelism=compact_parallelism)
         self._meta_db = DB(os.path.join(self.rocksdb_dir, "meta_db"))

@@ -205,6 +205,7 @@ class BatchCompactor:
                     fut.set_result(result)
 
         if self._use_tpu:
+            from ..storage.compaction import record_host_fallback
             from ..tpu.compaction_service import compact_dbs_batched
 
             try:
@@ -212,8 +213,11 @@ class BatchCompactor:
                 # over this pool; only the device launch is centralized
                 handled, remaining = compact_dbs_batched(
                     remaining, pool=self._pool)
-            except BaseException:  # launch machinery itself blew up
-                log.exception("compact_dbs_batched failed; per-db fallback")
+            except Exception:  # launch machinery itself blew up
+                record_host_fallback(
+                    "batched_dispatch",
+                    f"{len(by_db)} shards; re-compacting per-db",
+                    exc_info=True)
                 remaining = list(by_db.values())
             # everything not handed back for per-db fallback was compacted
             rem_ids = {id(db) for _n, db in remaining}

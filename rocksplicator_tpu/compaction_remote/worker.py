@@ -18,9 +18,10 @@ fallback). A worker crash therefore leaks nothing but garbage objects,
 which the leader's cleanup sweeps by job-id prefix.
 
 The merge backend defaults to the native CPU pipeline; set
-``RSTPU_COMPACT_WORKER_BACKEND=tpu`` to use the vmapped TPU backend —
-one accelerator worker host then naturally serves many shards'
-compactions, which is the silicon story this tier exists for.
+``RSTPU_COMPACT_WORKER_BACKEND=tpu`` to use the TPU backend — one
+accelerator worker host then serves many shards' compactions. A chip
+belongs to one process: a tpu worker and a serving node with
+``tpu_compaction`` on the same host are two claimants of one chip.
 
 ``tools/compaction_worker.py`` is the CLI shell around this module.
 """
@@ -36,6 +37,7 @@ import time
 import uuid
 from typing import List, Optional, Tuple
 
+from ..storage.compaction import record_host_fallback
 from ..storage.merge import MERGE_OPERATORS
 from ..storage.sst import SSTReader, SSTWriter
 from ..testing import failpoints as fp
@@ -52,18 +54,16 @@ class ChecksumMismatch(Exception):
 
 
 def _build_backend(name: Optional[str]):
-    """Resolve the merge backend. "tpu" gates on an importable jax —
-    the worker container may be CPU-only, in which case it degrades to
-    the native CPU pipeline rather than refusing jobs."""
+    """Resolve the merge backend. "tpu" means the device: a worker asked
+    for it on a host where jax finds no chip fails to start
+    (TpuCompactionBackend raises) — it never serves jobs on the CPU
+    under the TPU's name."""
     name = (name or os.environ.get("RSTPU_COMPACT_WORKER_BACKEND")
             or "cpu").lower()
     if name == "tpu":
-        try:
-            from ..tpu.backend import TpuCompactionBackend
+        from ..tpu.backend import TpuCompactionBackend
 
-            return TpuCompactionBackend()
-        except Exception:
-            log.warning("TPU backend unavailable; worker using CPU merge")
+        return TpuCompactionBackend()
     from ..storage.native_compaction import NativeCompactionBackend
 
     return NativeCompactionBackend()
@@ -94,6 +94,7 @@ def merge_job_to_files(job: CompactionJob, input_paths: List[str],
 
     outputs = None
     direct = getattr(backend, "merge_runs_to_files", None)
+    on_device = getattr(backend, "runs_on_device", False)
     if direct is not None:
         kwargs = {}
         if getattr(backend, "supports_subcompactions", False):
@@ -107,8 +108,17 @@ def merge_job_to_files(job: CompactionJob, input_paths: List[str],
                 job.block_bytes, job.compression, job.bits_per_key,
                 job.target_file_bytes, **kwargs)
         except Exception:
-            log.exception("worker direct merge failed; using tuple path")
             outputs = None
+            if on_device:
+                record_host_fallback("worker_direct_sink_error",
+                                     job.job_id, exc_info=True)
+            else:
+                log.exception(
+                    "worker direct merge failed; using tuple path")
+        else:
+            if outputs is None and on_device:
+                record_host_fallback("worker_direct_sink_declined",
+                                     job.job_id)
     if outputs is None:
         stream = backend.merge_runs(
             [r.iterate() for r in readers], merge_op, job.drop_tombstones)

@@ -70,16 +70,12 @@ def deployment_sort_backend() -> str:
     flag (utils/flags.py: env ``RSTPU_FLAG_SORT_BACKEND``, runtime
     ``FLAGS.set``, visible in the /gflags.txt dump). One source of truth
     for every runtime consumer of merge_resolve_kernel that has no
-    per-call configuration. An unknown value logs loudly once and runs
-    the lax path rather than silently misconfiguring the fleet."""
+    per-call configuration. An unknown value RAISES: a misspelt flag
+    must not run the fleet on a backend nobody chose."""
     v = FLAGS.get("sort_backend")
     if v not in _SORT_BACKENDS:
-        import logging
-
-        logging.getLogger(__name__).warning(
-            "sort_backend flag %r is not one of %s — using lax",
-            v, _SORT_BACKENDS)
-        return "lax"
+        raise ValueError(
+            f"sort_backend flag {v!r} is not one of {_SORT_BACKENDS}")
     return v
 
 
@@ -168,13 +164,15 @@ def _sort_merge_order(
     num_keys = len(operands)
     operands.extend(payload)
     if sort_backend == "pallas":
-        from .pallas_sort import sort_lanes
+        from .pallas_sort import sort_lanes  # pallas imports stay lazy
 
         sorted_ops = sort_lanes(tuple(operands), num_keys=num_keys,
                                 backend="pallas")
-    else:
+    elif sort_backend == "lax":
         sorted_ops = lax.sort(tuple(operands), num_keys=num_keys,
                               is_stable=False)
+    else:
+        raise ValueError(f"unknown sort backend {sort_backend!r}")
     key_lanes, klen_s, shi_s, slo_s, valid_s, pos = split_composite_lanes(
         sorted_ops, key_words, uniform_klen=uniform_klen, seq32=seq32)
     return key_lanes, klen_s, shi_s, slo_s, valid_s, sorted_ops[pos:]
@@ -501,23 +499,16 @@ def merge_resolve_kernel(
     promises (see _sort_merge_order); results are identical either way.
     """
     if sort_backend == "pallas_fused":
-        from .pallas_resolve import fused_merge_resolve, fused_supported
+        from .pallas_resolve import fused_merge_resolve
 
-        n = seq_lo.shape[0]
-        if fused_supported(n):
-            return fused_merge_resolve(
-                key_words_be, key_len, seq_hi, seq_lo, vtype, val_words,
-                val_len, valid, merge_kind=merge_kind,
-                drop_tombstones=drop_tombstones,
-                uniform_klen=uniform_klen, seq32=seq32,
-                key_words=key_words,
-            )
-        import logging
-
-        logging.getLogger(__name__).warning(
-            "pallas_fused backend requested but capacity %d is "
-            "unsupported (needs a power of two >= 256) — falling back "
-            "to the lax path", n)
+        # raises for capacities the fused kernel does not take
+        return fused_merge_resolve(
+            key_words_be, key_len, seq_hi, seq_lo, vtype, val_words,
+            val_len, valid, merge_kind=merge_kind,
+            drop_tombstones=drop_tombstones,
+            uniform_klen=uniform_klen, seq32=seq32,
+            key_words=key_words,
+        )
 
     n_val_words = val_words.shape[1]
     # uniform_klen reconstruction constant: the one valid key length

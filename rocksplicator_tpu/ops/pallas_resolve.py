@@ -32,9 +32,13 @@ the chip compiles the same network). Reference semantics reproduced:
 compaction.py resolve_stream, same as the unfused kernel — see
 /root/reference/rocksdb_admin (SST compaction) and SURVEY §3.3.
 
-Opt-in via ``CompactionModel(sort_backend="pallas_fused")`` /
-``BENCH_PALLAS_SORT=2``; shapes the kernel can't take (non-power-of-two
-capacity, N < 256) fall back to the lax path with a warning.
+Opt-in via ``CompactionModel(sort_backend="pallas_fused")`` / the
+``sort_backend`` flag; shapes the kernel can't take (non-power-of-two
+capacity, N < 256) raise. Status on a v5e: the chip's compiler REFUSES
+this kernel today (``Reductions over unsigned integers not implemented``
+— the ``jnp.max`` over the u32 overflow mask — and behind it the shared
+bitonic network's lane-partner reshape; PERF.md "Chip status",
+tests/test_chip_compile.py), so it has only ever run interpreted.
 """
 
 from __future__ import annotations
@@ -55,9 +59,7 @@ from .pallas_sort import _LANES, _VMEM, bitonic_network
 
 def fused_supported(n: int) -> bool:
     """True when the fused kernel can take capacity ``n`` (the bitonic
-    network needs a power of two spanning at least two rows). The
-    dispatcher in merge_resolve_kernel consults this single source of
-    truth before routing to ``fused_merge_resolve``."""
+    network needs a power of two spanning at least two rows)."""
     return n >= 2 * _LANES and not (n & (n - 1))
 
 
@@ -240,8 +242,7 @@ def fused_merge_resolve(
     """Drop-in for ``merge_resolve_kernel`` (same contract, same output
     dict) running every phase in one VMEM residency. Requires capacity
     N to be a power of two >= 256 — callers dispatch via
-    ``merge_resolve_kernel(..., sort_backend="pallas_fused")``, which
-    falls back to the lax path for other shapes."""
+    ``merge_resolve_kernel(..., sort_backend="pallas_fused")``."""
     n = seq_lo.shape[0]
     if not fused_supported(n):
         raise ValueError(
@@ -272,8 +273,7 @@ def fused_merge_resolve(
     kernel = functools.partial(
         _fused_kernel, num_keys, r_rows, n_in, key_words, uniform_klen,
         seq32, merge_kind, drop_tombstones, n_val_words)
-    spec = (pl.BlockSpec(memory_space=_VMEM)
-            if (_VMEM is not None and not interpret) else pl.BlockSpec())
+    spec = pl.BlockSpec() if interpret else pl.BlockSpec(memory_space=_VMEM)
     out = pl.pallas_call(
         kernel,
         out_shape=(

@@ -32,9 +32,12 @@ payload-through contract as ``lax.sort(operands, num_keys=...)``, which
 this function is a drop-in replacement for (N must be a power of two;
 the compaction batches are always 2^k capacities).
 
-Opt-in (CompactionModel(sort_backend="pallas") / BENCH_PALLAS_SORT=1):
-the lax.sort path stays the default until the chip measurement says
-otherwise; ``interpret=True`` runs on CPU for the parity tests.
+Opt-in (CompactionModel(sort_backend="pallas") / the ``sort_backend``
+flag). Status on a v5e: the chip's compiler REFUSES this kernel today —
+the lane-partner stage's reshape splits the minor dim below 128
+(``infer-vector-layout: unsupported shape cast``; PERF.md "Chip
+status", tests/test_chip_compile.py) — so it has only ever run
+interpreted, on the CPU, for the parity tests.
 """
 
 from __future__ import annotations
@@ -45,15 +48,9 @@ from typing import Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu is unavailable on some CPU-only installs; interpret mode
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
-
+_VMEM = pltpu.VMEM
 _LANES = 128
 
 
@@ -185,8 +182,7 @@ def bitonic_sort_lanes(
     n_lanes = len(operands)
     lanes2d = [x.reshape(r_rows, _LANES) for x in operands]
     kernel = functools.partial(_sort_kernel, num_keys, r_rows, n_lanes)
-    spec = (pl.BlockSpec(memory_space=_VMEM)
-            if (_VMEM is not None and not interpret) else pl.BlockSpec())
+    spec = pl.BlockSpec() if interpret else pl.BlockSpec(memory_space=_VMEM)
     out = pl.pallas_call(
         kernel,
         out_shape=[jax.ShapeDtypeStruct((r_rows, _LANES), jnp.uint32)
@@ -202,23 +198,17 @@ def sort_lanes(operands: Sequence[jnp.ndarray], num_keys: int,
                backend: str = "lax",
                interpret: bool = None) -> Tuple[jnp.ndarray, ...]:
     """Sort dispatch: ``lax`` = XLA's sort (default), ``pallas`` = the
-    VMEM-resident bitonic kernel (falls back to lax for shapes the
-    kernel doesn't support). ``interpret=None`` auto-selects interpreter
-    mode on non-TPU backends so the same model code runs in the CPU test
-    suite and compiles natively on the chip."""
+    VMEM-resident bitonic kernel, which RAISES for shapes it does not
+    take (``bitonic_sort_lanes``: power-of-two N >= 256, u32 lanes) — a
+    caller that asked for the kernel never gets ``lax.sort`` under its
+    name. ``interpret=None`` selects interpreter mode off-chip only (the
+    CPU test suite); on a TPU backend it is always the native kernel."""
     ops = tuple(operands)
     if backend == "pallas":
-        n = ops[0].shape[0]
-        if (n >= 2 * _LANES and not (n & (n - 1))
-                and all(x.dtype == jnp.uint32 for x in ops)):
-            if interpret is None:
-                interpret = jax.default_backend() != "tpu"
-            return bitonic_sort_lanes(ops, num_keys=num_keys,
-                                      interpret=interpret)
-        import logging
-
-        logging.getLogger(__name__).warning(
-            "pallas sort backend requested but unsupported for this "
-            "shape/dtype (n=%d) — falling back to lax.sort; the measured "
-            "numbers are NOT the pallas kernel", n)
+        if interpret is None:
+            interpret = jax.default_backend() != "tpu"
+        return bitonic_sort_lanes(ops, num_keys=num_keys,
+                                  interpret=interpret)
+    if backend != "lax":
+        raise ValueError(f"unknown sort backend {backend!r}")
     return jax.lax.sort(ops, num_keys=num_keys, is_stable=False)

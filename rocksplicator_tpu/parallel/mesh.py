@@ -90,14 +90,7 @@ def sharded_compaction_step(mesh, model=None):
     """
     import jax
     import jax.numpy as jnp
-    try:
-        from jax import shard_map
-
-        replication_check = {"check_vma": False}
-    except ImportError:  # pre-0.5 jax: experimental namespace + old kwarg
-        from jax.experimental.shard_map import shard_map
-
-        replication_check = {"check_rep": False}
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..models.compaction_model import CompactionModel
@@ -220,7 +213,7 @@ def sharded_compaction_step(mesh, model=None):
             P(None, None),
             P(None, None),
         ),
-        **replication_check,
+        check_vma=False,
     )
     return jax.jit(step)
 

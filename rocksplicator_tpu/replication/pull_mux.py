@@ -20,7 +20,7 @@ server side serves each section through the SAME
 ``ReplicatedDB.handle_replicate_request`` (with ``max_wait_ms=0``), so
 fencing epochs, mode-1/2 acks, WAL_GAP typing, commit-point attestation
 and the adaptive max_updates clamp are per-section; the client side runs
-the SAME error taxonomy as ``_pull_loop`` per section, so an epoch bump
+the SAME error classes as ``_pull_loop`` per section, so an epoch bump
 fences ONE shard, a WAL_GAP stalls ONE shard, and each shard backs off
 on its own jittered RetryPolicy while the rest of the session keeps
 streaming.
@@ -285,7 +285,7 @@ class PullMuxSession:
     loop mirrors ``ReplicatedDB._pull_loop`` lifted to a member SET:
     whole-call failures are peer-level (one session backoff, per-member
     error accounting), per-SECTION failures run the exact per-shard
-    taxonomy and back off only that shard."""
+    classification and back off only that shard."""
 
     def __init__(self, mgr: PullMuxManager, addr: Tuple[str, int]):
         self.mgr = mgr

@@ -1,4 +1,4 @@
-"""RPC error taxonomy."""
+"""RPC error classes."""
 
 from __future__ import annotations
 

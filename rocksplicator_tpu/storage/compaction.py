@@ -18,15 +18,45 @@ order, with per-key resolution:
 from __future__ import annotations
 
 import heapq
+import logging
 from typing import Iterable, Iterator, List, Optional, Tuple
 
+from ..utils.stats import Stats, tagged
 from .merge import MergeOperator, resolve_entry_group
 
+log = logging.getLogger(__name__)
+
 Entry = Tuple[bytes, int, int, bytes]  # key, seq, vtype, value
+
+HOST_FALLBACKS = "tpu.host_fallbacks"
+
+
+def record_host_fallback(reason: str, detail: str = "",
+                         exc_info: bool = False) -> None:
+    """Work that was routed to the fast path (the device, the native
+    library) ran on slower host code instead. The node keeps going
+    (that is the guarantee), but never quietly: every such seam counts
+    under ONE family, ``tpu.host_fallbacks reason=<seam>``, and logs at
+    ERROR, so a chip run can require every reason to be zero."""
+    Stats.get().incr(tagged(HOST_FALLBACKS, reason=reason))
+    log.error("%s reason=%s: %s", HOST_FALLBACKS, reason, detail,
+              exc_info=exc_info)
+
+
+def host_fallback_counts() -> dict:
+    """{reason: count} of every ``tpu.host_fallbacks`` counter so far."""
+    prefix = HOST_FALLBACKS + " reason="
+    counters = Stats.get().export_state()["counters"]
+    return {name[len(prefix):]: int(c["total"])
+            for name, c in counters.items() if name.startswith(prefix)}
 
 
 class CompactionBackend:
     name = "base"
+    # True on backends whose merges are meant to run on an accelerator:
+    # the engine counts and logs every job such a backend hands back to
+    # host code (record_host_fallback).
+    runs_on_device = False
     # True on backends whose ``merge_runs_to_files`` accepts the
     # ``max_subcompactions``/``io_budget`` keywords (key-range
     # subcompactions + foreground-yielding IO budget); the engine only

@@ -34,7 +34,8 @@ from ..testing import failpoints as fp
 from ..utils.misc import write_file_atomic
 from ..utils.stats import Stats
 from . import wal as wal_mod
-from .compaction import CompactionBackend, CpuCompactionBackend, resolve_stream
+from .compaction import (CompactionBackend, CpuCompactionBackend,
+                         record_host_fallback, resolve_stream)
 from .errors import Corruption, InvalidArgument, StorageError
 from .memtable import MemTable
 from .merge import MERGE_OPERATORS, MergeOperator
@@ -1699,6 +1700,7 @@ class DB:
                 kwargs["mem_tracker"] = tracker
                 kwargs["memory_budget_bytes"] = (
                     self.options.compaction_memory_budget_bytes)
+            on_device = getattr(self._backend, "runs_on_device", False)
             try:
                 outputs = direct(
                     runs, self.options.merge_operator, drop_tombstones,
@@ -1707,8 +1709,16 @@ class DB:
                     self.options.target_file_bytes, **kwargs,
                 )
             except Exception:
-                log.exception("direct merge sink failed; using tuple path")
                 outputs = None
+                if on_device:
+                    record_host_fallback("direct_sink_error", self.path,
+                                         exc_info=True)
+                else:
+                    log.exception(
+                        "direct merge sink failed; using tuple path")
+            else:
+                if outputs is None and on_device:
+                    record_host_fallback("direct_sink_declined", self.path)
             finally:
                 if tracker is not None:
                     tracker.close()

@@ -1,4 +1,4 @@
-"""Storage engine error taxonomy."""
+"""Storage engine error classes."""
 
 
 class StorageError(Exception):

@@ -51,7 +51,9 @@ def _build() -> bool:
         )
         return os.path.isfile(_SO)
     except Exception as e:
-        log.info("native build unavailable: %s", e)
+        detail = getattr(e, "stderr", b"") or b""
+        log.error("native build failed: %s %s", e,
+                  detail.decode("utf-8", "replace")[-400:])
         return False
 
 
@@ -454,17 +456,23 @@ def _load() -> Optional[NativeLib]:
     # or a binary of unknown provenance. Rebuild from tsst_native.cc; on
     # build failure fall back to the pure-Python paths, loudly.
     if not _so_current() and not _build():
-        if os.path.isfile(_SO):
-            log.warning(
-                "refusing stale/unverified %s (build failed); "
-                "using pure-Python fallback paths", _SO,
-            )
+        _no_native("build failed" + (
+            f"; refusing stale/unverified {_SO}"
+            if os.path.isfile(_SO) else ""))
         return None
     try:
         return NativeLib(ctypes.CDLL(_SO))
     except (OSError, AttributeError) as e:
-        log.warning("native lib load failed: %s", e)
+        _no_native(f"load failed: {e}")
         return None
+
+
+def _no_native(why: str) -> None:
+    """The pure-Python codecs take over — counted and logged at ERROR
+    with the other host fallbacks, never quietly."""
+    from ..compaction import record_host_fallback
+
+    record_host_fallback("native_lib", why)
 
 
 _UNSET = object()
@@ -482,6 +490,26 @@ def get_native() -> Optional[NativeLib]:
             if _native is _UNSET:
                 _native = _load()
     return _native  # type: ignore[return-value]
+
+
+def rebuild_native() -> NativeLib:
+    """Rebuild the library from ``tsst_native.cc`` and load THAT build,
+    or raise. For runs that must show the library came from the
+    committed source: ``*.so`` is git-ignored and ``_so_current`` trusts
+    mtimes, which a copied tree may not preserve. Must run before the
+    library's first use in the process (a loaded image cannot be
+    swapped)."""
+    global _native
+    with _native_lock:
+        if _native is not _UNSET:
+            raise RuntimeError("native library already resolved in "
+                               "this process; rebuild first")
+        if os.path.isfile(_SO):
+            os.remove(_SO)
+        if not _build():
+            raise RuntimeError("native build failed (see the log)")
+        _native = NativeLib(ctypes.CDLL(_SO))
+        return _native
 
 
 def native_available() -> bool:

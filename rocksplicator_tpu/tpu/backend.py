@@ -25,6 +25,7 @@ from ..ops.compaction_kernel import (MergeKind, deployment_sort_backend,
                                      merge_resolve_kernel)
 from ..ops.kv_format import (KVBatch, UnsupportedBatch, fast_flags,
                              pack_entries, unpack_entries)
+from ..utils.stats import Stats, tagged
 
 log = logging.getLogger(__name__)
 
@@ -43,6 +44,23 @@ def _next_pow2(n: int) -> int:
     return p
 
 
+def require_accelerator() -> str:
+    """The platform jax resolved — which must be ``tpu``. A host with no
+    chip would otherwise run "the TPU backend" as XLA-CPU under the
+    TPU's name. The one exception is an EXPLICIT ``JAX_PLATFORMS=cpu``
+    (the test suite's setting): whoever set that asked for the CPU."""
+    import jax
+
+    platform = jax.default_backend()
+    if platform != "tpu" and (
+            os.environ.get("JAX_PLATFORMS", "").strip().lower() != "cpu"):
+        raise RuntimeError(
+            f"TPU compaction was asked for but jax resolved platform "
+            f"{platform!r}; refusing to run the device path on it (set "
+            f"JAX_PLATFORMS=cpu to run it on the CPU on purpose)")
+    return platform
+
+
 def _arrays_from_entries(entries: List[Entry]) -> Optional[dict]:
     """Entry tuples → valid-prefix lane arrays (tuple-source fallback)."""
     if not entries:
@@ -54,19 +72,22 @@ def _arrays_from_entries(entries: List[Entry]) -> Optional[dict]:
 
 class TpuCompactionBackend(CompactionBackend):
     name = "tpu"
+    runs_on_device = True
     supports_subcompactions = True
     supports_memory_budget = True
 
     def __init__(self, fallback: Optional[CompactionBackend] = None):
-        # default fallback is the VECTORIZED cpu path: on hosts where the
-        # accelerator is absent/wedged, the framework's compaction
-        # throughput is the lexsort+reduceat numpy pipeline (itself
-        # falling back to the streaming heap-merge for batches the lane
-        # representation can't express)
+        # inapplicable batches (custom operators, >24B keys, operand
+        # chains without an operator) take the VECTORIZED cpu path: the
+        # lexsort+reduceat numpy pipeline, itself falling back to the
+        # streaming heap-merge for what lanes can't express
         self._fallback = fallback or NumpyCompactionBackend()
-        import jax  # deferred so CPU-only deployments never touch jax
+        import jax
 
         self._jax = jax
+        self.platform = require_accelerator()
+        Stats.get().incr(tagged("tpu.backend_platform",
+                                platform=self.platform))
 
     def merge_runs(
         self,

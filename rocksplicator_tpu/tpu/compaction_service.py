@@ -31,7 +31,8 @@ from ..ops.bloom_tpu import bloom_build_tpu
 from ..ops.compaction_kernel import (MergeKind, deployment_sort_backend,
                                      merge_resolve_kernel)
 from ..ops.kv_format import KEY_WORDS, KVBatch, fast_flags, unpack_entries
-from .backend import TpuCompactionBackend, _next_pow2
+from ..storage.compaction import record_host_fallback
+from .backend import TpuCompactionBackend, _next_pow2, require_accelerator
 
 log = logging.getLogger(__name__)
 
@@ -46,6 +47,7 @@ class TpuCompactionService:
 
         self._jax = jax
         self._jnp = jnp
+        require_accelerator()
         self._bits_per_key = bits_per_key
         # deployment knob: run the service's kernels on the lax sort, the
         # VMEM-resident pallas sort, or the fully-fused pallas kernel —
@@ -195,16 +197,17 @@ class TpuCompactionService:
         staging cost ~3.7x the kernel (SURVEY §7 front-load item 2)."""
         if not batches:
             return []
+        capacity = _next_pow2(max(b.capacity for b in batches))
         with start_span("tpu.compact_stream", always=True,
-                        shards=len(batches), group_size=group_size):
+                        shards=len(batches), group_size=group_size,
+                        capacity=capacity):
             return self._compact_shard_stream(
                 batches, merge_kind, drop_tombstones, group_size,
-                return_arrays)
+                capacity, return_arrays)
 
     def _compact_shard_stream(self, batches, merge_kind, drop_tombstones,
-                              group_size, return_arrays=False):
+                              group_size, capacity, return_arrays=False):
         jax = self._jax
-        capacity = _next_pow2(max(b.capacity for b in batches))
         num_words = num_words_for(capacity, self._bits_per_key)
         flags = [fast_flags(b.key_len, b.seq_hi, b.valid) for b in batches]
         uniform_klen = all(u for u, _, _ in flags)
@@ -279,6 +282,8 @@ class TpuCompactionService:
         from ..storage.native.binding import get_native
         from .backend import cpu_merge_resolve
 
+        record_host_fallback(
+            "kernel_overflow", f"{batch.capacity}-entry shard")
         arrays, count = cpu_merge_resolve(
             batch, uint64_add=merge_kind is MergeKind.UINT64_ADD,
             drop_tombstones=drop_tombstones,
@@ -650,21 +655,20 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
                     b.val_words = np.pad(
                         b.val_words, [(0, 0), (0, vw - w)])
             try:
-                if len(batches) > group_size:
-                    # one compiled (group_size, capacity) shape serves
-                    # every group; H2D of group i+1 overlaps group i's
-                    # kernel
-                    results = svc.compact_shard_stream(
-                        batches, merge_kind=kind, drop_tombstones=drop,
-                        group_size=group_size, return_arrays=True)
-                else:
-                    results = svc.compact_shard_batch(
-                        batches, merge_kind=kind, drop_tombstones=drop,
-                        return_arrays=True)
-            except BaseException:
-                log.exception(
-                    "batched compaction launch failed (%d shards); "
-                    "falling back per-db", len(items))
+                # ALWAYS the fixed (group_size, capacity) launch shape,
+                # short groups padded with empty shards: how many shards
+                # coalesce into one dispatch is timing (BatchCompactor
+                # group commit), and a program per distinct count costs
+                # a compile of minutes on the chip (PERF.md "Chip
+                # status"). H2D of group i+1 overlaps group i's kernel.
+                results = svc.compact_shard_stream(
+                    batches, merge_kind=kind, drop_tombstones=drop,
+                    group_size=group_size, return_arrays=True)
+            except Exception:
+                record_host_fallback(
+                    "batched_launch",
+                    f"{len(items)} shards; re-compacting per-db",
+                    exc_info=True)
                 for name, db, plan, _b in items:
                     _abort(db, plan)
                     remaining.append((name, db))

@@ -1,0 +1,199 @@
+"""The main path's device programs, compiled for a DESCRIBED TPU v5e.
+
+No chip is attached here: the TPU compiler that ships with jax compiles
+for a topology that is described (`v5e:2x2`, one device of it). Nothing
+executes — a compile that passes is not a chip run — but what the chip's
+compiler refuses (a Mosaic layout it cannot infer, an op it has no
+lowering for, a program that does not fit) is refused here too, at no
+chip time. Interpret mode on the CPU backend shows none of that.
+
+Shapes are the counter deployment's (chip_smoke.py): 16-byte keys →
+``fast_flags`` = (uniform_klen, seq32, key_words=4), two value words,
+uint64-add. N is the largest power of two that keeps each compile
+≲ 30 s in this sandbox; the smoke's real shapes take minutes and are
+recorded in PERF.md "Chip status" from a scratch script, not from here.
+
+Everything that touches the topology lives in the module-scoped fixtures
+below — never at import, never in conftest.py, never autouse: only one
+process at a time may load the TPU library, and every xdist worker
+imports this file. Keep these tests in THIS file (one worker owns it)
+and compile in the test's own process.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from rocksplicator_tpu.ops.compaction_kernel import (MergeKind,
+                                                     merge_resolve_kernel)
+from rocksplicator_tpu.storage.bloom import num_words_for
+
+U32 = jnp.uint32
+# what fast_flags() gives for the counter data: one key length (16 B =
+# 4 BE words), every seq below 2^32
+FAST = dict(uniform_klen=True, seq32=True, key_words=4)
+BITS_PER_KEY = 10  # examples/counter_service/options.py
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """SingleDeviceSharding on device 0 of a described v5e:2x2, with the
+    persistent compile cache off for the module (a described-topology
+    executable is written to it but cannot be read back without a chip:
+    the next run would warn and recompile)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    assert topo.devices[0].platform == "tpu"
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def shape(one_chip):
+    def make(dims, dtype=U32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    return make
+
+
+def _kernel_lanes(shape, n, lead=()):
+    """merge_resolve_kernel's eight inputs at capacity ``n``."""
+    return (shape(lead + (n, 6)), shape(lead + (n,)), shape(lead + (n,)),
+            shape(lead + (n,)), shape(lead + (n,)), shape(lead + (n, 2)),
+            shape(lead + (n,)), shape(lead + (n,), jnp.bool_))
+
+
+def test_merge_resolve_lax_compiles(shape):
+    """The per-DB program (TpuCompactionBackend.merge_runs_to_files →
+    chunked.run_kernel_arrays): one shard, lax sort, uint64-add."""
+    compiled = merge_resolve_kernel.lower(
+        *_kernel_lanes(shape, 8192), merge_kind=MergeKind.UINT64_ADD,
+        drop_tombstones=True, sort_backend="lax", **FAST).compile()
+    assert compiled.memory_analysis() is not None
+
+
+def test_service_pipeline_group8_compiles(shape, monkeypatch):
+    """The batched post-load program (compact_dbs_batched): the service's
+    own vmapped merge-resolve + bloom pipeline at its group size 8."""
+    from rocksplicator_tpu.tpu.compaction_service import TpuCompactionService
+
+    # the platform rule reads the env; conftest set it, keep it explicit
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    n = 2048
+    fn = TpuCompactionService()._pipeline(
+        MergeKind.UINT64_ADD, True, num_words_for(n, BITS_PER_KEY), **FAST)
+    compiled = fn.lower(*_kernel_lanes(shape, n, lead=(8,))).compile()
+    assert compiled.memory_analysis() is not None
+
+
+def test_bloom_build_compiles(shape):
+    """The per-output-file bloom (one 16,384-key shard = one file)."""
+    from rocksplicator_tpu.ops.bloom_tpu import bloom_build_tpu
+
+    n = 16384
+    compiled = bloom_build_tpu.lower(
+        shape((n, 6)), shape((n,)), shape((n,), jnp.bool_),
+        num_words=num_words_for(n, BITS_PER_KEY)).compile()
+    assert compiled.memory_analysis() is not None
+
+
+def test_bloom_hash_pallas_compiles(shape):
+    """The one Pallas kernel the chip's compiler takes today."""
+    from rocksplicator_tpu.ops.pallas_kernels import bloom_hash_pallas
+
+    n = 131072
+    compiled = bloom_hash_pallas.lower(
+        shape((n, 6)), shape((n,)), interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_planar_encode_and_checksums_compile(shape):
+    from rocksplicator_tpu.ops.block_encode import (encode_planar_words_tpu,
+                                                    planar_checksums_tpu)
+
+    n, block_entries = 16384, 1024
+    enc = encode_planar_words_tpu.lower(
+        shape((n, 6)), shape((n,)), shape((n,)), shape((n,)),
+        shape((n, 2)), klen=16, vlen=8, seq32=True,
+        block_entries=block_entries).compile()
+    (words,) = jax.tree_util.tree_leaves(enc.out_info)
+    assert words.shape[0] == n // block_entries
+    chk = planar_checksums_tpu.lower(shape(words.shape)).compile()
+    assert chk.memory_analysis() is not None
+
+
+def test_graft_entry_forward_compiles(shape):
+    """The driver-facing single-chip step: CompactionModel.forward with
+    the planar sink stages, at entry()'s own shapes."""
+    import __graft_entry__ as graft
+
+    forward, example_args = graft.entry()
+    compiled = jax.jit(forward).lower(
+        *(shape(a.shape, a.dtype) for a in example_args)).compile()
+    assert compiled.memory_analysis() is not None
+
+
+# --- the two Pallas sort kernels: REFUSED by the v5e compiler today ------
+# strict xfails carrying the compiler's words, so the PR that repairs the
+# kernels (ROADMAP Queue 1 item 6) flips these tests. Interpret mode on
+# the CPU passes both, which is why nobody knew.
+
+
+class CompilerRefused(Exception):
+    """The chip's compiler refused the program with the recorded words."""
+
+
+def _lower_and_compile(lower, words: str):
+    """Only the RECORDED refusal is the expected failure: any other
+    exception (an import error, a new refusal) fails the test outright."""
+    try:
+        lower().compile()
+    except Exception as e:
+        if words in str(e):
+            raise CompilerRefused(str(e)[:400]) from e
+        raise
+
+
+@pytest.mark.xfail(
+    strict=True, raises=CompilerRefused,
+    reason='MosaicError: infer-vector-layout: unsupported shape cast — '
+           '"tpu.reshape"(vector<16x128xi32>) -> vector<16x64x2x1xi32>: '
+           "the lane-partner stage of ops/pallas_sort.py _stage splits "
+           "the minor dim below 128")
+def test_bitonic_sort_lanes_compiles(shape):
+    from rocksplicator_tpu.ops.pallas_sort import bitonic_sort_lanes
+
+    _lower_and_compile(
+        lambda: bitonic_sort_lanes.lower(
+            tuple(shape((2048,)) for _ in range(4)), num_keys=2,
+            interpret=False),
+        "unsupported shape cast")
+
+
+@pytest.mark.xfail(
+    strict=True, raises=CompilerRefused,
+    reason="NotImplementedError: Reductions over unsigned integers not "
+           "implemented — jnp.max over the u32 overflow mask in "
+           "ops/pallas_resolve.py _fused_kernel; the shared "
+           "bitonic_network's reshape refusal is behind it")
+def test_fused_merge_resolve_compiles(shape):
+    from rocksplicator_tpu.ops.pallas_resolve import fused_merge_resolve
+
+    _lower_and_compile(
+        lambda: fused_merge_resolve.lower(
+            *_kernel_lanes(shape, 2048), merge_kind=MergeKind.UINT64_ADD,
+            drop_tombstones=True, interpret=False, **FAST),
+        "Reductions over unsigned integers")

@@ -517,21 +517,30 @@ def test_pallas_bitonic_sort_parity_with_lax():
             _np.testing.assert_array_equal(_np.asarray(w), _np.asarray(g))
 
 
-def test_pallas_sort_dispatch_fallback():
-    """Non-power-of-two N falls back to lax.sort; power-of-two N takes
-    the pallas kernel — both must match lax exactly."""
+def test_pallas_sort_dispatch_is_loud():
+    """backend="pallas" means the kernel: a shape it does not take
+    RAISES (it used to warn and run lax.sort under the kernel's name);
+    a power-of-two N takes the pallas kernel and must match lax exactly.
+    An unknown backend name raises too."""
     import numpy as _np
 
     from rocksplicator_tpu.ops.pallas_sort import sort_lanes
 
     rng = _np.random.default_rng(3)
-    for n in (1000, 256):  # 1000: lax fallback; 256: pallas path
-        ops = (jnp.asarray(rng.integers(0, 99, n, dtype=_np.uint32)),
-               jnp.asarray(rng.integers(0, 99, n, dtype=_np.uint32)))
-        got = sort_lanes(ops, num_keys=1, backend="pallas", interpret=True)
-        want = jax.lax.sort(ops, num_keys=1, is_stable=False)
-        _np.testing.assert_array_equal(_np.asarray(want[0]),
-                                       _np.asarray(got[0]))
+
+    def ops(n):
+        return (jnp.asarray(rng.integers(0, 99, n, dtype=_np.uint32)),
+                jnp.asarray(rng.integers(0, 99, n, dtype=_np.uint32)))
+
+    with pytest.raises(ValueError, match="power-of-two"):
+        sort_lanes(ops(1000), num_keys=1, backend="pallas", interpret=True)
+    with pytest.raises(ValueError, match="unknown sort backend"):
+        sort_lanes(ops(256), num_keys=1, backend="palas")
+    o = ops(256)
+    got = sort_lanes(o, num_keys=1, backend="pallas", interpret=True)
+    want = jax.lax.sort(o, num_keys=1, is_stable=False)
+    _np.testing.assert_array_equal(_np.asarray(want[0]),
+                                   _np.asarray(got[0]))
 
 
 def test_merge_resolve_kernel_pallas_sort_backend_parity():
@@ -627,9 +636,10 @@ def test_fused_merge_resolve_parity_general_lanes():
                                   drop_tombstones=drop)
 
 
-def test_fused_merge_resolve_fallback_non_pow2():
-    """Capacities the fused kernel can't take (non-power-of-two) must
-    fall back to the lax path and still produce identical results."""
+def test_fused_merge_resolve_non_pow2_raises():
+    """Capacities the fused kernel can't take (non-power-of-two) RAISE:
+    sort_backend="pallas_fused" never runs the lax path under the fused
+    kernel's name (it used to, with a warning)."""
     entries = [
         (b"a", 1, OpType.PUT, pack64(10)),
         (b"a", 2, OpType.MERGE, pack64(5)),
@@ -639,7 +649,10 @@ def test_fused_merge_resolve_fallback_non_pow2():
     args = tuple(jnp.asarray(x) for x in (
         batch.key_words_be, batch.key_len, batch.seq_hi, batch.seq_lo,
         batch.vtype, batch.val_words, batch.val_len, batch.valid))
-    _assert_fused_matches_lax(args)
+    with pytest.raises(ValueError, match="power-of-two"):
+        merge_resolve_kernel(*args, sort_backend="pallas_fused")
+    with pytest.raises(ValueError, match="unknown sort backend"):
+        merge_resolve_kernel(*args, sort_backend="bogus")
 
 
 def test_vmem_scan_ladder_primitives_match_1d():

@@ -27,7 +27,8 @@ def main(argv=None) -> int:
     from rocksplicator_tpu.cluster.coordinator import CoordinatorClient
     from rocksplicator_tpu.compaction_remote.dispatch import \
         coord_endpoint_from_env
-    from rocksplicator_tpu.compaction_remote.worker import CompactionWorker
+    from rocksplicator_tpu.compaction_remote.worker import (
+        CompactionWorker, _build_backend)
 
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--coord", default=None,
@@ -58,11 +59,14 @@ def main(argv=None) -> int:
 
     workdir = args.workdir or tempfile.mkdtemp(prefix="rstpu-compact-")
     coord = CoordinatorClient(endpoint[0], endpoint[1])
-    backend = None
-    if args.backend:
-        from rocksplicator_tpu.compaction_remote.worker import _build_backend
+    # resolved here, not per job: a worker asked for the device on a host
+    # without one must fail to start, not fail every job it claims
+    backend = _build_backend(args.backend)
+    if backend.runs_on_device:
+        from rocksplicator_tpu.tpu.compile_cache import \
+            configure_compile_cache
 
-        backend = _build_backend(args.backend)
+        logging.info("compile cache: %s", configure_compile_cache())
     worker = CompactionWorker(
         coord, workdir, worker_id=args.worker_id, backend=backend,
         poll_interval=args.poll_interval)
