@@ -145,7 +145,9 @@ def run_load(handler_kwargs, store_uri, shards, keys_per_shard,
                 log(f"{label}: SPOT-CHECK FAILURE shard {shard}")
         collector = SpanCollector.get()
         phases = collector.phase_totals("admin.")
-        slowest = collector.slowest_trace("admin.add_s3_sst")
+        # every served RPC records its root: the ingest's trace starts there
+        slowest = collector.slowest_trace(
+            "rpc.server.add_s3_sst_files_to_db")
         trace_lines = None
         if slowest is not None:
             trace_lines = render_trace(

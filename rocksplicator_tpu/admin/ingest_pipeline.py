@@ -27,6 +27,7 @@ import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Dict, List, Optional, Tuple
 
+from ..observability.span import start_span
 from ..testing import failpoints as fp
 
 log = logging.getLogger(__name__)
@@ -101,6 +102,17 @@ class IngestGate:
             self._free.notify()
 
 
+class _Taken:
+    """A queued shard's signal that a dispatch has taken it (set by the
+    leader, with the batch's size)."""
+
+    __slots__ = ("event", "batch")
+
+    def __init__(self):
+        self.event = threading.Event()
+        self.batch = 0
+
+
 class BatchCompactor:
     """Group-commit for post-load compactions.
 
@@ -121,7 +133,7 @@ class BatchCompactor:
         self._use_tpu = use_tpu
         self._max_batch = max_batch
         self._lock = threading.Lock()
-        self._queue: List[Tuple[str, object, Future]] = []
+        self._queue: List[Tuple[str, object, Future, "_Taken"]] = []
         self._dispatching = False
         # compaction releases the GIL in its numpy/zlib/fsync phases, so
         # more workers than cores still overlaps usefully
@@ -138,43 +150,64 @@ class BatchCompactor:
         """Compact ``db`` (a storage.engine.DB), batched with concurrent
         callers. Returns the size of the batch this shard rode in."""
         fut: Future = Future()
-        with self._lock:
-            self._queue.append((db_name, db, fut))
-            leader = not self._dispatching
+        taken = _Taken()
+        leader, batch = False, None
+        try:
+            # enqueue → the start of the dispatch that takes this shard
+            with start_span("admin.compact.wait") as wsp:
+                with self._lock:
+                    self._queue.append((db_name, db, fut, taken))
+                    leader = not self._dispatching
+                    if leader:
+                        self._dispatching = True
+                if leader:
+                    # the queue was empty: this shard heads the batch
+                    batch = self._take_batch()
+                else:
+                    taken.event.wait()
+                wsp.annotate(batch=taken.batch)
+            if not leader:
+                # a rider: the leader's dispatch, whose trace holds the
+                # phases, compacts this shard too
+                with start_span("admin.compact.ride"):
+                    return fut.result()
+            while batch:
+                try:
+                    self._dispatch(batch)
+                except BaseException as e:
+                    # a dispatch blow-up (e.g. pool shutdown mid-close)
+                    # must fail ITS batch loudly and keep draining —
+                    # never strand waiters or the leadership flag
+                    log.exception("compact dispatch failed")
+                    for _n, _d, f in batch:
+                        if not f.done():
+                            f.set_exception(e)
+                batch = self._take_batch()
+        except BaseException:
+            # pathological (queue handling itself raised): hand
+            # leadership back so the compactor is not wedged forever
             if leader:
-                self._dispatching = True
-        if leader:
-            try:
-                while True:
-                    with self._lock:
-                        batch = self._queue[: self._max_batch]
-                        del self._queue[: self._max_batch]
-                        if not batch:
-                            self._dispatching = False
-                            break
-                    try:
-                        self._dispatch(batch)
-                    except BaseException as e:
-                        # a dispatch blow-up (e.g. pool shutdown mid-close)
-                        # must fail ITS batch loudly and keep draining —
-                        # never strand waiters or the leadership flag
-                        log.exception("compact dispatch failed")
-                        for _n, _d, f in batch:
-                            if not f.done():
-                                f.set_exception(e)
-            except BaseException:
-                # pathological (queue handling itself raised): hand
-                # leadership back so the compactor is not wedged forever
                 with self._lock:
                     self._dispatching = False
-                raise
+            raise
         return fut.result()
+
+    def _take_batch(self) -> List[Tuple[str, object, Future]]:
+        """The leader's next batch off the queue, its callers told that
+        their wait is over; empty hands leadership back."""
+        with self._lock:
+            entries = self._queue[: self._max_batch]
+            del self._queue[: self._max_batch]
+            if not entries:
+                self._dispatching = False
+        for _n, _d, _f, taken in entries:
+            taken.batch = len(entries)
+            taken.event.set()
+        return [(n, d, f) for n, d, f, _t in entries]
 
     # -- dispatch ---------------------------------------------------------
 
     def _dispatch(self, batch: List[Tuple[str, object, Future]]) -> None:
-        from ..observability.span import start_span
-
         with start_span("admin.compact_dispatch", always=True,
                         shards=len(batch), tpu=self._use_tpu):
             self._dispatch_spanned(batch)

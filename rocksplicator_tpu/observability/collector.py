@@ -15,8 +15,11 @@ is never mistaken for complete coverage).
 Head sampling: the sampling decision is made once at the trace ROOT
 (``sample()``, default ~1/1024) and inherited by every descendant,
 including across process hops (the wire context carries ``sampled``).
-``sample_rate=0`` disables tracing; the instrumented hot paths then cost
-one contextvar read + one roll per would-be root.
+``sample_rate=0`` turns head sampling off; the instrumented hot paths
+then cost one contextvar read + one roll per would-be root. What records
+at any rate: ``always=True`` operations, roots slower than ``tail_ms``,
+and the one root-only record of every served RPC (``boundary=True``,
+span.py). The kill switch (``enabled``, below) silences all of it.
 
 Read path (cold): ``traces()`` groups the ring by trace id,
 ``to_json_text()`` feeds the status server's ``/traces`` endpoint and
@@ -30,7 +33,11 @@ import json
 import os
 import random
 import threading
+import time
+from itertools import chain
 from typing import Any, Dict, List, Optional
+
+from .context import new_id
 
 DEFAULT_CAPACITY = 4096
 DEFAULT_SAMPLE_RATE = 1.0 / 1024.0
@@ -49,7 +56,10 @@ class SpanCollector:
     def __init__(self, capacity: int = DEFAULT_CAPACITY,
                  sample_rate: float = DEFAULT_SAMPLE_RATE):
         self._capacity = max(1, int(capacity))
-        self._ring: List[Optional[dict]] = [None] * self._capacity
+        # a finished span's record (dict), or a kept unsampled root's
+        # (flat tuple, record_root)
+        self._ring: List[Any] = [None] * self._capacity
+        self._id_salt = random.getrandbits(64)
         self._seq = itertools.count()
         self._recorded = 0  # highest seq observed + 1 (approximate is fine)
         env_rate = os.environ.get("RSTPU_TRACE_SAMPLE_RATE")
@@ -75,7 +85,7 @@ class SpanCollector:
         # separate small ring for tail-kept roots so head-sampled
         # traffic can never evict the rare slow outlier — the whole
         # point of keeping it
-        self._tail_ring: List[Optional[dict]] = [None] * DEFAULT_TAIL_CAPACITY
+        self._tail_ring: List[Any] = [None] * DEFAULT_TAIL_CAPACITY
         self._tail_seq = itertools.count()
         self._tail_recorded = 0
         # global kill switch: RSTPU_TRACING=0 disables EVERYTHING,
@@ -130,39 +140,47 @@ class SpanCollector:
 
     def record(self, span) -> None:
         """Called once per finished SAMPLED span (span.py __exit__)."""
-        d = span.to_dict(self.process)
-        i = next(self._seq)
+        self._put(next(self._seq), span.to_dict(self.process))
+
+    def _put(self, i: int, record) -> None:
         ring = self._ring
-        ring[i % len(ring)] = d
+        ring[i % len(ring)] = record
         self._recorded = i + 1
 
-    def record_tail(self, root, duration_ms: float,
+    def record_root(self, root, duration_ms: float, tail: bool,
                     error: Optional[str] = None) -> None:
-        """Keep a head-unsampled root that crossed the tail threshold
-        (span.py ``_TailRoot`` exit). Ids are minted HERE — only kept
-        tails pay for id generation. The span dict carries a
-        ``tail_kept`` annotation so /traces readers can tell a deferred
-        keep (root-only by construction) from a head-sampled trace."""
-        import time
+        """Keep a finished head-unsampled root (span.py ``_TailRoot``
+        exit): in the ring for a ``boundary`` root (every served RPC),
+        in the tail ring (``tail``) for one that crossed the tail
+        threshold, flagged ``tail_kept`` so /traces readers can tell a
+        deferred keep from a head-sampled trace. A slow boundary root is
+        ONE record in both (``snapshot`` shows it once), under the ids
+        its children carry.
 
-        from .context import new_id
-
-        d = {
-            "trace_id": new_id(),
-            "span_id": new_id(),
-            "parent_id": None,
-            "name": root.name,
-            "process": self.process,
-            # wall-clock start reconstructed at keep time — the fast
-            # (discarded) path never pays the time.time() syscall
-            "start_ms": round(time.time() * 1000.0 - duration_ms, 3),
-            "duration_ms": round(duration_ms, 3),
-            "annotations": {**root.annotations, "tail_kept": True},
-            "error": error,
-        }
+        The record is one FLAT tuple of strings and numbers,
+        ``(name, start_ms, duration_ms, error, trace_id, span_id, seq,
+        key, value, ...)``; ``_root_dict`` turns it into a span's dict
+        for a reader. Flat, because the ring keeps one for every served
+        RPC and a tuple of atoms leaves the garbage collector's lists at
+        its first pass: tens of thousands of kept dicts (or objects)
+        lengthen every full collection, which stops all threads."""
+        if tail:
+            root.annotations["tail_kept"] = True
+        if root.boundary:
+            seq = next(self._seq)
+            trace_id, span_id = root.minted_ids()
+        else:  # kept for its slowness alone: nobody's parent until now
+            seq, trace_id, span_id = -1, new_id(), new_id()
+        rec = (root.name, time.time() * 1000.0 - duration_ms, duration_ms,
+               error, trace_id, span_id, seq,
+               *chain.from_iterable(root.annotations.items()))
+        if root.boundary:
+            self._put(seq, rec)
+        if not tail:
+            return
         i = next(self._tail_seq)
         ring = self._tail_ring
-        ring[i % len(ring)] = d
+        ring[i % len(ring)] = rec
         self._tail_recorded = i + 1
         try:
             from ..utils.stats import Stats
@@ -170,6 +188,23 @@ class SpanCollector:
             Stats.get().incr("trace.tail_kept")
         except Exception:  # pragma: no cover - defensive
             pass
+
+    def _root_dict(self, rec: tuple) -> dict:
+        """A kept root's record as ``Span.to_dict`` shapes one. A root
+        that nothing asked for its ids gets them from its place in the
+        ring's sequence: the same on every call, unique in the process."""
+        name, start_ms, duration_ms, error, trace_id, span_id, seq = rec[:7]
+        return {
+            "trace_id": trace_id or f"{self._id_salt ^ (2 * seq):016x}",
+            "span_id": span_id or f"{self._id_salt ^ (2 * seq + 1):016x}",
+            "parent_id": None,
+            "name": name,
+            "process": self.process,
+            "start_ms": round(start_ms, 3),
+            "duration_ms": round(duration_ms, 3),
+            "annotations": dict(zip(rec[7::2], rec[8::2])),
+            "error": error,
+        }
 
     # -- cold read path ---------------------------------------------------
 
@@ -194,8 +229,12 @@ class SpanCollector:
     def snapshot(self) -> List[dict]:
         """All retained spans — head-sampled AND tail-kept — oldest
         first (by wall-clock start)."""
-        spans = [d for d in list(self._ring) if d is not None]
-        spans.extend(d for d in list(self._tail_ring) if d is not None)
+        kept = [e for e in list(self._ring) if e is not None]
+        held = {id(e) for e in kept}  # a slow boundary root is in both
+        kept.extend(e for e in list(self._tail_ring)
+                    if e is not None and id(e) not in held)
+        # a span's record is a dict; a kept root's becomes one here
+        spans = [e if type(e) is dict else self._root_dict(e) for e in kept]
         spans.sort(key=lambda d: d["start_ms"])
         return spans
 

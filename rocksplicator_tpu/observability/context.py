@@ -27,9 +27,10 @@ import contextvars
 import random
 from typing import Any, Dict, Optional
 
-# Holds the active Span (sampled) or the NOOP sentinel (an unsampled root
-# was opened: descendants must not re-roll sampling or they'd emit orphan
-# partial traces). None = no tracing decision made yet at this point.
+# Holds the active Span (sampled; or a recorded root-only root, which is
+# not) or the NOOP sentinel (an unsampled root was opened: descendants
+# must not re-roll sampling or they'd emit orphan partial traces).
+# None = no tracing decision made yet at this point.
 _current: contextvars.ContextVar = contextvars.ContextVar(
     "rstpu_active_span", default=None
 )
@@ -51,11 +52,13 @@ def current_span():
 
 
 def wire_context() -> Optional[Dict[str, Any]]:
-    """The active SAMPLED context as a wire/header dict, else None.
-    This is the injection half of cross-process (and cross-executor)
-    propagation."""
+    """The active context as a wire/header dict: that of a SAMPLED span,
+    or of a recorded root-only root (flagged ``root_only``: only an
+    ``always=True`` span joins it), else None. This is the injection
+    half of cross-process (and cross-executor) propagation."""
     span = _current.get()
-    if span is None or not span.sampled:
+    # the unsampled sentinels (NOOP, a deferred tail root) have no ids
+    if span is None or not (span.sampled or span.trace_id):
         return None
     return span.to_wire()
 

@@ -22,6 +22,10 @@ Usage::
 compaction) that are rare enough to trace unconditionally. ``remote=ctx``
 reattaches a wire/executor context captured via
 :func:`~.context.wire_context` — the server-side restore half.
+``boundary=True`` marks the root of a served request (the RPC server's
+dispatch, and nothing else): head-unsampled it is still recorded, ALONE
+(:class:`_TailRoot`), so every request has a named owner on the
+timeline and the always-on operations it starts have a parent.
 """
 
 from __future__ import annotations
@@ -96,6 +100,7 @@ class _NoopSpan:
 
     __slots__ = ()
     sampled = False
+    boundary = False
     trace_id = ""
     span_id = ""
 
@@ -107,34 +112,72 @@ NOOP_SPAN = _NoopSpan()
 
 
 class _TailRoot:
-    """A head-UNSAMPLED root under tail-keep (round 14): the deferred
-    sampling decision. Cheap enough for every root op — one small
-    object, one wall-clock read, one perf_counter read; ``sampled`` is
-    False so descendants still take the NOOP fast path (a kept tail
-    trace is root-only by design — the decision can't be made until the
-    duration is known, by which time the children are gone). On exit,
-    a root slower than the collector's ``tail_ms`` is retained in the
-    tail ring: the 1023/1024 head-unsampled p99 outlier becomes
-    inspectable on /traces instead of invisible."""
+    """A head-UNSAMPLED root whose record is decided at exit. Cheap
+    enough for every root op — one small object and one perf_counter
+    read going in; a kept one leaves behind ONE flat tuple in the
+    collector's ring (``SpanCollector.record_root``: no ids, no
+    rounding, no dict until a reader asks, and nothing the garbage
+    collector has to walk). ``sampled`` is False so ordinary
+    descendants still take the NOOP fast path. Two kinds:
 
-    __slots__ = ("name", "t0", "tail_ms", "annotations")
+    - under tail-keep (round 14), any root: a root slower than the
+      collector's ``tail_ms`` is retained in the tail ring — the
+      1023/1024 head-unsampled p99 outlier becomes inspectable on
+      /traces instead of invisible. Root-only by design: the decision
+      can't be made until the duration is known, by which time the
+      children are gone;
+    - ``boundary`` (``start_span(..., boundary=True)``, the RPC server's
+      dispatch): ALWAYS kept, alone, in the main ring, so every served
+      request has a named owner on the timeline. An ``always=True`` span
+      opened under it — in the same context, or through
+      ``wire_context()`` → ``remote=`` across an executor hop — becomes
+      its child (the ids are minted when the first one asks) and carries
+      a full trace below itself. A slow one is held in the tail ring
+      too: the same record with the same ids, never a second orphan."""
+
+    __slots__ = ("name", "t0", "collector", "tail_ms", "boundary",
+                 "annotations", "_trace_id", "_span_id")
     sampled = False
-    trace_id = ""
-    span_id = ""
 
-    def __init__(self, name: str, tail_ms: float,
+    def __init__(self, name: str, collector, boundary: bool,
                  annotations: Optional[Dict[str, Any]]):
         self.name = name
         self.t0 = time.perf_counter()
-        # threshold cached here so the (common) fast exit never touches
-        # the collector singleton; wall-clock start is reconstructed at
-        # keep time (start = now - duration) — one fewer syscall per
+        # collector and threshold cached here so the exit never looks
+        # the singleton up; wall-clock start is reconstructed at record
+        # time (start = now - duration) — one fewer syscall per
         # unsampled root
-        self.tail_ms = tail_ms
+        self.collector = collector
+        self.tail_ms = collector.tail_ms
+        self.boundary = boundary
         self.annotations = annotations or {}
+        self._trace_id = self._span_id = ""
+
+    @property
+    def trace_id(self) -> str:
+        """Minted on first use, for a boundary root only: a root of any
+        other kind is nobody's parent, and reads as unsampled ("")."""
+        if not self._trace_id and self.boundary:
+            self._trace_id = new_id()
+        return self._trace_id
+
+    @property
+    def span_id(self) -> str:
+        if not self._span_id and self.boundary:
+            self._span_id = new_id()
+        return self._span_id
+
+    def minted_ids(self):
+        """(trace_id, span_id) as far as anything has asked for them."""
+        return self._trace_id, self._span_id
 
     def annotate(self, **kv: Any) -> None:
         self.annotations.update(kv)
+
+    def to_wire(self) -> Dict[str, Any]:
+        # root_only: only an always=True span may attach (start_span)
+        return {"trace_id": self.trace_id, "span_id": self.span_id,
+                "sampled": True, "root_only": True}
 
 
 class start_span:
@@ -142,23 +185,31 @@ class start_span:
     sampled/unsampled root). See module docstring for the fast-path
     contract."""
 
-    __slots__ = ("_name", "_always", "_remote", "_ann", "_span", "_token")
+    __slots__ = ("_name", "_always", "_remote", "_boundary", "_ann",
+                 "_span", "_token")
 
     def __init__(self, name: str, always: bool = False,
-                 remote: Optional[dict] = None, **annotations: Any):
+                 remote: Optional[dict] = None, boundary: bool = False,
+                 **annotations: Any):
         self._name = name
         self._always = always
         self._remote = remote
+        self._boundary = boundary
         self._ann = annotations
         self._span = NOOP_SPAN
         self._token = None
 
     def __enter__(self):
         remote = self._remote
-        if remote is not None and valid_wire_context(remote) and _enabled():
+        if remote is not None and valid_wire_context(remote) \
+                and (self._always or not remote.get("root_only")) \
+                and _enabled():
             # (_enabled(): the RSTPU_TRACING=0 kill switch must silence
             # remotely-initiated spans too, or a disabled node would keep
             # recording and re-propagating peers' trace contexts)
+            # (root_only: the context of a boundary root, which only an
+            # always=True span may join; any other span goes on as if
+            # no context had been handed over)
             # An explicit remote context wins over any local parent: the
             # caller is continuing a trace that crossed a process (RPC
             # header) or executor boundary — e.g. a follower's apply span
@@ -169,7 +220,8 @@ class start_span:
         else:
             parent = _current.get()
             if parent is not None:
-                if not parent.sampled:
+                if not parent.sampled and not (
+                        self._always and parent.boundary):
                     # inside an unsampled trace: nothing to set or reset
                     return NOOP_SPAN
                 span = Span(self._name, parent.trace_id, parent.span_id,
@@ -180,11 +232,13 @@ class start_span:
                 col = SpanCollector.get()
                 if (self._always and col.enabled) or col.sample():
                     span = Span(self._name, new_id(), None, self._ann)
-                elif col.enabled and col.tail_ms > 0.0:
-                    # head-unsampled ROOT under tail-keep: defer the
-                    # decision to __exit__ (duration known). sampled is
-                    # False, so descendants still take the NOOP branch.
-                    root = _TailRoot(self._name, col.tail_ms, self._ann)
+                elif col.enabled and (self._boundary or col.tail_ms > 0.0):
+                    # head-unsampled ROOT at a service boundary, or under
+                    # tail-keep: the record is made at __exit__ (duration
+                    # known). sampled is False, so ordinary descendants
+                    # still take the NOOP branch.
+                    root = _TailRoot(self._name, col, self._boundary,
+                                     self._ann)
                     self._span = root
                     self._token = _current.set(root)
                     return root
@@ -210,15 +264,14 @@ class start_span:
             # DESIGN (a parked long-poll serve, a long-poll pull RTT) —
             # keeping those would fill the tail ring with waits and
             # evict the genuine outliers the ring exists for
-            if duration_ms >= span.tail_ms \
-                    and "tail_exempt" not in span.annotations:
-                from .collector import SpanCollector
-
-                col = SpanCollector.get()
+            tail = 0.0 < span.tail_ms <= duration_ms \
+                and "tail_exempt" not in span.annotations
+            if tail or span.boundary:
+                col = span.collector
                 if col.enabled:
-                    col.record_tail(
-                        span, duration_ms,
-                        error=repr(exc) if exc_type is not None else None)
+                    col.record_root(
+                        span, duration_ms, tail,
+                        repr(exc) if exc_type is not None else None)
             return False
         if exc_type is not None and span.error is None:
             span.error = repr(exc)

@@ -64,6 +64,16 @@ def _request_cost_bytes(args: Dict[str, Any]) -> int:
     return cost
 
 
+def _root_name(method: Any) -> str:
+    """``rpc.server.<method>``: readers group spans by name. The method
+    is the peer's text and the name is printed by /traces.txt, so what
+    cannot be a method name is not copied."""
+    if isinstance(method, str) and len(method) <= 64 \
+            and method.isidentifier():
+        return "rpc.server." + method
+    return "rpc.server.invalid"
+
+
 class RpcServer:
     """Serves one or more handler objects on a TCP port (plus any
     policy-derived or explicit fast-path endpoints).
@@ -340,17 +350,20 @@ class RpcServer:
         tenant = msg.get(TENANT_KEY) if armored else None
         deadline: Optional[Deadline] = None
         queue_wait_ms = 0.0
-        if armored and recv_ts is not None:
+        if recv_ts is not None:
             queue_wait_ms = max(
                 0.0,
                 (asyncio.get_running_loop().time() - recv_ts) * 1e3)
         # Reattach the caller's trace context (injected by RpcClient.call
         # into the JSON frame header): the server span joins the caller's
-        # trace; without a header it rolls local head sampling. This task
-        # was just created, so the contextvar set inside start_span is
-        # scoped to this request.
-        with start_span("rpc.server", remote=msg.get(TRACE_KEY),
-                        method=method) as sp:
+        # trace; without a header it rolls local head sampling, and
+        # unsampled it is still recorded, alone (boundary=True): every
+        # served request has one named root. This task was just created,
+        # so the contextvar set inside start_span is scoped to this
+        # request.
+        with start_span(_root_name(method), remote=msg.get(TRACE_KEY),
+                        boundary=True, method=method,
+                        queue_wait_ms=round(queue_wait_ms, 3)) as sp:
             t0 = time.monotonic()
             try:
                 if self._draining:

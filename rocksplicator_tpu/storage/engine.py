@@ -1509,13 +1509,19 @@ class DB:
         self._mem = MemTable()
         try:
             name = self._new_file_name()
-            self._write_mem_sst(os.path.join(self.path, name), mem)
-            self._readers[name] = SSTReader(os.path.join(self.path, name))
-            self._levels[0].append(name)
-            self._bytes_flushed_total += self._readers[name].file_size
-            self._persisted_seq = max(self._persisted_seq, mem.max_seq)
-            if not defer_manifest:
-                self._persist_manifest()
+            # the inline flush under the flusher thread's name
+            # (_flush_imms): a bulk ingest's flush of the memtable below
+            # the ingested file runs here, inside the ingest's trace
+            with start_span("storage.flush", always=True, memtables=1,
+                            bytes=mem.approximate_bytes()):
+                self._write_mem_sst(os.path.join(self.path, name), mem)
+                self._readers[name] = SSTReader(
+                    os.path.join(self.path, name))
+                self._levels[0].append(name)
+                self._bytes_flushed_total += self._readers[name].file_size
+                self._persisted_seq = max(self._persisted_seq, mem.max_seq)
+                if not defer_manifest:
+                    self._persist_manifest()
         except BaseException:
             # Keep read-your-writes: fold the unflushed entries back under
             # any writes that raced in. (Both sinks abandon their partial

@@ -19,7 +19,7 @@ runtime's dynamic cycle detection.
 RANKS = {
     "rocksplicator_tpu/replication/ack_window.py:127": ('AckWindow._cond', 0),
     "rocksplicator_tpu/admin/handler.py:161": ('AdminHandler._db_admin_lock', 1),
-    "rocksplicator_tpu/admin/ingest_pipeline.py:123": ('BatchCompactor._lock', 2),
+    "rocksplicator_tpu/admin/ingest_pipeline.py:135": ('BatchCompactor._lock', 2),
     "rocksplicator_tpu/storage/sst.py:99": ('BlockCache._instance_lock', 3),
     "rocksplicator_tpu/storage/sst.py:103": ('BlockCache._lock', 4),
     "rocksplicator_tpu/kafka/network.py:91": ('BrokerHandler._log_lock', 5),
@@ -37,7 +37,7 @@ RANKS = {
     "rocksplicator_tpu/utils/flags.py:34": ('FlagRegistry._lock', 17),
     "rocksplicator_tpu/utils/graceful_shutdown.py:30": ('GracefulShutdownHandler._lock', 18),
     "rocksplicator_tpu/utils/hot_key_detector.py:27": ('HotKeyDetector._lock', 19),
-    "rocksplicator_tpu/admin/ingest_pipeline.py:51": ('IngestGate._lock', 20),
+    "rocksplicator_tpu/admin/ingest_pipeline.py:52": ('IngestGate._lock', 20),
     "rocksplicator_tpu/storage/compaction_scheduler.py:118": ('IoBudget._fg_cv', 21),
     "rocksplicator_tpu/storage/compaction_scheduler.py:117": ('IoBudget._fg_lock', 22),
     "rocksplicator_tpu/rpc/ioloop.py:37": ('IoLoop._default_lock', 23),
@@ -61,7 +61,7 @@ RANKS = {
     "rocksplicator_tpu/replication/replicator.py:46": ('Replicator._instance_lock', 41),
     "rocksplicator_tpu/utils/retry_policy.py:77": ('RetryBudget._lock', 42),
     "rocksplicator_tpu/utils/s3_stub.py:48": ('S3StubServer.lock', 43),
-    "rocksplicator_tpu/observability/collector.py:47": ('SpanCollector._instance_lock', 44),
+    "rocksplicator_tpu/observability/collector.py:54": ('SpanCollector._instance_lock', 44),
     "rocksplicator_tpu/utils/ssl_context_manager.py:57": ('SslContextManager._lock', 45),
     "rocksplicator_tpu/utils/stats.py:231": ('Stats._buffers_lock', 46),
     "rocksplicator_tpu/utils/stats.py:240": ('Stats._dump_lock', 47),
@@ -70,7 +70,7 @@ RANKS = {
     "rocksplicator_tpu/rpc/admission.py:115": ('TenantAdmission._instance_lock', 50),
     "rocksplicator_tpu/rpc/admission.py:125": ('TenantAdmission._lock', 51),
     "rocksplicator_tpu/rpc/admission.py:67": ('TokenBucket._lock', 52),
-    "rocksplicator_tpu/tpu/compaction_service.py:42": ('TpuCompactionService._instance_lock', 53),
+    "rocksplicator_tpu/tpu/compaction_service.py:51": ('TpuCompactionService._instance_lock', 53),
     "rocksplicator_tpu/storage/archive.py:63": ('WalArchiver._mutex', 54),
     "rocksplicator_tpu/testing/failpoints.py:129": ('_Site.lock', 55),
     "rocksplicator_tpu/utils/stats.py:200": ('_ThreadBuffer.lock', 56),

@@ -24,6 +24,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..observability.context import wire_context
 from ..observability.span import start_span
 from ..storage.bloom import num_words_for
 from ..storage.engine import DBOptions
@@ -36,6 +37,14 @@ from .backend import TpuCompactionBackend, _next_pow2, require_accelerator
 
 log = logging.getLogger(__name__)
 
+# jit(vmap(...)) of the merge-resolve + bloom pipeline: XLA module
+# "jit_one_shard" (tests/test_tracing.py pins both programs' names)
+PIPELINE_PROGRAM = "one_shard"
+_GROUP_LANES = (
+    "key_words_be", "key_len", "seq_hi",
+    "seq_lo", "vtype", "val_words", "val_len", "valid",
+)
+
 
 class TpuCompactionService:
     _instance: Optional["TpuCompactionService"] = None
@@ -43,10 +52,8 @@ class TpuCompactionService:
 
     def __init__(self, bits_per_key: int = 10, sort_backend: str = None):
         import jax
-        import jax.numpy as jnp
 
         self._jax = jax
-        self._jnp = jnp
         require_accelerator()
         self._bits_per_key = bits_per_key
         # deployment knob: run the service's kernels on the lax sort, the
@@ -107,6 +114,10 @@ class TpuCompactionService:
                 out["bloom"] = bloom
                 return out
 
+            # the XLA module's name (jit_<name>), by which a device
+            # trace's reader finds the pipeline: fixed here, not left to
+            # whatever the closure happens to be called
+            one_shard.__name__ = PIPELINE_PROGRAM
             fn = jax.jit(jax.vmap(one_shard))
             self._vmapped_cache[key] = fn
         return fn
@@ -127,57 +138,16 @@ class TpuCompactionService:
         if not batches:
             return []
         capacity = _next_pow2(max(b.capacity for b in batches))
-        num_words = num_words_for(capacity, self._bits_per_key)
-        jnp = self._jnp
         # The job-level trace answers "where does a shard-batch's wall
-        # clock go": host stack+H2D staging vs kernel+D2H readback vs
-        # host unpack — the split the round-1 profile found dominated by
-        # transfer (SURVEY §7), now attributable per job.
+        # clock go": host stack + H2D (tpu.h2d), the launch
+        # (tpu.dispatch), the host blocked on the device and D2H
+        # (tpu.readback), host unpack (tpu.unpack) — one group of the
+        # streamed path, through the same code.
         with start_span("tpu.compact_batch", always=True,
-                        shards=len(batches), capacity=capacity) as jsp:
-            with start_span("tpu.stage"):
-                stacked = {
-                    name: jnp.asarray(np.stack([
-                        _pad_to(getattr(b, name), capacity) for b in batches
-                    ]))
-                    for name in (
-                        "key_words_be", "key_len", "seq_hi",
-                        "seq_lo", "vtype", "val_words", "val_len", "valid",
-                    )
-                }
-            flags = [fast_flags(b.key_len, b.seq_hi, b.valid)
-                     for b in batches]
-            uniform_klen = all(u for u, _, _ in flags)
-            seq32 = all(s for _, s, _ in flags)
-            key_words = max(k for _, _, k in flags)
-            fn = self._pipeline(merge_kind, drop_tombstones, num_words,
-                                uniform_klen, seq32, key_words)
-            with start_span("tpu.kernel"):
-                out = fn(
-                    stacked["key_words_be"],
-                    stacked["key_len"], stacked["seq_hi"], stacked["seq_lo"],
-                    stacked["vtype"], stacked["val_words"],
-                    stacked["val_len"], stacked["valid"],
-                )
-                # np.asarray blocks on the device: readback time lands in
-                # the kernel span (dispatch is async; the two are not
-                # separable without a device profiler)
-                host = {k: np.asarray(v) for k, v in out.items()}
-            results = []
-            fallbacks = 0
-            with start_span("tpu.unpack"):
-                for s in range(len(batches)):
-                    if bool(host["needs_cpu_fallback"][s]):
-                        fallbacks += 1
-                        results.append(self._cpu_recompute(
-                            batches[s], merge_kind, drop_tombstones,
-                            num_words, return_arrays=return_arrays))
-                        continue
-                    results.append(_shard_result(
-                        host, s, int(host["count"][s]), return_arrays))
-            if fallbacks:
-                jsp.annotate(cpu_fallbacks=fallbacks)
-            return results
+                        shards=len(batches), capacity=capacity):
+            return self._compact_shard_stream(
+                batches, merge_kind, drop_tombstones, len(batches),
+                capacity, return_arrays)
 
     def compact_shard_stream(
         self,
@@ -215,23 +185,20 @@ class TpuCompactionService:
         key_words = max(k for _, _, k in flags)
         fn = self._pipeline(merge_kind, drop_tombstones, num_words,
                             uniform_klen, seq32, key_words)
-        names = (
-            "key_words_be", "key_len", "seq_hi",
-            "seq_lo", "vtype", "val_words", "val_len", "valid",
-        )
-
         def stage(lo: int) -> Dict[str, object]:
             """Stack one group on host and issue its async H2D."""
             group = list(batches[lo:lo + group_size])
             pad_shards = group_size - len(group)
             stacked = {}
-            for name in names:
-                arr = np.stack([_pad_to(getattr(b, name), capacity)
-                                for b in group])
-                if pad_shards:
-                    arr = np.pad(
-                        arr, [(0, pad_shards)] + [(0, 0)] * (arr.ndim - 1))
-                stacked[name] = jax.device_put(arr)
+            with start_span("tpu.h2d", shards=len(group)):
+                for name in _GROUP_LANES:
+                    arr = np.stack([_pad_to(getattr(b, name), capacity)
+                                    for b in group])
+                    if pad_shards:
+                        arr = np.pad(
+                            arr,
+                            [(0, pad_shards)] + [(0, 0)] * (arr.ndim - 1))
+                    stacked[name] = jax.device_put(arr)
             return stacked
 
         groups = list(range(0, len(batches), group_size))
@@ -239,7 +206,8 @@ class TpuCompactionService:
         pending: List[Tuple[int, dict]] = []  # (group_lo, device outputs)
         dev = stage(groups[0])
         for gi, lo in enumerate(groups):
-            out = fn(*(dev[name] for name in names))  # async dispatch
+            with start_span("tpu.dispatch"):  # the async launch alone
+                out = fn(*(dev[name] for name in _GROUP_LANES))
             if gi + 1 < len(groups):
                 dev = stage(groups[gi + 1])  # H2D overlaps the kernel
             pending.append((lo, out))
@@ -258,17 +226,19 @@ class TpuCompactionService:
     def _drain(self, lo: int, out, batches, merge_kind, drop_tombstones,
                num_words, return_arrays=False) -> List[dict]:
         """Readback + unpack one group's device outputs."""
-        host = {k: np.asarray(v) for k, v in out.items()}
+        with start_span("tpu.readback"):  # blocked on the device, and D2H
+            host = {k: np.asarray(v) for k, v in out.items()}
         group = batches[lo:lo + len(host["count"])]
         results = []
-        for s in range(min(len(group), len(host["count"]))):
-            if bool(host["needs_cpu_fallback"][s]):
-                results.append(self._cpu_recompute(
-                    group[s], merge_kind, drop_tombstones, num_words,
-                    return_arrays=return_arrays))
-                continue
-            results.append(_shard_result(
-                host, s, int(host["count"][s]), return_arrays))
+        with start_span("tpu.unpack", shards=len(group)):
+            for s in range(min(len(group), len(host["count"]))):
+                if bool(host["needs_cpu_fallback"][s]):
+                    results.append(self._cpu_recompute(
+                        group[s], merge_kind, drop_tombstones, num_words,
+                        return_arrays=return_arrays))
+                    continue
+                results.append(_shard_result(
+                    host, s, int(host["count"][s]), return_arrays))
         return results
 
     def _cpu_recompute(self, batch: KVBatch, merge_kind: MergeKind,
@@ -457,17 +427,18 @@ def _db_lanes(plan: dict) -> Optional[Dict[str, np.ndarray]]:
     return {f: np.concatenate([p[f] for p in parts]) for f in FIELDS}
 
 
-def _install_arrays(db, plan: dict, res: dict) -> None:
+def _write_arrays(db, res: dict, tctx: Optional[dict]) -> dict:
     """Write one shard's resolved lanes as PLANAR SSTs (vectorized sink,
-    kernel-built per-file blooms) and install them; falls back to the
-    entry-tuple sink when the planar layout can't express the result."""
+    kernel-built per-file blooms). Returns how to install them, as
+    ``install_full_compaction``'s keywords: ``files``, or the entry-tuple
+    sink's ``entries`` when the planar layout can't express the result.
+    ``tctx``: the dispatch's trace context (this runs on a pool thread)."""
     from ..storage.bloom import num_words_for as bloom_words_for
     from .format import planar_stride, planar_widths, write_sst_from_arrays
 
     arrays, count = res["arrays"], int(res["count"])
     if count == 0:
-        db.install_full_compaction(plan, entries=[])
-        return
+        return {"entries": []}
     widths = planar_widths(arrays, count)
     if widths is not None:
         import jax.numpy as jnp
@@ -487,18 +458,23 @@ def _install_arrays(db, plan: dict, res: dict) -> None:
             # padded max capacity (and the service default bits), so
             # reusing it would write a max-shard-sized bloom into every
             # small shard of a mixed batch
-            bloom = np.asarray(bloom_build_tpu(
-                jnp.asarray(sub["key_words_le"]),
-                jnp.asarray(sub["key_len"]),
-                jnp.asarray(np.ones(end - start, dtype=bool)),
-                num_words=bloom_words_for(end - start, opts.bits_per_key),
-            ))
+            with start_span("tpu.bloom", remote=tctx, rows=end - start):
+                bloom = np.asarray(bloom_build_tpu(
+                    jnp.asarray(sub["key_words_le"]),
+                    jnp.asarray(sub["key_len"]),
+                    jnp.asarray(np.ones(end - start, dtype=bool)),
+                    num_words=bloom_words_for(end - start,
+                                              opts.bits_per_key),
+                ))
             name, path = db.allocate_sst()
-            props = write_sst_from_arrays(
-                sub, end - start, path, bloom_words=bloom,
-                block_entries=block_entries, compression=opts.compression,
-                bits_per_key=opts.bits_per_key, planar=True,
-            )
+            with start_span("tpu.planar.write", remote=tctx,
+                            rows=end - start):
+                props = write_sst_from_arrays(
+                    sub, end - start, path, bloom_words=bloom,
+                    block_entries=block_entries,
+                    compression=opts.compression,
+                    bits_per_key=opts.bits_per_key, planar=True,
+                )
             if props is None:
                 ok = False
                 for p in paths:
@@ -510,15 +486,13 @@ def _install_arrays(db, plan: dict, res: dict) -> None:
             names.append(name)
             paths.append(path)
         if ok:
-            db.install_full_compaction(plan, files=names)
-            return
+            return {"files": names}
     # tuple fallback (non-uniform keys/values)
-    entries = unpack_entries(
+    return {"entries": unpack_entries(
         arrays["key_words_be"], arrays["key_len"], arrays["seq_hi"],
         arrays["seq_lo"], arrays["vtype"], arrays["val_words"],
         arrays["val_len"], count,
-    )
-    db.install_full_compaction(plan, entries=entries)
+    )}
 
 
 def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
@@ -566,11 +540,15 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
         db.abort_full_compaction(plan)
 
     def _pmap(fn, items):
+        # the pool's threads start with an empty context: each per-shard
+        # span below reattaches the caller's (``remote=``), so the
+        # phases stay in the dispatch's trace
+        tctx = wire_context()
         if pool is None or len(items) <= 1:
-            return [fn(it) for it in items]
-        return list(pool.map(fn, items))
+            return [fn(it, tctx) for it in items]
+        return list(pool.map(lambda it: fn(it, tctx), items))
 
-    def _stage(item):
+    def _stage(item, tctx):
         """(name, db) → ("handled"|"remaining"|("grouped", key, payload)).
 
         MUST NOT raise: staging runs through pool.map, and an exception
@@ -585,7 +563,10 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
                 merge_op, UInt64AddOperator):
             return ("remaining", name, db, None)
         try:
-            plan = db.plan_full_compaction()
+            # mostly the wait for the memtable flush, which the engine's
+            # flusher thread runs (a storage.flush trace of its own)
+            with start_span("admin.compact.plan", remote=tctx, db=name):
+                plan = db.plan_full_compaction()
         except BaseException:
             log.exception("plan failed for %s; declining to per-db", name)
             return ("remaining", name, db, None)
@@ -593,7 +574,10 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
             return ("handled", name, db, None)  # nothing to compact
         _track(db, plan)
         try:
-            lanes = _db_lanes(plan)
+            with start_span("tpu.lanes.decode", remote=tctx) as lsp:
+                lanes = _db_lanes(plan)
+                if lanes is not None:
+                    lsp.annotate(rows=int(lanes["key_len"].shape[0]))
         except BaseException:
             log.exception(
                 "lane read failed for %s; declining to per-db", name)
@@ -619,11 +603,23 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
         key = (kind, plan["drop_tombstones"])
         return ("grouped", name, db, (key, plan, _LaneBatch(lanes)))
 
-    def _install(args):
+    def _install(args, tctx):
         name, db, plan, res = args
+        try:
+            how = _write_arrays(db, res, tctx)
+        except BaseException:
+            # nothing is installed yet: hand the mutex back, so that the
+            # per-db retry via compact_range can take it
+            log.exception(
+                "batched compaction output failed for %s; "
+                "will re-compact per-db", name)
+            _abort(db, plan)
+            return ("remaining", name, db)
         _untrack(plan)  # install consumes the plan either way
         try:
-            _install_arrays(db, plan, res)
+            with start_span("admin.compact.install_db", remote=tctx,
+                            db=name):
+                db.install_full_compaction(plan, **how)
             return ("handled", name, db)
         except BaseException:
             # the mutex was released in install's finally; a per-db

@@ -171,8 +171,8 @@ def test_rpc_trace_context_roundtrip():
         tid = ioloop.run_sync(go())
         # server span sampled and stitched onto the client's trace id
         assert wait_until(lambda: any(
-            s["trace_id"] == tid for s in _spans_by_name("rpc.server")))
-        server_span = [s for s in _spans_by_name("rpc.server")
+            s["trace_id"] == tid for s in _spans_by_name("rpc.server.echo")))
+        server_span = [s for s in _spans_by_name("rpc.server.echo")
                        if s["trace_id"] == tid][0]
         assert server_span["annotations"]["method"] == "echo"
         rtt = [s for s in _spans_by_name("rpc.rtt")
@@ -438,3 +438,332 @@ def test_three_process_chain_one_stitched_trace(tmp_path):
                     pass
         leader.stop()
         ldb.close()
+
+
+# ---------------------------------------------------------------------------
+# one root per served RPC (root-only when head-unsampled)
+# ---------------------------------------------------------------------------
+
+
+class _RootHandler:
+    """Handlers that open what a served RPC can open under its root."""
+
+    def __init__(self):
+        self.seen = {}
+
+    async def handle_plain(self):
+        with start_span("plain.child") as c:  # ordinary: stays free
+            self.seen["child_sampled"] = c.sampled
+        return {}
+
+    async def handle_control(self, sleep_ms=0):
+        from rocksplicator_tpu.observability import wire_context
+
+        root = current_span()
+        self.seen["root"] = (root.trace_id, root.span_id)
+        with start_span("ctl.direct", always=True):
+            pass
+        tctx = wire_context()  # the executor drops contextvars
+
+        def hop():
+            with start_span("ctl.hop", always=True, remote=tctx):
+                with start_span("ctl.hop.child"):
+                    pass
+            # not always-on: the root-only context is not its to join
+            with start_span("ctl.hop.plain", remote=tctx) as p:
+                self.seen["plain_sampled"] = p.sampled
+
+        await asyncio.get_running_loop().run_in_executor(None, hop)
+        if sleep_ms:
+            await asyncio.sleep(sleep_ms / 1000.0)
+        return {}
+
+
+def _serve_calls(calls, handler=None):
+    """Serve ``calls`` ``[(method, args)]`` from a fresh server; returns
+    the handler once every reply is in (and so every root finished:
+    wait for the records, the reply is sent inside the span)."""
+    ioloop = IoLoop.default()
+    server = RpcServer(port=0, ioloop=ioloop)
+    handler = handler or _RootHandler()
+    server.add_handler(handler)
+    server.start()
+    try:
+        async def go():
+            pool = RpcClientPool()
+            for method, args in calls:
+                try:
+                    await pool.call("127.0.0.1", server.port, method, args)
+                except Exception:
+                    pass  # an application error is a served RPC too
+            await pool.close()
+
+        ioloop.run_sync(go())
+    finally:
+        server.stop()
+    return handler
+
+
+def test_unsampled_rpc_leaves_one_root_named_by_method():
+    col = SpanCollector.get()
+    col.configure(sample_rate=0.0)
+    handler = _serve_calls([("plain", {}), ("nope", {}), ("a b\n", {})])
+    assert wait_until(lambda: col.recorded == 3)
+    assert handler.seen["child_sampled"] is False
+    snap = col.snapshot()
+    assert [s["name"] for s in snap] == [
+        "rpc.server.plain", "rpc.server.nope", "rpc.server.invalid"]
+    plain, nope, _bad = snap
+    assert plain["parent_id"] is None and plain["error"] is None
+    assert plain["annotations"]["method"] == "plain"
+    assert plain["annotations"]["queue_wait_ms"] >= 0.0
+    assert "error_code" not in plain["annotations"]
+    assert nope["annotations"]["error_code"] == "NO_SUCH_METHOD"
+    assert col.tail_kept == 0
+
+
+def test_always_span_joins_root_only_root_directly_and_across_a_hop():
+    col = SpanCollector.get()
+    col.configure(sample_rate=0.0)
+    handler = _serve_calls([("control", {})])
+    assert wait_until(lambda: _spans_by_name("rpc.server.control"))
+    (root,) = _spans_by_name("rpc.server.control")
+    assert handler.seen["root"] == (root["trace_id"], root["span_id"])
+    assert handler.seen["plain_sampled"] is False
+    for name in ("ctl.direct", "ctl.hop"):
+        (child,) = _spans_by_name(name)
+        assert child["trace_id"] == root["trace_id"]
+        assert child["parent_id"] == root["span_id"]
+    # below an always-on span the trace is a full one
+    (grandchild,) = _spans_by_name("ctl.hop.child")
+    assert grandchild["parent_id"] == _spans_by_name("ctl.hop")[0]["span_id"]
+    assert grandchild["trace_id"] == root["trace_id"]
+    assert not _spans_by_name("ctl.hop.plain")
+    assert {s["trace_id"] for s in col.snapshot()} == {root["trace_id"]}
+
+
+def test_slow_root_is_kept_once_with_the_ids_its_children_carry():
+    col = SpanCollector.get()
+    col.configure(sample_rate=0.0, tail_ms=30.0)
+    _serve_calls([("control", {"sleep_ms": 60}), ("plain", {})])
+    assert wait_until(lambda: _spans_by_name("rpc.server.plain"))
+    roots = _spans_by_name("rpc.server.control")
+    (root,) = roots  # once, though the main and the tail ring hold it
+    # (the other slow root is the caller's own, client side)
+    assert sorted(s["name"] for s in col.snapshot()
+                  if s["annotations"].get("tail_kept")) == [
+        "rpc.rtt", "rpc.server.control"]
+    assert root["annotations"]["tail_kept"] is True
+    assert root["duration_ms"] >= 30.0
+    assert _spans_by_name("ctl.direct")[0]["parent_id"] == root["span_id"]
+    assert "tail_kept" not in _spans_by_name(
+        "rpc.server.plain")[0]["annotations"]
+    # /traces shows it as one trace, the root with its children
+    (trace,) = [t for t in json.loads(col.to_json_text())["traces"]
+                if t["trace_id"] == root["trace_id"]]
+    assert trace["span_count"] == 4
+
+
+def test_kill_switch_silences_roots_and_what_runs_under_them():
+    col = SpanCollector.get()
+    col.configure(sample_rate=1.0, tail_ms=1.0)
+    col.enabled = False  # RSTPU_TRACING=0
+    try:
+        _serve_calls([("control", {"sleep_ms": 5}), ("plain", {})])
+    finally:
+        col.enabled = True
+    assert col.recorded == 0 and col.tail_kept == 0
+    assert col.snapshot() == []
+
+
+def test_kept_root_records_leave_the_garbage_collectors_lists():
+    """One record is kept for every served RPC. A full collection stops
+    every thread for as long as it takes to walk what is tracked (on the
+    chip, PR 27: ~70 ms, 63 times a run, and 25,000 kept dicts moved
+    write_p95_ms by 14 %), so a kept record is a flat tuple of atoms,
+    which a collection drops from its lists."""
+    import gc
+
+    col = SpanCollector.get()
+    col.configure(sample_rate=0.0, tail_ms=1.0)
+    _serve_calls([("plain", {}), ("control", {"sleep_ms": 5}), ("nope", {})])
+    assert wait_until(lambda: col.recorded >= 3)
+    gc.collect()
+    roots = [e for e in col._ring + col._tail_ring if type(e) is tuple]
+    assert len(roots) >= 4  # three in the ring, the slow one kept twice
+    assert not any(gc.is_tracked(e) for e in roots)
+    # and a reader still gets a span's dict, the same on every call
+    assert _spans_by_name("rpc.server.nope") == _spans_by_name(
+        "rpc.server.nope")
+
+
+# ---------------------------------------------------------------------------
+# the post-load compaction, phase by phase, across its thread hops
+# ---------------------------------------------------------------------------
+
+PER_SHARD = ("admin.compact.plan", "tpu.lanes.decode",
+             "admin.compact.install_db", "tpu.bloom", "tpu.planar.write")
+PER_LAUNCH = ("tpu.h2d", "tpu.dispatch", "tpu.readback", "tpu.unpack")
+
+
+def test_batched_compaction_is_one_trace_across_the_pool(tmp_path,
+                                                         monkeypatch):
+    """A dispatch of three shards over the BatchCompactor's pool: every
+    phase is in the dispatch's trace, the per-shard ones once a shard,
+    though they ran on pool threads; a rider's admin.compact.wait ends
+    where the dispatch that took it starts."""
+    import struct
+    import threading
+
+    from rocksplicator_tpu.admin.ingest_pipeline import BatchCompactor
+    from rocksplicator_tpu.storage.sst import SSTWriter
+    from rocksplicator_tpu.storage.records import OpType
+    from rocksplicator_tpu.testing import failpoints as fp
+    from rocksplicator_tpu.tpu import compaction_service as cs
+
+    pack64 = struct.Struct("<q").pack
+    col = SpanCollector.get()
+    col.configure(sample_rate=0.0, capacity=4096)
+    dbs = []
+    for s in range(4):
+        db = DB(str(tmp_path / f"db{s}"))
+        for i in range(30):
+            db.write(WriteBatch().put(f"k{i:03d}".encode(), pack64(-1)))
+        sst = tmp_path / f"in{s}.tsst"
+        w = SSTWriter(str(sst))
+        for i in range(10, 40):
+            w.add(f"k{i:03d}".encode(), 0, OpType.PUT, pack64(s * 1000 + i))
+        w.finish()
+        db.ingest_external_file([str(sst)], move_files=True,
+                                allow_global_seqno=True)
+        dbs.append(db)
+
+    decode_threads = []
+    real_lanes = cs._db_lanes
+
+    def db_lanes(plan):
+        decode_threads.append(threading.current_thread().name)
+        return real_lanes(plan)
+
+    monkeypatch.setattr(cs, "_db_lanes", db_lanes)
+    compactor = BatchCompactor(use_tpu=True, compact_parallelism=3)
+
+    def submit(s):
+        # an ingest RPC's place: the always-on trace the caller is in
+        with start_span("test.caller", always=True, shard=s):
+            compactor.compact(f"db{s}", dbs[s])
+
+    # every dispatch starts with a 600 ms pause: the riders queue behind
+    # the leader's own, and their dispatch is long beside a thread's
+    # wake-up
+    fp.activate("compact.dispatch", "delay_ms:600")
+    try:
+        threads = [threading.Thread(target=submit, args=(s,))
+                   for s in range(4)]
+        threads[0].start()
+        assert wait_until(lambda: compactor.dispatch_count == 1)
+        for t in threads[1:]:
+            t.start()
+        for t in threads:
+            t.join(120)
+            assert not t.is_alive()
+    finally:
+        fp.deactivate("compact.dispatch")
+        compactor.close()
+    assert compactor.batch_sizes == [1, 3]
+    for s, db in enumerate(dbs):
+        assert db.get(b"k015") == pack64(s * 1000 + 15)
+        db.close()
+
+    snap = col.snapshot()
+    (dispatch,) = [s for s in snap if s["name"] == "admin.compact_dispatch"
+                   and s["annotations"]["shards"] == 3]
+    under = {dispatch["span_id"]}
+    inside = []
+    for s in snap:  # sorted by start: a parent comes before its children
+        if s["parent_id"] in under:
+            under.add(s["span_id"])
+            inside.append(s)
+    assert {s["trace_id"] for s in inside} == {dispatch["trace_id"]}
+    count = {}
+    for s in inside:
+        count[s["name"]] = count.get(s["name"], 0) + 1
+    for name in PER_SHARD:
+        assert count.get(name) == 3, (name, count)
+    for name in PER_LAUNCH + ("tpu.compact_stream", "admin.compact_stage",
+                              "admin.compact_install"):
+        assert count.get(name) == 1, (name, count)
+    assert "tpu.stage" not in count and "tpu.kernel" not in count
+    rows = [s["annotations"]["rows"] for s in inside
+            if s["name"] == "tpu.lanes.decode"]
+    assert rows == [60, 60, 60]
+    # the three-shard stage went over the pool
+    assert sum(n.startswith("post-load-compact")
+               for n in decode_threads) == 3, decode_threads
+
+    # the riders' wait: from the enqueue (inside the leader's own
+    # dispatch) to the start of the dispatch that took them
+    start, end = dispatch["start_ms"], \
+        dispatch["start_ms"] + dispatch["duration_ms"]
+    assert end - start >= 600.0
+    waits = [s for s in snap if s["name"] == "admin.compact.wait"]
+    assert len(waits) == 4
+    riders = [w for w in waits if w["annotations"]["batch"] == 3]
+    assert len(riders) == 3
+    for w in riders:
+        assert abs(w["start_ms"] + w["duration_ms"] - start) < 250.0
+    (leader,) = [w for w in waits if w["annotations"]["batch"] == 1]
+    assert leader["duration_ms"] < 250.0
+    rides = [s for s in snap if s["name"] == "admin.compact.ride"]
+    assert len(rides) == 3
+    for r in rides:
+        assert abs(r["start_ms"] + r["duration_ms"] - end) < 250.0
+
+
+def test_device_programs_have_the_names_the_trace_readers_look_for():
+    """An XLA module is named after the jitted function: the device
+    trace's readers (chipbench/layers) find the programs by these."""
+    import numpy as np
+
+    from rocksplicator_tpu.ops import MergeKind, pack_entries
+    from rocksplicator_tpu.ops.bloom_tpu import bloom_build_tpu
+    from rocksplicator_tpu.storage.records import OpType
+    from rocksplicator_tpu.tpu import compaction_service as cs
+
+    batch = pack_entries([(b"k%02d" % i, i + 1, OpType.PUT, b"v" * 8)
+                          for i in range(8)])
+    fn = cs.TpuCompactionService()._pipeline(MergeKind.NONE, True, 64)
+    args = [np.stack([getattr(batch, name)]) for name in cs._GROUP_LANES]
+    assert "@jit_" + cs.PIPELINE_PROGRAM in fn.lower(*args).as_text()[:200]
+    assert cs.PIPELINE_PROGRAM == "one_shard"
+    text = bloom_build_tpu.lower(
+        batch.key_words_le, batch.key_len, batch.valid,
+        num_words=64).as_text()
+    assert "@jit_bloom_build_tpu" in text[:200]
+
+
+def test_clock_check_pairs_module_events_with_their_launch_spans():
+    """tools/chip_clock_check.py on a hand-written recording: the first
+    launch keeps order, the second's device event outlasts its readback
+    span by 1,000 us, as two clocks that disagree would show."""
+    from tools.chip_clock_check import clock_check
+
+    ms = 1e6  # ns
+    recording = {"planes": [{"name": "/device:TPU:0", "lines": [
+        {"name": "XLA Modules", "events": [
+            ["jit_one_shard(1)", 1 * ms, 9 * ms],
+            ["jit_bloom_build_tpu(2)", 20 * ms, 0.3 * ms],
+            ["jit_one_shard(1)", 500 * ms, 9 * ms]]}]}]}
+    spans = [("tpu.compact_stream", 0.0, 30 * ms, "p1", None),
+             ("tpu.dispatch", 0.2 * ms, 0.9 * ms, "a", "p1"),
+             ("tpu.readback", 5 * ms, 12 * ms, "b", "p1"),
+             ("tpu.dispatch", 499 * ms, 500.1 * ms, "c", "p2"),
+             ("tpu.readback", 505 * ms, 508 * ms, "d", "p2")]
+    assert clock_check(recording, (0.0, 1000 * ms), spans) == {
+        "module_events": 2, "launch_pairs": 2,
+        "events_ms": [[0.8, 0.7, 9.0, 7.0, 2.0], [1.0, 1.1, 9.0, 3.0, -1.0]],
+        "least_lead_us": 800.0,
+        "median_lead_us": 1000.0, "least_tail_us": -1000.0,
+        "largest_violation_us": 1000.0}
+    assert clock_check(recording, (0.0, 1000 * ms), spans[:1]) == {
+        "module_events": 2, "launch_pairs": 0}

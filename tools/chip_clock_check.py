@@ -1,0 +1,107 @@
+#!/usr/bin/env python3
+"""One traced run of a chipbench cell, with two looks that the harness
+does not take itself (reported, never asserted; the result line stays
+the run's own and the last):
+
+    python3 tools/chip_clock_check.py --workload <cell> --seed <n> --trace 1
+
+- **clock check**: the program's spans are put on the recording's clock
+  by one mark (``chipbench/run.py`` ``reduce_trace``). If the two clocks
+  agree, every ``one_shard`` module event of the slice starts after the
+  ``tpu.dispatch`` span that launched it starts, and ends before the
+  matching ``tpu.readback`` span ends. Printed: the events checked, the
+  least lead and tail, and the largest violation, in microseconds.
+- **seconds ``reduce_trace`` takes** after the window (its ``blame`` is
+  gaps x spans, and every served RPC records a span).
+
+Arguments are ``chipbench/run.py``'s own.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+
+def clock_check(recording: dict, slice_ns, host_spans: list) -> dict:
+    """``host_spans``: ``(name, start_ns, end_ns, span_id, parent_id)`` on
+    the recording's clock. A launch group's ``tpu.dispatch`` and
+    ``tpu.readback`` are the k-th of each under one ``tpu.compact_stream``
+    (or ``tpu.compact_batch``) span; a module event belongs to the pair
+    whose dispatch ends nearest to the event's start."""
+    from chipbench import trace_reduce as tr
+    from rocksplicator_tpu.tpu.compaction_service import PIPELINE_PROGRAM
+
+    lo, hi = slice_ns
+    events = [(start, start + dur)
+              for plane in recording["planes"]
+              if plane["name"].startswith(tr.DEVICE_PLANE)
+              for line in plane["lines"] if line["name"] == tr.MODULES_LINE
+              for name, start, dur in line["events"]
+              if PIPELINE_PROGRAM in name and lo <= start < hi]
+    by_parent: dict = {}
+    for name, start, end, _sid, parent in sorted(
+            host_spans, key=lambda s: s[1]):
+        if name in ("tpu.dispatch", "tpu.readback"):
+            by_parent.setdefault(parent, {}).setdefault(name, []).append(
+                (start, end))
+    pairs = [(d, r) for group in by_parent.values()
+             for d, r in zip(group.get("tpu.dispatch", ()),
+                             group.get("tpu.readback", ()))]
+    out = {"module_events": len(events), "launch_pairs": len(pairs)}
+    if not events or not pairs:
+        return out
+    leads, tails, rows = [], [], []
+    for e_start, e_end in events:
+        dispatch, readback = min(pairs, key=lambda p: abs(p[0][1] - e_start))
+        leads.append((e_start - dispatch[0]) / 1e3)
+        tails.append((readback[1] - e_end) / 1e3)
+        # one row an event, ms: lead, the dispatch span, the module
+        # event, the readback span, tail
+        rows.append([round(x / 1e6, 3) for x in (
+            e_start - dispatch[0], dispatch[1] - dispatch[0],
+            e_end - e_start, readback[1] - readback[0],
+            readback[1] - e_end)])
+    out.update(
+        events_ms=rows,
+        least_lead_us=round(min(leads), 1),
+        median_lead_us=round(sorted(leads)[len(leads) // 2], 1),
+        least_tail_us=round(min(tails), 1),
+        largest_violation_us=round(max(0.0, -min(leads), -min(tails)), 1))
+    return out
+
+
+def main(argv=None) -> int:
+    from chipbench import run as harness
+    from chipbench import trace_reduce as tr
+
+    real_reduce, real_reduce_trace = tr.reduce, harness.reduce_trace
+
+    def reduce(recording, slice_ns, host_spans=(), top=10):
+        host = list(host_spans)
+        harness.say("clock check: " + json.dumps(
+            clock_check(recording, slice_ns, host)))
+        return real_reduce(recording, slice_ns, host, top)
+
+    def reduce_trace(trace_dir, marks, spans, out_dir=None):
+        t = time.monotonic()
+        out = real_reduce_trace(trace_dir, marks, spans, out_dir)
+        harness.say(f"reduce_trace: {time.monotonic() - t:.2f} s over "
+                    f"{len(spans)} spans")
+        return out
+
+    tr.reduce, harness.reduce_trace = reduce, reduce_trace
+    try:
+        return harness.main(argv)
+    finally:
+        tr.reduce, harness.reduce_trace = real_reduce, real_reduce_trace
+
+
+if __name__ == "__main__":
+    sys.exit(main())
