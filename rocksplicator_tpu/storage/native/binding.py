@@ -29,6 +29,8 @@ _u64p = ctypes.POINTER(ctypes.c_uint64)
 _i64p = ctypes.POINTER(ctypes.c_int64)
 
 
+_SHORT_CALL_BYTES = 64 * 1024
+
 _SRC = os.path.join(_DIR, "tsst_native.cc")
 _MAKEFILE = os.path.join(_DIR, "Makefile")
 
@@ -58,8 +60,17 @@ def _build() -> bool:
 
 
 class NativeLib:
-    def __init__(self, lib: ctypes.CDLL):
+    def __init__(self, so_path: str):
+        lib = ctypes.CDLL(so_path)
         self._lib = lib
+        # The same image through PyDLL: its calls KEEP the GIL. For
+        # microseconds of C on a request's path (a point lookup, the
+        # RLZ transform of a small frame on the event loop): a call
+        # that drops the GIL has to win it back behind whichever thread
+        # took it, up to a switch interval (5 ms) for ~10 us of work.
+        # Everything that runs for long goes through ``lib``.
+        held = ctypes.PyDLL(so_path)
+        self._held = held
         lib.tsst_crc32.restype = ctypes.c_uint32
         lib.tsst_crc32.argtypes = [_u8p, ctypes.c_uint64]
         lib.tsst_encode_block.restype = ctypes.c_int64
@@ -72,15 +83,15 @@ class NativeLib:
             _u8p, ctypes.c_uint64, ctypes.c_uint64,
             _u64p, _u64p, _u64p, _u8p, _u64p, _u64p,
         ]
-        lib.tsst_get_entries.restype = ctypes.c_int64
-        lib.tsst_get_entries.argtypes = [
+        held.tsst_get_entries.restype = ctypes.c_int64
+        held.tsst_get_entries.argtypes = [
             _u8p, ctypes.c_uint64, _u8p, ctypes.c_uint64, ctypes.c_uint64,
             _u64p, _u8p, _u64p, _u64p, ctypes.POINTER(ctypes.c_int32),
         ]
         # planar lookup may be absent in stale builds; probe and gate
         try:
-            lib.tsst_planar_get_entries.restype = ctypes.c_int64
-            lib.tsst_planar_get_entries.argtypes = [
+            held.tsst_planar_get_entries.restype = ctypes.c_int64
+            held.tsst_planar_get_entries.argtypes = [
                 _u8p, ctypes.c_uint64, _u8p, ctypes.c_uint64,
                 ctypes.c_uint64, _u64p, _u8p, _u8p, ctypes.c_uint64,
                 _u64p, ctypes.POINTER(ctypes.c_int32),
@@ -113,17 +124,47 @@ class NativeLib:
             self.has_merge_resolve_runs = False
         # RLZ codec may be absent in stale builds; probe and gate
         try:
-            lib.rlz_compress.restype = ctypes.c_int64
-            lib.rlz_compress.argtypes = [
-                _u8p, ctypes.c_uint64, _u8p, ctypes.c_uint64,
-            ]
-            lib.rlz_decompress.restype = ctypes.c_int64
-            lib.rlz_decompress.argtypes = [
-                _u8p, ctypes.c_uint64, _u8p, ctypes.c_uint64,
-            ]
+            for dll in (lib, held):
+                dll.rlz_compress.restype = ctypes.c_int64
+                dll.rlz_compress.argtypes = [
+                    _u8p, ctypes.c_uint64, _u8p, ctypes.c_uint64,
+                ]
+                dll.rlz_decompress.restype = ctypes.c_int64
+                dll.rlz_decompress.argtypes = [
+                    _u8p, ctypes.c_uint64, _u8p, ctypes.c_uint64,
+                ]
             self.has_rlz = True
         except AttributeError:
             self.has_rlz = False
+        # whole-file codecs (one GIL-free call per file) may be absent in
+        # stale builds; probe and gate
+        try:
+            lib.tsst_planar_encode_file.restype = ctypes.c_int64
+            lib.tsst_planar_encode_file.argtypes = [
+                _u32p, ctypes.c_uint32, _u32p, _u32p, _u8p,
+                _u32p, ctypes.c_uint32,
+                ctypes.c_uint64, ctypes.c_uint32, ctypes.c_uint32,
+                ctypes.c_int32, ctypes.c_uint32, ctypes.c_int32,
+                _u8p, ctypes.c_uint64, _u64p, _u32p, _u8p, _u32p,
+            ]
+            lib.tsst_decode_file_lanes.restype = ctypes.c_int64
+            lib.tsst_decode_file_lanes.argtypes = [
+                ctypes.c_int32, _u64p, ctypes.c_uint64, ctypes.c_int32,
+                _u32p, _u32p, ctypes.c_uint64, ctypes.c_uint32,
+                _u32p, _u32p, _u32p, _u32p, _u32p, _u32p, _u32p, _u32p,
+                ctypes.c_int32, ctypes.c_uint64, _u32p, _i64p,
+            ]
+            lib.tsst_read_block.restype = ctypes.c_int64
+            lib.tsst_read_block.argtypes = [
+                ctypes.c_int32, ctypes.c_uint64, ctypes.c_uint64,
+                ctypes.c_uint32, ctypes.POINTER(ctypes.c_void_p),
+                ctypes.c_int32, ctypes.c_uint64, _u32p,
+            ]
+            held.tsst_free.restype = None
+            held.tsst_free.argtypes = [ctypes.c_void_p]
+            self.has_file_codecs = True
+        except AttributeError:
+            self.has_file_codecs = False
         lib.wal_scan.restype = ctypes.c_int64
         lib.wal_scan.argtypes = [
             _u8p, ctypes.c_uint64, ctypes.c_uint64,
@@ -141,6 +182,12 @@ class NativeLib:
         ]
 
     # -- helpers -----------------------------------------------------------
+
+    def _for_bytes(self, n: int):
+        """The handle for a byte-codec call over ``n`` bytes: GIL kept
+        while the call is short (~1 GB/s: 64 KB is ~60 us), dropped for
+        anything longer."""
+        return self._held if n <= _SHORT_CALL_BYTES else self._lib
 
     @staticmethod
     def _u8(arr: np.ndarray):
@@ -225,7 +272,7 @@ class NativeLib:
         val_off = np.empty(max_matches, dtype=np.uint64)
         val_len = np.empty(max_matches, dtype=np.uint64)
         past_end = ctypes.c_int32(0)
-        n = self._lib.tsst_get_entries(
+        n = self._held.tsst_get_entries(
             self._u8(data), len(raw), self._u8(kbuf), len(key), max_matches,
             self._u64(seqs), self._u8(vtypes), self._u64(val_off),
             self._u64(val_len), ctypes.byref(past_end),
@@ -268,7 +315,7 @@ class NativeLib:
         vals = np.zeros((max_matches, max(1, vlen_cap)), dtype=np.uint8)
         val_len = np.empty(max_matches, dtype=np.uint64)
         past_end = ctypes.c_int32(0)
-        n = self._lib.tsst_planar_get_entries(
+        n = self._held.tsst_planar_get_entries(
             self._u8(data), len(raw), self._u8(kbuf), len(key),
             max_matches, self._u64(seqs), self._u8(vtypes),
             self._u8(vals), max(1, vlen_cap), self._u64(val_len),
@@ -366,6 +413,125 @@ class NativeLib:
         return (out_kw, out_klen, out_seq, out_vtype, out_vw, out_vlen,
                 int(count))
 
+    def planar_encode_file(self, arrays, count: int, klen: int, vlen: int,
+                           seq32: bool, block_entries: int,
+                           compression: int):
+        """Every PLANAR block of one file from lanes ``[0, count)`` in
+        ONE call (tsst_planar_encode_file; the GIL is released for all
+        of it). Returns ``(payload, offsets, sizes, codecs, checksums)``:
+        the blocks' bytes back to back in a u8 array, and per block its
+        offset there, size, index codec nibble and ``poly1w`` value.
+        None when the lanes are narrower than the widths ask (the Python
+        sink says what is wrong with them)."""
+        kw, vw = (klen + 3) // 4, (vlen + 3) // 4
+        kw_be = np.ascontiguousarray(
+            arrays["key_words_be"][:count], dtype=np.uint32)
+        val_words = np.ascontiguousarray(
+            arrays["val_words"][:count], dtype=np.uint32)
+        if (kw_be.ndim != 2 or val_words.ndim != 2
+                or kw_be.shape[1] < kw or val_words.shape[1] < vw):
+            return None
+        seq_lo = np.ascontiguousarray(
+            arrays["seq_lo"][:count], dtype=np.uint32)
+        seq_hi = np.ascontiguousarray(
+            arrays["seq_hi"][:count], dtype=np.uint32)
+        vtype = np.ascontiguousarray(arrays["vtype"][:count], dtype=np.uint8)
+        nblocks = (count + block_entries - 1) // block_entries
+        per_entry = 4 * (kw + 1 + (0 if seq32 else 1) + vw)
+        # a block's payload is never larger than its uncompressed bytes
+        cap = count * per_entry + nblocks * (16 + 4) + count
+        out = np.empty(cap, dtype=np.uint8)
+        offs = np.empty(nblocks, dtype=np.uint64)
+        sizes = np.empty(nblocks, dtype=np.uint32)
+        codecs = np.empty(nblocks, dtype=np.uint8)
+        chks = np.empty(nblocks, dtype=np.uint32)
+        wrote = self._lib.tsst_planar_encode_file(
+            kw_be.ctypes.data_as(_u32p), kw_be.shape[1],
+            seq_lo.ctypes.data_as(_u32p), seq_hi.ctypes.data_as(_u32p),
+            self._u8(vtype),
+            val_words.ctypes.data_as(_u32p), val_words.shape[1],
+            count, klen, vlen, int(seq32), block_entries, int(compression),
+            self._u8(out), cap, self._u64(offs),
+            sizes.ctypes.data_as(_u32p), self._u8(codecs),
+            chks.ctypes.data_as(_u32p),
+        )
+        if wrote < 0:
+            raise ValueError(f"tsst_planar_encode_file failed ({wrote})")
+        return out[:wrote], offs, sizes, codecs, chks
+
+    def decode_file_lanes(self, fd: int, index: np.ndarray, planar: bool,
+                          klen: int, vlen: int, rows: int,
+                          chk_mode: int = 0, chk_len: int = 0):
+        """Every block of one file -> the eight kernel lanes in ONE call
+        (tsst_decode_file_lanes: pread, inflate, transpose; the GIL is
+        released for all of it). ``index``: (nblocks, 3) u64 of offset,
+        size, codec nibble. ``klen == 0`` (row format only): infer the
+        uniform widths from block 0. ``chk_mode`` 1 / 2: also each
+        block's poly1 / poly1w value over ``chk_len``.
+
+        Returns ``(status, lanes, checksums, blocks)``: status >= 0 is
+        the row count (lanes cut to it); below zero the C routine's code
+        (-1 read, -2 corrupt block, -3 width drift, -4 more rows than
+        ``rows``) and lanes is None. ``blocks``: how many blocks it got
+        through, the one it stopped at included — that many checksums
+        are computed."""
+        index = np.ascontiguousarray(index, dtype=np.uint64)
+        nblocks = len(index)
+        chks = np.zeros(nblocks if chk_mode else 1, dtype=np.uint32)
+        klen_io = ctypes.c_uint32(klen)
+        vlen_io = ctypes.c_uint32(vlen)
+        err_block = ctypes.c_int64(-1)
+        val_cols = max(2, (vlen + 3) // 4)
+        while True:
+            lanes = {
+                "key_words_be": np.empty((rows, 6), dtype=np.uint32),
+                "key_words_le": np.empty((rows, 6), dtype=np.uint32),
+                "key_len": np.empty(rows, dtype=np.uint32),
+                "seq_hi": np.empty(rows, dtype=np.uint32),
+                "seq_lo": np.empty(rows, dtype=np.uint32),
+                "vtype": np.empty(rows, dtype=np.uint32),
+                "val_words": np.empty((rows, val_cols), dtype=np.uint32),
+                "val_len": np.empty(rows, dtype=np.uint32),
+            }
+            got = self._lib.tsst_decode_file_lanes(
+                fd, self._u64(index), nblocks, int(planar),
+                ctypes.byref(klen_io), ctypes.byref(vlen_io), rows,
+                val_cols,
+                *(lanes[f].ctypes.data_as(_u32p) for f in (
+                    "key_words_be", "key_words_le", "key_len", "seq_hi",
+                    "seq_lo", "vtype", "val_words", "val_len")),
+                chk_mode, chk_len, chks.ctypes.data_as(_u32p),
+                ctypes.byref(err_block),
+            )
+            if got != -5:
+                break
+            # inferred widths want wider value lanes: once more, exact
+            val_cols = max(2, (int(vlen_io.value) + 3) // 4)
+        if got < 0:
+            return int(got), None, chks, int(err_block.value) + 1
+        if got < rows:
+            lanes = {f: a[:got] for f, a in lanes.items()}
+        return int(got), lanes, chks, nblocks
+
+    def read_block(self, fd: int, off: int, size: int, codec: int,
+                   chk_mode: int = 0, chk_len: int = 0):
+        """One block for a point read: pread + inflate (+ ``chk_mode``
+        1 / 2: its poly1 / poly1w value over ``chk_len``) in ONE call
+        that drops the GIL once (tsst_read_block). ``(raw bytes,
+        checksum)``, or None when the block cannot be read or does not
+        inflate: the Python reader then says what is wrong with it."""
+        out = ctypes.c_void_p()
+        chk = ctypes.c_uint32(0)
+        n = self._lib.tsst_read_block(
+            fd, off, size, codec, ctypes.byref(out), chk_mode, chk_len,
+            ctypes.byref(chk))
+        if n < 0:
+            return None
+        try:
+            return ctypes.string_at(out, n), chk.value
+        finally:
+            self._held.tsst_free(out)
+
     def rlz_compress(self, data: bytes) -> bytes:
         from ..rlz import max_compressed_len
 
@@ -373,7 +539,7 @@ class NativeLib:
                else np.zeros(1, np.uint8))
         cap = max_compressed_len(len(data))
         out = np.empty(cap, dtype=np.uint8)
-        wrote = self._lib.rlz_compress(
+        wrote = self._for_bytes(len(data)).rlz_compress(
             self._u8(src), len(data), self._u8(out), cap)
         if wrote < 0:  # sized by max_compressed_len — cannot happen
             raise ValueError("rlz_compress overflow")
@@ -393,7 +559,7 @@ class NativeLib:
         # +32 slack enables the decoder's 16-byte wildcopy fast path
         # (it may scribble up to 15 bytes past the logical end)
         out = np.empty(declared + 32, dtype=np.uint8)
-        n = self._lib.rlz_decompress(
+        n = self._for_bytes(declared).rlz_decompress(
             self._u8(src), len(data), self._u8(out), declared + 32)
         if n < 0:
             return None
@@ -461,7 +627,7 @@ def _load() -> Optional[NativeLib]:
             if os.path.isfile(_SO) else ""))
         return None
     try:
-        return NativeLib(ctypes.CDLL(_SO))
+        return NativeLib(_SO)
     except (OSError, AttributeError) as e:
         _no_native(f"load failed: {e}")
         return None
@@ -492,6 +658,14 @@ def get_native() -> Optional[NativeLib]:
     return _native  # type: ignore[return-value]
 
 
+def get_file_codecs() -> Optional[NativeLib]:
+    """The library when it has the whole-file / whole-block codecs
+    (``has_file_codecs``), else None: their callers then take the Python
+    codecs."""
+    lib = get_native()
+    return lib if lib is not None and lib.has_file_codecs else None
+
+
 def rebuild_native() -> NativeLib:
     """Rebuild the library from ``tsst_native.cc`` and load THAT build,
     or raise. For runs that must show the library came from the
@@ -508,7 +682,7 @@ def rebuild_native() -> NativeLib:
             os.remove(_SO)
         if not _build():
             raise RuntimeError("native build failed (see the log)")
-        _native = NativeLib(ctypes.CDLL(_SO))
+        _native = NativeLib(_SO)
         return _native
 
 

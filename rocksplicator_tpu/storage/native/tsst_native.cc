@@ -13,11 +13,18 @@
 //   bloom       : register-blocked, FNV-1a over 6 LE u32 prefix words +
 //                 length word, murmur fmix32 finalizer, K=6 bits from 5-bit
 //                 slices of h2
+//   PLANAR block: storage/planar.py (header + u32 planes); whole-file
+//                 encode / decode at the end of this file
 
 #include <algorithm>
+#include <cerrno>
 #include <cstdint>
+#include <cstdlib>
 #include <cstring>
 #include <vector>
+
+#include <unistd.h>
+#include <zlib.h>
 
 extern "C" {
 
@@ -772,3 +779,388 @@ extern "C" int64_t cpu_merge_resolve_runs(
                        drop_tombstones, &out);
   return (int64_t)out.count;
 }
+
+// ---------------------------------------------------------------------------
+// Whole-file codecs: every block of one TSST file in ONE call
+// ---------------------------------------------------------------------------
+//
+// tpu/format.py's sink and source ran a Python loop per 4-32 KB block
+// (a dozen small numpy calls, zlib, the checksum). Eight of them at once
+// on a pool serialise on the interpreter; ctypes drops the GIL for a
+// whole call, so a file is one trip. Same bytes as the Python codecs
+// (parity-tested): block layout as storage/planar.py / sst.py, the
+// checksum as utils/checksum.py, zlib level 1 through the libz Python
+// itself links.
+
+namespace {
+
+const uint32_t CHK_R = 0x01000193u;  // utils/checksum.py CHK_R
+const uint64_t MAX_BLOCK_BYTES = 64ull << 20;  // sst.py's RLZ bound
+
+// r^1..r^n (wrapping u32), grown on demand: one table a call
+struct ChkPowers {
+  std::vector<uint32_t> p;
+  const uint32_t* upto(uint64_t n) {
+    uint64_t have = p.size();
+    if (have < n) {
+      p.resize(n);
+      uint32_t v = have ? p[have - 1] : 1u;
+      for (uint64_t i = have; i < n; i++) { v *= CHK_R; p[i] = v; }
+    }
+    return p.data();
+  }
+};
+
+// poly_checksum_words: sum (w_i + 1) * r^(i+1) over the words zero-
+// padded to `length` (a block longer than `length` sums over its own)
+static uint32_t chk_words(const uint8_t* data, uint64_t nwords,
+                          uint64_t length, ChkPowers* pw) {
+  uint64_t total = nwords > length ? nwords : length;
+  const uint32_t* p = pw->upto(total);
+  uint32_t h = 0;
+  for (uint64_t i = 0; i < nwords; i++)
+    h += (get_u32(data + 4 * i) + 1u) * p[i];
+  for (uint64_t i = nwords; i < total; i++) h += p[i];
+  return h;
+}
+
+// poly_checksum: the byte-domain variant (row-format device blocks)
+static uint32_t chk_bytes(const uint8_t* data, uint64_t n, uint64_t length,
+                          ChkPowers* pw) {
+  uint64_t total = n > length ? n : length;
+  const uint32_t* p = pw->upto(total);
+  uint32_t h = 0;
+  for (uint64_t i = 0; i < n; i++) h += ((uint32_t)data[i] + 1u) * p[i];
+  for (uint64_t i = n; i < total; i++) h += p[i];
+  return h;
+}
+
+static inline uint64_t planar_plane_words(uint64_t n, uint64_t kw,
+                                          uint64_t vw, bool seq32) {
+  return n * (kw + 1 + (seq32 ? 0 : 1) + vw) + (n + 3) / 4;
+}
+
+static bool pread_all(int fd, uint8_t* dst, uint64_t size, uint64_t off) {
+  while (size > 0) {
+    ssize_t got = pread(fd, dst, size, (off_t)off);
+    if (got < 0 && errno == EINTR) continue;
+    if (got <= 0) return false;  // error, or the file ends inside a block
+    dst += got; size -= (uint64_t)got; off += (uint64_t)got;
+  }
+  return true;
+}
+
+
+// codec nibbles of the block index (storage/sst.py)
+#define TSST_CODEC_NONE 0
+#define TSST_CODEC_ZLIB 1
+#define TSST_BLOCK_PLANAR 2
+#define TSST_BLOCK_PLANAR_ZLIB 3
+#define TSST_CODEC_RLZ 4
+#define TSST_BLOCK_PLANAR_RLZ 5
+
+// pread + inflate of one block after another, buffers and the zlib
+// stream kept between blocks. fetch() returns 0, -1 (the read failed or
+// came up short) or -2 (the block does not inflate / unknown codec);
+// *raw points into this object's buffers until the next fetch().
+struct BlockFetcher {
+  std::vector<uint8_t> payload, inflated;
+  z_stream zs;
+  bool zs_live = false;
+  BlockFetcher() { memset(&zs, 0, sizeof(zs)); }
+  ~BlockFetcher() { if (zs_live) inflateEnd(&zs); }
+
+  int fetch(int fd, uint64_t off, uint64_t size, uint64_t codec,
+            const uint8_t** raw, uint64_t* raw_len) {
+    if (size > MAX_BLOCK_BYTES) return -2;
+    payload.resize(size);
+    if (!pread_all(fd, payload.data(), size, off)) return -1;
+    *raw = payload.data();
+    *raw_len = size;
+    if (codec == TSST_CODEC_ZLIB || codec == TSST_BLOCK_PLANAR_ZLIB) {
+      if (!zs_live) {
+        if (inflateInit(&zs) != Z_OK) return -2;
+        zs_live = true;
+      } else if (inflateReset(&zs) != Z_OK) {
+        return -2;
+      }
+      if (inflated.size() < 65536) inflated.resize(65536);
+      zs.next_in = payload.data(); zs.avail_in = (uInt)size;
+      int z;
+      while (true) {
+        zs.next_out = inflated.data() + zs.total_out;
+        zs.avail_out = (uInt)(inflated.size() - zs.total_out);
+        z = inflate(&zs, Z_NO_FLUSH);
+        if (z != Z_OK || zs.avail_out != 0) break;
+        if (inflated.size() >= MAX_BLOCK_BYTES) break;
+        inflated.resize(inflated.size() * 2);
+      }
+      if (z != Z_STREAM_END) return -2;
+      *raw = inflated.data(); *raw_len = zs.total_out;
+    } else if (codec == TSST_CODEC_RLZ || codec == TSST_BLOCK_PLANAR_RLZ) {
+      if (size < 4) return -2;
+      uint64_t declared = get_u32(payload.data());
+      if (declared > MAX_BLOCK_BYTES) return -2;
+      if (inflated.size() < declared + 32) inflated.resize(declared + 32);
+      int64_t got = rlz_decompress(payload.data(), size, inflated.data(),
+                                   declared + 32);
+      if (got < 0) return -2;
+      *raw = inflated.data(); *raw_len = (uint64_t)got;
+    } else if (codec != TSST_CODEC_NONE && codec != TSST_BLOCK_PLANAR) {
+      return -2;
+    }
+    return 0;
+  }
+};
+
+}  // namespace
+
+// PLANAR sink: lanes [0, count) -> every block's payload, back to back
+// in `out` (capacity: the blocks' uncompressed bytes; a block is kept
+// compressed only when that is smaller). Per block: offset in `out`,
+// size, codec nibble, and the poly1w checksum over the uncompressed
+// plane words padded to a full block's. Returns the bytes written, -1
+// when `out_cap` is short, -2 on arguments the layout can't take, -3
+// when zlib fails.
+extern "C" int64_t tsst_planar_encode_file(
+    const uint32_t* kw_be, uint32_t kw_cols,
+    const uint32_t* seq_lo, const uint32_t* seq_hi, const uint8_t* vtype,
+    const uint32_t* val_words, uint32_t val_cols,
+    uint64_t count, uint32_t klen, uint32_t vlen, int32_t seq32,
+    uint32_t block_entries, int32_t compression,
+    uint8_t* out, uint64_t out_cap,
+    uint64_t* blk_off, uint32_t* blk_size, uint8_t* blk_codec,
+    uint32_t* blk_chk) {
+  uint64_t kw = (klen + 3) / 4, vw = ((uint64_t)vlen + 3) / 4;
+  if (klen == 0 || klen > 24 || vlen > 0xFFFFu || block_entries == 0
+      || kw > kw_cols || vw > val_cols || (!seq32 && seq_hi == nullptr))
+    return -2;
+  uint64_t full_words = planar_plane_words(block_entries, kw, vw, seq32);
+  ChkPowers pw;
+  z_stream zs;
+  memset(&zs, 0, sizeof(zs));
+  bool zlib_on = compression == TSST_CODEC_ZLIB;
+  bool rlz_on = compression == TSST_CODEC_RLZ;
+  std::vector<uint8_t> scratch;
+  if (zlib_on && deflateInit(&zs, 1) != Z_OK) return -3;
+  uint64_t pos = 0;
+  int64_t rc = 0;
+  for (uint64_t start = 0, bi = 0; start < count;
+       start += block_entries, bi++) {
+    uint64_t n = std::min<uint64_t>(block_entries, count - start);
+    uint64_t words = planar_plane_words(n, kw, vw, seq32);
+    uint64_t raw_len = 16 + 4 * words;
+    if (pos + raw_len > out_cap) { rc = -1; break; }
+    uint8_t* raw = out + pos;
+    put_u32(raw, (uint32_t)n);
+    raw[4] = (uint8_t)klen; raw[5] = (uint8_t)(vlen & 0xFF);
+    raw[6] = seq32 ? 1 : 0; raw[7] = (uint8_t)(vlen >> 8);
+    put_u64(raw + 8, 0);
+    uint8_t* w = raw + 16;
+    for (uint64_t k = 0; k < kw; k++)
+      for (uint64_t i = 0; i < n; i++, w += 4)
+        put_u32(w, kw_be[(start + i) * kw_cols + k]);
+    memcpy(w, seq_lo + start, 4 * n); w += 4 * n;
+    if (!seq32) { memcpy(w, seq_hi + start, 4 * n); w += 4 * n; }
+    uint64_t vt_bytes = 4 * ((n + 3) / 4);
+    memcpy(w, vtype + start, n);
+    memset(w + n, 0, vt_bytes - n);
+    w += vt_bytes;
+    for (uint64_t k = 0; k < vw; k++)
+      for (uint64_t i = 0; i < n; i++, w += 4)
+        put_u32(w, val_words[(start + i) * val_cols + k]);
+    blk_chk[bi] = chk_words(raw + 16, words, full_words, &pw);
+    uint64_t size = raw_len;
+    uint8_t codec = TSST_BLOCK_PLANAR;
+    if (zlib_on) {
+      if (deflateReset(&zs) != Z_OK) { rc = -3; break; }
+      scratch.resize(deflateBound(&zs, raw_len));
+      zs.next_in = raw; zs.avail_in = (uInt)raw_len;
+      zs.next_out = scratch.data(); zs.avail_out = (uInt)scratch.size();
+      if (deflate(&zs, Z_FINISH) != Z_STREAM_END) { rc = -3; break; }
+      if (zs.total_out < raw_len) {
+        size = zs.total_out; codec = TSST_BLOCK_PLANAR_ZLIB;
+      }
+    } else if (rlz_on) {
+      scratch.resize(4 + raw_len + (raw_len + 126) / 127 + 3);
+      int64_t z = rlz_compress(raw, raw_len, scratch.data(),
+                               scratch.size());
+      if (z >= 0 && (uint64_t)z < raw_len) {
+        size = (uint64_t)z; codec = TSST_BLOCK_PLANAR_RLZ;
+      }
+    }
+    if (codec != TSST_BLOCK_PLANAR) memcpy(raw, scratch.data(), size);
+    blk_off[bi] = pos; blk_size[bi] = (uint32_t)size; blk_codec[bi] = codec;
+    pos += size;
+  }
+  if (zlib_on) deflateEnd(&zs);
+  return rc < 0 ? rc : (int64_t)pos;
+}
+
+// Lane source: pread + inflate every block of one file and fill the
+// eight kernel lanes (the arrays tpu/format.py's Python decoders
+// return). `index` is (nblocks, 3) u64: offset, size, codec nibble.
+// planar != 0: PLANAR blocks, widths as the file's props give them.
+// planar == 0: entry-stream blocks of ONE uniform stride; *klen_io == 0
+// asks for the widths to be inferred from block 0, as
+// _infer_uniform_widths does. chk_mode 1 / 2: each block's poly1 (bytes)
+// / poly1w (plane words) value over `chk_len`, into blk_chk: the caller
+// holds them against the file's block_chk prop.
+//
+// Returns the rows decoded, or
+//   -1  a read failed or came up short          (*err_block: which)
+//   -2  a block is corrupt: inflate, layout, codec
+//   -3  widths drift / no uniform stride: not lanes, the tuple path's
+//   -4  more rows than row_cap
+//   -5  inferred widths need another val_cols: *klen_io / *vlen_io are
+//       filled, call again with them
+extern "C" int64_t tsst_decode_file_lanes(
+    int32_t fd, const uint64_t* index, uint64_t nblocks, int32_t planar,
+    uint32_t* klen_io, uint32_t* vlen_io, uint64_t row_cap,
+    uint32_t val_cols,
+    uint32_t* kw_be, uint32_t* kw_le, uint32_t* key_len,
+    uint32_t* seq_hi, uint32_t* seq_lo, uint32_t* vtype,
+    uint32_t* val_words, uint32_t* val_len,
+    int32_t chk_mode, uint64_t chk_len, uint32_t* blk_chk,
+    int64_t* err_block) {
+  uint32_t klen = *klen_io, vlen = *vlen_io;
+  bool infer = !planar && klen == 0;
+  if (!infer && (klen == 0 || klen > 24)) return -2;
+  ChkPowers pw;
+  BlockFetcher fetcher;
+  uint64_t row = 0;
+  int64_t rc = 0;
+  for (uint64_t bi = 0; bi < nblocks && rc == 0; bi++) {
+    *err_block = (int64_t)bi;
+    const uint8_t* raw;
+    uint64_t raw_len;
+    rc = fetcher.fetch(fd, index[3 * bi], index[3 * bi + 1],
+                       index[3 * bi + 2], &raw, &raw_len);
+    if (rc < 0) break;
+
+    if (planar) {
+      if (raw_len < 16) { rc = -2; break; }
+      uint64_t n = get_u32(raw);
+      uint32_t bklen = raw[4];
+      uint32_t bvlen = (uint32_t)raw[5] | ((uint32_t)raw[7] << 8);
+      bool seq32 = raw[6] & 1;
+      if (bklen == 0 || bklen > 24) { rc = -2; break; }
+      uint64_t kw = (bklen + 3) / 4, vw = ((uint64_t)bvlen + 3) / 4;
+      uint64_t words = planar_plane_words(n, kw, vw, seq32);
+      if (raw_len != 16 + 4 * words) { rc = -2; break; }
+      if (chk_mode == 2)
+        blk_chk[bi] = chk_words(raw + 16, words, chk_len, &pw);
+      if (bklen != klen || bvlen != vlen || vw > val_cols) {
+        rc = -3; break;
+      }
+      if (row + n > row_cap) { rc = -4; break; }
+      const uint8_t* kwp = raw + 16;
+      const uint8_t* slo = kwp + 4 * kw * n;
+      const uint8_t* shi = seq32 ? nullptr : slo + 4 * n;
+      const uint8_t* vtp = slo + 4 * n * (seq32 ? 1 : 2);
+      const uint8_t* vvp = vtp + 4 * ((n + 3) / 4);
+      // bytes of the last key word past klen are not key: zeroed, as
+      // decode_planar_block's key_buf[:, :klen] leaves them
+      uint32_t tail_mask = (klen % 4)
+          ? 0xFFFFFFFFu << (8 * (4 - klen % 4)) : 0xFFFFFFFFu;
+      for (uint64_t i = 0; i < n; i++) {
+        uint64_t r = row + i;
+        for (uint64_t k = 0; k < 6; k++) {
+          uint32_t be = 0;
+          if (k < kw) {
+            be = get_u32(kwp + 4 * (k * n + i));
+            if (k == kw - 1) be &= tail_mask;
+          }
+          kw_be[r * 6 + k] = be;
+          kw_le[r * 6 + k] = __builtin_bswap32(be);
+        }
+        key_len[r] = klen;
+        seq_lo[r] = get_u32(slo + 4 * i);
+        seq_hi[r] = shi ? get_u32(shi + 4 * i) : 0u;
+        uint32_t vt = vtp[i];
+        vtype[r] = vt;
+        val_len[r] = vt == 2 ? 0u : vlen;
+        for (uint64_t k = 0; k < val_cols; k++)
+          val_words[r * val_cols + k] =
+              k < vw ? get_u32(vvp + 4 * (k * n + i)) : 0u;
+      }
+      row += n;
+      continue;
+    }
+
+    if (chk_mode == 1) blk_chk[bi] = chk_bytes(raw, raw_len, chk_len, &pw);
+    if (infer) {
+      // _infer_uniform_widths over block 0
+      if (raw_len < 17) { rc = -3; break; }
+      klen = get_u32(raw);
+      if (klen == 0 || klen > 24 || raw_len < 17 + (uint64_t)klen) {
+        rc = -3; break;
+      }
+      vlen = get_u32(raw + klen + 13);
+      if (raw_len % (17 + (uint64_t)klen + vlen)) { rc = -3; break; }
+      infer = false;
+      *klen_io = klen; *vlen_io = vlen;
+    }
+    uint64_t need_cols = std::max<uint64_t>(2, ((uint64_t)vlen + 3) / 4);
+    if (need_cols != val_cols) { rc = -5; break; }
+    uint64_t stride = 17 + (uint64_t)klen + vlen;
+    if (raw_len % stride) { rc = -3; break; }
+    uint64_t n = raw_len / stride;
+    if (row + n > row_cap) { rc = -4; break; }
+    for (uint64_t i = 0; i < n; i++) {
+      const uint8_t* e = raw + i * stride;
+      if (get_u32(e) != klen || get_u32(e + klen + 13) != vlen) {
+        rc = -3; break;
+      }
+      uint64_t r = row + i;
+      uint8_t key[24];
+      memset(key, 0, 24);
+      memcpy(key, e + 4, klen);
+      for (int k = 0; k < 6; k++) {
+        uint32_t le = get_u32(key + 4 * k);
+        kw_le[r * 6 + k] = le;
+        kw_be[r * 6 + k] = __builtin_bswap32(le);
+      }
+      key_len[r] = klen;
+      uint64_t seq = get_u64(e + 4 + klen);
+      seq_hi[r] = (uint32_t)(seq >> 32);
+      seq_lo[r] = (uint32_t)seq;
+      vtype[r] = e[klen + 12];
+      val_len[r] = vlen;
+      uint32_t* vdst = val_words + r * val_cols;
+      memset(vdst, 0, 4 * (size_t)val_cols);
+      memcpy(vdst, e + klen + 17, vlen);
+    }
+    row += n;
+  }
+  return rc < 0 ? rc : (int64_t)row;
+}
+
+// One block for a point read: pread + inflate in ONE call (the Python
+// reader made two that each drop the GIL, os.pread and zlib.decompress,
+// and a numpy pass for the checksum). *out is malloc'ed here and freed
+// by tsst_free. chk_mode as above; a PLANAR block whose plane bytes are
+// not whole words is -2. Returns the block's uncompressed length, -1 /
+// -2 as BlockFetcher::fetch.
+extern "C" int64_t tsst_read_block(
+    int32_t fd, uint64_t off, uint64_t size, uint32_t codec,
+    uint8_t** out, int32_t chk_mode, uint64_t chk_len, uint32_t* chk) {
+  BlockFetcher fetcher;
+  const uint8_t* raw;
+  uint64_t raw_len;
+  int rc = fetcher.fetch(fd, off, size, codec, &raw, &raw_len);
+  if (rc < 0) return rc;
+  ChkPowers pw;
+  if (chk_mode == 2) {
+    if (raw_len < 16 || (raw_len - 16) % 4) return -2;
+    *chk = chk_words(raw + 16, (raw_len - 16) / 4, chk_len, &pw);
+  } else if (chk_mode == 1) {
+    *chk = chk_bytes(raw, raw_len, chk_len, &pw);
+  }
+  *out = (uint8_t*)malloc(raw_len ? raw_len : 1);
+  if (*out == nullptr) return -1;
+  memcpy(*out, raw, raw_len);
+  return (int64_t)raw_len;
+}
+
+extern "C" void tsst_free(uint8_t* p) { free(p); }

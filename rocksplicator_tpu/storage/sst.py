@@ -259,18 +259,30 @@ class SSTWriter:
         path: blocks arrive already packed (and optionally compressed) and
         are appended without re-serialization. ``codec`` overrides the
         compressed flag for non-entry-stream encodings (BLOCK_PLANAR*)."""
-        if self._block:
-            self._flush_block()
-        self._file.write(block_payload)
         if codec is None:
             codec = COMPRESSION_ZLIB if compressed else COMPRESSION_NONE
-        self._index.append(
-            (last_key, self._offset, len(block_payload), codec)
-        )
-        self._offset += len(block_payload)
+        self.add_encoded_blocks(
+            block_payload, [(last_key, 0, len(block_payload), codec)],
+            num_entries, keys, min_key, max_key, min_seq, max_seq)
+
+    def add_encoded_blocks(self, payload, blocks, num_entries: int,
+                           keys: List[bytes], min_key: bytes,
+                           max_key: bytes, min_seq: int, max_seq: int
+                           ) -> None:
+        """A run of pre-encoded data blocks, back to back in ONE buffer
+        (written once): ``blocks`` is ``[(last_key, offset in payload,
+        size, codec)]`` in key order; the keys and seqs are the run's."""
+        if self._block:
+            self._flush_block()
+        self._file.write(payload)
+        base = self._offset
+        self._index.extend(
+            (last_key, base + off, size, codec)
+            for last_key, off, size, codec in blocks)
+        self._offset += len(payload)
         self._keys.extend(keys)
         self._num_entries += num_entries
-        self._raw_bytes += len(block_payload)
+        self._raw_bytes += len(payload)
         if self._min_key is None:
             self._min_key = min_key
         self._max_key = max_key
@@ -438,22 +450,25 @@ class SSTReader:
                 Stats.get().incr("storage.block_cache.hit")
                 return raw
         _last_key, off, size, codec = self._index[block_idx]
-        payload = os.pread(self._fd, size, off)
-        if codec in (COMPRESSION_ZLIB, BLOCK_PLANAR_ZLIB):
-            raw = zlib.decompress(payload)
-        elif codec in (COMPRESSION_RLZ, BLOCK_PLANAR_RLZ):
-            # bound: a block decodes to at most a handful of block_bytes
-            # (the writer flushes at the threshold); 64 MiB is far above
-            # any legitimate block and guards a crafted header
-            raw = rlz.decompress(payload, 64 << 20)
-        elif codec in (COMPRESSION_NONE, BLOCK_PLANAR):
-            raw = payload
-        else:
-            # a file from a newer writer (future codec) must fail LOUDLY,
-            # not parse compressed bytes as entries
-            raise Corruption(
-                f"unsupported block codec {codec} (newer writer?)")
-        self._verify_block_chk(block_idx, raw)
+        raw = self._read_block_native(block_idx, off, size, codec)
+        if raw is None:
+            payload = os.pread(self._fd, size, off)
+            if codec in (COMPRESSION_ZLIB, BLOCK_PLANAR_ZLIB):
+                raw = zlib.decompress(payload)
+            elif codec in (COMPRESSION_RLZ, BLOCK_PLANAR_RLZ):
+                # bound: a block decodes to at most a handful of
+                # block_bytes (the writer flushes at the threshold);
+                # 64 MiB is far above any legitimate block and guards a
+                # crafted header
+                raw = rlz.decompress(payload, 64 << 20)
+            elif codec in (COMPRESSION_NONE, BLOCK_PLANAR):
+                raw = payload
+            else:
+                # a file from a newer writer (future codec) must fail
+                # LOUDLY, not parse compressed bytes as entries
+                raise Corruption(
+                    f"unsupported block codec {codec} (newer writer?)")
+            self._verify_block_chk(block_idx, raw)
         if cache is not None and fill_cache:
             # only verified payloads enter the cache (a cached block skips
             # re-verification, like the _verified_blocks memo)
@@ -461,35 +476,84 @@ class SSTReader:
             cache.put(self._cache_token, block_idx, raw)
         return raw
 
+    def _read_block_native(self, block_idx: int, off: int, size: int,
+                           codec: int) -> Optional[bytes]:
+        """The block through ONE native call (pread, inflate, its
+        ``block_chk`` value; the GIL dropped once, where ``os.pread``,
+        ``zlib.decompress`` and the numpy checksum each queue for the
+        interpreter). None without the library, and for a block it
+        cannot read or inflate: the Python path says what is wrong."""
+        from .native.binding import get_file_codecs
+
+        lib = get_file_codecs()
+        if lib is None:
+            return None
+        want = self._chk_want(block_idx)
+        mode = 0 if want is None else (2 if want[0] == "poly1w" else 1)
+        got = lib.read_block(self._fd, off, size, codec, mode,
+                             want[1] if want else 0)
+        if got is None:
+            return None
+        raw, chk = got
+        if want is not None:
+            self._check_block_chk(block_idx, chk, want[2])
+        return raw
+
     def _block_is_planar(self, block_idx: int) -> bool:
         return self._index[block_idx][3] in (
             BLOCK_PLANAR, BLOCK_PLANAR_ZLIB, BLOCK_PLANAR_RLZ)
 
+    def block_chk_spec(self):
+        """The "block_chk" prop as ``(algo, block_len, values)``, or None
+        when the file has none. Crafted/foreign prop shapes read as none
+        (same convention as the 'uniform' prop): they degrade to no
+        verification rather than raising arbitrary exceptions."""
+        chk = self.props.get("block_chk")
+        try:
+            if (not isinstance(chk, dict)
+                    or chk.get("algo") not in ("poly1", "poly1w")):
+                return None
+            algo = chk["algo"]
+            values = chk["values"]
+            if not isinstance(values, list):
+                return None
+            block_len = int(chk["block_words" if algo == "poly1w"
+                                else "block_bytes"])
+        except (KeyError, TypeError, ValueError):
+            return None
+        return algo, block_len, values
+
+    def _chk_want(self, block_idx: int):
+        """``(algo, block_len, value)`` this block is still to be held
+        to, or None: no prop, no value for it, or verified before (the
+        memo keeps repeated point lookups from recomputing it)."""
+        spec = self.block_chk_spec()
+        if spec is None:
+            return None
+        algo, block_len, values = spec
+        if block_idx >= len(values) or block_idx in self._verified_blocks:
+            return None
+        try:
+            return algo, block_len, int(values[block_idx]) & 0xFFFFFFFF
+        except (TypeError, ValueError):
+            return None  # foreign/crafted prop — treat as absent
+
+    def _check_block_chk(self, block_idx: int, got: int, want: int) -> None:
+        if got != want:
+            raise Corruption(
+                f"block {block_idx} checksum mismatch: "
+                f"{got:#010x} != {want:#010x}"
+            )
+        self._verified_blocks.add(block_idx)
+
     def _verify_block_chk(self, block_idx: int, raw: bytes) -> None:
         """Device-computed per-block integrity checksums (props
         "block_chk", written by the TPU sink — ops/block_encode.py).
-        Files without the prop (v1 / flush-written) skip verification;
-        crafted/foreign prop shapes degrade to no verification rather
-        than raising arbitrary exceptions (same convention as the
-        'uniform' prop). A verified block index is cached so repeated
-        point lookups don't recompute the checksum."""
-        chk = self.props.get("block_chk")
-        try:
-            if (
-                not isinstance(chk, dict)
-                or chk.get("algo") not in ("poly1", "poly1w")
-                or block_idx >= len(chk["values"])
-                or block_idx in self._verified_blocks
-            ):
-                return
-            algo = chk["algo"]
-            want = int(chk["values"][block_idx]) & 0xFFFFFFFF
-            if algo == "poly1w":
-                block_len = int(chk["block_words"])
-            else:
-                block_len = int(chk["block_bytes"])
-        except (KeyError, TypeError, ValueError):
-            return  # foreign/crafted prop — treat as absent
+        Files without the prop (v1 / flush-written) skip verification."""
+        chk = self._chk_want(block_idx)
+        if chk is None:
+            return
+        algo, block_len, want = chk
         if algo == "poly1w":
             # word-domain MAC over a planar block's plane words (the
             # 16-byte header is host-written and excluded)
@@ -513,12 +577,7 @@ class SSTReader:
             from ..utils.checksum import poly_checksum
 
             got = poly_checksum(raw, length=block_len)
-        if got != want:
-            raise Corruption(
-                f"block {block_idx} checksum mismatch: "
-                f"{got:#010x} != {want:#010x}"
-            )
-        self._verified_blocks.add(block_idx)
+        self._check_block_chk(block_idx, got, want)
 
     @staticmethod
     def _iter_block(raw: bytes) -> Iterator[Tuple[bytes, int, int, bytes]]:

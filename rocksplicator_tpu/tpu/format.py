@@ -15,17 +15,45 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from ..observability.context import current_span
 from ..storage.bloom import BloomFilter
-from ..storage.planar import (decode_planar_block, encode_planar_block,
-                              plane_words, planar_props)
+from ..storage.errors import Corruption
+from ..storage.native.binding import get_file_codecs
+from ..storage.planar import (PLANAR_MAX_VLEN, decode_planar_block,
+                              encode_planar_block, plane_words, planar_props)
 from ..storage import rlz
 from ..storage.sst import (BLOCK_PLANAR, BLOCK_PLANAR_RLZ,
-                           BLOCK_PLANAR_ZLIB, COMPRESSION_RLZ,
-                           COMPRESSION_ZLIB,
+                           BLOCK_PLANAR_ZLIB, COMPRESSION_NONE,
+                           COMPRESSION_RLZ, COMPRESSION_ZLIB,
                            ENTRY_FIXED_OVERHEAD, SSTWriter)
 from ..utils.checksum import poly_checksum_words
+from ..utils.stats import Stats
 
 _ENTRY_FIXED_OVERHEAD = ENTRY_FIXED_OVERHEAD
+
+# Whole-file native codecs (storage/native tsst_planar_encode_file /
+# tsst_decode_file_lanes): a file's blocks are encoded, or decoded, by ONE
+# call that holds no GIL, where the Python codecs below make a dozen
+# interpreter trips per block — eight pool threads at once serialise on
+# those. Which codec takes a file is decided by what the code sees: the
+# library with the symbols, block codecs it knows, props it understands.
+# Everything else stays with the Python codecs. Same bytes, same lanes,
+# same checksums verified (tests/test_native.py).
+_ROW_CODECS = (COMPRESSION_NONE, COMPRESSION_ZLIB, COMPRESSION_RLZ)
+_PLANAR_CODECS = (BLOCK_PLANAR, BLOCK_PLANAR_ZLIB, BLOCK_PLANAR_RLZ)
+_NOT_TAKEN = object()  # the native source leaves the file to Python
+
+
+def _count_codec(native: bool) -> None:
+    """One file through a lane codec: counted by which codec took it
+    (``codec.native_files`` / ``codec.python_files``), and noted on the
+    open span as ``native`` — 1 while every file under it went native."""
+    Stats.get().incr(
+        "codec.native_files" if native else "codec.python_files")
+    span = current_span()
+    if span is not None and span.sampled:
+        span.annotate(
+            native=int(native and span.annotations.get("native", 1)))
 
 
 def uniform_widths(arrays: Dict[str, np.ndarray], count: int):
@@ -80,8 +108,80 @@ def read_sst_arrays(reader) -> Optional[Dict[str, np.ndarray]]:
     straight into kernel lanes (no per-entry Python). Returns the arrays
     dict (+ implicit count = rows) or None when the file lacks the uniform
     property (flush-written / foreign files use the tuple path)."""
-    if reader.props.get("planar"):
-        return _read_planar_arrays(reader)
+    planar = bool(reader.props.get("planar"))
+    lanes = _read_lanes_native(reader, planar)
+    _count_codec(lanes is not _NOT_TAKEN and lanes is not None)
+    if lanes is _NOT_TAKEN:
+        lanes = (_read_planar_arrays(reader) if planar
+                 else _read_uniform_arrays(reader))
+    # ingestion-time global seqno overrides per-entry seqs, same as the
+    # reader's _effective_seq
+    if lanes is not None and reader.global_seqno is not None:
+        n = len(lanes["seq_lo"])
+        lanes["seq_lo"] = np.full(
+            n, reader.global_seqno & 0xFFFFFFFF, dtype=np.uint32)
+        lanes["seq_hi"] = np.full(
+            n, reader.global_seqno >> 32, dtype=np.uint32)
+    return lanes
+
+
+def _read_lanes_native(reader, planar: bool):
+    """The whole file through ONE native call (pread, inflate, transpose
+    into the lanes, each block's checksum). ``_NOT_TAKEN`` when there is
+    no library or the file is not one it reads (unknown block codec,
+    props it does not understand): the Python source decides about
+    those. None on width drift (the tuple path's file). Raises
+    Corruption for a block that does not inflate, does not fit its
+    layout, or fails its ``block_chk`` value."""
+    lib = get_file_codecs()
+    if lib is None or not reader._index or not reader.num_entries:
+        return _NOT_TAKEN
+    props = reader.props
+    widths = props.get("planar") if planar else props.get("uniform")
+    klen = vlen = 0  # row format without the sink's prop: inferred
+    if planar or widths:
+        try:
+            klen, vlen = int(widths[0]), int(widths[1])
+        except (TypeError, ValueError, IndexError, KeyError):
+            return _NOT_TAKEN
+        if not (0 < klen <= 24) or not (0 <= vlen <= PLANAR_MAX_VLEN):
+            return _NOT_TAKEN
+    index = np.array([e[1:] for e in reader._index], dtype=np.uint64)
+    if not np.isin(index[:, 2],
+                   _PLANAR_CODECS if planar else _ROW_CODECS).all():
+        return _NOT_TAKEN
+    chk_mode, chk_len, want = 0, 0, ()
+    spec = reader.block_chk_spec()
+    if spec is not None:
+        algo, chk_len, want = spec
+        if (algo == "poly1w") != planar:
+            return _NOT_TAKEN
+        chk_mode = 2 if planar else 1
+    got, lanes, chks, done = lib.decode_file_lanes(
+        reader._fd, index, planar, klen, vlen, int(reader.num_entries),
+        chk_mode, chk_len)
+    if got in (-1, -2):
+        raise Corruption(
+            f"{reader._path}: block {done - 1} "
+            + ("could not be read" if got == -1 else "is corrupt"))
+    # every block it got through is held to its block_chk value first,
+    # as _read_block holds it before anything reads the block
+    for i, value in enumerate(want[:done] if chk_mode else ()):
+        try:
+            value = int(value) & 0xFFFFFFFF
+        except (TypeError, ValueError):
+            continue  # foreign/crafted prop — treat as absent
+        if int(chks[i]) != value:
+            raise Corruption(
+                f"block {i} checksum mismatch: "
+                f"{int(chks[i]):#010x} != {value:#010x}")
+    if got == -4:
+        return _NOT_TAKEN  # more rows than the footer says
+    return lanes  # None on width drift (-3)
+
+
+def _read_uniform_arrays(reader) -> Optional[Dict[str, np.ndarray]]:
+    """Row-format source path: blocks joined, decoded as one row matrix."""
     from ..ops.kv_format import UnsupportedBatch
 
     # Validate BEFORE reading the whole file: a file the array path will
@@ -114,18 +214,9 @@ def read_sst_arrays(reader) -> Optional[Dict[str, np.ndarray]]:
         ]
     raw = b"".join(blocks)
     try:
-        lanes = _decode_uniform_rows(raw, klen, vlen)
+        return _decode_uniform_rows(raw, klen, vlen)
     except UnsupportedBatch:
         return None  # misaligned/non-uniform — tuple path handles it
-    # ingestion-time global seqno overrides per-entry seqs, same as the
-    # reader's _effective_seq
-    if reader.global_seqno is not None:
-        n = len(lanes["seq_lo"])
-        lanes["seq_lo"] = np.full(
-            n, reader.global_seqno & 0xFFFFFFFF, dtype=np.uint32)
-        lanes["seq_hi"] = np.full(
-            n, reader.global_seqno >> 32, dtype=np.uint32)
-    return lanes
 
 
 class SstBlockLaneSource:
@@ -311,10 +402,15 @@ def planar_widths(arrays: Dict[str, np.ndarray], count: int):
     # Header bound (u16 vlen): wider values take the entry-stream sink.
     # The round-2 crash was this check missing — every uniform workload
     # with values >= 256 B died in the header packer (VERDICT r2 #1).
-    from ..storage.planar import PLANAR_MAX_VLEN
     if v0 > PLANAR_MAX_VLEN:
         return None
     return k0, v0
+
+
+def _key_rows(arrays: Dict[str, np.ndarray], rows, klen: int) -> np.ndarray:
+    """(len(rows), klen) u8: the key bytes of the given rows."""
+    kw = np.ascontiguousarray(arrays["key_words_be"][rows].astype(">u4"))
+    return kw.view(np.uint8).reshape(len(kw), 24)[:, :klen]
 
 
 def _write_planar(
@@ -324,83 +420,57 @@ def _write_planar(
     device_words: Optional[np.ndarray],
     device_checksums: Optional[np.ndarray],
 ) -> Optional[dict]:
-    """PLANAR sink body: per-block plane bytes + word-domain checksums."""
+    """PLANAR sink body: per-block plane bytes + word-domain checksums.
+    Without device-encoded words the native library, where it is, makes
+    every block in one call; else ``_planar_blocks`` does, block by
+    block. The file is the same."""
     seq32 = bool((arrays["seq_hi"][:count] == 0).all())
     full_words = plane_words(block_entries, klen, vlen, seq32)
+    lib = get_file_codecs() if device_words is None else None
+    encoded = None
+    if lib is not None:
+        encoded = lib.planar_encode_file(
+            arrays, count, klen, vlen, seq32, block_entries, compression)
+    _count_codec(encoded is not None)
     writer = SSTWriter(path, compression=compression,
                        bits_per_key=bits_per_key)
     try:
-        key_bytes = (
-            np.ascontiguousarray(
-                arrays["key_words_be"][:count].astype(">u4"))
-            .view(np.uint8).reshape(count, 24)[:, :klen]
-        )
+        if encoded is None:
+            encoded = _planar_blocks(
+                arrays, count, block_entries, compression, klen, vlen,
+                seq32, full_words, device_words, device_checksums)
+        payload, offs, sizes, codecs, chks = encoded
+        ends = np.minimum(
+            np.arange(1, len(offs) + 1) * block_entries, count)
+        last_keys = _key_rows(arrays, ends - 1, klen).tobytes()
+        first_key = _key_rows(arrays, slice(0, 1), klen).tobytes()
         seqs = (
             arrays["seq_hi"][:count].astype(np.uint64) << np.uint64(32)
         ) | arrays["seq_lo"][:count].astype(np.uint64)
-        from ..storage.planar import (PLANAR_HEADER, PLANAR_FLAG_SEQ32,
-                                      pack_planar_header)
-
-        chks: List[int] = []
-        nblocks = (count + block_entries - 1) // block_entries
-        for bi, start in enumerate(range(0, count, block_entries)):
-            end = min(start + block_entries, count)
-            full = end - start == block_entries
-            if device_words is not None and full and bi < len(device_words):
-                words = np.ascontiguousarray(
-                    device_words[bi], dtype="<u4")
-                raw = pack_planar_header(
-                    block_entries, klen, vlen,
-                    PLANAR_FLAG_SEQ32 if seq32 else 0,
-                ) + words.tobytes()
-                if device_checksums is not None and bi < len(
-                        device_checksums):
-                    chks.append(int(device_checksums[bi]))
-                else:
-                    chks.append(poly_checksum_words(words, full_words))
-            else:
-                raw = encode_planar_block(
-                    arrays, start, end, klen, vlen, seq32)
-                words = np.frombuffer(
-                    raw, dtype="<u4", offset=PLANAR_HEADER.size)
-                chks.append(poly_checksum_words(words, full_words))
-            codec = BLOCK_PLANAR
-            payload = raw
-            if compression == COMPRESSION_ZLIB:
-                z = zlib.compress(raw, 1)
-                if len(z) < len(raw):
-                    codec, payload = BLOCK_PLANAR_ZLIB, z
-            elif compression == COMPRESSION_RLZ:
-                z = rlz.compress(raw)
-                if len(z) < len(raw):
-                    codec, payload = BLOCK_PLANAR_RLZ, z
-            writer.add_encoded_block(
-                payload,
-                last_key=key_bytes[end - 1].tobytes(),
-                num_entries=end - start,
-                keys=[],
-                min_key=key_bytes[start].tobytes(),
-                max_key=key_bytes[end - 1].tobytes(),
-                min_seq=int(seqs[start:end].min()),
-                max_seq=int(seqs[start:end].max()),
-                compressed=False,
-                codec=codec,
-            )
+        writer.add_encoded_blocks(
+            payload,
+            [(last_keys[i * klen:(i + 1) * klen], off, size, codec)
+             for i, (off, size, codec) in enumerate(
+                 zip(offs.tolist(), sizes.tolist(), codecs.tolist()))],
+            num_entries=count, keys=[],
+            min_key=first_key, max_key=last_keys[-klen:],
+            min_seq=int(seqs.min()), max_seq=int(seqs.max()),
+        )
         if bloom_words is not None:
             bloom = BloomFilter(
                 len(bloom_words), np.asarray(bloom_words, dtype=np.uint32)
             )
         else:
-            bloom = BloomFilter.build(
-                [key_bytes[i].tobytes() for i in range(count)], bits_per_key
-            )
+            bloom = BloomFilter.build_from_arrays(
+                _key_rows(arrays, slice(0, count), klen),
+                np.full(count, klen, dtype=np.uint64), bits_per_key)
         extra_props = {
             "num_keys": int(count),
             "planar": planar_props(klen, vlen, seq32),
             "block_chk": {
                 "algo": "poly1w",
                 "block_words": int(full_words),
-                "values": chks,
+                "values": chks.tolist(),
             },
         }
         return writer.finish(precomputed_bloom=bloom,
@@ -408,6 +478,58 @@ def _write_planar(
     except BaseException:
         writer.abandon()
         raise
+
+
+def _planar_blocks(
+    arrays: Dict[str, np.ndarray], count: int, block_entries: int,
+    compression: int, klen: int, vlen: int, seq32: bool, full_words: int,
+    device_words: Optional[np.ndarray],
+    device_checksums: Optional[np.ndarray],
+):
+    """The Python block loop of the PLANAR sink, in the shape the native
+    encoder returns: ``(payload, offsets, sizes, codecs, checksums)``.
+    Takes the device planar encoder's words for full blocks."""
+    from ..storage.planar import (PLANAR_HEADER, PLANAR_FLAG_SEQ32,
+                                  pack_planar_header)
+
+    payloads: List[bytes] = []
+    codecs: List[int] = []
+    chks: List[int] = []
+    for bi, start in enumerate(range(0, count, block_entries)):
+        end = min(start + block_entries, count)
+        full = end - start == block_entries
+        if device_words is not None and full and bi < len(device_words):
+            words = np.ascontiguousarray(device_words[bi], dtype="<u4")
+            raw = pack_planar_header(
+                block_entries, klen, vlen,
+                PLANAR_FLAG_SEQ32 if seq32 else 0,
+            ) + words.tobytes()
+            if device_checksums is not None and bi < len(
+                    device_checksums):
+                chks.append(int(device_checksums[bi]))
+            else:
+                chks.append(poly_checksum_words(words, full_words))
+        else:
+            raw = encode_planar_block(
+                arrays, start, end, klen, vlen, seq32)
+            words = np.frombuffer(
+                raw, dtype="<u4", offset=PLANAR_HEADER.size)
+            chks.append(poly_checksum_words(words, full_words))
+        codec = BLOCK_PLANAR
+        payload = raw
+        if compression == COMPRESSION_ZLIB:
+            z = zlib.compress(raw, 1)
+            if len(z) < len(raw):
+                codec, payload = BLOCK_PLANAR_ZLIB, z
+        elif compression == COMPRESSION_RLZ:
+            z = rlz.compress(raw)
+            if len(z) < len(raw):
+                codec, payload = BLOCK_PLANAR_RLZ, z
+        payloads.append(payload)
+        codecs.append(codec)
+    sizes = np.fromiter(map(len, payloads), np.int64, len(payloads))
+    return (b"".join(payloads), np.cumsum(sizes) - sizes, sizes,
+            np.asarray(codecs), np.asarray(chks, dtype=np.int64))
 
 
 def _read_planar_arrays(reader) -> Optional[Dict[str, np.ndarray]]:
@@ -422,17 +544,10 @@ def _read_planar_arrays(reader) -> Optional[Dict[str, np.ndarray]]:
         return None  # foreign/corrupt planar props — tuple path validates
     if not parts:
         return None
-    lanes = {
+    return {
         f: np.concatenate([p[f] for p in parts])
         for f in parts[0]
     }
-    if reader.global_seqno is not None:
-        n = len(lanes["seq_lo"])
-        lanes["seq_lo"] = np.full(
-            n, reader.global_seqno & 0xFFFFFFFF, dtype=np.uint32)
-        lanes["seq_hi"] = np.full(
-            n, reader.global_seqno >> 32, dtype=np.uint32)
-    return lanes
 
 
 def write_sst_from_arrays(
@@ -477,14 +592,11 @@ def write_sst_from_arrays(
     stride = _ENTRY_FIXED_OVERHEAD + klen + vlen
     if device_rows is not None and device_rows.shape != (count, stride):
         return None  # shape mismatch — let the host path handle it
+    _count_codec(False)  # the row-format sink has a Python block loop only
     writer = SSTWriter(path, compression=compression,
                        bits_per_key=bits_per_key)
     try:
-        key_bytes = (
-            np.ascontiguousarray(
-                arrays["key_words_be"][:count].astype(">u4"))
-            .view(np.uint8).reshape(count, 24)[:, :klen]
-        )
+        key_bytes = _key_rows(arrays, slice(0, count), klen)
         seqs = (
             arrays["seq_hi"][:count].astype(np.uint64) << np.uint64(32)
         ) | arrays["seq_lo"][:count].astype(np.uint64)
@@ -515,15 +627,14 @@ def write_sst_from_arrays(
                 compressed=False,
                 codec=codec,
             )
-        bloom = None
         if bloom_words is not None:
             bloom = BloomFilter(
                 len(bloom_words), np.asarray(bloom_words, dtype=np.uint32)
             )
         else:
-            bloom = BloomFilter.build(
-                [key_bytes[i].tobytes() for i in range(count)], bits_per_key
-            )
+            bloom = BloomFilter.build_from_arrays(
+                key_bytes, np.full(count, klen, dtype=np.uint64),
+                bits_per_key)
         # kernel output has one entry per key; the uniform prop lets the
         # vectorized SOURCE reader decode this file array-to-array
         extra_props = {"num_keys": int(count),

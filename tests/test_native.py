@@ -513,3 +513,448 @@ def test_direct_sink_midloop_failure_cleans_outputs(tmp_path):
         )
     assert made and not os.path.exists(made[0]), (
         "orphaned output file left on disk after mid-loop failure")
+
+
+# ---------------------------------------------------------------------------
+# whole-file codecs: one native call per file (tpu/format.py sink + source)
+# ---------------------------------------------------------------------------
+
+_LANE_FIELDS = ("key_words_be", "key_words_le", "key_len", "seq_hi",
+                "seq_lo", "vtype", "val_words", "val_len")
+
+# name: rows, block_entries, compression, klen, vlen, seq past 32 bits,
+# kept tombstones. The first two are counter_64x20k.refresh's own files.
+_FILE_CASES = {
+    "cell_compaction_output": (20250, 124, 1, 16, 8, False, False),
+    "cell_flush_file": (5875, 124, 1, 16, 8, False, True),
+    "tail_block": (300, 124, 1, 16, 8, False, False),
+    "one_entry": (1, 124, 1, 16, 8, False, False),
+    "seq_past_32_bits": (700, 64, 1, 16, 8, True, False),
+    "kept_tombstones": (700, 64, 1, 16, 8, False, True),
+    "codec_none": (700, 64, 0, 16, 8, False, True),
+    "codec_rlz": (700, 64, 4, 16, 8, True, True),
+    "klen_10_vlen_20": (700, 64, 1, 10, 20, False, True),
+    "no_value": (700, 64, 1, 7, 0, False, False),
+}
+
+
+def _file_lanes(rows, klen, vlen, big_seq, deletes, seed=0):
+    rng = np.random.default_rng(seed + rows)
+    kb = np.zeros((rows, 24), dtype=np.uint8)
+    kb[:, :klen] = rng.integers(0, 256, (rows, klen), dtype=np.uint8)
+    kb = kb[np.lexsort(kb[:, ::-1].T)]  # rows ascending as byte strings
+    vtype = np.ones(rows, dtype=np.uint32)
+    val_len = np.full(rows, vlen, dtype=np.uint32)
+    vb = np.zeros((rows, max(2, (vlen + 3) // 4) * 4), dtype=np.uint8)
+    vb[:, :vlen] = rng.integers(0, 256, (rows, vlen), dtype=np.uint8)
+    if deletes:
+        dead = rng.random(rows) < 0.15
+        vtype[dead], val_len[dead], vb[dead] = 2, 0, 0
+    return {
+        "key_words_be": kb.view(">u4").astype(np.uint32).reshape(rows, 6),
+        "key_words_le": kb.view("<u4").reshape(rows, 6).copy(),
+        "key_len": np.full(rows, klen, dtype=np.uint32),
+        "seq_hi": np.full(rows, 3 if big_seq else 0, dtype=np.uint32),
+        "seq_lo": rng.integers(1, 2 ** 32, rows, dtype=np.uint64
+                               ).astype(np.uint32),
+        "vtype": vtype,
+        "val_words": vb.view("<u4").reshape(rows, -1).copy(),
+        "val_len": val_len,
+    }
+
+
+def _python_codecs(monkeypatch):
+    """Hide the whole-file codecs only (the Python block loops still use
+    the library's rlz, as they do in a process that has it)."""
+    monkeypatch.setattr(NATIVE, "has_file_codecs", False)
+
+
+def _assert_same_lanes(got, want):
+    assert got is not None and want is not None
+    for f in _LANE_FIELDS:
+        assert got[f].dtype == want[f].dtype, f
+        assert got[f].shape == want[f].shape, f
+        assert np.array_equal(got[f], want[f]), f
+
+
+def _write_case(case, path, bloom=True):
+    from rocksplicator_tpu.tpu.format import write_sst_from_arrays
+
+    rows, block_entries, compression, klen, vlen, big_seq, deletes = \
+        _FILE_CASES[case]
+    lanes = _file_lanes(rows, klen, vlen, big_seq, deletes)
+    props = write_sst_from_arrays(
+        lanes, rows, path, block_entries=block_entries,
+        compression=compression, planar=True,
+        bloom_words=np.arange(64, dtype=np.uint32) if bloom else None)
+    assert props is not None
+    return lanes, props
+
+
+@pytest.mark.parametrize("case", sorted(_FILE_CASES))
+def test_native_planar_sink_writes_the_python_sinks_file(
+        case, tmp_path, monkeypatch):
+    """Same props, same index, same uncompressed bytes in every block,
+    same lanes back: the native sink's file IS the Python sink's."""
+    from rocksplicator_tpu.storage.sst import SSTReader
+    from rocksplicator_tpu.tpu.format import read_sst_arrays
+    from rocksplicator_tpu.utils.stats import Stats
+
+    assert NATIVE.has_file_codecs
+    before = Stats.get().get_counter("codec.native_files")
+    p_native = str(tmp_path / "native.tsst")
+    lanes, props_native = _write_case(case, p_native)
+    assert Stats.get().get_counter("codec.native_files") == before + 1
+    _python_codecs(monkeypatch)
+    p_python = str(tmp_path / "python.tsst")
+    _lanes, props_python = _write_case(case, p_python)
+    for key in ("planar", "block_chk", "num_keys", "num_entries",
+                "min_key", "max_key", "min_seq", "max_seq"):
+        assert props_native[key] == props_python[key], key
+    rn, rp = SSTReader(p_native), SSTReader(p_python)
+    try:
+        assert len(rn._index) == len(rp._index) == len(
+            props_native["block_chk"]["values"])
+        assert [e[0] for e in rn._index] == [e[0] for e in rp._index]
+        for i in range(len(rn._index)):
+            assert (rn._read_block(i, fill_cache=False)
+                    == rp._read_block(i, fill_cache=False)), i
+        want = read_sst_arrays(rp)  # Python sink, Python source
+        _assert_same_lanes(read_sst_arrays(rn), want)
+        for f in _LANE_FIELDS:
+            assert np.array_equal(want[f], lanes[f]), f
+        assert list(rn.iterate()) == list(rp.iterate())
+    finally:
+        rn.close()
+        rp.close()
+
+
+def test_native_planar_sink_same_libz_same_file(tmp_path, monkeypatch):
+    """Python's zlib and the library's are one libz here, so even the
+    compressed bytes are the Python sink's: the files are identical."""
+    p_native = str(tmp_path / "native.tsst")
+    p_python = str(tmp_path / "python.tsst")
+    _write_case("cell_compaction_output", p_native)
+    _python_codecs(monkeypatch)
+    _write_case("cell_compaction_output", p_python)
+    with open(p_native, "rb") as a, open(p_python, "rb") as b:
+        assert a.read() == b.read()
+
+
+def test_native_planar_sink_builds_bloom_from_keys(tmp_path):
+    """Without a prebuilt bloom the sink builds one from the keys."""
+    from rocksplicator_tpu.storage.sst import SSTReader
+
+    path = str(tmp_path / "f.tsst")
+    lanes, _props = _write_case("tail_block", path, bloom=False)
+    r = SSTReader(path)
+    keys = np.ascontiguousarray(
+        lanes["key_words_be"].astype(">u4")).view(np.uint8)[:, :16]
+    assert all(r.get(keys[i].tobytes()) is not None for i in (0, 150, 299))
+    r.close()
+
+
+@pytest.mark.parametrize("sink", ["native_sink", "python_sink"])
+@pytest.mark.parametrize("case", sorted(_FILE_CASES))
+def test_each_sinks_file_reads_the_same_by_each_source(
+        case, sink, tmp_path, monkeypatch):
+    from rocksplicator_tpu.storage.sst import SSTReader
+    from rocksplicator_tpu.tpu import format as fmt
+
+    path = str(tmp_path / "f.tsst")
+    if sink == "python_sink":
+        with monkeypatch.context() as m:
+            _python_codecs(m)
+            lanes, _props = _write_case(case, path)
+    else:
+        lanes, _props = _write_case(case, path)
+    r = SSTReader(path)
+    try:
+        with monkeypatch.context() as m:
+            # the native source must not lean on the Python one
+            m.setattr(fmt, "_read_planar_arrays", None)
+            from_native = fmt.read_sst_arrays(r)
+        _python_codecs(monkeypatch)
+        from_python = fmt.read_sst_arrays(r)
+        _assert_same_lanes(from_native, from_python)
+        for f in _LANE_FIELDS:
+            assert np.array_equal(from_native[f], lanes[f]), f
+    finally:
+        r.close()
+
+
+def _write_rows(path, rows, compression, klen=16, vlen=8, seed=0,
+                global_seqno=None, extra_props=None):
+    """A row-format file as a bulk loader writes it (SSTWriter.add)."""
+    from rocksplicator_tpu.storage.sst import SSTWriter
+
+    lanes = _file_lanes(rows, klen, vlen, False, False, seed)
+    kb = np.ascontiguousarray(
+        lanes["key_words_be"].astype(">u4")).view(np.uint8)
+    vb = lanes["val_words"].view(np.uint8)
+    w = SSTWriter(path, compression=compression)
+    for i in range(rows):
+        w.add(kb[i, :klen].tobytes(), 1000 - i % 7, 1, vb[i, :vlen].tobytes())
+    w.finish(global_seqno=global_seqno, extra_props=extra_props)
+
+
+@pytest.mark.parametrize("case", [
+    # rows, compression, klen, vlen, global_seqno, the sink's prop
+    pytest.param((20000, 1, 16, 8, 7, False), id="cell_bulk_file"),
+    pytest.param((900, 0, 16, 8, None, False), id="codec_none"),
+    pytest.param((900, 4, 16, 8, (5 << 32) + 9, False), id="codec_rlz"),
+    pytest.param((900, 1, 16, 8, None, True), id="uniform_prop"),
+    pytest.param((900, 1, 9, 23, 11, False), id="klen_9_vlen_23"),
+    pytest.param((900, 1, 9, 23, None, True), id="klen_9_vlen_23_prop"),
+    pytest.param((1, 1, 24, 0, None, False), id="one_entry_no_value"),
+])
+def test_native_row_source_matches_python(case, tmp_path, monkeypatch):
+    """Row-format blocks (a bulk loader's file): widths inferred from
+    block 0 or taken from the sink's prop, global_seqno stamped."""
+    from rocksplicator_tpu.storage.sst import SSTReader
+    from rocksplicator_tpu.tpu import format as fmt
+
+    rows, compression, klen, vlen, seqno, prop = case
+    path = str(tmp_path / "rows.tsst")
+    _write_rows(path, rows, compression, klen, vlen, global_seqno=seqno,
+                extra_props={"uniform": [klen, vlen]} if prop else None)
+    r = SSTReader(path)
+    try:
+        with monkeypatch.context() as m:
+            m.setattr(fmt, "_read_uniform_arrays", None)
+            from_native = fmt.read_sst_arrays(r)
+        _python_codecs(monkeypatch)
+        from_python = fmt.read_sst_arrays(r)
+        _assert_same_lanes(from_native, from_python)
+        assert len(from_native["key_len"]) == rows
+        if seqno is not None:
+            assert (from_native["seq_lo"] == seqno & 0xFFFFFFFF).all()
+            assert (from_native["seq_hi"] == seqno >> 32).all()
+    finally:
+        r.close()
+
+
+def test_native_row_source_verifies_poly1_block_chk(tmp_path, monkeypatch):
+    """A row-format file with byte-domain checksums (the device block
+    encoder's): the native source computes each and the caller holds it
+    against the prop — intact reads, a flipped byte raises Corruption."""
+    from rocksplicator_tpu.storage.errors import Corruption
+    from rocksplicator_tpu.storage.sst import SSTReader
+    from rocksplicator_tpu.tpu import format as fmt
+    from rocksplicator_tpu.utils.checksum import poly_checksum
+
+    rows, block_entries, stride = 500, 100, 17 + 16 + 8
+    lanes = _file_lanes(rows, 16, 8, False, False)
+    chks = [poly_checksum(
+        fmt.encode_uniform_block(lanes, s, min(s + block_entries, rows),
+                                 16, 8), length=block_entries * stride)
+        for s in range(0, rows, block_entries)]
+    path = str(tmp_path / "rows.tsst")
+    assert fmt.write_sst_from_arrays(
+        lanes, rows, path, block_entries=block_entries, compression=0,
+        device_checksums=np.asarray(chks, dtype=np.uint32)) is not None
+    monkeypatch.setattr(fmt, "_read_uniform_arrays", None)
+    r = SSTReader(path)
+    got = fmt.read_sst_arrays(r)
+    for f in _LANE_FIELDS:
+        assert np.array_equal(got[f], lanes[f]), f
+    r.close()
+    with open(path, "r+b") as f:
+        f.seek(2 * block_entries * stride + 30)  # inside block 2
+        b = f.read(1)
+        f.seek(-1, 1)
+        f.write(bytes([b[0] ^ 0x01]))
+    r = SSTReader(path)
+    with pytest.raises(Corruption, match="block 2 checksum mismatch"):
+        fmt.read_sst_arrays(r)
+    r.close()
+
+
+def test_native_row_source_width_drift_is_not_lanes(tmp_path, monkeypatch):
+    """Width drift still yields None (the tuple path's file), decided by
+    the native source itself; a prop it cannot read is the Python
+    source's to judge."""
+    from rocksplicator_tpu.storage.sst import SSTReader, SSTWriter
+    from rocksplicator_tpu.tpu import format as fmt
+    from rocksplicator_tpu.utils.stats import Stats
+
+    # 41 + 37 + 45 = 3 x 41: the block-0 probe passes, the rows do not
+    tricky = str(tmp_path / "tricky.tsst")
+    w = SSTWriter(tricky)
+    w.add(b"a" * 16, 3, 1, b"12345678")
+    w.add(b"b" * 16, 2, 1, b"1234")
+    w.add(b"c" * 16, 1, 1, b"123456789012")
+    w.finish()
+    # drift in a later block only
+    late = str(tmp_path / "late.tsst")
+    w = SSTWriter(late, block_bytes=41 * 50)
+    for i in range(120):
+        w.add(f"key{i:013d}".encode(), 1, 1, b"12345678" if i < 110
+              else b"1234")
+    w.finish()
+    before = Stats.get().get_counter("codec.python_files")
+    with monkeypatch.context() as m:
+        m.setattr(fmt, "_read_uniform_arrays", None)
+        for path in (tricky, late):
+            r = SSTReader(path)
+            assert fmt.read_sst_arrays(r) is None
+            r.close()
+    # not lanes: counted with the files the interpreter decodes
+    assert Stats.get().get_counter("codec.python_files") == before + 2
+    foreign = str(tmp_path / "foreign.tsst")
+    w = SSTWriter(foreign)
+    w.add(b"k" * 30, 1, 1, b"v")
+    w.finish(extra_props={"uniform": [30, 1]})
+    r = SSTReader(foreign)
+    assert fmt._read_lanes_native(r, False) is fmt._NOT_TAKEN
+    assert fmt.read_sst_arrays(r) is None
+    r.close()
+
+
+def test_native_source_leaves_unknown_block_codecs_to_python(tmp_path):
+    """A codec nibble the library does not know is not guessed at."""
+    from rocksplicator_tpu.storage.errors import Corruption
+    from rocksplicator_tpu.storage.sst import SSTReader
+    from rocksplicator_tpu.tpu import format as fmt
+
+    path = str(tmp_path / "rows.tsst")
+    _write_rows(path, 200, 0)
+    r = SSTReader(path)
+    r._index[0] = r._index[0][:3] + (9,)
+    assert fmt._read_lanes_native(r, False) is fmt._NOT_TAKEN
+    with pytest.raises(Corruption, match="unsupported block codec 9"):
+        fmt.read_sst_arrays(r)
+    r.close()
+
+
+@pytest.mark.parametrize("library", ["native", "hidden"])
+def test_codec_counters_say_which_codec_ran(library, tmp_path, monkeypatch):
+    """A flush and a batched post-load compaction (CPU backend): every
+    file read and written is counted, under the codec that took it —
+    all native with the library, all Python with it hidden — and the
+    answers are the same."""
+    from rocksplicator_tpu.observability.collector import SpanCollector
+    from rocksplicator_tpu.storage import DB, DBOptions
+    from rocksplicator_tpu.storage.merge import UInt64AddOperator
+    from rocksplicator_tpu.storage.native import binding
+    from rocksplicator_tpu.storage.records import WriteBatch
+    from rocksplicator_tpu.storage.sst import SSTWriter
+    from rocksplicator_tpu.tpu.compaction_service import compact_dbs_batched
+    from rocksplicator_tpu.utils.stats import Stats
+
+    if library == "hidden":
+        monkeypatch.setattr(binding, "_native", None)
+    SpanCollector.reset_for_test()
+    SpanCollector.get().configure(sample_rate=1.0)
+    pack = struct.Struct("<Q").pack
+    stats = Stats.get()
+    before = {k: stats.get_counter(k)
+              for k in ("codec.native_files", "codec.python_files")}
+    dbs = []
+    for s in range(2):
+        db = DB(str(tmp_path / f"db{s}"),
+                DBOptions(merge_operator=UInt64AddOperator()))
+        for i in range(300):
+            db.write(WriteBatch().merge(f"key{i:013d}".encode(), pack(i)))
+        db.write(WriteBatch().delete(b"key" + b"0" * 13))
+        db.flush()                                   # 1 file written
+        sst = str(tmp_path / f"in{s}.tsst")
+        w = SSTWriter(sst)
+        for i in range(100, 400):
+            w.add(f"key{i:013d}".encode(), 0, 1, pack(s * 1000 + i))
+        w.finish()
+        db.ingest_external_file([sst], move_files=True,
+                                allow_global_seqno=True)
+        dbs.append((f"db{s}", db))
+    handled, remaining = compact_dbs_batched(dbs)    # 2 read, 1 written
+    assert sorted(handled) == ["db0", "db1"] and remaining == []
+    native = stats.get_counter("codec.native_files") - before[
+        "codec.native_files"]
+    python = stats.get_counter("codec.python_files") - before[
+        "codec.python_files"]
+    assert (native, python) == ((8, 0) if library == "native" else (0, 8))
+    for s, (_name, db) in enumerate(dbs):
+        assert db.get(b"key" + b"0" * 13) is None
+        assert db.get(f"key{50:013d}".encode()) == pack(50)
+        assert db.get(f"key{150:013d}".encode()) == pack(s * 1000 + 150)
+        assert db.get(f"key{399:013d}".encode()) == pack(s * 1000 + 399)
+        db.close()
+    spans = [s for s in SpanCollector.get().snapshot()
+             if s["name"] in ("flush.encode", "tpu.lanes.decode",
+                              "tpu.planar.write")]
+    assert {s["name"] for s in spans} == {
+        "flush.encode", "tpu.lanes.decode", "tpu.planar.write"}
+    assert all(s["annotations"]["native"] == int(library == "native")
+               for s in spans)
+    SpanCollector.reset_for_test()
+
+
+@pytest.mark.parametrize("compression", [0, 1, 4])
+@pytest.mark.parametrize("layout", ["planar", "rows"])
+def test_native_read_block_matches_python(layout, compression, tmp_path,
+                                          monkeypatch):
+    """A point read's block through one native call (pread, inflate,
+    block_chk) is the Python reader's block, byte for byte, and a block
+    that fails its checksum still raises Corruption."""
+    from rocksplicator_tpu.storage.errors import Corruption
+    from rocksplicator_tpu.storage.sst import BlockCache, SSTReader
+    from rocksplicator_tpu.tpu.format import write_sst_from_arrays
+
+    monkeypatch.setattr(BlockCache, "_instance", None)
+    monkeypatch.setattr(BlockCache, "_disabled", True)
+    rows, block_entries = 500, 100
+    lanes = _file_lanes(rows, 16, 8, False, layout == "planar")
+    path = str(tmp_path / "f.tsst")
+    assert write_sst_from_arrays(
+        lanes, rows, path, block_entries=block_entries,
+        compression=compression, planar=layout == "planar") is not None
+    r = SSTReader(path)
+    calls = []
+    real = NATIVE.read_block
+    monkeypatch.setattr(
+        NATIVE, "read_block",
+        lambda *a: calls.append(a) or real(*a))
+    native_blocks = [r._read_block(i) for i in range(len(r._index))]
+    assert len(calls) == len(r._index)
+    if layout == "planar":  # each held to its poly1w value, then memoed
+        assert r._verified_blocks == set(range(len(r._index)))
+        assert all(c[4] == 2 for c in calls)
+    entries = list(r.iterate())
+    r.close()
+    with monkeypatch.context() as m:
+        _python_codecs(m)
+        r = SSTReader(path)
+        assert [r._read_block(i) for i in range(len(r._index))] \
+            == native_blocks
+        assert list(r.iterate()) == entries
+        off1, size1 = r._index[1][1], r._index[1][2]
+        r.close()
+    if layout == "planar":
+        with open(path, "r+b") as f:
+            f.seek(off1 + size1 - 6)
+            b = f.read(1)
+            f.seek(-1, 1)
+            f.write(bytes([b[0] ^ 0x04]))
+        r = SSTReader(path)
+        assert r._read_block(0) == native_blocks[0]
+        with pytest.raises((Corruption, zlib.error, ValueError)):
+            r._read_block(1)
+        r.close()
+
+
+def test_native_short_calls_keep_the_gil():
+    """Point lookups and small RLZ transforms go through the PyDLL
+    handle (no GIL hand-over for microseconds of C); a large buffer
+    goes through the handle that drops it."""
+    import ctypes
+
+    from rocksplicator_tpu.storage.native import binding
+
+    assert isinstance(NATIVE._held, ctypes.PyDLL)
+    assert not isinstance(NATIVE._lib, ctypes.PyDLL)
+    assert NATIVE._for_bytes(4096) is NATIVE._held
+    assert NATIVE._for_bytes(binding._SHORT_CALL_BYTES + 1) is NATIVE._lib
+    big = os.urandom(1024) * 100  # beyond the short-call size
+    for data in (b"", b"abc" * 1000, big):
+        packed = NATIVE.rlz_compress(data)
+        assert NATIVE.rlz_decompress(packed, len(data) + 1) == data

@@ -404,3 +404,120 @@ def test_engine_flush_64kb_values_fallback(tmp_path):
     for i in range(4):
         assert db.get(b"wide%04d" % i) == bytes([i + 1]) * big
     db.close()
+
+
+# ---------------------------------------------------------------------------
+# the native whole-file source over PLANAR files (one call per file)
+# ---------------------------------------------------------------------------
+
+def _native_source_only(monkeypatch):
+    """Skip without the library; with it, take the Python planar source
+    away so that what answers is the native one."""
+    from rocksplicator_tpu.storage.native.binding import get_native
+    from rocksplicator_tpu.tpu import format as fmt
+
+    lib = get_native()
+    if lib is None or not lib.has_file_codecs:
+        pytest.skip("native lib not built")
+    monkeypatch.setattr(fmt, "_read_planar_arrays", None)
+    return fmt
+
+
+@pytest.mark.parametrize("compression", [0, 1, 4])
+def test_native_source_flipped_byte_raises_corruption(
+        compression, tmp_path, monkeypatch):
+    """A flipped byte inside a block: the native source reports it as
+    Corruption (the block does not inflate, does not fit its layout, or
+    fails its block_chk value) — never as lanes."""
+    fmt = _native_source_only(monkeypatch)
+    arrays, n = _arrays(_entries(600, with_deletes=True))
+    path = str(tmp_path / "planar.tsst")
+    props = write_sst_from_arrays(
+        arrays, n, path, block_entries=256, compression=compression,
+        planar=True)
+    assert props["block_chk"]["algo"] == "poly1w"
+    r = SSTReader(path)
+    assert len(fmt.read_sst_arrays(r)["key_len"]) == n
+    off1, size1 = r._index[1][1], r._index[1][2]
+    r.close()
+    for at in (off1 + size1 // 2, off1 + size1 - 3):
+        with open(path, "r+b") as f:
+            f.seek(at)
+            b = f.read(1)
+            f.seek(-1, 1)
+            f.write(bytes([b[0] ^ 0x10]))
+        r = SSTReader(path)
+        with pytest.raises(Corruption):
+            fmt.read_sst_arrays(r)
+        r.close()
+        with open(path, "r+b") as f:  # put it back for the next offset
+            f.seek(at)
+            f.write(b)
+
+
+def test_native_source_corrupt_header_and_short_file(tmp_path, monkeypatch):
+    """A block whose header no longer fits its bytes, and a file cut
+    short inside a block, are Corruption too."""
+    fmt = _native_source_only(monkeypatch)
+    arrays, n = _arrays(_entries(300))
+    path = str(tmp_path / "planar.tsst")
+    assert write_sst_from_arrays(
+        arrays, n, path, block_entries=100, compression=0,
+        planar=True) is not None
+    r = SSTReader(path)
+    off2 = r._index[2][1]
+    r.close()
+    with open(path, "r+b") as f:
+        f.seek(off2)  # block 2's entry count
+        f.write((99).to_bytes(4, "little"))
+    r = SSTReader(path)
+    with pytest.raises(Corruption, match="block 2 is corrupt"):
+        fmt.read_sst_arrays(r)
+    # the index points past the end of the file
+    r._index[2] = r._index[2][:1] + (1 << 30,) + r._index[2][2:]
+    with pytest.raises(Corruption, match="block 2 could not be read"):
+        fmt.read_sst_arrays(r)
+    r.close()
+
+
+def test_native_source_global_seqno_override(tmp_path, monkeypatch):
+    fmt = _native_source_only(monkeypatch)
+    arrays, n = _arrays(_entries(300, big_seq=True))
+    path = str(tmp_path / "planar.tsst")
+    assert write_sst_from_arrays(
+        arrays, n, path, block_entries=64, planar=True) is not None
+    r = SSTReader(path)
+    plain = fmt.read_sst_arrays(r)
+    assert (plain["seq_hi"] == 1 << 8).all()
+    assert np.array_equal(plain["seq_lo"], arrays["seq_lo"])
+    r.global_seqno = (7 << 32) + 5
+    lanes = fmt.read_sst_arrays(r)
+    assert (lanes["seq_lo"] == 5).all() and (lanes["seq_hi"] == 7).all()
+    for f in ("key_words_be", "vtype", "val_words", "val_len"):
+        assert np.array_equal(lanes[f], plain[f])
+    r.close()
+
+
+def test_native_source_width_drift_and_foreign_props(tmp_path, monkeypatch):
+    """Blocks whose widths are not the file's yield None (the tuple
+    path's file); a ``planar`` prop the native source cannot read is
+    left to the Python source, which says None as it always did."""
+    from rocksplicator_tpu.tpu import format as fmt
+
+    arrays, n = _arrays(_entries(200))
+    path = str(tmp_path / "planar.tsst")
+    assert write_sst_from_arrays(
+        arrays, n, path, block_entries=64, planar=True) is not None
+    r = SSTReader(path)
+    r.props["planar"] = [12, 8, 1]  # the blocks say 16
+    with monkeypatch.context() as m:
+        _native_source_only(m)
+        assert fmt.read_sst_arrays(r) is None
+    for foreign in (True, [0, 8, 1], ["x", 8, 1], {"klen": 16}):
+        r.props["planar"] = foreign
+        assert fmt._read_lanes_native(r, True) is fmt._NOT_TAKEN
+    r.props["planar"] = True
+    r.props["block_chk"] = {"algo": "poly1", "block_bytes": 64,
+                            "values": [1, 2, 3, 4]}
+    assert fmt._read_lanes_native(r, True) is fmt._NOT_TAKEN
+    r.close()

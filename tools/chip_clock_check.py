@@ -13,6 +13,9 @@ the run's own and the last):
   least lead and tail, and the largest violation, in microseconds.
 - **seconds ``reduce_trace`` takes** after the window (its ``blame`` is
   gaps x spans, and every served RPC records a span).
+- **which host codec ran**: the process's ``codec.native_files`` and
+  ``codec.python_files`` counters (set-up and window; ``tpu/format.py``),
+  and how many point reads found their block in the block cache.
 
 Arguments are ``chipbench/run.py``'s own.
 """
@@ -96,11 +99,26 @@ def main(argv=None) -> int:
                     f"{len(spans)} spans")
         return out
 
+    real_run_cell = harness.run_cell
+
+    def run_cell(*args):
+        from rocksplicator_tpu.utils.stats import Stats
+
+        out = real_run_cell(*args)
+        harness.say("host codecs, files of the whole process: " + json.dumps(
+            {k: Stats.get().get_counter(k)
+             for k in ("codec.native_files", "codec.python_files",
+                       "storage.block_cache.hit",
+                       "storage.block_cache.miss")}))
+        return out
+
     tr.reduce, harness.reduce_trace = reduce, reduce_trace
+    harness.run_cell = run_cell
     try:
         return harness.main(argv)
     finally:
         tr.reduce, harness.reduce_trace = real_reduce, real_reduce_trace
+        harness.run_cell = real_run_cell
 
 
 if __name__ == "__main__":
