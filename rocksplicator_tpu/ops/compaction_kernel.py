@@ -5,12 +5,19 @@ SURVEY §3.3) with a fixed-shape array program:
 
 1. one multi-key ``lax.sort`` orders every entry by (validity, key lex asc,
    seq desc) — the k-way merge collapses into a sort because the runs are
-   concatenated into one batch. Every payload lane RIDES THE SORT as a
-   non-key operand: round-2 device profiling showed TPU row gathers cost
-   ~16 ms/lane at 131k rows while extra sort operands are nearly free
-   (an 18-operand sort times the same as a 10-operand one), so the kernel
-   carries payload through the sort network instead of gathering by the
-   sorted permutation;
+   concatenated into one batch. What the sort carries beside its keys
+   depends on the value width it is traced with (``value_path``):
+   - **riding** (the uint64-add fold, and values up to
+     ``RIDE_MAX_VAL_WORDS`` words): every value word is a non-key operand
+     of this sort and of the compaction sort below. An operand costs
+     nothing to run (a launch of 8 x 8,192 rows takes 2.7-3.5 ms with 2 to
+     32 value words riding) and 10-20 s to compile, twice over: 65 s at 2
+     words, 380 s at 32 (one v5e, PR 29, tools/value_path_bench.py);
+   - **index** (wider values, no merge operator): ONE row-index lane
+     rides both sorts and the values are moved once at the end, output
+     row i taking the whole input row ``val_row[i]``
+     (``gather_value_rows``). With no operator the resolve never rewrites
+     a value (newest PUT/DELETE wins), so the move is all values need;
 2. key-boundary detection with adjacent-lane compares, then per-segment
    aggregates via cumulative sums + two flagged segmented fills
    (``lax.associative_scan``) — one forward fill of segment-start values,
@@ -19,14 +26,22 @@ SURVEY §3.3) with a fixed-shape array program:
    operands above the base fold via the uint64-add operator as 16-bit-limb
    prefix-sum differences (carry-safe for < 2^16 operands per key);
 4. stream compaction via a second stable sort, again carrying every output
-   lane as payload.
+   lane (or the row index) as payload.
 
-**TPU design note:** everything here is sorts, cumulative/associative
-scans, and elementwise ops — ZERO gathers, zero scatters, and no
-``jax.ops.segment_*``. Gathers were the round-1 kernel's actual bottleneck
-(~70% of its 500 ms/launch on hardware); this formulation removes them
-entirely. Static shapes throughout: capacity N in → capacity N out +
-count; the whole pipeline jits once and vmaps over shards.
+**TPU design note:** phases 1-4 are sorts, cumulative/associative scans
+and elementwise ops: no per-lane gather, no scatter, no
+``jax.ops.segment_*``; static shapes throughout (capacity N in → capacity
+N out + count), so the pipeline jits once and vmaps over shards. The one
+gather is the index path's move of whole value rows, which XLA keeps a
+row gather (W contiguous words a row, ``slice_sizes={1, W}``). Measured
+on one v5e (PR 29, tools/value_path_bench.py): 32,768 rows of 1 KB
+(33.5 MB read, 33.5 MB written) in 1.04 ms as a program of its own; the
+whole pipeline of 8 shards x 32,768 rows of 1 KB (sorts, resolve, bloom,
+eight such moves) in 7.99 ms a launch, less than the 9.1 ms of the
+counters' riding program at the same capacity. An earlier docstring here
+argued against all gathers from a per-LANE reading of rounds 1-2
+(16 ms a lane at 131 k rows) that was later withdrawn; a row gather is
+another access pattern, and this is its first measurement.
 
 ``key_words_le`` is never carried: a little-endian key word is the
 byteswap of the big-endian word over the same bytes, so it is recomputed
@@ -85,6 +100,34 @@ class MergeKind(enum.Enum):
     # which preserves unresolved operand chains like the reference).
     NONE = "none"
     UINT64_ADD = "uint64add"  # the counter operator (merge_operator.h:20-40)
+
+
+# Widest value, in u32 words, that still rides the two sorts as operands.
+# Wider ones (MergeKind.NONE only) take the index path: one row-index
+# lane rides, the values are moved once by the resolved order. Measured
+# on one v5e with tools/value_path_bench.py (PR 29; a group of 8 shards of
+# 8,192 rows, no operator; cold compile s / device ms a launch):
+#   words   2: ride  65 / 3.11   index 56 / 3.24
+#   words   4: ride  74 / 2.82   index 54 / 2.99
+#   words   8: ride 116 / 2.75   index 54 / 3.16
+#   words  16: ride 225 / 2.90   index 54 / 3.17
+#   words  32: ride 379 / 3.47   index 46 / 3.17
+#   words 256: index 51 / 3.35;  words 1024: index 46 / 4.14
+# A riding word costs nothing to run and 10-20 s more to COMPILE, twice
+# over; the index path costs 0.1-0.4 ms a launch up to 16 words, wins from
+# 32, and compiles in the same time whatever the width. 8-byte values keep
+# the programs they have; everything wider takes the index path.
+RIDE_MAX_VAL_WORDS = 2
+
+
+def value_path(merge_kind: MergeKind, n_val_words: int) -> str:
+    """``"ride"`` or ``"index"``: how a batch's values get from input to
+    output order, from what the program is traced with and nothing else.
+    The uint64-add fold rewrites value words, so it always rides (its
+    values are 8 bytes)."""
+    if merge_kind is MergeKind.NONE and n_val_words > RIDE_MAX_VAL_WORDS:
+        return "index"
+    return "ride"
 
 
 def bswap32(w: jnp.ndarray) -> jnp.ndarray:
@@ -397,14 +440,22 @@ def resolve_sorted_lanes(
     uniform_klen: bool,
     seq32: bool,
     key_words: int,
+    val_row=None,               # (N,) u32: the index path's one lane
 ) -> Dict[str, jnp.ndarray]:
     """Phases 2-4 of the kernel on ALREADY merge-ordered lanes
     ((invalid-last, key asc, seq desc) order): boundary detection,
     segmented LSM resolution, stream compaction. Shared by the full-sort
     kernel below and the sorted-runs merge-network kernel
-    (ops/merge_network.py), which produce that order two different ways."""
+    (ops/merge_network.py), which produce that order two different ways.
+
+    ``val_row`` (the index path, ``vw_lanes`` empty): each row's index
+    into the caller's value matrix rides the compaction in the value
+    lanes' place and comes back as ``val_row`` instead of ``val_words``;
+    the caller moves the values once (``gather_value_rows``)."""
     n = seq_lo.shape[0]
-    n_val_words = len(vw_lanes)
+    if val_row is not None and (vw_lanes or merge_kind is not MergeKind.NONE):
+        raise ValueError("the index path carries no value lane and takes "
+                         "no merge operator (a fold rewrites values)")
     seq_hi = seq_hi if seq_hi is not None else jnp.zeros_like(seq_lo)
 
     vtype, val_len, vw_lanes, keep, overflow_mask = resolve_decisions(
@@ -416,7 +467,8 @@ def resolve_sorted_lanes(
 
     # --- stream compaction: stable sort, output lanes as payload -------
     not_keep = jnp.where(keep, jnp.uint32(0), jnp.uint32(1))
-    out_payload = list(key_lanes) + [seq_lo, vtype, val_len] + vw_lanes
+    carried = [val_row] if val_row is not None else vw_lanes
+    out_payload = list(key_lanes) + [seq_lo, vtype, val_len] + carried
     if not seq32:
         out_payload.append(seq_hi)
     if not uniform_klen:
@@ -435,8 +487,8 @@ def resolve_sorted_lanes(
     out_seq_lo = m1(sorted2[pos]); pos += 1
     out_vtype = m1(sorted2[pos]); pos += 1
     out_val_len = m1(sorted2[pos]); pos += 1
-    out_vw = [m1(sorted2[pos + w]) for w in range(n_val_words)]
-    pos += n_val_words
+    out_vw = [m1(sorted2[pos + w]) for w in range(len(carried))]
+    pos += len(carried)
     if not seq32:
         out_seq_hi = m1(sorted2[pos]); pos += 1
     else:
@@ -453,18 +505,22 @@ def resolve_sorted_lanes(
     out_kw_le = jnp.stack(
         [bswap32(w) for w in out_key_lanes] + zeros_tail, axis=1)
 
-    return {
+    out = {
         "key_words_be": out_kw_be,
         "key_words_le": out_kw_le,
         "key_len": out_key_len,
         "seq_hi": out_seq_hi,
         "seq_lo": out_seq_lo,
         "vtype": out_vtype,
-        "val_words": jnp.stack(out_vw, axis=1),
         "val_len": out_val_len,
         "count": count,
         "needs_cpu_fallback": overflow_risk,
     }
+    if val_row is not None:
+        out["val_row"] = out_vw[0]
+    else:
+        out["val_words"] = jnp.stack(out_vw, axis=1)
+    return out
 
 
 @functools.partial(
@@ -510,24 +566,73 @@ def merge_resolve_kernel(
             key_words=key_words,
         )
 
-    n_val_words = val_words.shape[1]
+    index = value_path(merge_kind, val_words.shape[1]) == "index"
+    out = _sort_resolve(
+        key_words_be, key_len, seq_hi, seq_lo, vtype, val_words, val_len,
+        valid, index=index, merge_kind=merge_kind,
+        drop_tombstones=drop_tombstones, uniform_klen=uniform_klen,
+        seq32=seq32, key_words=key_words, sort_backend=sort_backend)
+    if index:
+        out["val_words"] = gather_value_rows(
+            val_words, out.pop("val_row"), out["count"])
+    return out
+
+
+def _sort_resolve(key_words_be, key_len, seq_hi, seq_lo, vtype, val_words,
+                  val_len, valid, *, index, merge_kind, drop_tombstones,
+                  uniform_klen, seq32, key_words, sort_backend):
+    """Phases 1-4 with the values' lanes riding both sorts: every word
+    of ``val_words`` (the riding path), or with ``index`` ONE lane, each
+    row's own index (``val_words`` is not looked at, and the output has
+    ``val_row`` in ``val_words``' place)."""
     # uniform_klen reconstruction constant: the one valid key length
     # (input order differs from output order, so the lane itself can't be
     # passed through; invalid rows may carry zero lengths)
     klen_const = jnp.max(jnp.where(valid, key_len, jnp.uint32(0)))
 
     # --- phase 1: merge-order sort, payload riding the network ---------
-    payload = (vtype, val_len) + tuple(
-        val_words[:, w] for w in range(n_val_words)
-    )
+    carried = ((lax.iota(jnp.uint32, key_len.shape[0]),) if index else
+               tuple(val_words[:, w] for w in range(val_words.shape[1])))
     key_lanes, klen_s, shi_s, slo_s, valid_s, payload = _sort_merge_order(
-        key_words_be, key_len, seq_hi, seq_lo, valid, payload,
+        key_words_be, key_len, seq_hi, seq_lo, valid,
+        (vtype, val_len) + carried,
         uniform_klen=uniform_klen, seq32=seq32, key_words=key_words,
         sort_backend=sort_backend,
     )
     return resolve_sorted_lanes(
         list(key_lanes), klen_s, shi_s, slo_s, valid_s,
-        payload[0], payload[1], list(payload[2:]), klen_const,
+        payload[0], payload[1], [] if index else list(payload[2:]),
+        klen_const,
         merge_kind=merge_kind, drop_tombstones=drop_tombstones,
         uniform_klen=uniform_klen, seq32=seq32, key_words=key_words,
+        val_row=payload[2] if index else None,
     )
+
+
+def merge_resolve_rows(key_words_be, key_len, seq_hi, seq_lo, vtype,
+                       val_len, valid, *, drop_tombstones: bool,
+                       uniform_klen: bool = False, seq32: bool = False,
+                       key_words: int = KEY_WORDS,
+                       sort_backend: str = "lax"):
+    """The index path's sorts and resolve, with no value in sight
+    (``MergeKind.NONE``: newest PUT/DELETE wins, no value is rewritten).
+    The output of ``merge_resolve_kernel`` without ``val_words``, with
+    ``val_row`` in its place: for each output row, the input row whose
+    value it keeps (0 beyond ``count``). ``gather_value_rows`` moves the
+    values; a caller with several shards' values in separate buffers
+    (tpu/compaction_service.py) vmaps this and moves each shard's apart."""
+    return _sort_resolve(
+        key_words_be, key_len, seq_hi, seq_lo, vtype, None, val_len, valid,
+        index=True, merge_kind=MergeKind.NONE,
+        drop_tombstones=drop_tombstones, uniform_klen=uniform_klen,
+        seq32=seq32, key_words=key_words, sort_backend=sort_backend)
+
+
+def gather_value_rows(val_words: jnp.ndarray, val_row: jnp.ndarray,
+                      count) -> jnp.ndarray:
+    """The index path's one move: output row i takes the whole value row
+    ``val_words[val_row[i]]`` (W contiguous words); rows from ``count``
+    on are zero, as the riding path leaves them."""
+    live = lax.iota(jnp.int32, val_row.shape[0]) < count
+    rows = val_words.at[val_row].get(mode="promise_in_bounds")
+    return jnp.where(live[:, None], rows, jnp.zeros_like(rows))

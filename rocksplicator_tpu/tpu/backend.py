@@ -2,9 +2,17 @@
 
 ``TpuCompactionBackend`` implements the storage engine's CompactionBackend
 seam with the ops/compaction_kernel pipeline; anything the fixed-shape
-representation can't express (long keys, wide values, custom merge
-operators) falls back to the CPU heap-merge, mirroring the north star's
-"fall back to CPU on kernel inapplicability".
+representation can't express (keys over 24 B, values over
+``device_value_bytes_max``, custom merge operators) falls back to the CPU
+heap-merge, mirroring the north star's "fall back to CPU on kernel
+inapplicability". Values: 8 bytes under the uint64-add operator (they
+ride both sorts and the fold rewrites them); with no operator, up to
+``RIDE_MAX_VAL_WORDS`` words ride and wider ones, up to
+``DEVICE_VALUE_BYTES_MAX`` bytes, take the kernel's index path (a row
+index rides, the values are moved once). The array sink
+(``merge_runs_to_files``) declines a wider shard before the kernel; the
+tuple path (``merge_runs``) packs 8-byte values only and hands anything
+else to the CPU.
 
 ``NumpyCompactionBackend`` is the honest vectorized CPU baseline the bench
 compares against (np.lexsort + reduceat segment folds — the best a CPU
@@ -35,6 +43,28 @@ _PUT, _DELETE, _MERGE = 1, 2, 3
 # merge (tpu/chunked.py): batches up to this size launch once; larger ones
 # fold per-run chunks then summaries at this fixed launch shape.
 MAX_TPU_ENTRIES = 1 << 22
+
+
+# Widest value, in bytes, that the device path takes without a merge
+# operator (the index path of ops/compaction_kernel.py: the width costs
+# no sort operand, only bytes moved once). The widest that has run on the
+# chip: a group of 8 shards of 8,192 rows of 4 KB, 4.14 ms a launch
+# (tools/value_path_bench.py, PR 29); a launch holds its values twice
+# (group x capacity x width, in and out), 0.54 GB at the 1 KB
+# deployment's (8, 32768).
+DEVICE_VALUE_BYTES_MAX = 4096
+
+
+def device_value_bytes_max(merge_operator: Optional[MergeOperator]) -> int:
+    """The widest value, in bytes, of a shard the device path compacts
+    for a DB with this merge operator; 0 where it takes none. The
+    uint64-add fold is defined on 8-byte values; a custom operator runs
+    Python. Wider shards are DECLINED to the host path before any
+    program is built (``compact_dbs_batched``, ``merge_runs_to_files``),
+    counted under ``tpu.host_fallbacks reason=value_width``."""
+    if merge_operator is None:
+        return DEVICE_VALUE_BYTES_MAX
+    return 8 if isinstance(merge_operator, UInt64AddOperator) else 0
 
 
 def _next_pow2(n: int) -> int:
@@ -272,6 +302,10 @@ class TpuCompactionBackend(CompactionBackend):
         # instead of staying verbatim as the stream path keeps it
         if (merge_op is not None and len(non_del_vlens)
                 and not (non_del_vlens == 8).all()):
+            return None
+        # wider than the device path takes: the host path, no program
+        if (len(non_del_vlens)
+                and int(non_del_vlens[0]) > device_value_bytes_max(merge_op)):
             return None
         kind = (
             MergeKind.UINT64_ADD if isinstance(merge_op, UInt64AddOperator)

@@ -24,22 +24,29 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..observability.context import wire_context
+from ..observability.context import current_span, wire_context
 from ..observability.span import start_span
 from ..storage.bloom import num_words_for
 from ..storage.engine import DBOptions
 from ..ops.bloom_tpu import bloom_build_tpu
 from ..ops.compaction_kernel import (MergeKind, deployment_sort_backend,
-                                     merge_resolve_kernel)
+                                     gather_value_rows,
+                                     merge_resolve_kernel,
+                                     merge_resolve_rows, value_path)
 from ..ops.kv_format import KEY_WORDS, KVBatch, fast_flags, unpack_entries
 from ..storage.compaction import record_host_fallback
-from .backend import TpuCompactionBackend, _next_pow2, require_accelerator
+from ..utils.stats import Stats
+from .backend import (TpuCompactionBackend, _next_pow2,
+                      device_value_bytes_max, require_accelerator)
 
 log = logging.getLogger(__name__)
 
 # jit(vmap(...)) of the merge-resolve + bloom pipeline: XLA module
 # "jit_one_shard" (tests/test_tracing.py pins both programs' names)
 PIPELINE_PROGRAM = "one_shard"
+# the index path's pipeline (values moved once, inside the same module):
+# its name holds the other's, so one reader finds either
+PIPELINE_PROGRAM_INDEX = "one_shard_index"
 _GROUP_LANES = (
     "key_words_be", "key_len", "seq_hi",
     "seq_lo", "vtype", "val_words", "val_len", "valid",
@@ -64,6 +71,7 @@ class TpuCompactionService:
         # part of the pipeline cache key).
         self._sort_backend = sort_backend
         self._vmapped_cache: Dict[tuple, object] = {}
+        self._zero_rows: Dict[tuple, object] = {}  # (capacity, words)
 
     @classmethod
     def instance(cls) -> "TpuCompactionService":
@@ -89,36 +97,60 @@ class TpuCompactionService:
 
     def _pipeline(self, merge_kind: MergeKind, drop_tombstones: bool,
                   num_words: int, uniform_klen: bool = False,
-                  seq32: bool = False, key_words: int = KEY_WORDS):
+                  seq32: bool = False, key_words: int = KEY_WORDS,
+                  val_words: int = 0):
+        """The jitted pipeline of one launch group. ``val_words`` (the
+        group's value width in u32 words) chooses the value path as the
+        kernel does (``value_path``). Riding: every lane stacked
+        ``(group, N, ...)``. Index: ``val_words`` goes in and comes out
+        as a TUPLE of per-shard ``(N, W)`` buffers, so that only real
+        shards' values cross the host-device seam."""
         sort_backend = self._sort_backend or deployment_sort_backend()
+        index = value_path(merge_kind, val_words) == "index"
         key = (merge_kind, drop_tombstones, num_words, uniform_klen, seq32,
-               key_words, sort_backend)
+               key_words, sort_backend, index)
         fn = self._vmapped_cache.get(key)
         if fn is None:
             jax = self._jax
+            flags = dict(drop_tombstones=drop_tombstones,
+                         uniform_klen=uniform_klen, seq32=seq32,
+                         key_words=key_words, sort_backend=sort_backend)
+
+            def with_bloom(out, n):
+                out_valid = jax.lax.iota(jax.numpy.int32, n) < out["count"]
+                out["bloom"] = bloom_build_tpu(
+                    out["key_words_le"], out["key_len"], out_valid,
+                    num_words=num_words,
+                )
+                return out
 
             def one_shard(kwbe, klen, shi, slo, vt, vw, vl, valid):
                 out = merge_resolve_kernel(
                     kwbe, klen, shi, slo, vt, vw, vl, valid,
-                    merge_kind=merge_kind, drop_tombstones=drop_tombstones,
-                    uniform_klen=uniform_klen, seq32=seq32,
-                    key_words=key_words, sort_backend=sort_backend,
-                )
-                out_valid = (
-                    jax.lax.iota(jax.numpy.int32, klen.shape[0]) < out["count"]
-                )
-                bloom = bloom_build_tpu(
-                    out["key_words_le"], out["key_len"], out_valid,
-                    num_words=num_words,
-                )
-                out["bloom"] = bloom
+                    merge_kind=merge_kind, **flags)
+                return with_bloom(out, klen.shape[0])
+
+            def one_shard_rows(kwbe, klen, shi, slo, vt, vl, valid):
+                out = merge_resolve_rows(
+                    kwbe, klen, shi, slo, vt, vl, valid, **flags)
+                return with_bloom(out, klen.shape[0])
+
+            def one_shard_index(kwbe, klen, shi, slo, vt, vws, vl, valid):
+                out = jax.vmap(one_shard_rows)(
+                    kwbe, klen, shi, slo, vt, vl, valid)
+                rows = out.pop("val_row")
+                out["val_words"] = tuple(
+                    gather_value_rows(vw, rows[s], out["count"][s])
+                    for s, vw in enumerate(vws))
                 return out
 
             # the XLA module's name (jit_<name>), by which a device
             # trace's reader finds the pipeline: fixed here, not left to
             # whatever the closure happens to be called
             one_shard.__name__ = PIPELINE_PROGRAM
-            fn = jax.jit(jax.vmap(one_shard))
+            one_shard_index.__name__ = PIPELINE_PROGRAM_INDEX
+            fn = jax.jit(one_shard_index if index
+                         else jax.vmap(one_shard))
             self._vmapped_cache[key] = fn
         return fn
 
@@ -183,15 +215,32 @@ class TpuCompactionService:
         uniform_klen = all(u for u, _, _ in flags)
         seq32 = all(s for _, s, _ in flags)
         key_words = max(k for _, _, k in flags)
+        val_words = batches[0].val_words.shape[1]  # group-uniform
+        path = value_path(merge_kind, val_words)
+        index = path == "index"
+        span = current_span()  # tpu.compact_stream / tpu.compact_batch
+        if span is not None:
+            span.annotate(val_words=val_words, value_path=path)
+        Stats.get().incr("compact.value_path." + path, len(batches))
         fn = self._pipeline(merge_kind, drop_tombstones, num_words,
-                            uniform_klen, seq32, key_words)
+                            uniform_klen, seq32, key_words, val_words)
+
         def stage(lo: int) -> Dict[str, object]:
-            """Stack one group on host and issue its async H2D."""
+            """Stack one group on host and issue its async H2D. On the
+            index path a shard's values go up as a buffer of their own,
+            never stacked; an empty place takes the one zero buffer the
+            device already holds."""
             group = list(batches[lo:lo + group_size])
             pad_shards = group_size - len(group)
             stacked = {}
             with start_span("tpu.h2d", shards=len(group)):
                 for name in _GROUP_LANES:
+                    if index and name == "val_words":
+                        stacked[name] = tuple(
+                            jax.device_put(_pad_to(b.val_words, capacity))
+                            for b in group) + (
+                            self._zeros(capacity, val_words),) * pad_shards
+                        continue
                     arr = np.stack([_pad_to(getattr(b, name), capacity)
                                     for b in group])
                     if pad_shards:
@@ -223,15 +272,28 @@ class TpuCompactionService:
                 num_words, return_arrays))
         return results
 
+    def _zeros(self, capacity: int, val_words: int):
+        """The device's one all-zero ``(capacity, val_words)`` buffer:
+        what an empty place of an index-path group reads."""
+        key = (capacity, val_words)
+        if key not in self._zero_rows:
+            self._zero_rows[key] = self._jax.device_put(
+                np.zeros(key, dtype=np.uint32))
+        return self._zero_rows[key]
+
     def _drain(self, lo: int, out, batches, merge_kind, drop_tombstones,
                num_words, return_arrays=False) -> List[dict]:
-        """Readback + unpack one group's device outputs."""
+        """Readback + unpack one group's device outputs. The index
+        path's values come back per shard (``out["val_words"]`` is a
+        tuple), the real shards' only, each as the padded block."""
+        group = batches[lo:lo + out["count"].shape[0]]
         with start_span("tpu.readback"):  # blocked on the device, and D2H
-            host = {k: np.asarray(v) for k, v in out.items()}
-        group = batches[lo:lo + len(host["count"])]
+            host = {k: np.asarray(v) if not isinstance(v, tuple)
+                    else [np.asarray(a) for a in v[:len(group)]]
+                    for k, v in out.items()}
         results = []
         with start_span("tpu.unpack", shards=len(group)):
-            for s in range(min(len(group), len(host["count"]))):
+            for s in range(len(group)):
                 if bool(host["needs_cpu_fallback"][s]):
                     results.append(self._cpu_recompute(
                         group[s], merge_kind, drop_tombstones, num_words,
@@ -507,10 +569,17 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
 
     Per DB: plan (engine plan_full_compaction: flush + snapshot under the
     compaction mutex), read its runs as lanes, launch the group, install
-    each shard's output files (engine install_full_compaction). DBs the
-    lane representation can't express (custom merge operators, >24B keys,
-    wide values, MERGE records with no operator, oversized shards) are
-    declined untouched.
+    each shard's output files (engine install_full_compaction). Values:
+    with the uint64-add operator, 8 bytes, riding both sorts; with no
+    operator, up to ``RIDE_MAX_VAL_WORDS`` words ride, wider ones up to
+    ``device_value_bytes_max(None)`` bytes take the index path (one
+    row-index lane rides, the values are moved once inside the same
+    module; ``value_path`` on the ``tpu.compact_stream`` span says
+    which). DBs the device path can't express (custom merge operators,
+    >24B keys, values over ``device_value_bytes_max``, MERGE records with
+    no operator, oversized shards) are declined untouched, before any
+    program is built; a decline for width counts under
+    ``tpu.host_fallbacks reason=value_width``.
 
     Returns ``(handled, remaining)``: db names compacted here, and the
     (name, db) pairs the caller must compact per-db (compact_range).
@@ -520,7 +589,7 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
     dbs = list(dbs)
     handled: List[str] = []
     remaining: List[tuple] = []
-    groups: Dict[tuple, List[tuple]] = {}  # (kind, drop) -> items
+    groups: Dict[tuple, List[tuple]] = {}  # (kind, drop, width) -> items
     # every un-consumed plan holds its DB's compaction mutex; the finally
     # below releases any leaked by an unexpected raise so the caller's
     # per-db compact_range fallback can never deadlock
@@ -584,6 +653,14 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
             _abort(db, plan)
             return ("remaining", name, db, None)
         total = lanes["key_len"].shape[0] if lanes is not None else 0
+        widest = int(lanes["val_len"].max()) if total else 0
+        if widest > device_value_bytes_max(merge_op):
+            # wider than the device path takes (for uint64-add: than the
+            # fold is defined on): the host path, and no program built
+            record_host_fallback(
+                "value_width", f"{name}: {widest}-byte values")
+            _abort(db, plan)
+            return ("remaining", name, db, None)
         if (
             lanes is None
             or total == 0
@@ -600,7 +677,11 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
         kind = (
             MergeKind.UINT64_ADD if merge_op is not None else MergeKind.NONE
         )
-        key = (kind, plan["drop_tombstones"])
+        # index-path shards group by their width as well: their values
+        # go up as they are, never padded to a wider neighbour's
+        vw = lanes["val_words"].shape[1]
+        key = (kind, plan["drop_tombstones"],
+               vw if value_path(kind, vw) == "index" else 0)
         return ("grouped", name, db, (key, plan, _LaneBatch(lanes)))
 
     def _install(args, tctx):
@@ -642,7 +723,7 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
                 groups.setdefault(key, []).append((name, db, plan, batch))
 
         svc = TpuCompactionService.instance()
-        for (kind, drop), items in groups.items():
+        for (kind, drop, _vw), items in groups.items():
             batches = [b for _n, _d, _p, b in items]
             vw = max(b.val_words.shape[1] for b in batches)
             for b in batches:  # group-uniform value lanes for np.stack

@@ -99,6 +99,34 @@ def test_service_pipeline_group8_compiles(shape, monkeypatch):
     assert compiled.memory_analysis() is not None
 
 
+def test_service_index_pipeline_group8_compiles(shape, monkeypatch):
+    """The record deployment's program (rec1k_32x20k: 1 KB values, no
+    operator): sorts and resolve vmapped over 8 shards with one row-index
+    lane, then each shard's 256-word rows gathered from a buffer of its
+    own. The chip's compiler keeps the move a row gather (no per-word
+    lowering) and adds no scratch memory of the values' size."""
+    from rocksplicator_tpu.tpu.compaction_service import (
+        PIPELINE_PROGRAM, PIPELINE_PROGRAM_INDEX, TpuCompactionService)
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    n, words = 512, 256
+    fn = TpuCompactionService()._pipeline(
+        MergeKind.NONE, True, num_words_for(n, BITS_PER_KEY), **FAST,
+        val_words=words)
+    lanes = list(_kernel_lanes(shape, n, lead=(8,)))
+    lanes[5] = tuple(shape((n, words)) for _ in range(8))
+    lowered = fn.lower(*lanes)
+    assert PIPELINE_PROGRAM in PIPELINE_PROGRAM_INDEX
+    assert "@jit_" + PIPELINE_PROGRAM_INDEX in lowered.as_text()[:200]
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count(f"u32[{n},{words}]") and " gather(" in text
+    values = 8 * n * words * 4
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < values // 4
+    assert mem.output_size_in_bytes < values + values // 4
+
+
 def test_bloom_build_compiles(shape):
     """The per-output-file bloom (one 16,384-key shard = one file)."""
     from rocksplicator_tpu.ops.bloom_tpu import bloom_build_tpu

@@ -16,6 +16,10 @@ the run's own and the last):
 - **which host codec ran**: the process's ``codec.native_files`` and
   ``codec.python_files`` counters (set-up and window; ``tpu/format.py``),
   and how many point reads found their block in the block cache.
+- **which value path the device compaction took**: the process's
+  ``compact.value_path.ride`` / ``compact.value_path.index`` counters
+  (shards launched with their values riding the sorts / moved once by
+  the resolved order; ``tpu/compaction_service.py``).
 
 Arguments are ``chipbench/run.py``'s own.
 """
@@ -110,6 +114,10 @@ def main(argv=None) -> int:
              for k in ("codec.native_files", "codec.python_files",
                        "storage.block_cache.hit",
                        "storage.block_cache.miss")}))
+        harness.say("shards by value path, whole process: " + json.dumps(
+            {k: Stats.get().get_counter(k)
+             for k in ("compact.value_path.ride",
+                       "compact.value_path.index")}))
         return out
 
     tr.reduce, harness.reduce_trace = reduce, reduce_trace
