@@ -831,7 +831,9 @@ class AdminHandler:
         re-taken — with a close/idempotency staleness re-check — for the
         engine ingest + meta write only. N shards therefore download
         while others ingest; the post-load compaction coalesces across
-        shards in the BatchCompactor."""
+        shards in the BatchCompactor, which is told of each admitted
+        ingest that will end there, so that the first to arrive waits
+        for the rest (one dispatch for the N, not 1 then N - 1)."""
         store = self._store(s3_bucket)
         tctx = wire_context()
 
@@ -865,12 +867,16 @@ class AdminHandler:
                 f"{self._ingest_gate.in_flight} ingests in flight "
                 f"(max {self._ingest_gate.capacity})",
             )
+        # admitted: tell the compactor that this RPC's shard is on its
+        # way, so that a sibling's dispatch does not leave without it
+        ticket = self._batch_compactor.expect() if compact_after else None
         try:
             return self._do_ingest(
                 sp, db_name, store, s3_bucket, s3_path,
-                ingest_behind, allow_overlapping_keys, compact_after,
+                ingest_behind, allow_overlapping_keys, ticket,
             )
         finally:
+            self._batch_compactor.retire(ticket)  # unless compact() did
             self._ingest_gate.exit()
 
     @staticmethod
@@ -888,7 +894,7 @@ class AdminHandler:
 
     def _do_ingest(
         self, sp, db_name, store, s3_bucket, s3_path,
-        ingest_behind, allow_overlapping_keys, compact_after,
+        ingest_behind, allow_overlapping_keys, compact_ticket,
     ) -> dict:
         tmp = tempfile.mkdtemp(prefix=f"rstpu-ingest-{db_name}-")
         try:
@@ -968,12 +974,13 @@ class AdminHandler:
                     self.write_meta_data(db_name, s3_bucket, s3_path)  # :1836
             # -- post-load compaction: outside the admin lock, batched
             #    across concurrently-loading shards ------------------------
-            if compact_after:
+            if compact_ticket is not None:
                 with Timer("admin.post_ingest_compact_ms"), \
                         start_span("admin.ingest.compact") as csp:
                     try:
                         batched_with = self._batch_compactor.compact(
-                            db_name, target_db.db)  # :1845-1850
+                            db_name, target_db.db,
+                            compact_ticket)  # :1845-1850
                         csp.annotate(batch=batched_with)
                     except StorageError:
                         # compaction is advisory: a closeDB/clearDB that

@@ -12,8 +12,9 @@ The pipelined load_sst path (ISSUE 3) is three bounded stages:
   re-check (the lock-narrowing half; see admin/handler.py);
 - **post-load compact** — :class:`BatchCompactor`: concurrent shards'
   compactions coalesce AckWindow/group-commit style; one submitter
-  becomes the dispatch leader and drains the whole queue as a batch (one
-  padded device launch on the TPU backend via
+  becomes the dispatch leader, waits (at most one dispatch time) for the
+  ingests the handler has already admitted, and dispatches the queue as
+  a batch (one padded device launch on the TPU backend via
   tpu.compaction_service.compact_dbs_batched; thread-pool fan-out on
   CPU), every submitter just waits on its shard's future.
 """
@@ -24,11 +25,13 @@ import logging
 import os
 import threading
 import time
+from collections import deque
 from concurrent.futures import Future, ThreadPoolExecutor
-from typing import Dict, List, Optional, Tuple
+from typing import Deque, Dict, List, Optional, Set, Tuple
 
 from ..observability.span import start_span
 from ..testing import failpoints as fp
+from ..utils.stats import Stats
 
 log = logging.getLogger(__name__)
 
@@ -102,15 +105,21 @@ class IngestGate:
             self._free.notify()
 
 
+# The device launch's shard places (compact_dbs_batched's ``group_size``):
+# a queue that fills whole launches has nobody left worth waiting for.
+LAUNCH_GROUP = 8
+
+
 class _Taken:
     """A queued shard's signal that a dispatch has taken it (set by the
-    leader, with the batch's size)."""
+    leader, with the batch's size), and when it was queued."""
 
-    __slots__ = ("event", "batch")
+    __slots__ = ("event", "batch", "since")
 
     def __init__(self):
         self.event = threading.Event()
         self.batch = 0
+        self.since = time.monotonic()
 
 
 class BatchCompactor:
@@ -118,13 +127,26 @@ class BatchCompactor:
 
     ``compact(db_name, db)`` blocks until the shard's full compaction is
     done, but concurrent callers are BATCHED: the first submitter into an
-    idle compactor becomes the leader and repeatedly drains everything
-    queued (shards that arrive while a batch runs form the next batch —
-    the same natural coalescing as WAL group commit). Dispatch goes
-    through the configured backend: one padded device launch per batch
-    when ``use_tpu`` (compact_dbs_batched), thread-pool fan-out of
-    per-db ``compact_range`` otherwise (and for shards the lane
-    representation declines).
+    idle compactor becomes the leader and dispatches what is queued,
+    batch after batch, until the queue is empty (shards that arrive
+    while a batch runs form the next batch — the same natural coalescing
+    as WAL group commit).
+
+    The leader does not launch alone while siblings are on their way.
+    The handler announces an ingest that will end in ``compact`` as soon
+    as it is admitted (``expect``); before each batch the leader lingers
+    while announced callers have not queued yet, and goes when none is
+    left, when the queue fills whole launches (``LAUNCH_GROUP``), or
+    when the oldest queued shard has waited as long as a dispatch lately
+    takes: a sibling that misses the batch waits one dispatch, so a
+    longer wait for it cannot pay. That bound is the mean of this
+    compactor's own recent dispatch times; with none observed, or
+    nothing announced, there is no linger.
+
+    Dispatch goes through the configured backend: one padded device
+    launch per batch when ``use_tpu`` (compact_dbs_batched), thread-pool
+    fan-out of per-db ``compact_range`` otherwise (and for shards the
+    lane representation declines).
     """
 
     def __init__(self, use_tpu: bool = False,
@@ -133,8 +155,14 @@ class BatchCompactor:
         self._use_tpu = use_tpu
         self._max_batch = max_batch
         self._lock = threading.Lock()
+        # the leader's linger: a caller queued, an announcement retired
+        self._arrival = threading.Condition(self._lock)
         self._queue: List[Tuple[str, object, Future, "_Taken"]] = []
+        self._expected: Set[object] = set()  # announced, not yet queued
         self._dispatching = False
+        # wall seconds of the latest dispatches (the leader's alone to
+        # write and read): their mean bounds a linger
+        self._dispatch_s: Deque[float] = deque(maxlen=8)
         # compaction releases the GIL in its numpy/zlib/fsync phases, so
         # more workers than cores still overlaps usefully
         self._pool = ThreadPoolExecutor(
@@ -146,20 +174,44 @@ class BatchCompactor:
         self.dispatch_count = 0
         self.batch_sizes: List[int] = []
 
-    def compact(self, db_name: str, db) -> int:
+    def expect(self) -> object:
+        """Announce a caller that will reach ``compact``: the ticket to
+        hand it. Whoever announces retires the ticket exactly once:
+        ``compact`` does as it queues the shard, ``retire`` when the
+        caller leaves any other way."""
+        ticket = object()
+        with self._lock:
+            self._expected.add(ticket)
+        return ticket
+
+    def retire(self, ticket: Optional[object]) -> None:
+        """The announced caller is not coming (or has queued already:
+        then this does nothing)."""
+        with self._arrival:
+            if ticket in self._expected:
+                self._expected.remove(ticket)
+                self._arrival.notify()  # the leader, in its linger
+
+    def compact(self, db_name: str, db,
+                ticket: Optional[object] = None) -> int:
         """Compact ``db`` (a storage.engine.DB), batched with concurrent
-        callers. Returns the size of the batch this shard rode in."""
+        callers. ``ticket``: this caller's ``expect()``. Returns the
+        size of the batch this shard rode in."""
         fut: Future = Future()
         taken = _Taken()
         leader, batch = False, None
         try:
             # enqueue → the start of the dispatch that takes this shard
+            # (a leader's linger for its siblings included)
             with start_span("admin.compact.wait") as wsp:
-                with self._lock:
+                with self._arrival:
+                    self._expected.discard(ticket)
                     self._queue.append((db_name, db, fut, taken))
                     leader = not self._dispatching
                     if leader:
                         self._dispatching = True
+                    else:
+                        self._arrival.notify()
                 if leader:
                     # the queue was empty: this shard heads the batch
                     batch = self._take_batch()
@@ -172,6 +224,7 @@ class BatchCompactor:
                 with start_span("admin.compact.ride"):
                     return fut.result()
             while batch:
+                t0 = time.monotonic()
                 try:
                     self._dispatch(batch)
                 except BaseException as e:
@@ -182,6 +235,7 @@ class BatchCompactor:
                     for _n, _d, f in batch:
                         if not f.done():
                             f.set_exception(e)
+                self._dispatch_s.append(time.monotonic() - t0)
                 batch = self._take_batch()
         except BaseException:
             # pathological (queue handling itself raised): hand
@@ -193,8 +247,10 @@ class BatchCompactor:
         return fut.result()
 
     def _take_batch(self) -> List[Tuple[str, object, Future]]:
-        """The leader's next batch off the queue, its callers told that
-        their wait is over; empty hands leadership back."""
+        """The leader's next batch off the queue (after the linger for
+        announced siblings), its callers told that their wait is over;
+        empty hands leadership back."""
+        self._linger()
         with self._lock:
             entries = self._queue[: self._max_batch]
             del self._queue[: self._max_batch]
@@ -204,6 +260,38 @@ class BatchCompactor:
             taken.batch = len(entries)
             taken.event.set()
         return [(n, d, f) for n, d, f, _t in entries]
+
+    def _linger_left(self) -> Optional[float]:
+        """Seconds the leader may still wait for announced siblings
+        (``_lock`` held); None when there is nobody to wait for."""
+        if not (self._queue and self._expected and self._dispatch_s) \
+                or len(self._queue) % LAUNCH_GROUP == 0:
+            return None
+        bound = sum(self._dispatch_s) / len(self._dispatch_s)
+        return self._queue[0][3].since + bound - time.monotonic()
+
+    def _linger(self) -> None:
+        with self._lock:
+            left = self._linger_left()
+            if left is None or left <= 0:
+                # a batch that formed while a dispatch ran has waited
+                # that long already
+                return
+            expected, had = len(self._expected), len(self._queue)
+        t0 = time.monotonic()
+        with start_span("admin.compact.linger", expected=expected) as sp:
+            with self._arrival:
+                while left is not None and left > 0:
+                    self._arrival.wait(left)
+                    left = self._linger_left()
+                joined = len(self._queue) - had
+            # the bound ran out with siblings still on their way
+            timed_out = left is not None
+            sp.annotate(joined=joined, timed_out=timed_out)
+        stats = Stats.get()
+        stats.incr("compact.linger.joined", joined)
+        stats.incr("compact.linger.timeouts", int(timed_out))
+        stats.incr("compact.linger.ms", (time.monotonic() - t0) * 1000.0)
 
     # -- dispatch ---------------------------------------------------------
 
@@ -245,7 +333,7 @@ class BatchCompactor:
                 # host stages (plan/lane-read, SST write/install) fan out
                 # over this pool; only the device launch is centralized
                 handled, remaining = compact_dbs_batched(
-                    remaining, pool=self._pool)
+                    remaining, group_size=LAUNCH_GROUP, pool=self._pool)
             except Exception:  # launch machinery itself blew up
                 record_host_fallback(
                     "batched_dispatch",

@@ -19,7 +19,7 @@ runtime's dynamic cycle detection.
 RANKS = {
     "rocksplicator_tpu/replication/ack_window.py:127": ('AckWindow._cond', 0),
     "rocksplicator_tpu/admin/handler.py:161": ('AdminHandler._db_admin_lock', 1),
-    "rocksplicator_tpu/admin/ingest_pipeline.py:135": ('BatchCompactor._lock', 2),
+    "rocksplicator_tpu/admin/ingest_pipeline.py:157": ('BatchCompactor._lock', 2),
     "rocksplicator_tpu/storage/sst.py:99": ('BlockCache._instance_lock', 3),
     "rocksplicator_tpu/storage/sst.py:103": ('BlockCache._lock', 4),
     "rocksplicator_tpu/kafka/network.py:91": ('BrokerHandler._log_lock', 5),
@@ -37,7 +37,7 @@ RANKS = {
     "rocksplicator_tpu/utils/flags.py:34": ('FlagRegistry._lock', 17),
     "rocksplicator_tpu/utils/graceful_shutdown.py:30": ('GracefulShutdownHandler._lock', 18),
     "rocksplicator_tpu/utils/hot_key_detector.py:27": ('HotKeyDetector._lock', 19),
-    "rocksplicator_tpu/admin/ingest_pipeline.py:52": ('IngestGate._lock', 20),
+    "rocksplicator_tpu/admin/ingest_pipeline.py:55": ('IngestGate._lock', 20),
     "rocksplicator_tpu/storage/compaction_scheduler.py:118": ('IoBudget._fg_cv', 21),
     "rocksplicator_tpu/storage/compaction_scheduler.py:117": ('IoBudget._fg_lock', 22),
     "rocksplicator_tpu/rpc/ioloop.py:37": ('IoLoop._default_lock', 23),

@@ -734,10 +734,12 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
             try:
                 # ALWAYS the fixed (group_size, capacity) launch shape,
                 # short groups padded with empty shards: how many shards
-                # coalesce into one dispatch is timing (BatchCompactor
-                # group commit), and a program per distinct count costs
-                # a compile of minutes on the chip (PERF.md "Chip
-                # status"). H2D of group i+1 overlaps group i's kernel.
+                # a dispatch carries is the BatchCompactor's to decide
+                # (its leader waits for the admitted ingests, up to a
+                # full group; a lone caller or a straggler still makes a
+                # short one), and a program per distinct count costs a
+                # compile of minutes on the chip (PERF.md section 6, PR
+                # 22). H2D of group i+1 overlaps group i's kernel.
                 results = svc.compact_shard_stream(
                     batches, merge_kind=kind, drop_tombstones=drop,
                     group_size=group_size, return_arrays=True)

@@ -22,6 +22,7 @@ from rocksplicator_tpu.rpc import (IoLoop, RpcApplicationError, RpcClientPool,
                                    RpcServer)
 from rocksplicator_tpu.storage import DB, OpType, WriteBatch
 from rocksplicator_tpu.storage.sst import SSTWriter
+from rocksplicator_tpu.testing import failpoints as fp
 from rocksplicator_tpu.utils.objectstore import (LocalObjectStore,
                                                  ObjectStoreError)
 
@@ -241,7 +242,7 @@ def test_close_racing_post_load_compact_is_benign(
     n.handler._store = lambda uri: store
     n.call("add_db", db_name="seg00001", role="LEADER")
 
-    def torn_down_compact(self, db_name, db):
+    def torn_down_compact(self, db_name, db, ticket=None):
         # simulate the race outcome: close lands first, compact then
         # sees a closed engine
         n.handler.db_manager.remove_db(db_name)
@@ -320,6 +321,343 @@ def test_batch_compactor_propagates_per_db_errors():
         assert ok == ["good"]
     finally:
         compactor.close()
+
+
+# ---------------------------------------------------------------------------
+# the leader's linger for the siblings the admin plane has admitted
+# ---------------------------------------------------------------------------
+
+LONG = 60.0  # a dispatch time no linger in this file may run out
+
+
+def wait_until(pred, timeout=20.0):
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        if pred():
+            return True
+        time.sleep(0.005)
+    return pred()
+
+
+def linger_counters():
+    from rocksplicator_tpu.utils.stats import Stats
+
+    return {k: Stats.get().get_counter("compact.linger." + k)
+            for k in ("joined", "timeouts", "ms")}
+
+
+def lingering(compactor):
+    """The leader has been chosen and has not taken its batch yet."""
+    return compactor._dispatching and compactor.dispatch_count == 0
+
+
+class Callers:
+    """Threads through ``BatchCompactor.compact``, each over a StubDB."""
+
+    def __init__(self, compactor):
+        self.compactor = compactor
+        self.done, self.sizes, self.threads = [], {}, []
+
+    def submit(self, name, ticket=None):
+        def run():
+            self.sizes[name] = self.compactor.compact(
+                name, StubDB(self.done, name), ticket)
+
+        t = threading.Thread(target=run)
+        t.start()
+        self.threads.append(t)
+
+    def join(self):
+        for t in self.threads:
+            t.join(30)
+            assert not t.is_alive()
+
+
+@pytest.fixture()
+def compactor():
+    c = BatchCompactor(use_tpu=False, compact_parallelism=2)
+    yield c
+    c.close()
+
+
+@pytest.mark.parametrize("n", [2, 5, 7])
+def test_announced_callers_arriving_staggered_ride_one_dispatch(
+        compactor, n):
+    compactor._dispatch_s.append(LONG)
+    tickets = [compactor.expect() for _ in range(n)]
+    callers = Callers(compactor)
+    t0 = time.monotonic()
+    for i, ticket in enumerate(tickets):
+        callers.submit(f"db{i}", ticket)
+        time.sleep(0.02)  # an arrival spread, not a dispatch time
+    callers.join()
+    assert compactor.batch_sizes == [n]
+    assert set(callers.sizes.values()) == {n}
+    assert time.monotonic() - t0 < LONG / 4  # the last arrival ended it
+    assert not compactor._expected
+    c = linger_counters()
+    assert c["joined"] == n - 1 and c["timeouts"] == 0 and c["ms"] > 0
+
+
+@pytest.mark.parametrize("announced, observed", [
+    (0, True),    # a direct caller, nobody announced: as before PR 30
+    (0, False),
+    (1, False),   # a sibling is on its way, but no dispatch was timed yet
+])
+def test_no_linger_without_an_announcement_or_an_estimate(
+        compactor, announced, observed):
+    if observed:
+        compactor._dispatch_s.append(LONG)
+    tickets = [compactor.expect() for _ in range(announced)]
+    callers = Callers(compactor)
+    t0 = time.monotonic()
+    callers.submit("db0")
+    callers.join()
+    assert time.monotonic() - t0 < LONG / 4
+    assert compactor.batch_sizes == [1]
+    assert linger_counters() == {"joined": 0, "timeouts": 0, "ms": 0}
+    # the dispatch itself was timed: the next leader has an estimate
+    assert len(compactor._dispatch_s) == 1 + observed
+    for ticket in tickets:
+        compactor.retire(ticket)
+    assert not compactor._expected
+
+
+def test_straggler_past_the_bound_rides_the_next_batch(compactor):
+    compactor._dispatch_s.append(0.05)  # the injected dispatch time
+    on_time, late = compactor.expect(), compactor.expect()
+    callers = Callers(compactor)
+    callers.submit("db0", on_time)
+    # the leader gives the straggler one dispatch time, then goes
+    assert wait_until(lambda: compactor.dispatch_count == 1)
+    assert compactor.batch_sizes == [1]
+    c = linger_counters()
+    assert c["timeouts"] == 1 and c["joined"] == 0 and c["ms"] >= 50.0
+    callers.submit("db1", late)
+    callers.join()
+    assert compactor.batch_sizes == [1, 1] and callers.done == ["db0", "db1"]
+    assert not compactor._expected
+    assert linger_counters()["timeouts"] == 1
+
+
+def test_leaked_announcement_costs_each_leader_the_bound_and_is_counted(
+        compactor):
+    """What a handler that forgot to retire would do: every later leader
+    waits the full bound, and the timeouts counter says so."""
+    compactor.expect()  # planted: never retired
+    for i in range(3):
+        compactor._dispatch_s.clear()
+        compactor._dispatch_s.append(0.03)
+        callers = Callers(compactor)
+        callers.submit(f"db{i}")
+        callers.join()
+    assert compactor.batch_sizes == [1, 1, 1]
+    c = linger_counters()
+    assert c["timeouts"] == 3 and c["ms"] >= 3 * 30.0
+
+
+def test_full_launch_group_does_not_wait_for_a_ninth(compactor):
+    from rocksplicator_tpu.admin.ingest_pipeline import LAUNCH_GROUP
+
+    compactor._dispatch_s.append(LONG)
+    tickets = [compactor.expect() for _ in range(LAUNCH_GROUP + 1)]
+    callers = Callers(compactor)
+    t0 = time.monotonic()
+    for i in range(LAUNCH_GROUP):
+        callers.submit(f"db{i}", tickets[i])
+        time.sleep(0.01)
+    callers.join()
+    assert time.monotonic() - t0 < LONG / 4
+    assert compactor.batch_sizes == [LAUNCH_GROUP]
+    assert len(compactor._expected) == 1  # the ninth, still on its way
+    assert linger_counters()["timeouts"] == 0
+    compactor.retire(tickets[-1])
+    compactor.retire(tickets[-1])  # retiring twice retires once
+    assert not compactor._expected
+
+
+def test_retire_of_the_last_sibling_releases_the_leader(compactor):
+    compactor._dispatch_s.append(LONG)
+    mine, sibling = compactor.expect(), compactor.expect()
+    callers = Callers(compactor)
+    t0 = time.monotonic()
+    callers.submit("db0", mine)
+    assert wait_until(lambda: lingering(compactor))
+    time.sleep(0.05)
+    assert compactor.dispatch_count == 0  # held by the sibling alone
+    compactor.retire(sibling)
+    callers.join()
+    assert time.monotonic() - t0 < LONG / 4
+    assert compactor.batch_sizes == [1]
+    assert linger_counters()["timeouts"] == 0
+
+
+def test_linger_under_thread_churn_loses_no_caller_and_no_ticket(compactor):
+    """More threads than cores, a short switch interval: every caller
+    that announced either compacts or retires, in any interleaving; each
+    shard is dispatched exactly once, nothing stays announced, and the
+    leadership is handed back."""
+    import random
+    import sys
+
+    workers, rounds = 24, 12
+    compactor._dispatch_s.append(0.002)
+    done, errors = [], []
+
+    def churn(w):
+        rng = random.Random(w)
+        try:
+            for r in range(rounds):
+                ticket = compactor.expect() if rng.random() < 0.8 else None
+                if rng.random() < 0.25:
+                    compactor.retire(ticket)  # left before the compactor
+                    compactor.retire(ticket)
+                    continue
+                compactor.compact(f"w{w}r{r}", StubDB(done, (w, r)), ticket)
+        except BaseException as e:  # surfaced by the assert below
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=churn, args=(w,))
+                   for w in range(workers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+    assert not errors
+    assert len(done) == len(set(done)) == sum(compactor.batch_sizes)
+    assert not compactor._expected and not compactor._queue
+    assert not compactor._dispatching
+
+
+class PerBucketStores:
+    """``handler._store`` by bucket name: ``gated`` parks its downloads,
+    every other bucket reads the same directory ungated."""
+
+    def __init__(self, root):
+        self.gated = GatedStore(root)
+        self.plain = LocalObjectStore(root)
+
+    def __call__(self, uri):
+        return self.gated if uri == "gated" else self.plain
+
+
+def _die_at_failpoint(site):
+    def arm(n):
+        fp.activate(site, "fail_first:1")
+    return arm
+
+
+def _die_of_db_not_found(n):
+    n.call("close_db", db_name="seg00002")
+
+
+def _die_of_bad_sst(n):
+    bad = n.stores.plain._path("sst/b/bulk.tsst")
+    os.remove(bad)  # the download's hardlink would share the inode
+    with open(bad, "wb") as f:
+        f.write(b"not an sst")
+
+
+def _leave_by_the_idempotent_skip(n):
+    n.handler.write_meta_data("seg00002", "gated", "sst/b")
+
+
+@pytest.mark.parametrize("how, code", [
+    (_die_at_failpoint("admin.ingest.engine"), "INTERNAL"),
+    (_die_at_failpoint("admin.ingest.meta"), "INTERNAL"),
+    (_die_of_bad_sst, "DB_ADMIN_ERROR"),
+    (_die_of_db_not_found, "DB_NOT_FOUND"),
+    (_leave_by_the_idempotent_skip, None),
+], ids=["fp_engine", "fp_meta", "bad_sst", "db_not_found", "skipped"])
+def test_sibling_that_never_reaches_the_compactor_retires_its_announcement(
+        node_factory, tmp_path, how, code):
+    """Two admitted ingests; one leads the compactor and lingers for the
+    other, which leaves its RPC before it enqueues: the leader goes at
+    once, alone, and nothing stays announced."""
+    n = node_factory()
+    n.stores = PerBucketStores(str(tmp_path / "bucket"))
+    put_sst(n.stores.plain, "sst/a", [(b"a", b"1")], tmp_path)
+    put_sst(n.stores.plain, "sst/b", [(b"b", b"2")], tmp_path)
+    n.handler._store = n.stores
+    compactor = n.handler._batch_compactor
+    compactor._dispatch_s.append(LONG)
+    n.call("add_db", db_name="seg00001", role="LEADER")
+    n.call("add_db", db_name="seg00002", role="LEADER")
+    t0 = time.monotonic()
+    doomed = n.call_async(
+        "add_s3_sst_files_to_db", db_name="seg00002", s3_bucket="gated",
+        s3_path="sst/b", compact_db_after_load=True)
+    assert n.stores.gated.started.acquire(timeout=10)  # admitted: announced
+    healthy = n.call_async(
+        "add_s3_sst_files_to_db", db_name="seg00001", s3_bucket="plain",
+        s3_path="sst/a", compact_db_after_load=True)
+    assert wait_until(lambda: lingering(compactor))
+    assert len(compactor._expected) == 1
+    try:
+        how(n)
+        n.stores.gated.release.set()
+        if code is None:
+            assert doomed.result(30) == {"skipped": True}
+        else:
+            with pytest.raises(RpcApplicationError) as ei:
+                doomed.result(30)
+            assert ei.value.code == code
+        assert healthy.result(30)["ingested_files"] == 1
+    finally:
+        fp.reset_for_test()
+    assert time.monotonic() - t0 < LONG / 4
+    assert compactor.batch_sizes == [1]
+    assert not compactor._expected
+    assert linger_counters()["timeouts"] == 0
+
+
+def test_eight_concurrent_served_ingests_make_one_dispatch(
+        node_factory, tmp_path):
+    """Through the served RPC on the CPU backend: eight admitted ingests
+    with compact_db_after_load are one dispatch, an ingest without it
+    announces nothing."""
+    shards = 8
+    n = node_factory(max_sst_loading_concurrency=shards + 1,
+                     executor_threads=shards + 1)
+    store = GatedStore(str(tmp_path / "bucket"))
+    for s in range(shards + 1):
+        put_sst(store, f"sst/{s:05d}",
+                [(f"s{s}-k{i:03d}".encode(), pack64(s * 100 + i))
+                 for i in range(20)], tmp_path)
+        n.call("add_db", db_name=f"seg{s:05d}", role="LEADER")
+    n.handler._store = lambda uri: store
+    compactor = n.handler._batch_compactor
+    compactor._dispatch_s.append(LONG)
+    plain = n.call_async(
+        "add_s3_sst_files_to_db", db_name=f"seg{shards:05d}",
+        s3_bucket="bkt", s3_path=f"sst/{shards:05d}")
+    assert store.started.acquire(timeout=10)
+    assert not compactor._expected
+    futs = [
+        n.call_async("add_s3_sst_files_to_db", db_name=f"seg{s:05d}",
+                     s3_bucket="bkt", s3_path=f"sst/{s:05d}",
+                     compact_db_after_load=True)
+        for s in range(shards)
+    ]
+    for _ in range(shards):
+        assert store.started.acquire(timeout=10)
+    assert len(compactor._expected) == shards  # all admitted, none queued
+    store.release.set()
+    for f in futs + [plain]:
+        assert f.result(60)["ingested_files"] == 1
+    assert compactor.batch_sizes == [shards]
+    assert not compactor._expected
+    c = linger_counters()
+    assert c["joined"] == shards - 1 and c["timeouts"] == 0
+    for s in range(shards):
+        app_db = n.handler.db_manager.get_db(f"seg{s:05d}")
+        assert app_db.get(f"s{s}-k019".encode()) == pack64(s * 100 + 19)
 
 
 def test_compact_dbs_batched_tpu_parity(tmp_path):

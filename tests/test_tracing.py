@@ -767,3 +767,62 @@ def test_clock_check_pairs_module_events_with_their_launch_spans():
         "largest_violation_us": 1000.0}
     assert clock_check(recording, (0.0, 1000 * ms), spans[:1]) == {
         "module_events": 2, "launch_pairs": 0}
+
+
+@pytest.mark.parametrize("straggler", [False, True],
+                         ids=["all_join", "bound_runs_out"])
+def test_leaders_linger_is_a_span_under_its_wait(straggler):
+    """The leader of a group commit waits for the siblings the handler
+    announced: admin.compact.linger, a child of the LEADER's
+    admin.compact.wait (which therefore contains it), annotated with how
+    many were expected when it began, how many joined, and whether the
+    bound (the injected dispatch time) ran out first."""
+    import threading
+
+    from rocksplicator_tpu.admin.ingest_pipeline import BatchCompactor
+
+    class Stub:
+        def compact_range(self):
+            pass
+
+    col = SpanCollector.get()
+    col.configure(sample_rate=0.0, capacity=4096)
+    compactor = BatchCompactor(use_tpu=False, compact_parallelism=2)
+    compactor._dispatch_s.append(0.2 if straggler else 60.0)
+    tickets = [compactor.expect() for _ in range(3)]
+
+    def submit(s):
+        with start_span("test.caller", always=True, shard=s):
+            compactor.compact(f"db{s}", Stub(), tickets[s])
+
+    threads = [threading.Thread(target=submit, args=(s,)) for s in range(3)]
+    try:
+        threads[0].start()
+        assert wait_until(lambda: compactor._dispatching, interval=0.005)
+        time.sleep(0.02)  # the leader is in its linger, two are expected
+        threads[1].start()
+        if straggler:
+            assert wait_until(lambda: compactor.dispatch_count == 1,
+                              interval=0.005)
+        threads[2].start()
+        for t in threads:
+            t.join(30)
+            assert not t.is_alive()
+    finally:
+        compactor.close()
+    assert compactor.batch_sizes == ([2, 1] if straggler else [3])
+
+    (linger,) = _spans_by_name("admin.compact.linger")
+    assert linger["annotations"] == {
+        "expected": 2, "joined": 1 if straggler else 2,
+        "timed_out": straggler}
+    waits = {w["span_id"]: w for w in _spans_by_name("admin.compact.wait")}
+    assert len(waits) == 3
+    leader = waits[linger["parent_id"]]
+    assert leader["trace_id"] == linger["trace_id"]
+    assert leader["annotations"]["batch"] == (2 if straggler else 3)
+    assert leader["duration_ms"] >= linger["duration_ms"]
+    if straggler:
+        assert linger["duration_ms"] >= 200.0 - 20.0  # one dispatch time
+    riders = [w for w in waits.values() if w is not leader]
+    assert all(w["duration_ms"] <= leader["duration_ms"] for w in riders)

@@ -20,6 +20,13 @@ the run's own and the last):
   ``compact.value_path.ride`` / ``compact.value_path.index`` counters
   (shards launched with their values riding the sorts / moved once by
   the resolved order; ``tpu/compaction_service.py``).
+- **what the group commit's linger did**: the process's
+  ``compact.linger.joined`` / ``.timeouts`` / ``.ms`` counters (siblings
+  that joined a leader's batch while it waited for them, lingers that
+  ran into their bound, milliseconds lingered in all;
+  ``admin/ingest_pipeline.py``) beside the window's
+  ``admin.compact.linger`` spans: how many, their mean and their longest.
+  This look needs no recording: it is printed with ``--trace 0`` too.
 
 Arguments are ``chipbench/run.py``'s own.
 """
@@ -120,13 +127,29 @@ def main(argv=None) -> int:
                        "compact.value_path.index")}))
         return out
 
+    real_read_metrics = harness.read_metrics
+
+    def read_metrics(bench, group, package, cell, run):
+        from rocksplicator_tpu.utils.stats import Stats
+
+        ms = [s["duration_ms"] for s in run.spans
+              if s["name"] == "admin.compact.linger"]
+        harness.say("group commit's linger: " + json.dumps(dict(
+            {"window_spans": len(ms),
+             "window_mean_ms": round(sum(ms) / len(ms), 2) if ms else None,
+             "window_max_ms": round(max(ms), 2) if ms else None},
+            **{"process_" + k: Stats.get().get_counter("compact.linger." + k)
+               for k in ("joined", "timeouts", "ms")})))
+        return real_read_metrics(bench, group, package, cell, run)
+
     tr.reduce, harness.reduce_trace = reduce, reduce_trace
-    harness.run_cell = run_cell
+    harness.run_cell, harness.read_metrics = run_cell, read_metrics
     try:
         return harness.main(argv)
     finally:
         tr.reduce, harness.reduce_trace = real_reduce, real_reduce_trace
         harness.run_cell = real_run_cell
+        harness.read_metrics = real_read_metrics
 
 
 if __name__ == "__main__":
