@@ -505,7 +505,7 @@ def run_main_path(args) -> bool:
 def run_mesh_path(args) -> bool:
     """``--chips 4``: the sharded compaction step on a 4-device mesh vs
     the unsharded single-chip pipeline over the same data, hashes equal
-    (``__graft_entry__.dryrun_multichip``, lax backend, which prints each
+    (``__graft_entry__.dryrun_multichip``, which prints each
     input's sharding and bytes per device) — and no other phase."""
     import jax
 
@@ -516,7 +516,6 @@ def run_mesh_path(args) -> bool:
             f"has {len(jax.devices())}")
         return False
     t0 = time.monotonic()
-    os.environ.pop("RSTPU_DRYRUN_BACKEND", None)  # the lax leg only
     graft.dryrun_multichip(args.chips,
                            entries_per_block=args.entries_per_block)
     say(f"sharded vs unsharded: hashes equal "

@@ -15,7 +15,7 @@ system:
                     machines, shard-map generation (reference
                     cluster_management/ Java+Helix, rebuilt without a JVM).
 - ``tpu``         : the new part — compaction / SST bulk-ingest hot path
-                    offloaded to TPU via JAX/Pallas kernels (k-way merge,
+                    offloaded to TPU as JAX array programs (k-way merge,
                     bloom construction, block encoding), sharded over a
                     ``jax.sharding.Mesh``.
 - ``rpc``         : typed async RPC with zero-copy binary payloads

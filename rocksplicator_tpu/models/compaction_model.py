@@ -49,13 +49,6 @@ class CompactionModel:
     # pipeline while planar is concatenation (PERF.md)
     emit_planar: bool = False
     planar_block_entries: int = 1024
-    # "lax" = XLA's generic sort; "pallas" = the VMEM-resident bitonic
-    # kernel (ops/pallas_sort.py) that holds every operand lane on-chip
-    # across all compare-exchange stages — the attack on the sort's HBM
-    # traffic (PERF.md round-2 lever); "pallas_fused" = the whole
-    # merge-resolve (sort + scans + compaction) in one VMEM residency
-    # (ops/pallas_resolve.py). Opt-in until chip-measured.
-    sort_backend: str = "lax"
 
     @property
     def num_bloom_words(self) -> int:
@@ -78,7 +71,6 @@ class CompactionModel:
             drop_tombstones=self.drop_tombstones,
             uniform_klen=self.uniform_klen, seq32=self.seq32,
             key_words=self.key_words,
-            sort_backend=self.sort_backend,
         )
         out_valid = jax.lax.iota(jnp.int32, key_len.shape[0]) < out["count"]
         out["bloom"] = bloom_build_tpu(

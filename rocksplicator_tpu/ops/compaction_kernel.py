@@ -61,37 +61,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..utils.flags import FLAGS, define_flag
 from .kv_format import KEY_WORDS
 
 # OpType values (storage/records.py) as device constants
 _PUT = 1
 _DELETE = 2
 _MERGE = 3
-
-
-_SORT_BACKENDS = ("lax", "pallas", "pallas_fused")
-
-define_flag(
-    "sort_backend", "lax",
-    "merge_resolve_kernel sort backend for consumers with no per-call "
-    "configuration (compaction service / engine-seam TPU backend / "
-    "chunked merge): lax | pallas | pallas_fused. Env override: "
-    "RSTPU_FLAG_SORT_BACKEND; runtime: FLAGS.set('sort_backend', ...)")
-
-
-def deployment_sort_backend() -> str:
-    """The deployment-wide sort backend choice — the ``sort_backend``
-    flag (utils/flags.py: env ``RSTPU_FLAG_SORT_BACKEND``, runtime
-    ``FLAGS.set``, visible in the /gflags.txt dump). One source of truth
-    for every runtime consumer of merge_resolve_kernel that has no
-    per-call configuration. An unknown value RAISES: a misspelt flag
-    must not run the fleet on a backend nobody chose."""
-    v = FLAGS.get("sort_backend")
-    if v not in _SORT_BACKENDS:
-        raise ValueError(
-            f"sort_backend flag {v!r} is not one of {_SORT_BACKENDS}")
-    return v
 
 
 class MergeKind(enum.Enum):
@@ -141,10 +116,9 @@ def composite_key_lanes(invalid, key_word_lanes, key_len, seq_hi, seq_lo,
     """THE canonical comparator lane order — (invalid-last, key words BE
     asc, [key_len], [~seq_hi], ~seq_lo) — as a lane list. Every consumer
     of the composite order builds it here so they cannot desync: the
-    full-sort kernel (_sort_merge_order), the sorted-runs merge network
-    (ops/merge_network.py), and its host-side precondition check
-    (runs_are_sorted — numpy arrays work too: only list-building and
-    ``~`` are used)."""
+    kernel's sort (_sort_merge_order) and the host paths' sorted-run
+    check (storage/native_compaction.py — numpy arrays work too: only
+    list-building and ``~`` are used)."""
     keys = [invalid, *key_word_lanes]
     if not uniform_klen:
         keys.append(key_len)
@@ -187,7 +161,6 @@ def _sort_merge_order(
     uniform_klen: bool = False,
     seq32: bool = False,
     key_words: int = KEY_WORDS,
-    sort_backend: str = "lax",
 ):
     """One variadic sort into (invalid-last, key asc, seq desc) order,
     carrying ``payload`` lanes through the sort network. Returns
@@ -206,16 +179,8 @@ def _sort_merge_order(
         key_len, seq_hi, seq_lo, uniform_klen=uniform_klen, seq32=seq32)
     num_keys = len(operands)
     operands.extend(payload)
-    if sort_backend == "pallas":
-        from .pallas_sort import sort_lanes  # pallas imports stay lazy
-
-        sorted_ops = sort_lanes(tuple(operands), num_keys=num_keys,
-                                backend="pallas")
-    elif sort_backend == "lax":
-        sorted_ops = lax.sort(tuple(operands), num_keys=num_keys,
-                              is_stable=False)
-    else:
-        raise ValueError(f"unknown sort backend {sort_backend!r}")
+    sorted_ops = lax.sort(tuple(operands), num_keys=num_keys,
+                          is_stable=False)
     key_lanes, klen_s, shi_s, slo_s, valid_s, pos = split_composite_lanes(
         sorted_ops, key_words, uniform_klen=uniform_klen, seq32=seq32)
     return key_lanes, klen_s, shi_s, slo_s, valid_s, sorted_ops[pos:]
@@ -267,54 +232,30 @@ def _limb_combine(lo16_0, lo16_1, hi16_0, hi16_1):
     return l0 | (l1 << 16), l2 | (l3 << 16)
 
 
-class ScanPrims:
-    """The shift/scan primitive seam phases 2-3 are written against, so
-    the XLA lane path (``resolve_sorted_lanes``) and the fused VMEM
-    kernel (ops/pallas_resolve.py) share ONE copy of the resolve math:
-    the XLA instance works on (N,) lanes with ``cumsum``/
-    ``associative_scan``; the Pallas instance works on (R, 128) VMEM
-    values with Hillis-Steele shift ladders. ``iota`` is the linear
-    entry index in the instance's layout."""
-
-    def __init__(self, iota, size, shift_prev, shift_next, cumsum_tuple,
-                 fill_forward, fill_backward):
-        self.iota = iota              # linear int32 index array
-        self.size = size              # static N
-        self.shift_prev = shift_prev  # y[i] = x[i-1] (x[0] arbitrary)
-        self.shift_next = shift_next  # y[i] = x[i+1] (x[n-1] arbitrary)
-        self.cumsum_tuple = cumsum_tuple    # inclusive prefix sums
-        self.fill_forward = fill_forward    # (flag, values) seg fill
-        self.fill_backward = fill_backward  # (flag_last, values)
+def _shift_prev(x):
+    """y[i] = x[i-1]; y[0] is zero (callers force row 0 themselves)."""
+    return jnp.concatenate([jnp.zeros((1,), x.dtype), x[:-1]])
 
 
-def _prims_1d(n: int) -> ScanPrims:
-    iota = lax.iota(jnp.int32, n)
-
-    def shift_prev(x):
-        return jnp.concatenate([jnp.zeros((1,), x.dtype), x[:-1]])
-
-    def shift_next(x):
-        return jnp.concatenate([x[1:], jnp.zeros((1,), x.dtype)])
-
-    return ScanPrims(
-        iota, n, shift_prev, shift_next,
-        lambda values: tuple(jnp.cumsum(v) for v in values),
-        _seg_fill_forward, _seg_fill_backward)
+def _shift_next(x):
+    """y[i] = x[i+1]; y[n-1] is zero (callers force the last row)."""
+    return jnp.concatenate([x[1:], jnp.zeros((1,), x.dtype)])
 
 
 def resolve_decisions(
-    prims: ScanPrims, key_lanes, key_len, valid, vtype, val_len,
-    vw_lanes, *, merge_kind: MergeKind, drop_tombstones: bool,
-    uniform_klen: bool, key_words: int,
+    key_lanes, key_len, valid, vtype, val_len, vw_lanes, *,
+    merge_kind: MergeKind, drop_tombstones: bool, uniform_klen: bool,
+    key_words: int,
 ):
-    """Phases 2-3 on merge-ordered lanes: key-boundary detection +
-    segmented LSM resolution, in terms of the ``prims`` seam only.
-    Returns ``(vtype, val_len, vw_lanes, keep, overflow_mask_or_None)``
+    """Phases 2-3 on merge-ordered ``(N,)`` lanes: key-boundary
+    detection + segmented LSM resolution (cumulative sums and the two
+    flagged segmented fills; no index gathers). Returns
+    ``(vtype, val_len, vw_lanes, keep, overflow_mask_or_None)``
     — ``keep`` marks each key's representative row for the compaction
     phase; ``overflow_mask`` (UINT64_ADD only) marks rows whose segment
     exceeds the 2^16-operand limb-sum bound."""
-    iota = prims.iota
-    n = prims.size
+    n = valid.shape[0]
+    iota = lax.iota(jnp.int32, n)
     n_val_words = len(vw_lanes)
     vw_lanes = list(vw_lanes)
 
@@ -322,14 +263,14 @@ def resolve_decisions(
     # invalid rows are forced segment starts --------------------------
     prev_equal = None
     for w in range(key_words):
-        eq = key_lanes[w] == prims.shift_prev(key_lanes[w])
+        eq = key_lanes[w] == _shift_prev(key_lanes[w])
         prev_equal = eq if prev_equal is None else prev_equal & eq
     if not uniform_klen:
         # with uniform lengths, equal words imply equal keys among valid
         # rows (invalid rows get their own segments below regardless)
-        prev_equal = prev_equal & (key_len == prims.shift_prev(key_len))
+        prev_equal = prev_equal & (key_len == _shift_prev(key_len))
     new_key = ~prev_equal | (iota == 0) | ~valid
-    last_key = prims.shift_next(new_key) | (iota == n - 1)
+    last_key = _shift_next(new_key) | (iota == n - 1)
 
     is_put = (vtype == _PUT) & valid
     is_del = (vtype == _DELETE) & valid
@@ -341,9 +282,9 @@ def resolve_decisions(
         # prefix counts of base entries: how many bases strictly before
         # row i within its segment. Segment-start values arrive via ONE
         # forward flagged fill — no index gathers.
-        (base_incl,) = prims.cumsum_tuple((is_base.astype(jnp.int32),))
+        base_incl = jnp.cumsum(is_base.astype(jnp.int32))
         base_excl = base_incl - is_base.astype(jnp.int32)
-        base_excl_start, iota_start = prims.fill_forward(
+        base_excl_start, iota_start = _seg_fill_forward(
             new_key, (base_excl, iota))
         base_before = base_excl - base_excl_start
         operand_mask = is_merge & (base_before == 0)
@@ -368,12 +309,12 @@ def resolve_decisions(
         # back to every row via one backward flagged fill. Segment total
         # for a row = end_prefix - (own_prefix - own_x) — all local
         # afterwards.
-        pref = list(prims.cumsum_tuple(tuple(limbs) + (
+        pref = [jnp.cumsum(v) for v in limbs + [
             operand_mask.astype(jnp.int32),
             (first_base_mask & is_put).astype(jnp.int32),
             (first_base_mask & is_del).astype(jnp.int32),
-        ))) + [iota]
-        ends = prims.fill_backward(last_key, tuple(pref))
+        ]] + [iota]
+        ends = _seg_fill_backward(last_key, tuple(pref))
         excl = lambda c, x: c - x  # noqa: E731
 
         sums = [
@@ -444,9 +385,7 @@ def resolve_sorted_lanes(
 ) -> Dict[str, jnp.ndarray]:
     """Phases 2-4 of the kernel on ALREADY merge-ordered lanes
     ((invalid-last, key asc, seq desc) order): boundary detection,
-    segmented LSM resolution, stream compaction. Shared by the full-sort
-    kernel below and the sorted-runs merge-network kernel
-    (ops/merge_network.py), which produce that order two different ways.
+    segmented LSM resolution, stream compaction.
 
     ``val_row`` (the index path, ``vw_lanes`` empty): each row's index
     into the caller's value matrix rides the compaction in the value
@@ -459,8 +398,8 @@ def resolve_sorted_lanes(
     seq_hi = seq_hi if seq_hi is not None else jnp.zeros_like(seq_lo)
 
     vtype, val_len, vw_lanes, keep, overflow_mask = resolve_decisions(
-        _prims_1d(n), key_lanes, key_len, valid, vtype, val_len,
-        vw_lanes, merge_kind=merge_kind, drop_tombstones=drop_tombstones,
+        key_lanes, key_len, valid, vtype, val_len, vw_lanes,
+        merge_kind=merge_kind, drop_tombstones=drop_tombstones,
         uniform_klen=uniform_klen, key_words=key_words)
     overflow_risk = (jnp.any(overflow_mask) if overflow_mask is not None
                      else jnp.asarray(False))
@@ -526,7 +465,7 @@ def resolve_sorted_lanes(
 @functools.partial(
     jax.jit,
     static_argnames=("merge_kind", "drop_tombstones", "uniform_klen",
-                     "seq32", "key_words", "sort_backend"),
+                     "seq32", "key_words"),
 )
 def merge_resolve_kernel(
     key_words_be: jnp.ndarray,  # (N, 6) u32
@@ -543,7 +482,6 @@ def merge_resolve_kernel(
     uniform_klen: bool = False,
     seq32: bool = False,
     key_words: int = KEY_WORDS,
-    sort_backend: str = "lax",
 ) -> Dict[str, jnp.ndarray]:
     """Merge + resolve a concatenated batch of runs (order-free input).
 
@@ -554,24 +492,12 @@ def merge_resolve_kernel(
     ``uniform_klen``/``seq32``/``key_words`` are caller-verified fast-path
     promises (see _sort_merge_order); results are identical either way.
     """
-    if sort_backend == "pallas_fused":
-        from .pallas_resolve import fused_merge_resolve
-
-        # raises for capacities the fused kernel does not take
-        return fused_merge_resolve(
-            key_words_be, key_len, seq_hi, seq_lo, vtype, val_words,
-            val_len, valid, merge_kind=merge_kind,
-            drop_tombstones=drop_tombstones,
-            uniform_klen=uniform_klen, seq32=seq32,
-            key_words=key_words,
-        )
-
     index = value_path(merge_kind, val_words.shape[1]) == "index"
     out = _sort_resolve(
         key_words_be, key_len, seq_hi, seq_lo, vtype, val_words, val_len,
         valid, index=index, merge_kind=merge_kind,
         drop_tombstones=drop_tombstones, uniform_klen=uniform_klen,
-        seq32=seq32, key_words=key_words, sort_backend=sort_backend)
+        seq32=seq32, key_words=key_words)
     if index:
         out["val_words"] = gather_value_rows(
             val_words, out.pop("val_row"), out["count"])
@@ -580,7 +506,7 @@ def merge_resolve_kernel(
 
 def _sort_resolve(key_words_be, key_len, seq_hi, seq_lo, vtype, val_words,
                   val_len, valid, *, index, merge_kind, drop_tombstones,
-                  uniform_klen, seq32, key_words, sort_backend):
+                  uniform_klen, seq32, key_words):
     """Phases 1-4 with the values' lanes riding both sorts: every word
     of ``val_words`` (the riding path), or with ``index`` ONE lane, each
     row's own index (``val_words`` is not looked at, and the output has
@@ -597,7 +523,6 @@ def _sort_resolve(key_words_be, key_len, seq_hi, seq_lo, vtype, val_words,
         key_words_be, key_len, seq_hi, seq_lo, valid,
         (vtype, val_len) + carried,
         uniform_klen=uniform_klen, seq32=seq32, key_words=key_words,
-        sort_backend=sort_backend,
     )
     return resolve_sorted_lanes(
         list(key_lanes), klen_s, shi_s, slo_s, valid_s,
@@ -612,8 +537,7 @@ def _sort_resolve(key_words_be, key_len, seq_hi, seq_lo, vtype, val_words,
 def merge_resolve_rows(key_words_be, key_len, seq_hi, seq_lo, vtype,
                        val_len, valid, *, drop_tombstones: bool,
                        uniform_klen: bool = False, seq32: bool = False,
-                       key_words: int = KEY_WORDS,
-                       sort_backend: str = "lax"):
+                       key_words: int = KEY_WORDS):
     """The index path's sorts and resolve, with no value in sight
     (``MergeKind.NONE``: newest PUT/DELETE wins, no value is rewritten).
     The output of ``merge_resolve_kernel`` without ``val_words``, with
@@ -625,7 +549,7 @@ def merge_resolve_rows(key_words_be, key_len, seq_hi, seq_lo, vtype,
         key_words_be, key_len, seq_hi, seq_lo, vtype, None, val_len, valid,
         index=True, merge_kind=MergeKind.NONE,
         drop_tombstones=drop_tombstones, uniform_klen=uniform_klen,
-        seq32=seq32, key_words=key_words, sort_backend=sort_backend)
+        seq32=seq32, key_words=key_words)
 
 
 def gather_value_rows(val_words: jnp.ndarray, val_row: jnp.ndarray,

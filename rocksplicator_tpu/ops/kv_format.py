@@ -1,7 +1,7 @@
 """Fixed-shape KV batch representation for TPU kernels.
 
 The hard part the SURVEY flags up front (§7): variable-length keys/values
-vs Pallas/XLA's fixed-shape world. Representation chosen:
+vs XLA's fixed-shape world. Representation chosen:
 
 - **keys** → 24-byte zero-padded prefixes as 6 *big-endian* u32 lanes plus a
   length lane. For keys ≤ 24 bytes (the counter workload and most sharded-KV

@@ -99,7 +99,6 @@ def sharded_compaction_step(mesh, model=None):
 
     model = model or CompactionModel()
     merge_kind = model.merge_kind
-    sort_backend = model.sort_backend
 
     def local_step(kwbe, klen, shi, slo, vt, vw, vl, valid):
         # local shapes: (s, 1, N, ...) — one block column per device
@@ -108,9 +107,7 @@ def sharded_compaction_step(mesh, model=None):
 
         def run(args, drop):
             return merge_resolve_kernel(
-                *args, merge_kind=merge_kind, drop_tombstones=drop,
-                sort_backend=sort_backend,
-            )
+                *args, merge_kind=merge_kind, drop_tombstones=drop)
 
         # 1) block-local merge (keep tombstones: blocks are partial views)
         local = dict(jax.vmap(lambda *a: run(a, False))(
@@ -144,7 +141,6 @@ def sharded_compaction_step(mesh, model=None):
             lambda *a: merge_resolve_kernel(
                 *a, merge_kind=merge_kind,
                 drop_tombstones=model.drop_tombstones,
-                sort_backend=sort_backend,
             )
         )(
             flat["key_words_be"], flat["key_len"],

@@ -207,7 +207,9 @@ def read_runs_as_lanes(
     concatenated lane arrays. Returns (parts, lanes, total, vw) or None
     when the lane representation can't express the inputs (per-run
     checks bail early, before materializing the rest). Shared by the
-    direct compaction sink and the batched cross-shard service.
+    direct compaction sink and both device doors (tpu/backend.py,
+    tpu/compaction_service.py), which pass no ``merge_op``: the uint64-add
+    bail below is then theirs to make, with the rest of their rule.
 
     Deliberately single-threaded: the per-block Python between the
     GIL-releasing zlib/numpy stretches convoys badly under a thread
@@ -264,45 +266,55 @@ def read_runs_as_lanes(
     return parts, lanes, total, vw
 
 
-def lanes_resolvable(lanes: dict, merge_op: Optional[MergeOperator]) -> bool:
-    """True when the array merge-resolve can express these lanes' MERGE
-    semantics (the PLANAR-sink preconditions shared by every array
-    compaction path)."""
+def lanes_decline_reason(lanes: dict,
+                         merge_op: Optional[MergeOperator]) -> Optional[str]:
+    """None when the array merge-resolve and the PLANAR sink can express
+    these lanes, else why not — THE eligibility rule of every array
+    compaction path (the device doors add their width limit on top:
+    tpu/backend.py ``device_decline_reason``):
+
+    - ``merge_without_operator``: MERGE records and no operator (only
+      the tuple path keeps an unresolved operand chain);
+    - ``key_width`` / ``value_width_mixed``: the PLANAR sink needs one
+      key width and one non-delete value width (kept tombstones are
+      fine: the layout derives val_len from vtype);
+    - ``uint64add_width``: the uint64-add RESOLUTION assumes 8-byte
+      values: the fold rewrites every PUT segment to the operand sum,
+      and a non-8-byte PUT parses as 0 (stream semantics only invoke
+      the operator when operands exist, so a lone non-8-byte PUT must
+      stay verbatim, which the array fold cannot express)."""
     if merge_op is None and bool((lanes["vtype"] == _MERGE).any()):
-        return False
-    # PLANAR sink preconditions (same as the TPU sink): uniform keys,
-    # uniform non-delete value widths
+        return "merge_without_operator"
     kl = lanes["key_len"]
     if len(kl) and not (kl == kl[0]).all():
-        return False
-    is_del = lanes["vtype"] == _DELETE
-    non_del_vlens = lanes["val_len"][~is_del]
+        return "key_width"
+    non_del_vlens = lanes["val_len"][lanes["vtype"] != _DELETE]
     if len(non_del_vlens) and not (
             non_del_vlens == non_del_vlens[0]).all():
-        return False
-    # uint64-add RESOLUTION assumes 8-byte values: the fold rewrites
-    # every PUT segment to the operand sum, and a non-8-byte PUT
-    # parses as 0 (stream semantics only invoke the operator when
-    # operands exist, so a lone non-8-byte PUT must stay verbatim —
-    # which the array fold cannot express). Route such shapes to the
-    # tuple path.
+        return "value_width_mixed"
     if (merge_op is not None and len(non_del_vlens)
             and not (non_del_vlens == 8).all()):
-        return False
-    return True
+        return "uint64add_width"
+    return None
 
 
 def write_resolved_lanes(
     arrays: dict, count: int, path_factory, block_bytes: int,
     compression: int, bits_per_key: int, target_file_bytes: int,
-    io_budget=None,
+    io_budget=None, build_bloom=None, trace: Optional[dict] = None,
 ) -> Optional[List[Tuple[str, dict]]]:
-    """Write resolved lanes as PLANAR SSTs split at target_file_bytes
-    with bulk-built blooms — the shared array file sink. None when the
-    planar layout can't express the rows; a mid-loop failure cleans up
-    every file already written (nothing would ever GC the orphans).
-    ``io_budget`` (compaction callers only) throttles after each output
-    file so compaction IO yields to foreground fsyncs."""
+    """Write resolved lanes as PLANAR SSTs split at target_file_bytes,
+    a bloom each — THE array file sink, the device doors' too. None when
+    the planar layout can't express the rows; a mid-loop failure cleans
+    up every file already written (nothing would ever GC the orphans).
+    ``build_bloom(sub, n)`` gives one output file's bloom words from its
+    own ``n`` rows (default: the host bulk bloom; the device doors build
+    theirs on the device). ``io_budget`` (compaction callers only)
+    throttles after each output file so compaction IO yields to
+    foreground fsyncs. Each file's write is a ``tpu.planar.write`` span
+    (``rows``), under ``trace`` where the caller's thread carries no
+    trace context of its own (a pool thread)."""
+    from ..observability.span import start_span
     from ..tpu.format import planar_stride, planar_widths, \
         write_sst_from_arrays
 
@@ -313,6 +325,10 @@ def write_resolved_lanes(
     stride = planar_stride(klen0, vlen0)
     entries_per_file = max(1024, target_file_bytes // max(1, stride))
     block_entries = max(64, block_bytes // max(1, stride))
+    if build_bloom is None:
+        def build_bloom(sub, n):
+            return NativeCompactionBackend._bulk_bloom(
+                sub, n, klen0, bits_per_key).words
     outputs: List[Tuple[str, dict]] = []
 
     def cleanup():
@@ -326,17 +342,18 @@ def write_resolved_lanes(
         for start in range(0, count, entries_per_file):
             end = min(start + entries_per_file, count)
             sub = {f: arrays[f][start:end] for f in arrays}
-            bloom = NativeCompactionBackend._bulk_bloom(
-                sub, end - start, klen0, bits_per_key)
+            bloom_words = build_bloom(sub, end - start)
             path = path_factory()
-            props = write_sst_from_arrays(
-                sub, end - start, path,
-                bloom_words=bloom.words,
-                block_entries=block_entries,
-                compression=compression,
-                bits_per_key=bits_per_key,
-                planar=True,
-            )
+            with start_span("tpu.planar.write", remote=trace,
+                            rows=end - start):
+                props = write_sst_from_arrays(
+                    sub, end - start, path,
+                    bloom_words=bloom_words,
+                    block_entries=block_entries,
+                    compression=compression,
+                    bits_per_key=bits_per_key,
+                    planar=True,
+                )
             if props is None:  # should not happen after width checks
                 cleanup()
                 return None
@@ -373,7 +390,7 @@ def write_resolved_lanes(
 
 def _part_key(part: dict, i: int, klen: int) -> bytes:
     """Key bytes of row ``i`` (uniform width ``klen`` — guaranteed by
-    lanes_resolvable before slicing is attempted)."""
+    lanes_decline_reason before slicing is attempted)."""
     return part["key_words_be"][i].astype(">u4").tobytes()[:klen]
 
 
@@ -435,13 +452,11 @@ def plan_subcompactions(parts: List[dict], total: int,
 
 
 def slice_parts(parts: List[dict], bounds: List[bytes], si: int,
-                klen: int, cuts: List[List[int]],
-                fields: Optional[Tuple[str, ...]] = None) -> List[dict]:
+                klen: int, cuts: List[List[int]]) -> List[dict]:
     """Slice ``si``'s row ranges of every part (``cuts[p]`` = the
     per-part boundary row indices from _first_row_ge)."""
-    if fields is None:
-        fields = ("key_words_be", "key_len", "seq_hi", "seq_lo", "vtype",
-                  "val_words", "val_len")
+    fields = ("key_words_be", "key_len", "seq_hi", "seq_lo", "vtype",
+              "val_words", "val_len")
     out: List[dict] = []
     for p, c in zip(parts, cuts):
         lo = c[si - 1] if si > 0 else 0
@@ -577,7 +592,7 @@ def direct_merge_runs_to_files(
     if read is None:
         return None
     parts, lanes, total, vw = read
-    if not lanes_resolvable(lanes, merge_op):
+    if lanes_decline_reason(lanes, merge_op) is not None:
         return None
     # in-RAM accounting for the peak gauge: per-run parts plus their
     # concatenation are live together right now

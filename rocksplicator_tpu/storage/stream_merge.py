@@ -534,7 +534,7 @@ def _lanes_nbytes_list(parts: List[dict]) -> int:
 
 
 def _check_chunk_semantics(lanes: dict, merge_op) -> None:
-    """The lanes_resolvable() preconditions, applied per chunk instead
+    """The lanes_decline_reason() preconditions, applied per chunk instead
     of per dataset (probes promise widths; vtype content can only be
     checked once decoded)."""
     if merge_op is None:

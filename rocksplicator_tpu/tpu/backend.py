@@ -27,10 +27,11 @@ from typing import Iterable, Iterator, List, Optional, Tuple
 
 import numpy as np
 
-from ..storage.compaction import CompactionBackend, CpuCompactionBackend, Entry
+from ..storage.compaction import (CompactionBackend, CpuCompactionBackend,
+                                  Entry, record_host_fallback)
 from ..storage.merge import MergeOperator, UInt64AddOperator
-from ..ops.compaction_kernel import (MergeKind, deployment_sort_backend,
-                                     merge_resolve_kernel)
+from ..storage.native_compaction import lanes_decline_reason
+from ..ops.compaction_kernel import MergeKind, merge_resolve_kernel
 from ..ops.kv_format import (KVBatch, UnsupportedBatch, fast_flags,
                              pack_entries, unpack_entries)
 from ..utils.stats import Stats, tagged
@@ -59,12 +60,62 @@ def device_value_bytes_max(merge_operator: Optional[MergeOperator]) -> int:
     """The widest value, in bytes, of a shard the device path compacts
     for a DB with this merge operator; 0 where it takes none. The
     uint64-add fold is defined on 8-byte values; a custom operator runs
-    Python. Wider shards are DECLINED to the host path before any
-    program is built (``compact_dbs_batched``, ``merge_runs_to_files``),
-    counted under ``tpu.host_fallbacks reason=value_width``."""
+    Python."""
     if merge_operator is None:
         return DEVICE_VALUE_BYTES_MAX
     return 8 if isinstance(merge_operator, UInt64AddOperator) else 0
+
+
+def device_decline_reason(lanes: Optional[dict],
+                          merge_operator: Optional[MergeOperator],
+                          ) -> Optional[str]:
+    """None when the device path compacts a DB with this operator whose
+    runs read as these concatenated ``lanes``, else why it declines them
+    to the host path, before any program is built. THE rule of both
+    device doors (``merge_runs_to_files``, ``compact_dbs_batched``):
+
+    - ``custom_operator``: the operator runs Python;
+    - ``value_width``: a value wider than ``device_value_bytes_max``
+      (each door counts it under ``tpu.host_fallbacks reason=value_width``);
+    - what no array path expresses (``lanes_decline_reason``):
+      ``merge_without_operator``, ``key_width``, ``value_width_mixed``,
+      ``uint64add_width``.
+
+    ``lanes=None`` asks about the operator alone (a plan costs a flush)."""
+    limit = device_value_bytes_max(merge_operator)
+    if limit == 0:
+        return "custom_operator"
+    if lanes is None:
+        return None
+    if len(lanes["val_len"]) and int(lanes["val_len"].max()) > limit:
+        return "value_width"
+    return lanes_decline_reason(lanes, merge_operator)
+
+
+def _device_bloom_builder(bits_per_key: int, trace: Optional[dict] = None):
+    """``write_resolved_lanes``' bloom builder for the device doors: one
+    output file's bloom built on the device, sized from the file's own
+    count and the DB's ``bits_per_key`` (a launch's own bloom is sized by
+    the group's padded capacity and the service's default bits: reusing
+    it would write a max-shard-sized bloom into every small shard of a
+    mixed batch). A ``tpu.bloom`` span (``rows``) each, under ``trace``
+    on a pool thread."""
+    import jax.numpy as jnp
+
+    from ..observability.span import start_span
+    from ..ops.bloom_tpu import bloom_build_tpu
+    from ..storage.bloom import num_words_for
+
+    def build(sub: dict, n: int) -> np.ndarray:
+        with start_span("tpu.bloom", remote=trace, rows=n):
+            return np.asarray(bloom_build_tpu(
+                jnp.asarray(sub["key_words_le"]),
+                jnp.asarray(sub["key_len"]),
+                jnp.asarray(np.ones(n, dtype=bool)),
+                num_words=num_words_for(n, bits_per_key),
+            ))
+
+    return build
 
 
 def _next_pow2(n: int) -> int:
@@ -89,15 +140,6 @@ def require_accelerator() -> str:
             f"{platform!r}; refusing to run the device path on it (set "
             f"JAX_PLATFORMS=cpu to run it on the CPU on purpose)")
     return platform
-
-
-def _arrays_from_entries(entries: List[Entry]) -> Optional[dict]:
-    """Entry tuples → valid-prefix lane arrays (tuple-source fallback)."""
-    if not entries:
-        return None
-    from .chunked import _batch_to_arrays
-
-    return _batch_to_arrays(pack_entries(entries))[0]
 
 
 class TpuCompactionBackend(CompactionBackend):
@@ -237,17 +279,18 @@ class TpuCompactionBackend(CompactionBackend):
         ``max_subcompactions > 1``: an in-RAM job splits into disjoint
         key-range slices resolved as ONE padded vmapped device batch
         (tpu/compaction_service.resolve_slices_batched) — k smaller
-        bitonic sorts in one launch instead of one pow2(total) sort.
-        ``io_budget`` paces the output file writes."""
-        from ..ops.bloom_tpu import bloom_build_tpu
-        from ..storage.bloom import num_words_for
+        sorts in one launch instead of one pow2(total) sort.
+        ``io_budget`` paces the output file writes. What the door takes
+        is ``device_decline_reason``'s to say; the runs are read and the
+        files written by the host array path's own reader and writer
+        (storage/native_compaction.py)."""
+        from ..storage.native_compaction import (read_runs_as_lanes,
+                                                 write_resolved_lanes)
         from ..storage.stream_merge import maybe_stream_merge
-        from .chunked import FIELDS, run_kernel_arrays
+        from .chunked import run_kernel_arrays
         from .compaction_service import TpuChunkResolver
-        from .format import (planar_stride, planar_widths, read_sst_arrays,
-                             write_sst_from_arrays)
 
-        if merge_op is not None and not isinstance(merge_op, UInt64AddOperator):
+        if device_decline_reason(None, merge_op) is not None:
             return None
         streamed = maybe_stream_merge(
             runs, merge_op, drop_tombstones, path_factory, block_bytes,
@@ -258,54 +301,17 @@ class TpuCompactionBackend(CompactionBackend):
         )
         if streamed is not None:
             return streamed
-        parts: List[dict] = []
-        try:
-            for run in runs:
-                if hasattr(run, "iterate"):  # an SSTReader
-                    arr = read_sst_arrays(run)
-                    if arr is None:
-                        arr = _arrays_from_entries(list(run.iterate()))
-                else:
-                    arr = _arrays_from_entries(list(run))
-                if arr is not None:
-                    parts.append(arr)
-        except UnsupportedBatch:
+        # (larger than one launch: the chunked/CPU paths return entries,
+        # not files)
+        read = read_runs_as_lanes(runs, None, max_entries=MAX_TPU_ENTRIES)
+        if read is None:
             return None
-        total = sum(p["key_len"].shape[0] for p in parts)
-        if total == 0 or total > MAX_TPU_ENTRIES:
-            return None  # chunked/CPU paths return entries, not files (yet)
-        # normalize value-lane widths (sources may carry different paddings)
-        vw = max(p["val_words"].shape[1] for p in parts)
-        for p in parts:
-            w = p["val_words"].shape[1]
-            if w < vw:
-                p["val_words"] = np.pad(p["val_words"], [(0, 0), (0, vw - w)])
-        lanes = {
-            f: np.concatenate([p[f] for p in parts]) for f in FIELDS
-        }
-        if merge_op is None and bool((lanes["vtype"] == _MERGE).any()):
-            return None
-        # Cheap pre-check BEFORE the kernel: the PLANAR sink needs uniform
-        # keys and uniform non-delete value widths (kept tombstones are
-        # fine — the planar layout derives val_len from vtype, so deletes
-        # coexist with fixed-width values, unlike the old row sink).
-        kl = lanes["key_len"]
-        if total and not (kl == kl[0]).all():
-            return None
-        is_del = lanes["vtype"] == _DELETE
-        vlens = lanes["val_len"]
-        non_del_vlens = vlens[~is_del]
-        if len(non_del_vlens) and not (non_del_vlens == non_del_vlens[0]).all():
-            return None
-        # uint64-add fold semantics require 8-byte values: a lone
-        # non-8-byte PUT would be rewritten to the (zero) operand sum
-        # instead of staying verbatim as the stream path keeps it
-        if (merge_op is not None and len(non_del_vlens)
-                and not (non_del_vlens == 8).all()):
-            return None
-        # wider than the device path takes: the host path, no program
-        if (len(non_del_vlens)
-                and int(non_del_vlens[0]) > device_value_bytes_max(merge_op)):
+        parts, lanes, total, _vw = read
+        reason = device_decline_reason(lanes, merge_op)
+        if reason is not None:
+            if reason == "value_width":
+                record_host_fallback(
+                    reason, f"{int(lanes['val_len'].max())}-byte values")
             return None
         kind = (
             MergeKind.UINT64_ADD if isinstance(merge_op, UInt64AddOperator)
@@ -321,7 +327,7 @@ class TpuCompactionBackend(CompactionBackend):
         if arrays is None:
             all_valid = np.ones(total, dtype=bool)
             uniform_klen, seq32, key_words = fast_flags(
-                kl, lanes["seq_hi"], all_valid)
+                lanes["key_len"], lanes["seq_hi"], all_valid)
             arrays, count = run_kernel_arrays(
                 lanes, total, kind, drop_tombstones,
                 pad_to=_next_pow2(total),
@@ -332,52 +338,13 @@ class TpuCompactionBackend(CompactionBackend):
             return None
         if count == 0:
             return []  # fully compacted away — nothing to write
-        widths = planar_widths(arrays, count)
-        if widths is None:
-            return None
-        klen0, vlen0 = widths
-        stride = planar_stride(klen0, vlen0)
-        entries_per_file = max(1024, target_file_bytes // max(1, stride))
-        block_entries = max(64, block_bytes // max(1, stride))
-        outputs: List[Tuple[str, dict]] = []
-        for start in range(0, count, entries_per_file):
-            end = min(start + entries_per_file, count)
-            sub = {f: arrays[f][start:end] for f in arrays}
-            sub_valid = np.ones(end - start, dtype=bool)
-            num_words = num_words_for(end - start, bits_per_key)
-            import jax.numpy as jnp
-
-            bloom = bloom_build_tpu(
-                jnp.asarray(sub["key_words_le"]),
-                jnp.asarray(sub["key_len"]),
-                jnp.asarray(sub_valid), num_words=num_words,
-            )
-            path = path_factory()
-            # PLANAR output: the kernel's struct-of-array lanes ARE the
-            # block planes (storage/planar.py) — no byte interleaving on
-            # either side, ~29% smaller uncompressed than the row format
-            props = write_sst_from_arrays(
-                sub, end - start, path,
-                bloom_words=np.asarray(bloom),
-                block_entries=block_entries,
-                compression=compression,
-                bits_per_key=bits_per_key,
-                planar=True,
-            )
-            if props is None:  # should not happen after the width checks
-                for p, _ in outputs:
-                    try:
-                        os.remove(p)
-                    except OSError:
-                        pass
-                return None
-            outputs.append((path, props))
-            if io_budget is not None:
-                try:
-                    io_budget.throttle(os.path.getsize(path))
-                except OSError:
-                    pass
-        return outputs
+        # PLANAR output: the kernel's struct-of-array lanes ARE the block
+        # planes (storage/planar.py) — no byte interleaving on either
+        # side, ~29% smaller uncompressed than the row format
+        return write_resolved_lanes(
+            arrays, count, path_factory, block_bytes, compression,
+            bits_per_key, target_file_bytes, io_budget=io_budget,
+            build_bloom=_device_bloom_builder(bits_per_key))
 
     @staticmethod
     def _subcompact_arrays(parts, lanes, total, kind, drop_tombstones,
@@ -391,7 +358,6 @@ class TpuCompactionBackend(CompactionBackend):
         from ..storage.native_compaction import (_first_row_ge,
                                                  plan_subcompactions,
                                                  slice_parts)
-        from .chunked import FIELDS
         from .compaction_service import resolve_slices_batched
 
         kl = lanes["key_len"]
@@ -402,10 +368,10 @@ class TpuCompactionBackend(CompactionBackend):
         cuts = [[_first_row_ge(p, b, klen) for b in bounds] for p in parts]
         slices = []
         for si in range(len(bounds) + 1):
-            sub = slice_parts(parts, bounds, si, klen, cuts, fields=FIELDS)
+            sub = slice_parts(parts, bounds, si, klen, cuts)
             if sub:
                 slices.append({
-                    f: np.concatenate([p[f] for p in sub]) for f in FIELDS})
+                    f: np.concatenate([p[f] for p in sub]) for f in sub[0]})
         if not slices:
             return None
         per_slice = resolve_slices_batched(slices, kind, drop_tombstones)
@@ -440,7 +406,6 @@ class TpuCompactionBackend(CompactionBackend):
             jnp.asarray(batch.valid),
             merge_kind=kind, drop_tombstones=drop_tombstones,
             uniform_klen=uniform_klen, seq32=seq32, key_words=key_words,
-            sort_backend=deployment_sort_backend(),
         )
         if bool(out["needs_cpu_fallback"]):
             return None

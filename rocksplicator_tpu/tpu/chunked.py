@@ -24,8 +24,7 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
-from ..ops.compaction_kernel import (MergeKind, deployment_sort_backend,
-                                     merge_resolve_kernel)
+from ..ops.compaction_kernel import MergeKind, merge_resolve_kernel
 from ..ops.kv_format import KVBatch
 
 log = logging.getLogger(__name__)
@@ -61,7 +60,7 @@ def run_kernel_arrays(
         batch_arrays = {
             f: jnp.pad(batch_arrays[f],
                        [(0, pad)] + [(0, 0)] * (batch_arrays[f].ndim - 1))
-            for f in FIELDS
+            for f in INPUT_FIELDS
         }
         n_rows = pad_to
     valid = np.zeros(n_rows, dtype=bool)
@@ -73,7 +72,6 @@ def run_kernel_arrays(
         jnp.asarray(valid),
         merge_kind=merge_kind, drop_tombstones=drop_tombstones,
         uniform_klen=uniform_klen, seq32=seq32, key_words=kw,
-        sort_backend=deployment_sort_backend(),
     )
     if bool(out["needs_cpu_fallback"]):
         return None, 0

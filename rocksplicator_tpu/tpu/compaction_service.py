@@ -29,15 +29,19 @@ from ..observability.span import start_span
 from ..storage.bloom import num_words_for
 from ..storage.engine import DBOptions
 from ..ops.bloom_tpu import bloom_build_tpu
-from ..ops.compaction_kernel import (MergeKind, deployment_sort_backend,
-                                     gather_value_rows,
+from ..ops.compaction_kernel import (MergeKind, gather_value_rows,
                                      merge_resolve_kernel,
                                      merge_resolve_rows, value_path)
 from ..ops.kv_format import KEY_WORDS, KVBatch, fast_flags, unpack_entries
 from ..storage.compaction import record_host_fallback
+from ..storage.native_compaction import (read_runs_as_lanes,
+                                         write_resolved_lanes)
 from ..utils.stats import Stats
-from .backend import (TpuCompactionBackend, _next_pow2,
-                      device_value_bytes_max, require_accelerator)
+from .backend import (TpuCompactionBackend, _device_bloom_builder,
+                      _next_pow2, device_decline_reason, require_accelerator)
+# what the device path takes is asked HERE by the record deployment's
+# driver (chipbench/drivers/refresh_rec.py)
+from .backend import device_value_bytes_max  # noqa: F401
 
 log = logging.getLogger(__name__)
 
@@ -57,19 +61,12 @@ class TpuCompactionService:
     _instance: Optional["TpuCompactionService"] = None
     _instance_lock = threading.Lock()
 
-    def __init__(self, bits_per_key: int = 10, sort_backend: str = None):
+    def __init__(self, bits_per_key: int = 10):
         import jax
 
         self._jax = jax
         require_accelerator()
         self._bits_per_key = bits_per_key
-        # deployment knob: run the service's kernels on the lax sort, the
-        # VMEM-resident pallas sort, or the fully-fused pallas kernel —
-        # whichever the bench shootout crowned on this hardware. None =
-        # resolve the sort_backend FLAG per pipeline build, so a runtime
-        # FLAGS.set flip reaches the singleton too (the flag value is
-        # part of the pipeline cache key).
-        self._sort_backend = sort_backend
         self._vmapped_cache: Dict[tuple, object] = {}
         self._zero_rows: Dict[tuple, object] = {}  # (capacity, words)
 
@@ -105,16 +102,15 @@ class TpuCompactionService:
         ``(group, N, ...)``. Index: ``val_words`` goes in and comes out
         as a TUPLE of per-shard ``(N, W)`` buffers, so that only real
         shards' values cross the host-device seam."""
-        sort_backend = self._sort_backend or deployment_sort_backend()
         index = value_path(merge_kind, val_words) == "index"
         key = (merge_kind, drop_tombstones, num_words, uniform_klen, seq32,
-               key_words, sort_backend, index)
+               key_words, index)
         fn = self._vmapped_cache.get(key)
         if fn is None:
             jax = self._jax
             flags = dict(drop_tombstones=drop_tombstones,
                          uniform_klen=uniform_klen, seq32=seq32,
-                         key_words=key_words, sort_backend=sort_backend)
+                         key_words=key_words)
 
             def with_bloom(out, n):
                 out_valid = jax.lax.iota(jax.numpy.int32, n) < out["count"]
@@ -435,8 +431,6 @@ def resolve_slices_batched(
 # cross-DB batched full compaction (the post-load_sst path)
 # ---------------------------------------------------------------------------
 
-_PUT, _DELETE, _MERGE = 1, 2, 3
-
 # One shard above this entry count would inflate the whole padded launch
 # (every shard pays the max shard's capacity); such shards compact per-db.
 MAX_BATCHED_DB_ENTRIES = 1 << 20
@@ -444,13 +438,13 @@ MAX_BATCHED_DB_ENTRIES = 1 << 20
 
 class _LaneBatch:
     """Duck-typed KVBatch over pre-read lane arrays — the arrays-native
-    input to compact_shard_batch/stream (no per-entry pack loop)."""
+    input to compact_shard_batch/stream (no per-entry pack loop): the
+    lanes a launch takes (``_GROUP_LANES``), every row valid."""
 
-    __slots__ = ("key_words_be", "key_words_le", "key_len", "seq_hi",
-                 "seq_lo", "vtype", "val_words", "val_len", "valid")
+    __slots__ = _GROUP_LANES
 
     def __init__(self, lanes: Dict[str, np.ndarray]):
-        for f in _LANES:
+        for f in _GROUP_LANES[:-1]:
             setattr(self, f, lanes[f])
         self.valid = np.ones(lanes["key_len"].shape[0], dtype=bool)
 
@@ -458,97 +452,28 @@ class _LaneBatch:
     def capacity(self) -> int:
         return self.key_len.shape[0]
 
-
-def _db_lanes(plan: dict) -> Optional[Dict[str, np.ndarray]]:
-    """A plan's input runs as one concatenated lane dict (planar/uniform
-    files decode straight to lanes; row-format files pay one pack). None
-    when the lane representation can't express a run."""
-    from ..ops.kv_format import UnsupportedBatch
-    from .backend import _arrays_from_entries
-    from .chunked import FIELDS
-    from .format import read_sst_arrays
-
-    parts: List[dict] = []
-    try:
-        for r in plan["runs"]:
-            arr = read_sst_arrays(r)
-            if arr is None:
-                arr = _arrays_from_entries(list(r.iterate()))
-            if arr is not None:
-                parts.append(arr)
-    except UnsupportedBatch as e:
-        log.debug("batched compaction lane read declined: %s", e)
-        return None
-    if not parts:
-        return None
-    vw = max(p["val_words"].shape[1] for p in parts)
-    for p in parts:
-        w = p["val_words"].shape[1]
-        if w < vw:
-            p["val_words"] = np.pad(p["val_words"], [(0, 0), (0, vw - w)])
-    return {f: np.concatenate([p[f] for p in parts]) for f in FIELDS}
+    def num_valid(self) -> int:
+        return self.capacity
 
 
 def _write_arrays(db, res: dict, tctx: Optional[dict]) -> dict:
-    """Write one shard's resolved lanes as PLANAR SSTs (vectorized sink,
-    kernel-built per-file blooms). Returns how to install them, as
-    ``install_full_compaction``'s keywords: ``files``, or the entry-tuple
-    sink's ``entries`` when the planar layout can't express the result.
-    ``tctx``: the dispatch's trace context (this runs on a pool thread)."""
-    from ..storage.bloom import num_words_for as bloom_words_for
-    from .format import planar_stride, planar_widths, write_sst_from_arrays
-
+    """Write one shard's resolved lanes as PLANAR SSTs (the array sink,
+    per-file blooms built on the device). Returns how to install them,
+    as ``install_full_compaction``'s keywords: ``files``, or the
+    entry-tuple sink's ``entries`` when the planar layout can't express
+    the result. ``tctx``: the dispatch's trace context (this runs on a
+    pool thread)."""
     arrays, count = res["arrays"], int(res["count"])
     if count == 0:
         return {"entries": []}
-    widths = planar_widths(arrays, count)
-    if widths is not None:
-        import jax.numpy as jnp
-
-        opts = db.options
-        stride = planar_stride(*widths)
-        entries_per_file = max(1024, opts.target_file_bytes // max(1, stride))
-        block_entries = max(64, opts.block_bytes // max(1, stride))
-        names: List[str] = []
-        paths: List[str] = []
-        ok = True
-        for start in range(0, count, entries_per_file):
-            end = min(start + entries_per_file, count)
-            sub = {f: arrays[f][start:end] for f in arrays}
-            # per-file bloom sized from THIS file's count and the DB's own
-            # bits_per_key — the job-level bloom is sized by the group's
-            # padded max capacity (and the service default bits), so
-            # reusing it would write a max-shard-sized bloom into every
-            # small shard of a mixed batch
-            with start_span("tpu.bloom", remote=tctx, rows=end - start):
-                bloom = np.asarray(bloom_build_tpu(
-                    jnp.asarray(sub["key_words_le"]),
-                    jnp.asarray(sub["key_len"]),
-                    jnp.asarray(np.ones(end - start, dtype=bool)),
-                    num_words=bloom_words_for(end - start,
-                                              opts.bits_per_key),
-                ))
-            name, path = db.allocate_sst()
-            with start_span("tpu.planar.write", remote=tctx,
-                            rows=end - start):
-                props = write_sst_from_arrays(
-                    sub, end - start, path, bloom_words=bloom,
-                    block_entries=block_entries,
-                    compression=opts.compression,
-                    bits_per_key=opts.bits_per_key, planar=True,
-                )
-            if props is None:
-                ok = False
-                for p in paths:
-                    try:
-                        os.remove(p)
-                    except OSError:
-                        pass
-                break
-            names.append(name)
-            paths.append(path)
-        if ok:
-            return {"files": names}
+    opts = db.options
+    outputs = write_resolved_lanes(
+        arrays, count, db.allocate_sst_path, opts.block_bytes,
+        opts.compression, opts.bits_per_key, opts.target_file_bytes,
+        build_bloom=_device_bloom_builder(opts.bits_per_key, tctx),
+        trace=tctx)
+    if outputs is not None:
+        return {"files": [os.path.basename(path) for path, _ in outputs]}
     # tuple fallback (non-uniform keys/values)
     return {"entries": unpack_entries(
         arrays["key_words_be"], arrays["key_len"], arrays["seq_hi"],
@@ -575,17 +500,17 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
     ``device_value_bytes_max(None)`` bytes take the index path (one
     row-index lane rides, the values are moved once inside the same
     module; ``value_path`` on the ``tpu.compact_stream`` span says
-    which). DBs the device path can't express (custom merge operators,
-    >24B keys, values over ``device_value_bytes_max``, MERGE records with
-    no operator, oversized shards) are declined untouched, before any
-    program is built; a decline for width counts under
+    which). DBs the device path can't express (``device_decline_reason``
+    says which and why: custom merge operators, values over
+    ``device_value_bytes_max``, MERGE records with no operator, keys or
+    values of more than one width; besides >24B keys and oversized
+    shards, which the lane read declines) are declined untouched, before
+    any program is built; a decline for width counts under
     ``tpu.host_fallbacks reason=value_width``.
 
     Returns ``(handled, remaining)``: db names compacted here, and the
     (name, db) pairs the caller must compact per-db (compact_range).
     """
-    from ..storage.merge import UInt64AddOperator
-
     dbs = list(dbs)
     handled: List[str] = []
     remaining: List[tuple] = []
@@ -628,8 +553,8 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
         the per-db compact_range fallback instead."""
         name, db = item
         merge_op = db.options.merge_operator
-        if merge_op is not None and not isinstance(
-                merge_op, UInt64AddOperator):
+        # the operator alone, before the plan: a plan costs a flush
+        if device_decline_reason(None, merge_op) is not None:
             return ("remaining", name, db, None)
         try:
             # mostly the wait for the memtable flush, which the engine's
@@ -644,34 +569,29 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
         _track(db, plan)
         try:
             with start_span("tpu.lanes.decode", remote=tctx) as lsp:
-                lanes = _db_lanes(plan)
-                if lanes is not None:
-                    lsp.annotate(rows=int(lanes["key_len"].shape[0]))
+                # None: nothing to compact, a run the lanes can't
+                # express, or more rows than one place of a launch takes
+                read = read_runs_as_lanes(
+                    plan["runs"], None, max_entries=MAX_BATCHED_DB_ENTRIES)
+                if read is not None:
+                    lsp.annotate(rows=read[2])
         except BaseException:
             log.exception(
                 "lane read failed for %s; declining to per-db", name)
             _abort(db, plan)
             return ("remaining", name, db, None)
-        total = lanes["key_len"].shape[0] if lanes is not None else 0
-        widest = int(lanes["val_len"].max()) if total else 0
-        if widest > device_value_bytes_max(merge_op):
-            # wider than the device path takes (for uint64-add: than the
-            # fold is defined on): the host path, and no program built
-            record_host_fallback(
-                "value_width", f"{name}: {widest}-byte values")
+        if read is None:
             _abort(db, plan)
             return ("remaining", name, db, None)
-        if (
-            lanes is None
-            or total == 0
-            or total > MAX_BATCHED_DB_ENTRIES
-            # uint64-add fold needs 8-byte values (backend.py parity)
-            or (merge_op is not None and bool(
-                ((lanes["vtype"] != _DELETE)
-                 & (lanes["val_len"] != 8)).any()))
-            # MERGE records without an operator: CPU path only
-            or (merge_op is None and bool((lanes["vtype"] == _MERGE).any()))
-        ):
+        lanes = read[1]
+        reason = device_decline_reason(lanes, merge_op)
+        if reason is not None:
+            if reason == "value_width":
+                # wider than the device path takes (for uint64-add: than
+                # the fold is defined on): the host path, and no program
+                record_host_fallback(
+                    reason,
+                    f"{name}: {int(lanes['val_len'].max())}-byte values")
             _abort(db, plan)
             return ("remaining", name, db, None)
         kind = (

@@ -3,9 +3,8 @@
 No chip is attached here: the TPU compiler that ships with jax compiles
 for a topology that is described (`v5e:2x2`, one device of it). Nothing
 executes — a compile that passes is not a chip run — but what the chip's
-compiler refuses (a Mosaic layout it cannot infer, an op it has no
-lowering for, a program that does not fit) is refused here too, at no
-chip time. Interpret mode on the CPU backend shows none of that.
+compiler refuses (an op it has no lowering for, a program that does not
+fit) is refused here too, at no chip time.
 
 Shapes are the counter deployment's (chip_smoke.py): 16-byte keys →
 ``fast_flags`` = (uniform_klen, seq32, key_words=4), two value words,
@@ -78,10 +77,10 @@ def _kernel_lanes(shape, n, lead=()):
 
 def test_merge_resolve_lax_compiles(shape):
     """The per-DB program (TpuCompactionBackend.merge_runs_to_files →
-    chunked.run_kernel_arrays): one shard, lax sort, uint64-add."""
+    chunked.run_kernel_arrays): one shard, uint64-add."""
     compiled = merge_resolve_kernel.lower(
         *_kernel_lanes(shape, 8192), merge_kind=MergeKind.UINT64_ADD,
-        drop_tombstones=True, sort_backend="lax", **FAST).compile()
+        drop_tombstones=True, **FAST).compile()
     assert compiled.memory_analysis() is not None
 
 
@@ -138,16 +137,6 @@ def test_bloom_build_compiles(shape):
     assert compiled.memory_analysis() is not None
 
 
-def test_bloom_hash_pallas_compiles(shape):
-    """The one Pallas kernel the chip's compiler takes today."""
-    from rocksplicator_tpu.ops.pallas_kernels import bloom_hash_pallas
-
-    n = 131072
-    compiled = bloom_hash_pallas.lower(
-        shape((n, 6)), shape((n,)), interpret=False).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 def test_planar_encode_and_checksums_compile(shape):
     from rocksplicator_tpu.ops.block_encode import (encode_planar_words_tpu,
                                                     planar_checksums_tpu)
@@ -172,56 +161,3 @@ def test_graft_entry_forward_compiles(shape):
     compiled = jax.jit(forward).lower(
         *(shape(a.shape, a.dtype) for a in example_args)).compile()
     assert compiled.memory_analysis() is not None
-
-
-# --- the two Pallas sort kernels: REFUSED by the v5e compiler today ------
-# strict xfails carrying the compiler's words, so the PR that repairs the
-# kernels (ROADMAP Queue 1 item 6) flips these tests. Interpret mode on
-# the CPU passes both, which is why nobody knew.
-
-
-class CompilerRefused(Exception):
-    """The chip's compiler refused the program with the recorded words."""
-
-
-def _lower_and_compile(lower, words: str):
-    """Only the RECORDED refusal is the expected failure: any other
-    exception (an import error, a new refusal) fails the test outright."""
-    try:
-        lower().compile()
-    except Exception as e:
-        if words in str(e):
-            raise CompilerRefused(str(e)[:400]) from e
-        raise
-
-
-@pytest.mark.xfail(
-    strict=True, raises=CompilerRefused,
-    reason='MosaicError: infer-vector-layout: unsupported shape cast — '
-           '"tpu.reshape"(vector<16x128xi32>) -> vector<16x64x2x1xi32>: '
-           "the lane-partner stage of ops/pallas_sort.py _stage splits "
-           "the minor dim below 128")
-def test_bitonic_sort_lanes_compiles(shape):
-    from rocksplicator_tpu.ops.pallas_sort import bitonic_sort_lanes
-
-    _lower_and_compile(
-        lambda: bitonic_sort_lanes.lower(
-            tuple(shape((2048,)) for _ in range(4)), num_keys=2,
-            interpret=False),
-        "unsupported shape cast")
-
-
-@pytest.mark.xfail(
-    strict=True, raises=CompilerRefused,
-    reason="NotImplementedError: Reductions over unsigned integers not "
-           "implemented — jnp.max over the u32 overflow mask in "
-           "ops/pallas_resolve.py _fused_kernel; the shared "
-           "bitonic_network's reshape refusal is behind it")
-def test_fused_merge_resolve_compiles(shape):
-    from rocksplicator_tpu.ops.pallas_resolve import fused_merge_resolve
-
-    _lower_and_compile(
-        lambda: fused_merge_resolve.lower(
-            *_kernel_lanes(shape, 2048), merge_kind=MergeKind.UINT64_ADD,
-            drop_tombstones=True, interpret=False, **FAST),
-        "Reductions over unsigned integers")

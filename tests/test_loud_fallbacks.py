@@ -131,20 +131,6 @@ def test_worker_tpu_backend_no_longer_degrades(monkeypatch):
         _build_backend(None)
 
 
-def test_unknown_sort_backend_flag_raises():
-    from rocksplicator_tpu.ops.compaction_kernel import \
-        deployment_sort_backend
-    from rocksplicator_tpu.utils.flags import FLAGS
-
-    assert deployment_sort_backend() == "lax"
-    FLAGS.set("sort_backend", "palas")
-    try:
-        with pytest.raises(ValueError, match="sort_backend flag 'palas'"):
-            deployment_sort_backend()
-    finally:
-        FLAGS.set("sort_backend", "lax")
-
-
 def test_compile_cache_is_placed_from_outside_or_in_the_checkout(
         tmp_path, monkeypatch):
     import os

@@ -333,387 +333,3 @@ def test_synth_counter_batch_jax_matches_numpy_contract():
     assert got["key_words_be"][:, 1].max() < n // 8
     assert (got["val_len"] == np.where(got["vtype"] == 2, 0, 8)).all()
     assert got["valid"].all()
-
-
-# ---------------------------------------------------------------------
-# sorted-runs merge network (ops/merge_network.py)
-# ---------------------------------------------------------------------
-
-def _pack_runs(runs, run_capacity):
-    """Per-run entry lists -> stacked (R, L) lanes + valid matrix."""
-    batches = [pack_entries(r, capacity=run_capacity) for r in runs]
-    stack = lambda f: np.stack([getattr(b, f) for b in batches])  # noqa: E731
-    return {
-        "key_words_be": stack("key_words_be"),
-        "key_len": stack("key_len"),
-        "seq_hi": stack("seq_hi"),
-        "seq_lo": stack("seq_lo"),
-        "vtype": stack("vtype"),
-        "val_words": stack("val_words"),
-        "val_len": stack("val_len"),
-        "valid": stack("valid"),
-    }
-
-
-def _run_runs_kernel(runs, run_capacity, merge_kind=MergeKind.UINT64_ADD,
-                     drop_tombstones=True, **flags):
-    from rocksplicator_tpu.ops.merge_network import (
-        merge_resolve_runs_kernel, runs_are_sorted)
-
-    lanes = _pack_runs(runs, run_capacity)
-    assert runs_are_sorted(
-        lanes["key_words_be"], lanes["key_len"], lanes["seq_hi"],
-        lanes["seq_lo"], lanes["valid"])
-    out = merge_resolve_runs_kernel(
-        jnp.asarray(lanes["key_words_be"]), jnp.asarray(lanes["key_len"]),
-        jnp.asarray(lanes["seq_hi"]), jnp.asarray(lanes["seq_lo"]),
-        jnp.asarray(lanes["vtype"]), jnp.asarray(lanes["val_words"]),
-        jnp.asarray(lanes["val_len"]), jnp.asarray(lanes["valid"]),
-        merge_kind=merge_kind, drop_tombstones=drop_tombstones, **flags)
-    return unpack_entries(
-        np.asarray(out["key_words_be"]), np.asarray(out["key_len"]),
-        np.asarray(out["seq_hi"]), np.asarray(out["seq_lo"]),
-        np.asarray(out["vtype"]), np.asarray(out["val_words"]),
-        np.asarray(out["val_len"]), int(out["count"]),
-    )
-
-
-def _split_sorted_runs(entries, n_runs, rng):
-    """Assign entries to runs at random; each run sorted (key asc, seq
-    desc) — the precondition real SST/memtable runs satisfy."""
-    runs = [[] for _ in range(n_runs)]
-    for e in entries:
-        runs[rng.randrange(n_runs)].append(e)
-    return [sorted(r, key=lambda e: (e[0], -e[1])) for r in runs]
-
-
-@pytest.mark.parametrize("merge_kind,drop", [
-    (MergeKind.UINT64_ADD, True),
-    (MergeKind.UINT64_ADD, False),
-    (MergeKind.NONE, True),
-    (MergeKind.NONE, False),
-])
-def test_merge_network_matches_full_sort_kernel(merge_kind, drop):
-    rng = random.Random(42)
-    entries = []
-    seq = 1
-    for _ in range(500):
-        k = f"key{rng.randrange(60):04d}".encode()
-        r = rng.random()
-        if merge_kind is MergeKind.NONE:
-            vt = OpType.PUT if r < 0.8 else OpType.DELETE
-        else:
-            vt = (OpType.MERGE if r < 0.5
-                  else OpType.PUT if r < 0.85 else OpType.DELETE)
-        v = b"" if vt == OpType.DELETE else pack64(rng.randrange(1000))
-        entries.append((k, seq, vt, v))
-        seq += 1
-    want = run_kernel(entries, merge_kind=merge_kind, drop_tombstones=drop,
-                      capacity=1024)
-    for n_runs in (1, 2, 4, 8):
-        runs = _split_sorted_runs(entries, n_runs, random.Random(n_runs))
-        cap = 1
-        while cap < max(len(r) for r in runs):
-            cap *= 2
-        got = _run_runs_kernel(runs, cap, merge_kind=merge_kind,
-                               drop_tombstones=drop)
-        assert got == want, f"n_runs={n_runs}"
-
-
-def test_merge_network_fast_flags_parity():
-    rng = random.Random(7)
-    entries = []
-    for i in range(300):
-        k = f"k{rng.randrange(40):06d}".encode()  # uniform 7-byte keys
-        entries.append((k, i + 1, OpType.MERGE, pack64(i)))
-    want = run_kernel(entries, capacity=512)
-    runs = _split_sorted_runs(entries, 4, rng)
-    got = _run_runs_kernel(runs, 128, uniform_klen=True, seq32=True,
-                           key_words=2)
-    assert got == want
-
-
-def test_merge_network_uneven_and_empty_runs():
-    entries = [
-        (b"a", 3, OpType.PUT, pack64(1)),
-        (b"b", 2, OpType.DELETE, b""),
-        (b"c", 1, OpType.PUT, pack64(2)),
-    ]
-    want = run_kernel(entries, capacity=8)
-    runs = [sorted(entries, key=lambda e: (e[0], -e[1])), []]
-    got = _run_runs_kernel(runs, 4)
-    assert got == want
-
-
-def test_runs_are_sorted_detects_violations():
-    from rocksplicator_tpu.ops.merge_network import runs_are_sorted
-
-    ok = _pack_runs([[
-        (b"a", 2, OpType.PUT, b"x"),
-        (b"a", 1, OpType.PUT, b"y"),  # same key: seq desc
-        (b"b", 9, OpType.PUT, b"z"),
-    ]], 4)
-    assert runs_are_sorted(ok["key_words_be"], ok["key_len"], ok["seq_hi"],
-                           ok["seq_lo"], ok["valid"])
-    bad_key = _pack_runs([[
-        (b"b", 1, OpType.PUT, b"x"),
-        (b"a", 2, OpType.PUT, b"y"),
-    ]], 2)
-    assert not runs_are_sorted(
-        bad_key["key_words_be"], bad_key["key_len"], bad_key["seq_hi"],
-        bad_key["seq_lo"], bad_key["valid"])
-    bad_seq = _pack_runs([[
-        (b"a", 1, OpType.PUT, b"x"),
-        (b"a", 2, OpType.PUT, b"y"),  # seq ascending: newest must be first
-    ]], 2)
-    assert not runs_are_sorted(
-        bad_seq["key_words_be"], bad_seq["key_len"], bad_seq["seq_hi"],
-        bad_seq["seq_lo"], bad_seq["valid"])
-    # valid rows must form a prefix (a hole breaks run order)
-    hole = _pack_runs([[(b"a", 1, OpType.PUT, b"x")]], 2)
-    hole["valid"][0] = np.array([False, True])
-    assert not runs_are_sorted(
-        hole["key_words_be"], hole["key_len"], hole["seq_hi"],
-        hole["seq_lo"], hole["valid"])
-
-
-def test_merge_network_rejects_non_pow2_shapes():
-    from rocksplicator_tpu.ops.merge_network import merge_sorted_lanes
-
-    with pytest.raises(ValueError):
-        merge_sorted_lanes([jnp.zeros((2, 6), jnp.uint32)], 1)
-    with pytest.raises(ValueError):
-        merge_sorted_lanes([jnp.zeros((3, 4), jnp.uint32)], 1)
-
-
-def test_pallas_bitonic_sort_parity_with_lax():
-    """The VMEM-resident bitonic sort must order lanes EXACTLY like
-    lax.sort on the same (keys, payload) operands (interpret mode on
-    CPU; on-chip it is the same network)."""
-    import numpy as _np
-
-    from rocksplicator_tpu.ops.pallas_sort import bitonic_sort_lanes
-
-    rng = _np.random.default_rng(7)
-    n = 512  # interpret mode executes the full 45-stage network in pure
-    # python — keep the size small; the network is size-generic
-    for num_keys, n_payload in ((1, 0), (6, 4)):
-        ops = [rng.integers(0, 1 << 32, n, dtype=_np.uint32)
-               for _ in range(num_keys + n_payload)]
-        # duplicate keys to exercise payload stability-independence:
-        # compare VALUE-wise (payload under equal keys may permute in
-        # either unstable sort, so pin payload = f(keys) for determinism)
-        for i in range(num_keys):  # narrow ALL key lanes: real ties
-            ops[i] = (ops[i] % 7).astype(_np.uint32)
-        for i in range(num_keys, num_keys + n_payload):
-            ops[i] = sum(ops[:num_keys]).astype(_np.uint32)
-        want = jax.lax.sort(
-            tuple(jnp.asarray(o) for o in ops), num_keys=num_keys,
-            is_stable=False)
-        got = bitonic_sort_lanes(
-            tuple(jnp.asarray(o) for o in ops), num_keys=num_keys,
-            interpret=True)
-        for w, g in zip(want, got):
-            _np.testing.assert_array_equal(_np.asarray(w), _np.asarray(g))
-
-
-def test_pallas_sort_dispatch_is_loud():
-    """backend="pallas" means the kernel: a shape it does not take
-    RAISES (it used to warn and run lax.sort under the kernel's name);
-    a power-of-two N takes the pallas kernel and must match lax exactly.
-    An unknown backend name raises too."""
-    import numpy as _np
-
-    from rocksplicator_tpu.ops.pallas_sort import sort_lanes
-
-    rng = _np.random.default_rng(3)
-
-    def ops(n):
-        return (jnp.asarray(rng.integers(0, 99, n, dtype=_np.uint32)),
-                jnp.asarray(rng.integers(0, 99, n, dtype=_np.uint32)))
-
-    with pytest.raises(ValueError, match="power-of-two"):
-        sort_lanes(ops(1000), num_keys=1, backend="pallas", interpret=True)
-    with pytest.raises(ValueError, match="unknown sort backend"):
-        sort_lanes(ops(256), num_keys=1, backend="palas")
-    o = ops(256)
-    got = sort_lanes(o, num_keys=1, backend="pallas", interpret=True)
-    want = jax.lax.sort(o, num_keys=1, is_stable=False)
-    _np.testing.assert_array_equal(_np.asarray(want[0]),
-                                   _np.asarray(got[0]))
-
-
-def test_merge_resolve_kernel_pallas_sort_backend_parity():
-    """Full merge-resolve with sort_backend="pallas" must produce results
-    identical to the lax backend (the sort is a drop-in)."""
-    import numpy as _np
-
-    from rocksplicator_tpu.models.compaction_model import (
-        CompactionModel, synth_counter_batch)
-
-    b = synth_counter_batch(1024, key_space=128, seed=5, key_bytes=16)
-    args = (b["key_words_be"], b["key_len"], b["seq_hi"], b["seq_lo"],
-            b["vtype"], b["val_words"], b["val_len"], b["valid"])
-    base = CompactionModel(capacity=1024, uniform_klen=True, seq32=True,
-                           key_words=4)
-    pall = CompactionModel(capacity=1024, uniform_klen=True, seq32=True,
-                           key_words=4, sort_backend="pallas")
-    out_l = base.forward(*args)
-    out_p = pall.forward(*args)
-    assert int(out_l["count"]) == int(out_p["count"])
-    n = int(out_l["count"])
-    for k in ("key_words_be", "seq_lo", "vtype", "val_words", "val_len"):
-        _np.testing.assert_array_equal(
-            _np.asarray(out_l[k])[:n], _np.asarray(out_p[k])[:n], err_msg=k)
-
-
-def _assert_fused_matches_lax(args, **flags):
-    """Full-array parity (including the zero-masked dead rows, the count,
-    and the overflow flag) between the lax path and the fused VMEM
-    kernel."""
-    import numpy as _np
-
-    out_l = merge_resolve_kernel(*args, **flags)
-    out_f = merge_resolve_kernel(*args, sort_backend="pallas_fused",
-                                 **flags)
-    assert int(out_l["count"]) == int(out_f["count"])
-    assert (bool(out_l["needs_cpu_fallback"])
-            == bool(out_f["needs_cpu_fallback"]))
-    for k in ("key_words_be", "key_words_le", "key_len", "seq_lo",
-              "seq_hi", "vtype", "val_words", "val_len"):
-        _np.testing.assert_array_equal(
-            _np.asarray(out_l[k]), _np.asarray(out_f[k]), err_msg=k)
-
-
-def test_fused_merge_resolve_parity_counter_batch():
-    """The fully-fused pallas kernel (sort + resolve + compaction in one
-    VMEM residency) must match the lax path element-exactly on the bench
-    configuration (uniform klen, 32-bit seqs, uint64-add merges)."""
-    from rocksplicator_tpu.models.compaction_model import synth_counter_batch
-
-    b = synth_counter_batch(512, key_space=64, seed=5, key_bytes=16)
-    args = (b["key_words_be"], b["key_len"], b["seq_hi"], b["seq_lo"],
-            b["vtype"], b["val_words"], b["val_len"], b["valid"])
-    _assert_fused_matches_lax(args, uniform_klen=True, seq32=True,
-                              key_words=4)
-
-
-def test_fused_merge_resolve_parity_general_lanes():
-    """General configuration: ragged key lengths, seqs above 2^32, a
-    duplicate-key merge stack ending in a DELETE, padding rows — across
-    both merge kinds and both tombstone policies."""
-    rng = np.random.default_rng(11)
-    entries = []
-    seq = 1 << 33
-    for _ in range(180):
-        klen = int(rng.integers(1, 20))
-        key = bytes(rng.integers(97, 123, klen, dtype=np.uint8))
-        r = rng.random()
-        if r < 0.5:
-            entries.append((key, seq, OpType.MERGE,
-                            pack64(int(rng.integers(0, 99)))))
-        elif r < 0.6:
-            entries.append((key, seq, OpType.DELETE, b""))
-        else:
-            entries.append((key, seq, OpType.PUT,
-                            pack64(int(rng.integers(0, 99)))))
-        seq += 1
-    for _ in range(40):
-        entries.append((b"hotkey", seq, OpType.MERGE, pack64(1)))
-        seq += 1
-    entries.append((b"hotkey", seq, OpType.DELETE, b""))
-
-    batch = pack_entries(entries, capacity=256)
-    args = tuple(jnp.asarray(x) for x in (
-        batch.key_words_be, batch.key_len, batch.seq_hi, batch.seq_lo,
-        batch.vtype, batch.val_words, batch.val_len, batch.valid))
-    # two configs cover both merge kinds AND both keep policies; the
-    # remaining cross terms only recombine already-exercised branches
-    # (interpret-mode runs re-trace the whole unrolled ladder, so each
-    # config costs minutes on a small CPU)
-    for mk, drop in ((MergeKind.UINT64_ADD, True), (MergeKind.NONE, False)):
-        _assert_fused_matches_lax(args, merge_kind=mk,
-                                  drop_tombstones=drop)
-
-
-def test_fused_merge_resolve_non_pow2_raises():
-    """Capacities the fused kernel can't take (non-power-of-two) RAISE:
-    sort_backend="pallas_fused" never runs the lax path under the fused
-    kernel's name (it used to, with a warning)."""
-    entries = [
-        (b"a", 1, OpType.PUT, pack64(10)),
-        (b"a", 2, OpType.MERGE, pack64(5)),
-        (b"b", 3, OpType.DELETE, b""),
-    ]
-    batch = pack_entries(entries, capacity=100)
-    args = tuple(jnp.asarray(x) for x in (
-        batch.key_words_be, batch.key_len, batch.seq_hi, batch.seq_lo,
-        batch.vtype, batch.val_words, batch.val_len, batch.valid))
-    with pytest.raises(ValueError, match="power-of-two"):
-        merge_resolve_kernel(*args, sort_backend="pallas_fused")
-    with pytest.raises(ValueError, match="unknown sort backend"):
-        merge_resolve_kernel(*args, sort_backend="bogus")
-
-
-def test_vmem_scan_ladder_primitives_match_1d():
-    """The fused kernel's (R,128) Hillis-Steele shift/scan ladders must
-    reproduce the 1-D primitives exactly (cheap pinpoint coverage — the
-    interpret-mode kernel tests are minutes each; this isolates the scan
-    math in milliseconds)."""
-    import numpy as _np
-
-    from rocksplicator_tpu.ops.compaction_kernel import (
-        _seg_fill_backward, _seg_fill_forward)
-    from rocksplicator_tpu.ops.pallas_resolve import (
-        _cumsum_tuple, _fill_backward, _fill_forward, _shift_down,
-        _shift_up)
-
-    n, lanes = 1024, 128
-    r = n // lanes
-    rng = _np.random.default_rng(2)
-    x_np = rng.integers(0, 1000, n, dtype=_np.int32)
-    x1 = jnp.asarray(x_np)
-    x2 = x1.reshape(r, lanes)
-    iota2 = (jax.lax.broadcasted_iota(jnp.int32, (r, lanes), 0) * lanes
-             + jax.lax.broadcasted_iota(jnp.int32, (r, lanes), 1))
-
-    # linear-order shifts at lane, row, and multi-row distances
-    for d in (1, 2, 64, 128, 256):
-        want_dn = _np.concatenate([_np.zeros(d, _np.int32), x_np[:-d]])
-        want_up = _np.concatenate([x_np[d:], _np.zeros(d, _np.int32)])
-        _np.testing.assert_array_equal(
-            _np.asarray(_shift_down(x2, d)).reshape(n), want_dn, err_msg=f"down d={d}")
-        _np.testing.assert_array_equal(
-            _np.asarray(_shift_up(x2, d)).reshape(n), want_up, err_msg=f"up d={d}")
-
-    # batched inclusive prefix sums
-    y_np = rng.integers(0, 7, n, dtype=_np.int32)
-    got = _cumsum_tuple((x2, jnp.asarray(y_np).reshape(r, lanes)), n)
-    _np.testing.assert_array_equal(
-        _np.asarray(got[0]).reshape(n), _np.cumsum(x_np, dtype=_np.int32))
-    _np.testing.assert_array_equal(
-        _np.asarray(got[1]).reshape(n), _np.cumsum(y_np, dtype=_np.int32))
-
-    # segmented fills vs the associative_scan originals (row 0 / last
-    # row flagged per the contract)
-    flag_np = rng.random(n) < 0.07
-    flag_np[0] = True
-    flag1 = jnp.asarray(flag_np)
-    want_f = _seg_fill_forward(flag1, (x1, jnp.asarray(y_np)))
-    got_f = _fill_forward(flag1.reshape(r, lanes),
-                          (x2, jnp.asarray(y_np).reshape(r, lanes)),
-                          iota2, n)
-    for w, g in zip(want_f, got_f):
-        _np.testing.assert_array_equal(
-            _np.asarray(g).reshape(n), _np.asarray(w), err_msg="fwd")
-
-    lflag_np = rng.random(n) < 0.07
-    lflag_np[-1] = True
-    lflag1 = jnp.asarray(lflag_np)
-    want_b = _seg_fill_backward(lflag1, (x1, jnp.asarray(y_np)))
-    got_b = _fill_backward(lflag1.reshape(r, lanes),
-                           (x2, jnp.asarray(y_np).reshape(r, lanes)),
-                           iota2, n)
-    for w, g in zip(want_b, got_b):
-        _np.testing.assert_array_equal(
-            _np.asarray(g).reshape(n), _np.asarray(w), err_msg="bwd")

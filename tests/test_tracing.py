@@ -639,13 +639,13 @@ def test_batched_compaction_is_one_trace_across_the_pool(tmp_path,
         dbs.append(db)
 
     decode_threads = []
-    real_lanes = cs._db_lanes
+    real_lanes = cs.read_runs_as_lanes
 
-    def db_lanes(plan):
+    def db_lanes(*args, **kwargs):
         decode_threads.append(threading.current_thread().name)
-        return real_lanes(plan)
+        return real_lanes(*args, **kwargs)
 
-    monkeypatch.setattr(cs, "_db_lanes", db_lanes)
+    monkeypatch.setattr(cs, "read_runs_as_lanes", db_lanes)
     compactor = BatchCompactor(use_tpu=True, compact_parallelism=3)
 
     def submit(s):

@@ -113,7 +113,7 @@ def test_index_and_riding_paths_give_identical_lanes(vlen, fast):
         flags = dict(uniform_klen=u, seq32=s32, key_words=kw)
     ride = jax.jit(functools.partial(
         ck._sort_resolve, index=False, merge_kind=MergeKind.NONE,
-        drop_tombstones=True, sort_backend="lax", **flags))
+        drop_tombstones=True, **flags))
     a = ride(*(jnp.asarray(getattr(batch, f)) for f in LANES))
     b = index_path(batch, True, **flags)
     assert set(a) == set(b)
