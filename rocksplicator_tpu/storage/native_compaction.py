@@ -26,7 +26,7 @@ from __future__ import annotations
 
 import logging
 import os
-from typing import List, Optional, Tuple
+from typing import Callable, List, Optional, Tuple
 
 import numpy as np
 
@@ -202,6 +202,7 @@ class NativeCompactionBackend(CpuCompactionBackend):
 def read_runs_as_lanes(
     runs: List, merge_op: Optional[MergeOperator],
     max_entries: int = MAX_DIRECT_ENTRIES,
+    value_rows: Optional[Callable[[int, int], int]] = None,
 ) -> Optional[Tuple[List[dict], dict, int, int]]:
     """Decode input runs (SSTReaders or entry iterables) straight into
     concatenated lane arrays. Returns (parts, lanes, total, vw) or None
@@ -210,6 +211,12 @@ def read_runs_as_lanes(
     direct compaction sink and both device doors (tpu/backend.py,
     tpu/compaction_service.py), which pass no ``merge_op``: the uint64-add
     bail below is then theirs to make, with the rest of their rule.
+
+    ``value_rows(total, vw)`` (the served device door's alone) gives the
+    rows of a zero-tailed buffer that the runs' values are concatenated
+    INTO: ``lanes["val_words"]`` is then its first ``total`` rows and
+    ``lanes["val_words"].base`` the buffer, so that a caller that ships
+    the values padded to a launch's capacity copies them once.
 
     Deliberately single-threaded: the per-block Python between the
     GIL-releasing zlib/numpy stretches convoys badly under a thread
@@ -262,7 +269,12 @@ def read_runs_as_lanes(
             p["val_words"] = np.pad(p["val_words"], [(0, 0), (0, vw - w)])
     fields = ("key_words_be", "key_len", "seq_hi", "seq_lo", "vtype",
               "val_words", "val_len")
-    lanes = {f: np.concatenate([p[f] for p in parts]) for f in fields}
+    into = {}
+    if value_rows is not None:
+        into["val_words"] = np.zeros(
+            (value_rows(total, vw), vw), dtype=np.uint32)[:total]
+    lanes = {f: np.concatenate([p[f] for p in parts], out=into.get(f))
+             for f in fields}
     return parts, lanes, total, vw
 
 

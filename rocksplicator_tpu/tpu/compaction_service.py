@@ -22,6 +22,7 @@ import os
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from ..observability.context import current_span, wire_context
@@ -62,9 +63,6 @@ class TpuCompactionService:
     _instance_lock = threading.Lock()
 
     def __init__(self, bits_per_key: int = 10):
-        import jax
-
-        self._jax = jax
         require_accelerator()
         self._bits_per_key = bits_per_key
         self._vmapped_cache: Dict[tuple, object] = {}
@@ -107,7 +105,6 @@ class TpuCompactionService:
                key_words, index)
         fn = self._vmapped_cache.get(key)
         if fn is None:
-            jax = self._jax
             flags = dict(drop_tombstones=drop_tombstones,
                          uniform_klen=uniform_klen, seq32=seq32,
                          key_words=key_words)
@@ -205,7 +202,6 @@ class TpuCompactionService:
 
     def _compact_shard_stream(self, batches, merge_kind, drop_tombstones,
                               group_size, capacity, return_arrays=False):
-        jax = self._jax
         num_words = num_words_for(capacity, self._bits_per_key)
         flags = [fast_flags(b.key_len, b.seq_hi, b.valid) for b in batches]
         uniform_klen = all(u for u, _, _ in flags)
@@ -224,17 +220,31 @@ class TpuCompactionService:
         def stage(lo: int) -> Dict[str, object]:
             """Stack one group on host and issue its async H2D. On the
             index path a shard's values go up as a buffer of their own,
-            never stacked; an empty place takes the one zero buffer the
-            device already holds."""
+            never stacked: the one its batch brought (``val_words_dev``:
+            the caller's thread put it up already) where that is of this
+            group's capacity bucket, else the host values padded and put
+            here; an empty place takes the one zero buffer the device
+            already holds."""
             group = list(batches[lo:lo + group_size])
             pad_shards = group_size - len(group)
             stacked = {}
-            with start_span("tpu.h2d", shards=len(group)):
+            with start_span("tpu.h2d", shards=len(group)) as sp:
                 for name in _GROUP_LANES:
                     if index and name == "val_words":
-                        stacked[name] = tuple(
-                            jax.device_put(_pad_to(b.val_words, capacity))
-                            for b in group) + (
+                        ups, prestaged, restaged = [], 0, 0
+                        for b in group:
+                            dev = getattr(b, "val_words_dev", None)
+                            if dev is not None and dev.shape[0] == capacity:
+                                prestaged += 1
+                            else:  # none came, or one of another bucket
+                                restaged += dev is not None
+                                dev = jax.device_put(
+                                    _pad_to(b.val_words, capacity))
+                            ups.append(dev)
+                        Stats.get().incr("seam.values.prestaged", prestaged)
+                        Stats.get().incr("seam.values.restaged", restaged)
+                        sp.annotate(prestaged=prestaged)
+                        stacked[name] = tuple(ups) + (
                             self._zeros(capacity, val_words),) * pad_shards
                         continue
                     arr = np.stack([_pad_to(getattr(b, name), capacity)
@@ -273,7 +283,7 @@ class TpuCompactionService:
         what an empty place of an index-path group reads."""
         key = (capacity, val_words)
         if key not in self._zero_rows:
-            self._zero_rows[key] = self._jax.device_put(
+            self._zero_rows[key] = jax.device_put(
                 np.zeros(key, dtype=np.uint32))
         return self._zero_rows[key]
 
@@ -281,12 +291,23 @@ class TpuCompactionService:
                num_words, return_arrays=False) -> List[dict]:
         """Readback + unpack one group's device outputs. The index
         path's values come back per shard (``out["val_words"]`` is a
-        tuple), the real shards' only, each as the padded block."""
+        tuple), the real shards' only, each as the padded block: here,
+        or, for a shard whose batch brought its values up as a device
+        buffer, wherever the caller reads the device buffer its result
+        carries (the copy is started here, behind the small lanes)."""
         group = batches[lo:lo + out["count"].shape[0]]
         with start_span("tpu.readback"):  # blocked on the device, and D2H
-            host = {k: np.asarray(v) if not isinstance(v, tuple)
-                    else [np.asarray(a) for a in v[:len(group)]]
-                    for k, v in out.items()}
+            host = {k: np.asarray(v) for k, v in out.items()
+                    if not isinstance(v, tuple)}
+            if isinstance(out["val_words"], tuple):
+                host["val_words"] = vals = []
+                for b, a in zip(group, out["val_words"]):
+                    if (return_arrays and
+                            getattr(b, "val_words_dev", None) is not None):
+                        a.copy_to_host_async()
+                    else:
+                        a = np.asarray(a)
+                    vals.append(a)
         results = []
         with start_span("tpu.unpack", shards=len(group)):
             for s in range(len(group)):
@@ -369,8 +390,14 @@ def _shard_result(host: Dict[str, np.ndarray], s: int, count: int,
     """One shard's result from stacked device outputs: lane views (no
     per-entry work) or unpacked tuples."""
     if return_arrays:
+        def rows(f):
+            a = host[f][s]
+            # a value block left on the device stays whole: the thread
+            # that reads it back slices it (``_write_arrays``)
+            return a[:count] if isinstance(a, np.ndarray) else a
+
         return {
-            "arrays": {f: host[f][s][:count] for f in _LANES},
+            "arrays": {f: rows(f) for f in _LANES},
             "bloom_words": host["bloom"][s],
             "count": count,
         }
@@ -439,14 +466,19 @@ MAX_BATCHED_DB_ENTRIES = 1 << 20
 class _LaneBatch:
     """Duck-typed KVBatch over pre-read lane arrays — the arrays-native
     input to compact_shard_batch/stream (no per-entry pack loop): the
-    lanes a launch takes (``_GROUP_LANES``), every row valid."""
+    lanes a launch takes (``_GROUP_LANES``), every row valid.
+    ``val_words_dev``: the values on the device already, zero-padded to
+    ``_next_pow2(rows)`` rows, where the thread that decoded the shard
+    put them up itself (an index-path shard of the served door); such a
+    shard's resolved values come back as a device buffer too."""
 
-    __slots__ = _GROUP_LANES
+    __slots__ = _GROUP_LANES + ("val_words_dev",)
 
-    def __init__(self, lanes: Dict[str, np.ndarray]):
+    def __init__(self, lanes: Dict[str, np.ndarray], val_words_dev=None):
         for f in _GROUP_LANES[:-1]:
             setattr(self, f, lanes[f])
         self.valid = np.ones(lanes["key_len"].shape[0], dtype=bool)
+        self.val_words_dev = val_words_dev
 
     @property
     def capacity(self) -> int:
@@ -466,6 +498,13 @@ def _write_arrays(db, res: dict, tctx: Optional[dict]) -> dict:
     arrays, count = res["arrays"], int(res["count"])
     if count == 0:
         return {"entries": []}
+    vals = arrays["val_words"]
+    if not isinstance(vals, np.ndarray):
+        # the launch left this shard's padded block on the device
+        # (``_drain``): down on THIS thread, eight shards at once
+        with start_span("tpu.readback.values", remote=tctx,
+                        bytes=vals.nbytes):
+            arrays["val_words"] = np.asarray(vals)[:count]
     opts = db.options
     outputs = write_resolved_lanes(
         arrays, count, db.allocate_sst_path, opts.block_bytes,
@@ -490,7 +529,10 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
     through the PLANAR sink — no per-entry Python on either side). The
     per-db host stages (plan + lane read, then SST write + install) fan
     out over ``pool`` (any Executor) when given; only the device launch
-    is centralized.
+    is centralized. An index-path shard's value block crosses the
+    host-device seam on those per-db threads too (``tpu.h2d.values``
+    after its decode, ``tpu.readback.values`` before its write): the
+    launching thread carries the small lanes alone.
 
     Per DB: plan (engine plan_full_compaction: flush + snapshot under the
     compaction mutex), read its runs as lanes, launch the group, install
@@ -567,12 +609,24 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
         if plan is None:
             return ("handled", name, db, None)  # nothing to compact
         _track(db, plan)
+        kind = (
+            MergeKind.UINT64_ADD if merge_op is not None else MergeKind.NONE
+        )
+
+        def value_rows(total, vw):
+            # an index-path shard's values are decoded straight into the
+            # padded buffer that goes up: its own capacity bucket
+            if value_path(kind, vw) == "index":
+                return _next_pow2(total)
+            return total
+
         try:
             with start_span("tpu.lanes.decode", remote=tctx) as lsp:
                 # None: nothing to compact, a run the lanes can't
                 # express, or more rows than one place of a launch takes
                 read = read_runs_as_lanes(
-                    plan["runs"], None, max_entries=MAX_BATCHED_DB_ENTRIES)
+                    plan["runs"], None, max_entries=MAX_BATCHED_DB_ENTRIES,
+                    value_rows=value_rows)
                 if read is not None:
                     lsp.annotate(rows=read[2])
         except BaseException:
@@ -594,15 +648,26 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
                     f"{name}: {int(lanes['val_len'].max())}-byte values")
             _abort(db, plan)
             return ("remaining", name, db, None)
-        kind = (
-            MergeKind.UINT64_ADD if merge_op is not None else MergeKind.NONE
-        )
         # index-path shards group by their width as well: their values
         # go up as they are, never padded to a wider neighbour's
         vw = lanes["val_words"].shape[1]
-        key = (kind, plan["drop_tombstones"],
-               vw if value_path(kind, vw) == "index" else 0)
-        return ("grouped", name, db, (key, plan, _LaneBatch(lanes)))
+        index = value_path(kind, vw) == "index"
+        dev = None
+        if index:
+            # up from THIS thread, eight shards at once, and not one
+            # after another on the leader's (its ``tpu.h2d``)
+            padded = lanes["val_words"].base
+            try:
+                with start_span("tpu.h2d.values", remote=tctx,
+                                bytes=padded.nbytes):
+                    dev = jax.block_until_ready(jax.device_put(padded))
+            except BaseException:
+                log.exception(
+                    "value upload failed for %s; declining to per-db", name)
+                _abort(db, plan)
+                return ("remaining", name, db, None)
+        key = (kind, plan["drop_tombstones"], vw if index else 0)
+        return ("grouped", name, db, (key, plan, _LaneBatch(lanes, dev)))
 
     def _install(args, tctx):
         name, db, plan, res = args

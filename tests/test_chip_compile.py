@@ -126,6 +126,51 @@ def test_service_index_pipeline_group8_compiles(shape, monkeypatch):
     assert mem.output_size_in_bytes < values + values // 4
 
 
+# sha256 of the two cells' programs as lowered (the text the persistent
+# compile cache is keyed on) at the cells' own shapes, group 8, capacity
+# 32,768: PR 31's readings, which PR 32 (values cross the seam on the pool
+# threads) had to leave as they were, to the byte. jax 0.9.0; after an
+# upgrade of jax, or a PR that MEANS to change a program, read them anew.
+CELL_PROGRAMS = {
+    "counter_64x20k": (
+        MergeKind.UINT64_ADD, 2,
+        "86313af6e2a4f28e227ffa9171c4e8429c83d307fcfec4f7a552d2667a2d3827"),
+    "rec1k_32x20k": (
+        MergeKind.NONE, 256,
+        "b83ab464fc243d531ca85f7da0acfd0585f815ccfed7c0cff93dc1639083672b"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_PROGRAMS))
+def test_cell_program_text_is_the_accepted_one(cell, monkeypatch):
+    """Lowered here for the CPU (no topology, nothing compiled): a
+    change of one byte is a cold compile of 60-90 s inside the first
+    ingest RPC of every deployed process, and a new persistent-cache
+    key on the chip."""
+    import hashlib
+
+    from rocksplicator_tpu.ops.compaction_kernel import value_path
+    from rocksplicator_tpu.tpu.compaction_service import TpuCompactionService
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    kind, words, accepted = CELL_PROGRAMS[cell]
+    n, group = 32768, 8
+
+    def lane(*dims, dtype=U32):
+        return jax.ShapeDtypeStruct(dims, dtype)
+
+    values = lane(group, n, words)
+    if value_path(kind, words) == "index":
+        values = tuple(lane(n, words) for _ in range(group))
+    fn = TpuCompactionService()._pipeline(
+        kind, True, num_words_for(n, BITS_PER_KEY), **FAST, val_words=words)
+    text = fn.lower(
+        lane(group, n, 6), lane(group, n), lane(group, n), lane(group, n),
+        lane(group, n), values, lane(group, n),
+        lane(group, n, dtype=jnp.bool_)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == accepted
+
+
 def test_bloom_build_compiles(shape):
     """The per-output-file bloom (one 16,384-key shard = one file)."""
     from rocksplicator_tpu.ops.bloom_tpu import bloom_build_tpu

@@ -122,6 +122,11 @@ def test_device_decline_rule(reason, door, tmp_path, monkeypatch):
     before any program is built, counts a decline for width under its
     own name, and the host path leaves what ``resolve_stream`` gives."""
     merge_op, first, second = DECLINES[reason]
+    # tools/chaos_soak.py sets the streaming merge to "always" when it is
+    # imported, for the rest of an xdist worker's life: after a test file
+    # that imports it, the engine door would stream these 40 rows before
+    # it asks the rule about their lanes
+    monkeypatch.setattr(sm, "STREAM_MODE_OVERRIDE", None)
     options = DBOptions(merge_operator=merge_op)
     if door == "engine_seam":
         options.compaction_backend = tb.TpuCompactionBackend()

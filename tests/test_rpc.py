@@ -302,6 +302,12 @@ def test_router_hot_reload_from_file(tmp_path, file_watcher):
     path.write_text(json.dumps(SHARD_MAP))
     router = RpcRouter(local_az="az1", shard_map_path=str(path))
     assert router.num_shards("seg") == 3
+    # poll_now below is the ONLY poll. The watcher's own thread (every
+    # 0.1 s) could read the new map first, advance the content digest and
+    # still be inside the router's callback when poll_now, which finds
+    # the digest unchanged, returns: the layout asserted on was then the
+    # old one (seen under -n 6, a loaded host)
+    file_watcher.stop()
     new_map = {"seg": {"num_shards": 1, "10.9.9.9:1:az9": ["00000:M"]}}
     path.write_text(json.dumps(new_map))
     file_watcher.poll_now()

@@ -694,6 +694,9 @@ def test_batched_compaction_is_one_trace_across_the_pool(tmp_path,
                               "admin.compact_install"):
         assert count.get(name) == 1, (name, count)
     assert "tpu.stage" not in count and "tpu.kernel" not in count
+    # 8-byte values ride the sorts: stacked and read by the leader alone
+    assert "tpu.h2d.values" not in count
+    assert "tpu.readback.values" not in count
     rows = [s["annotations"]["rows"] for s in inside
             if s["name"] == "tpu.lanes.decode"]
     assert rows == [60, 60, 60]
@@ -718,6 +721,75 @@ def test_batched_compaction_is_one_trace_across_the_pool(tmp_path,
     assert len(rides) == 3
     for r in rides:
         assert abs(r["start_ms"] + r["duration_ms"] - end) < 250.0
+
+
+def test_index_path_values_cross_the_seam_in_spans_of_their_own(tmp_path):
+    """Three shards of 64-byte values (the index path) over a pool: each
+    shard's values go up in a ``tpu.h2d.values`` and come down in a
+    ``tpu.readback.values`` span, on pool threads, beside the codec spans
+    (whose sum is ``codec_ms_per_shard``) and never under them; the
+    leader's ``tpu.h2d`` and ``tpu.readback`` (``h2d_ms``, ``readback_ms``
+    match the names exactly) stay one a group."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from rocksplicator_tpu.storage.records import OpType
+    from rocksplicator_tpu.storage.sst import SSTWriter
+    from rocksplicator_tpu.tpu import compaction_service as cs
+
+    col = SpanCollector.get()
+    col.configure(sample_rate=0.0, capacity=4096)
+    dbs = []
+    for s in range(3):
+        db = DB(str(tmp_path / f"db{s}"))
+        for i in range(30):
+            db.write(WriteBatch().put(f"k{i:03d}".encode(), b"w" * 64))
+        sst = tmp_path / f"in{s}.tsst"
+        w = SSTWriter(str(sst))
+        for i in range(10, 40):
+            w.add(f"k{i:03d}".encode(), 0, OpType.PUT, bytes([s + 1]) * 64)
+        w.finish()
+        db.ingest_external_file([str(sst)], move_files=True,
+                                allow_global_seqno=True)
+        dbs.append((f"db{s}", db))
+    with ThreadPoolExecutor(3, thread_name_prefix="seam-pool") as pool:
+        with start_span("test.caller", always=True) as caller:
+            handled, remaining = cs.compact_dbs_batched(dbs, pool=pool)
+    assert sorted(handled) == ["db0", "db1", "db2"] and remaining == []
+    for s, (_name, db) in enumerate(dbs):
+        assert db.get(b"k015") == bytes([s + 1]) * 64
+        db.close()
+
+    inside = [s for s in col.snapshot() if s["trace_id"] == caller.trace_id]
+    by_id = {s["span_id"]: s for s in inside}
+    by_name = {}
+    for s in inside:
+        by_name.setdefault(s["name"], []).append(s)
+    for name in PER_SHARD + ("tpu.h2d.values", "tpu.readback.values"):
+        assert len(by_name.get(name, ())) == 3, (name, sorted(by_name))
+    for name in PER_LAUNCH + ("tpu.compact_stream",):
+        assert len(by_name.get(name, ())) == 1, (name, sorted(by_name))
+    # siblings of the codec spans: the stage's / the install's children
+    (stage,) = by_name["admin.compact_stage"]
+    (install,) = by_name["admin.compact_install"]
+    for name, parent, sibling in (
+            ("tpu.h2d.values", stage, "tpu.lanes.decode"),
+            ("tpu.readback.values", install, "tpu.planar.write")):
+        for s in by_name[name]:
+            assert by_id[s["parent_id"]] is parent, (name, s)
+            assert s["annotations"]["bytes"] == 64 * 64  # bucket x width
+        assert {s["parent_id"] for s in by_name[sibling]} == {
+            parent["span_id"]}
+    # up after its shard's decode ends, down before its shard's file
+    # starts: the three pairs interleave, so compare the extremes
+    assert (min(s["start_ms"] for s in by_name["tpu.h2d.values"])
+            >= min(s["start_ms"] + s["duration_ms"]
+                   for s in by_name["tpu.lanes.decode"]))
+    assert (max(s["start_ms"] + s["duration_ms"]
+                for s in by_name["tpu.readback.values"])
+            <= max(s["start_ms"] for s in by_name["tpu.planar.write"]))
+    (h2d,) = by_name["tpu.h2d"]
+    assert h2d["annotations"]["prestaged"] == 3
+    assert h2d["annotations"]["shards"] == 3
 
 
 def test_device_programs_have_the_names_the_trace_readers_look_for():

@@ -27,6 +27,15 @@ the run's own and the last):
   ``admin/ingest_pipeline.py``) beside the window's
   ``admin.compact.linger`` spans: how many, their mean and their longest.
   This look needs no recording: it is printed with ``--trace 0`` too.
+- **who carried the index path's values across the seam**: the
+  process's ``seam.values.prestaged`` / ``.restaged`` counters (shards
+  whose values the pool thread that decoded them had put on the device
+  in the launch's own capacity bucket / whose buffer was of another
+  bucket, so that the leader padded and put them again;
+  ``tpu/compaction_service.py``) beside the window's per-shard
+  ``tpu.h2d.values`` / ``tpu.readback.values`` spans and the leader's
+  ``tpu.h2d`` / ``tpu.readback``: how many and their mean.
+  ``--trace 0`` too.
 
 Arguments are ``chipbench/run.py``'s own.
 """
@@ -130,6 +139,7 @@ def main(argv=None) -> int:
     real_read_metrics = harness.read_metrics
 
     def read_metrics(bench, group, package, cell, run):
+        from chipbench.reduce import span_ms
         from rocksplicator_tpu.utils.stats import Stats
 
         ms = [s["duration_ms"] for s in run.spans
@@ -140,6 +150,15 @@ def main(argv=None) -> int:
              "window_max_ms": round(max(ms), 2) if ms else None},
             **{"process_" + k: Stats.get().get_counter("compact.linger." + k)
                for k in ("joined", "timeouts", "ms")})))
+        seam = {}
+        for name in ("tpu.h2d.values", "tpu.readback.values",
+                     "tpu.h2d", "tpu.readback"):
+            ms = span_ms(run, name)
+            seam[name] = [len(ms), round(sum(ms) / len(ms), 2) if ms else None]
+        harness.say("values across the seam: " + json.dumps(dict(
+            {"window_spans_count_mean_ms": seam},
+            **{"process_" + k: Stats.get().get_counter("seam.values." + k)
+               for k in ("prestaged", "restaged")})))
         return real_read_metrics(bench, group, package, cell, run)
 
     tr.reduce, harness.reduce_trace = reduce, reduce_trace
