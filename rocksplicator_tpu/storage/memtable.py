@@ -7,6 +7,7 @@ entries in (key asc, seq desc) order — the SST writer's required order.
 
 from __future__ import annotations
 
+from array import array
 from typing import Dict, Iterator, List, Optional, Tuple
 
 from .merge import MergeOperator
@@ -17,30 +18,54 @@ _Entry = Tuple[int, int, bytes]
 
 
 class MemTable:
+    """Columns, and no container a key or an entry for the collector
+    to walk. Every full collection stops the world and visits every
+    object that a container on the heap holds: as lists and tuples (a
+    list a key, a tuple an entry, four mirror lists) eight full
+    memtables are ~900k such visits, +90 ms a collection, and the
+    longest of a refresh cycle's collections falls into the bulk load
+    that follows the writes (PERF.md section 6, PR 33). So an entry is
+    a ROW of byte and machine-integer columns in arrival order, a key's
+    stack a chain of rows (``_prev``), and the one dict holds bytes and
+    ints alone, which the collector never tracks. The values stay a list of the
+    writers' own bytes objects (one visit each): copying 1 KB values
+    into a growing buffer cost the record cell's writes more than the
+    visits cost anyone."""
+
     def __init__(self) -> None:
-        self._data: Dict[bytes, List[_Entry]] = {}
+        self._newest: Dict[bytes, int] = {}  # key -> row of its newest entry
+        self._prev = array("q")   # row -> the key's next older row, or -1
+        self._key_buf = bytearray()
+        self._klens = array("I")
+        self._vals: List[bytes] = []
+        self._vlens = array("I")
+        self._seqs = array("Q")
+        self._vtypes = bytearray()
         self._bytes = 0
         self.min_seq: Optional[int] = None
         self.max_seq = 0
-        # Flat append-order columns mirroring _data — the vectorized
-        # flush drain reads THESE (byte joins + np.fromiter, no dict
-        # walk); ~4 list appends per write buy back ~70 ms per 200k-entry
-        # flush. References only, no copies.
-        self._flat_keys: List[bytes] = []
-        self._flat_vals: List[bytes] = []
-        self._flat_seqs: List[int] = []
-        self._flat_vtypes: List[int] = []
 
     def apply(self, key: bytes, seq: int, vtype: int, value: bytes) -> None:
-        self._data.setdefault(key, []).insert(0, (seq, vtype, value))
-        self._flat_keys.append(key)
-        self._flat_vals.append(value)
-        self._flat_seqs.append(seq)
-        self._flat_vtypes.append(vtype)
+        row = len(self._seqs)
+        self._prev.append(self._newest.get(key, -1))
+        self._key_buf += key
+        self._klens.append(len(key))
+        self._vals.append(value)
+        self._vlens.append(len(value))
+        self._seqs.append(seq)
+        self._vtypes.append(vtype)
+        self._newest[key] = row  # last: a reader finds whole rows only
         self._bytes += len(key) + len(value) + 16
         if self.min_seq is None:
             self.min_seq = seq
         self.max_seq = max(self.max_seq, seq)
+
+    def _stack(self, key: bytes) -> Iterator[_Entry]:
+        """The key's entries, newest first."""
+        row = self._newest.get(key, -1)
+        while row >= 0:
+            yield self._seqs[row], self._vtypes[row], self._vals[row]
+            row = self._prev[row]
 
     def get(
         self, key: bytes, merge_op: Optional[MergeOperator]
@@ -51,11 +76,10 @@ class MemTable:
         resolved=False: pending_operands are MERGE operands (newest last)
         still awaiting a base value from older levels.
         """
-        entries = self._data.get(key)
-        if not entries:
+        if key not in self._newest:  # the common miss: no generator
             return False, None, []
         operands: List[bytes] = []
-        for seq, vtype, value in entries:  # newest -> oldest
+        for _seq, vtype, value in self._stack(key):  # newest -> oldest
             if vtype == OpType.PUT:
                 if operands and merge_op:
                     return True, merge_op.merge(key, value, list(reversed(operands))), []
@@ -71,12 +95,22 @@ class MemTable:
     def absorb_older(self, older: "MemTable") -> None:
         """Fold an OLDER memtable's entries beneath this one's (flush-failure
         recovery path): older entries append after newer ones per key."""
-        for key, entries in older._data.items():
-            self._data.setdefault(key, []).extend(entries)
-        self._flat_keys.extend(older._flat_keys)
-        self._flat_vals.extend(older._flat_vals)
-        self._flat_seqs.extend(older._flat_seqs)
-        self._flat_vtypes.extend(older._flat_vtypes)
+        rows = len(self._seqs)
+        self._prev.extend(p + rows if p >= 0 else -1 for p in older._prev)
+        self._key_buf += older._key_buf
+        self._klens.extend(older._klens)
+        self._vals.extend(older._vals)
+        self._vlens.extend(older._vlens)
+        self._seqs.extend(older._seqs)
+        self._vtypes += older._vtypes
+        for key, row in older._newest.items():
+            mine = self._newest.get(key, -1)
+            if mine < 0:
+                self._newest[key] = row + rows
+                continue
+            while self._prev[mine] >= 0:  # to my oldest entry of the key
+                mine = self._prev[mine]
+            self._prev[mine] = row + rows
         self._bytes += older._bytes
         if older.min_seq is not None:
             self.min_seq = (
@@ -89,12 +123,12 @@ class MemTable:
         return self._bytes
 
     def __len__(self) -> int:
-        return len(self._data)
+        return len(self._newest)
 
     def entries(self) -> Iterator[Tuple[bytes, int, int, bytes]]:
         """(key, seq, vtype, value) in (key asc, seq desc) order."""
-        for key in sorted(self._data):
-            for seq, vtype, value in self._data[key]:
+        for key in sorted(self._newest):
+            for seq, vtype, value in self._stack(key):
                 yield key, seq, vtype, value
 
     def drain_lanes(self):
@@ -111,31 +145,30 @@ class MemTable:
 
         ``lanes`` is the kernel lane dict (key_words_be, key_len,
         seq_hi/lo, vtype, val_words, val_len); the (n, klen) u8 key
-        matrix rides along for bulk bloom construction. The columns come
-        from the flat per-apply mirror lists, so no dict walk or
-        per-entry tuple unpack happens here."""
+        matrix rides along for bulk bloom construction. The columns are
+        read as arrays where they lie (the key bytes copied once: a
+        view that outlives this call would pin the bytearray against
+        the next apply), the values joined."""
         import numpy as np
 
         from .planar import PLANAR_MAX_KLEN, PLANAR_MAX_VLEN
 
-        key_parts = self._flat_keys
-        val_parts = self._flat_vals
-        n = len(key_parts)
+        n = len(self._seqs)
         if n == 0:
             return None
-        # Width checks run VECTORIZED over the (cheap, 4n-byte) length
-        # lanes before any value-byte buffer is built — one oversized
-        # value among a million small ones bails here, not after a giant
-        # transient allocation.
-        klen = len(key_parts[0])
+        # Width checks run over the (cheap, 4n-byte) length lanes before
+        # any value-byte buffer is built — one oversized value among a
+        # million small ones bails here, not after a giant transient
+        # allocation.
+        klens = np.frombuffer(self._klens, dtype=np.uint32)
+        klen = int(klens[0])
         if not (0 < klen <= PLANAR_MAX_KLEN):
             return None
-        klens = np.fromiter(map(len, key_parts), dtype=np.uint32, count=n)
         if not bool((klens == klen).all()):
             return None
-        vtype_arr = np.fromiter(
-            self._flat_vtypes, dtype=np.uint32, count=n)
-        vlens = np.fromiter(map(len, val_parts), dtype=np.uint32, count=n)
+        vtype_arr = np.frombuffer(self._vtypes, dtype=np.uint8).astype(
+            np.uint32)
+        vlens = np.frombuffer(self._vlens, dtype=np.uint32)
         is_del = vtype_arr == 2  # DELETE: no value in the planar layout
         if bool(vlens[is_del].any()):
             return None
@@ -144,20 +177,17 @@ class MemTable:
         if vlen > PLANAR_MAX_VLEN or not bool((live_vlens == vlen).all()):
             return None
         key_mat = np.frombuffer(
-            b"".join(key_parts), dtype=np.uint8).reshape(n, klen)
-        seq = np.fromiter(self._flat_seqs, dtype=np.uint64, count=n)
+            bytes(self._key_buf), dtype=np.uint8).reshape(n, klen)
+        seq = np.frombuffer(self._seqs, dtype=np.uint64)
         key_buf = np.zeros((n, 24), dtype=np.uint8)
         key_buf[:, :klen] = key_mat
         vw = max(2, (vlen + 3) // 4)
         val_buf = np.zeros((n, vw * 4), dtype=np.uint8)
         if vlen:
-            if is_del.any():
-                pad = bytes(vlen)
-                joined = b"".join(v if v else pad for v in val_parts)
-            else:
-                joined = b"".join(val_parts)
-            val_buf[:, :vlen] = np.frombuffer(
-                joined, dtype=np.uint8).reshape(n, vlen)
+            # a DELETE brought no bytes: the join is the live rows'
+            # values back to back, in arrival order
+            val_buf[~is_del, :vlen] = np.frombuffer(
+                b"".join(self._vals), dtype=np.uint8).reshape(-1, vlen)
         lanes = {
             "key_words_be": key_buf.view(">u4").astype(
                 np.uint32).reshape(n, 6),

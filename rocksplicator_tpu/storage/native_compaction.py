@@ -267,15 +267,25 @@ def read_runs_as_lanes(
         w = p["val_words"].shape[1]
         if w < vw:
             p["val_words"] = np.pad(p["val_words"], [(0, 0), (0, vw - w)])
-    fields = ("key_words_be", "key_len", "seq_hi", "seq_lo", "vtype",
-              "val_words", "val_len")
+    return parts, concat_lanes(parts, total, value_rows), total, vw
+
+
+_LANE_FIELDS = ("key_words_be", "key_len", "seq_hi", "seq_lo", "vtype",
+                "val_words", "val_len")
+
+
+def concat_lanes(parts: List[dict], total: int,
+                 value_rows: Optional[Callable[[int, int], int]] = None,
+                 ) -> dict:
+    """The parts' rows (``total`` of them, one value width) as one dict
+    of concatenated lanes; ``value_rows`` as ``read_runs_as_lanes`` says."""
     into = {}
     if value_rows is not None:
+        vw = parts[0]["val_words"].shape[1]
         into["val_words"] = np.zeros(
             (value_rows(total, vw), vw), dtype=np.uint32)[:total]
-    lanes = {f: np.concatenate([p[f] for p in parts], out=into.get(f))
-             for f in fields}
-    return parts, lanes, total, vw
+    return {f: np.concatenate([p[f] for p in parts], out=into.get(f))
+            for f in _LANE_FIELDS}
 
 
 def lanes_decline_reason(lanes: dict,
@@ -320,10 +330,10 @@ def write_resolved_lanes(
     the planar layout can't express the rows; a mid-loop failure cleans
     up every file already written (nothing would ever GC the orphans).
     ``build_bloom(sub, n)`` gives one output file's bloom words from its
-    own ``n`` rows (default: the host bulk bloom; the device doors build
-    theirs on the device). ``io_budget`` (compaction callers only)
-    throttles after each output file so compaction IO yields to
-    foreground fsyncs. Each file's write is a ``tpu.planar.write`` span
+    own ``n`` rows (default, and where it gives None: the host bulk
+    bloom; the device doors build theirs on the device). ``io_budget``
+    (compaction callers only) throttles after each output file so
+    compaction IO yields to foreground fsyncs. Each file's write is a ``tpu.planar.write`` span
     (``rows``), under ``trace`` where the caller's thread carries no
     trace context of its own (a pool thread)."""
     from ..observability.span import start_span
@@ -337,10 +347,13 @@ def write_resolved_lanes(
     stride = planar_stride(klen0, vlen0)
     entries_per_file = max(1024, target_file_bytes // max(1, stride))
     block_entries = max(64, block_bytes // max(1, stride))
+
+    def host_bloom(sub, n):
+        return NativeCompactionBackend._bulk_bloom(
+            sub, n, klen0, bits_per_key).words
+
     if build_bloom is None:
-        def build_bloom(sub, n):
-            return NativeCompactionBackend._bulk_bloom(
-                sub, n, klen0, bits_per_key).words
+        build_bloom = host_bloom
     outputs: List[Tuple[str, dict]] = []
 
     def cleanup():
@@ -355,6 +368,8 @@ def write_resolved_lanes(
             end = min(start + entries_per_file, count)
             sub = {f: arrays[f][start:end] for f in arrays}
             bloom_words = build_bloom(sub, end - start)
+            if bloom_words is None:  # the caller's builder has none
+                bloom_words = host_bloom(sub, end - start)
             path = path_factory()
             with start_span("tpu.planar.write", remote=trace,
                             rows=end - start):
@@ -448,14 +463,86 @@ def choose_slice_boundaries(parts: List[dict], nslices: int,
     return bounds
 
 
+class KeyGroupOverSlice(Exception):
+    """One key's entry stack alone holds more rows than a slice may:
+    no cut at KEYS gives slices of at most that many rows."""
+
+
+def _bounded_boundaries(parts: List[dict], total: int, nslices: int,
+                        klen: int, max_rows: int) -> List[bytes]:
+    """Boundary keys of the FEWEST slices (and at least ``nslices``,
+    while the keys last) that hold at most ``max_rows`` rows each, as
+    near to equal as whole key groups allow; [] where a run's keys do
+    not ascend (the cut bisects each run by key). Exact, from every row:
+    the runs' keys merged (one stable sort of presorted runs), a cut
+    only where a key group starts. The keys' order is checked here, on
+    the byte strings the merge sorts, in two numpy calls a run, and the
+    seqs' not at all (the callers sort a slice's rows on the device):
+    the k-way merge's own check (``_run_is_sorted``) is fifty calls a
+    run over strided lanes, each handing the GIL round, which with
+    eight shards cut at once costs more than the cut."""
+    words = (klen + 3) // 4
+    runs = [np.ascontiguousarray(p["key_words_be"][:, :words].astype(">u4"))
+            .view(f"S{4 * words}").ravel() for p in parts]
+    if not all(bool((r[1:] >= r[:-1]).all()) for r in runs):
+        return []
+    keys = np.sort(np.concatenate(runs), kind="stable")
+    # pos[g]: the merged row at which key group g starts; pos[-1]: total
+    pos = np.append(
+        np.flatnonzero(np.concatenate(([True], keys[1:] != keys[:-1]))),
+        total)
+    groups = len(pos) - 1
+    # low[j]: the first group from which j slices reach the end (greedy
+    # from the right); its length says how few slices cover every row
+    low = [groups]
+    while low[-1] > 0:
+        g = int(np.searchsorted(pos, pos[low[-1]] - max_rows, side="left"))
+        if g == low[-1]:
+            raise KeyGroupOverSlice(
+                f"a key group of {int(np.diff(pos).max())} rows, "
+                f"{max_rows} rows a slice")
+        low.append(g)
+    n = min(max(nslices, len(low) - 1), groups)
+    cuts: List[int] = []
+    prev = 0
+    for i in range(1, n):
+        left = n - i  # slices after this cut
+        lo = max(prev + 1, low[left] if left < len(low) else 0)
+        hi = min(int(np.searchsorted(pos, pos[prev] + max_rows,
+                                     side="right")) - 1, groups - left)
+        want = int(np.searchsorted(pos, (i * total) // n, side="right")) - 1
+        prev = min(max(want, lo), hi)
+        cuts.append(prev)
+    return [keys[pos[g]:pos[g] + 1].tobytes()[:klen] for g in cuts]
+
+
 def plan_subcompactions(parts: List[dict], total: int,
-                        max_subcompactions: int, klen: int) -> List[bytes]:
-    """Boundary keys for this compaction, or [] to run unsliced. Slices
-    only when the parallelism is asked for, every slice would clear
-    MIN_SLICE_ENTRIES, and every run is (key, seq)-sorted — the bisect
-    cut is only meaningful on sorted runs (unsorted inputs take the
-    full-lexsort resolve unsliced)."""
+                        max_subcompactions: int, klen: int,
+                        max_slice_rows: Optional[int] = None) -> List[bytes]:
+    """Boundary keys for this compaction, or [] to run unsliced: THE
+    planner of every key-range cut (the CPU sink's subcompactions and
+    both device doors). Two rules, either or both:
+
+    - parallelism (``max_subcompactions`` > 1): that many slices where
+      every slice would clear MIN_SLICE_ENTRIES, at sampled quantiles of
+      the runs' keys (``choose_slice_boundaries``), no bound on a
+      slice's rows;
+    - a bound (``max_slice_rows``; the served device door's place
+      capacity): where ``total`` is over it, the fewest slices of at
+      most that many rows each, near-equal, cut exactly
+      (``_bounded_boundaries``). Raises ``KeyGroupOverSlice`` where one
+      key's stack is over the bound.
+
+    Slices only where every run is sorted: the bisect cut is only
+    meaningful on sorted runs (unsorted inputs take the full-lexsort
+    resolve unsliced). The parallelism rule wants (key, seq) order, as
+    the k-way merge its slices feed does; the bound wants the keys'
+    order alone (its callers sort on the device)."""
     nslices = min(int(max_subcompactions), total // max(1, MIN_SLICE_ENTRIES))
+    bounded = max_slice_rows is not None and total > max_slice_rows
+    if bounded:
+        return _bounded_boundaries(parts, total, nslices, klen,
+                                   max_slice_rows)
     if nslices <= 1:
         return []
     if not all(NativeCompactionBackend._run_is_sorted(p) for p in parts):
@@ -467,14 +554,28 @@ def slice_parts(parts: List[dict], bounds: List[bytes], si: int,
                 klen: int, cuts: List[List[int]]) -> List[dict]:
     """Slice ``si``'s row ranges of every part (``cuts[p]`` = the
     per-part boundary row indices from _first_row_ge)."""
-    fields = ("key_words_be", "key_len", "seq_hi", "seq_lo", "vtype",
-              "val_words", "val_len")
     out: List[dict] = []
     for p, c in zip(parts, cuts):
         lo = c[si - 1] if si > 0 else 0
         hi = c[si] if si < len(bounds) else p["key_len"].shape[0]
         if hi > lo:
-            out.append({f: p[f][lo:hi] for f in fields})
+            out.append({f: p[f][lo:hi] for f in _LANE_FIELDS})
+    return out
+
+
+def slice_lanes(parts: List[dict], bounds: List[bytes], klen: int,
+                value_rows: Optional[Callable[[int, int], int]] = None,
+                ) -> List[dict]:
+    """THE placement of both device doors: the runs cut at ``bounds``,
+    each non-empty slice as one dict of concatenated lanes, in key
+    order (``value_rows`` as ``read_runs_as_lanes`` says, per slice)."""
+    cuts = [[_first_row_ge(p, b, klen) for b in bounds] for p in parts]
+    out: List[dict] = []
+    for si in range(len(bounds) + 1):
+        sub = slice_parts(parts, bounds, si, klen, cuts)
+        if sub:
+            rows = sum(p["key_len"].shape[0] for p in sub)
+            out.append(concat_lanes(sub, rows, value_rows))
     return out
 
 

@@ -70,7 +70,7 @@ RANKS = {
     "rocksplicator_tpu/rpc/admission.py:115": ('TenantAdmission._instance_lock', 50),
     "rocksplicator_tpu/rpc/admission.py:125": ('TenantAdmission._lock', 51),
     "rocksplicator_tpu/rpc/admission.py:67": ('TokenBucket._lock', 52),
-    "rocksplicator_tpu/tpu/compaction_service.py:63": ('TpuCompactionService._instance_lock', 53),
+    "rocksplicator_tpu/tpu/compaction_service.py:72": ('TpuCompactionService._instance_lock', 53),
     "rocksplicator_tpu/storage/archive.py:63": ('WalArchiver._mutex', 54),
     "rocksplicator_tpu/testing/failpoints.py:129": ('_Site.lock', 55),
     "rocksplicator_tpu/utils/stats.py:200": ('_ThreadBuffer.lock', 56),

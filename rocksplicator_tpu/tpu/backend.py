@@ -277,9 +277,9 @@ class TpuCompactionBackend(CompactionBackend):
         (storage/stream_merge.py + compaction_service.TpuChunkResolver).
 
         ``max_subcompactions > 1``: an in-RAM job splits into disjoint
-        key-range slices resolved as ONE padded vmapped device batch
+        key-range slices resolved as places of the fixed group launch
         (tpu/compaction_service.resolve_slices_batched) — k smaller
-        sorts in one launch instead of one pow2(total) sort.
+        sorts, eight a launch, instead of one pow2(total) sort.
         ``io_budget`` paces the output file writes. What the door takes
         is ``device_decline_reason``'s to say; the runs are read and the
         files written by the host array path's own reader and writer
@@ -349,15 +349,15 @@ class TpuCompactionBackend(CompactionBackend):
     @staticmethod
     def _subcompact_arrays(parts, lanes, total, kind, drop_tombstones,
                            max_subcompactions):
-        """Key-range subcompactions on the device: choose boundary keys
-        from the runs' key distribution (shared helpers with the CPU
-        path), slice every run at them, and resolve ALL slices as one
-        padded vmapped batch. Returns (arrays, count) concatenated in
+        """Key-range subcompactions on the device: boundary keys from
+        the planner every key-range cut shares (``plan_subcompactions``),
+        the runs cut at them by the placement the served door uses too
+        (``slice_lanes``), and ALL slices resolved as places of the
+        fixed group launch. Returns (arrays, count) concatenated in
         boundary order — identical logical output to the single-shot
         kernel — or None to take the unsliced path."""
-        from ..storage.native_compaction import (_first_row_ge,
-                                                 plan_subcompactions,
-                                                 slice_parts)
+        from ..storage.native_compaction import (plan_subcompactions,
+                                                 slice_lanes)
         from .compaction_service import resolve_slices_batched
 
         kl = lanes["key_len"]
@@ -365,13 +365,7 @@ class TpuCompactionBackend(CompactionBackend):
         bounds = plan_subcompactions(parts, total, max_subcompactions, klen)
         if not bounds:
             return None
-        cuts = [[_first_row_ge(p, b, klen) for b in bounds] for p in parts]
-        slices = []
-        for si in range(len(bounds) + 1):
-            sub = slice_parts(parts, bounds, si, klen, cuts)
-            if sub:
-                slices.append({
-                    f: np.concatenate([p[f] for p in sub]) for f in sub[0]})
+        slices = slice_lanes(parts, bounds, klen)
         if not slices:
             return None
         per_slice = resolve_slices_batched(slices, kind, drop_tombstones)

@@ -13,6 +13,11 @@ Two integration levels:
   in ONE vmapped kernel launch (the 1000-shard load_sst path), each shard
   padded to a common capacity; returns per-shard merged entries + bloom
   words + counts.
+
+The served path is ``compact_dbs_batched`` (the post-load compaction of
+many DBs): fixed ``(group_size, capacity)`` launches through
+``compact_shard_stream``, a shard of more rows than ``PLACE_ROWS_MAX``
+cut by key range into several places of the same launch.
 """
 
 from __future__ import annotations
@@ -20,6 +25,7 @@ from __future__ import annotations
 import logging
 import os
 import threading
+from itertools import zip_longest
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -35,8 +41,11 @@ from ..ops.compaction_kernel import (MergeKind, gather_value_rows,
                                      merge_resolve_rows, value_path)
 from ..ops.kv_format import KEY_WORDS, KVBatch, fast_flags, unpack_entries
 from ..storage.compaction import record_host_fallback
-from ..storage.native_compaction import (read_runs_as_lanes,
+from ..storage.native_compaction import (KeyGroupOverSlice,
+                                         plan_subcompactions,
+                                         read_runs_as_lanes, slice_lanes,
                                          write_resolved_lanes)
+from ..testing import failpoints as fp
 from ..utils.stats import Stats
 from .backend import (TpuCompactionBackend, _device_bloom_builder,
                       _next_pow2, device_decline_reason, require_accelerator)
@@ -181,6 +190,7 @@ class TpuCompactionService:
         drop_tombstones: bool = True,
         group_size: int = 8,
         return_arrays: bool = False,
+        dbs: Optional[int] = None,
     ) -> List[dict]:
         """Pipelined variant of compact_shard_batch for big shard counts:
         shards run in fixed-size groups with double-buffered transfers —
@@ -189,13 +199,18 @@ class TpuCompactionService:
         (device_put and jit dispatch are async; only np.asarray blocks).
         One compiled shape serves every group (the last one is padded
         with empty shards). Addresses the round-1 finding that H2D
-        staging cost ~3.7x the kernel (SURVEY §7 front-load item 2)."""
+        staging cost ~3.7x the kernel (SURVEY §7 front-load item 2).
+        A batch is one PLACE of a launch: a whole shard, or one key
+        range of a shard that was cut; the span's ``shards`` counts the
+        places, ``dbs`` the whole shards they came from (the caller's
+        to say; every place its own shard otherwise)."""
         if not batches:
             return []
         capacity = _next_pow2(max(b.capacity for b in batches))
         with start_span("tpu.compact_stream", always=True,
                         shards=len(batches), group_size=group_size,
-                        capacity=capacity):
+                        capacity=capacity,
+                        dbs=len(batches) if dbs is None else dbs):
             return self._compact_shard_stream(
                 batches, merge_kind, drop_tombstones, group_size,
                 capacity, return_arrays)
@@ -418,37 +433,43 @@ def _shard_result(host: Dict[str, np.ndarray], s: int, count: int,
 # ---------------------------------------------------------------------------
 
 
+def _place_batches(slices: List[Dict[str, np.ndarray]],
+                   devs: Sequence = ()) -> List["_LaneBatch"]:
+    """One compaction's key-range slices as places of a launch, each a
+    subcompaction of its own (the ``compact.subcompact`` failpoint, the
+    ``compaction.subcompactions`` counter): both device doors'."""
+    batches = []
+    for lanes, dev in zip_longest(slices, devs):
+        fp.hit("compact.subcompact")
+        Stats.get().incr("compaction.subcompactions")
+        batches.append(_LaneBatch(lanes, dev))
+    return batches
+
+
 def resolve_slices_batched(
     slice_lanes: List[Dict[str, np.ndarray]],
     merge_kind: "MergeKind",
     drop_tombstones: bool,
 ) -> List[Tuple[dict, int]]:
-    """ONE compaction's key-range slices resolved as ONE padded vmapped
-    device launch — the TPU face of subcompactions: each slice is a
+    """ONE compaction's key-range slices resolved as places of the fixed
+    group launch (``compact_shard_stream``: eight places a launch, the
+    last group padded with empty ones, so that no program exists per
+    slice COUNT) — the TPU face of subcompactions: each slice is a
     "shard" of the job, padded to the common pow2 capacity exactly like
-    the cross-db batched path, so k smaller sorts ride one launch
+    the cross-db batched path, so k smaller sorts ride the launches
     instead of one pow2(total) sort. Returns per-slice
     ``(lane_arrays, count)`` in input order (empty slices come back as
     ``({}, 0)``); slice boundaries are keys, so MERGE operand groups
     are never split across slices by construction."""
-    from ..testing import failpoints as fp
-    from ..utils.stats import Stats
-
     out: List[Tuple[dict, int]] = [({}, 0)] * len(slice_lanes)
-    batches: List[_LaneBatch] = []
-    index: List[int] = []
-    for i, lanes in enumerate(slice_lanes):
-        if lanes["key_len"].shape[0] == 0:
-            continue
-        fp.hit("compact.subcompact")
-        Stats.get().incr("compaction.subcompactions")
-        batches.append(_LaneBatch(lanes))
-        index.append(i)
+    index = [i for i, lanes in enumerate(slice_lanes)
+             if lanes["key_len"].shape[0]]
+    batches = _place_batches([slice_lanes[i] for i in index])
     if batches:
         svc = TpuCompactionService.instance()
-        results = svc.compact_shard_batch(
+        results = svc.compact_shard_stream(
             batches, merge_kind=merge_kind,
-            drop_tombstones=drop_tombstones, return_arrays=True)
+            drop_tombstones=drop_tombstones, return_arrays=True, dbs=1)
         for i, res in zip(index, results):
             out[i] = (res["arrays"], int(res["count"]))
     return out
@@ -458,9 +479,32 @@ def resolve_slices_batched(
 # cross-DB batched full compaction (the post-load_sst path)
 # ---------------------------------------------------------------------------
 
-# One shard above this entry count would inflate the whole padded launch
-# (every shard pays the max shard's capacity); such shards compact per-db.
+# The most rows the served door reads of one shard (the lanes of a whole
+# shard are held on the host while it is cut and launched: ~48 B a row
+# plus its values); a larger shard compacts per-db.
 MAX_BATCHED_DB_ENTRIES = 1 << 20
+
+# The rows of one PLACE of the served door's launch: the largest capacity
+# bucket the door launches. A shard of more rows is cut by key range into
+# several places of the same fixed ``(group_size, PLACE_ROWS_MAX)`` launch
+# and never asks for a larger program: ``(8, 32768)`` is the largest whose
+# two ``lax.sort``s the chip's compiler builds inside a run (90 s cold
+# with values riding, 59 s on the index path; PERF.md section 6, PRs 22
+# and 29), and ``(8, 131072)`` is minutes of compile inside an ingest RPC.
+PLACE_ROWS_MAX = 1 << 15
+
+
+def device_shard_rows_max(merge_operator) -> int:
+    """The most rows of ONE shard that the served door
+    (``compact_dbs_batched``) compacts on the device for a DB with this
+    merge operator without building a program that a smaller shard has
+    not built: up to ``PLACE_ROWS_MAX`` rows as one place, more as
+    several places of the same launch; 0 where the door takes no shard
+    with this operator. Asked by a deployment's driver before it builds
+    anything (chipbench/drivers/refresh_ranges.py)."""
+    if device_decline_reason(None, merge_operator) is not None:
+        return 0
+    return MAX_BATCHED_DB_ENTRIES
 
 
 class _LaneBatch:
@@ -468,9 +512,10 @@ class _LaneBatch:
     input to compact_shard_batch/stream (no per-entry pack loop): the
     lanes a launch takes (``_GROUP_LANES``), every row valid.
     ``val_words_dev``: the values on the device already, zero-padded to
-    ``_next_pow2(rows)`` rows, where the thread that decoded the shard
-    put them up itself (an index-path shard of the served door); such a
-    shard's resolved values come back as a device buffer too."""
+    ``_next_pow2(rows)`` rows (a place of a cut shard: to the place
+    capacity), where the thread that decoded the shard put them up
+    itself (an index-path shard of the served door); such a shard's
+    resolved values come back as a device buffer too."""
 
     __slots__ = _GROUP_LANES + ("val_words_dev",)
 
@@ -488,13 +533,16 @@ class _LaneBatch:
         return self.capacity
 
 
-def _write_arrays(db, res: dict, tctx: Optional[dict]) -> dict:
-    """Write one shard's resolved lanes as PLANAR SSTs (the array sink,
+def _write_arrays(db, res: dict, tctx: Optional[dict],
+                  place_bloom: bool = False) -> dict:
+    """Write one place's resolved lanes as PLANAR SSTs (the array sink,
     per-file blooms built on the device). Returns how to install them,
     as ``install_full_compaction``'s keywords: ``files``, or the
     entry-tuple sink's ``entries`` when the planar layout can't express
     the result. ``tctx``: the dispatch's trace context (this runs on a
-    pool thread)."""
+    pool thread). ``place_bloom`` (a place of a shard that was cut):
+    the file takes the bloom its launch built over exactly the place's
+    keys (``_launch_bloom``) and no bloom program of its own."""
     arrays, count = res["arrays"], int(res["count"])
     if count == 0:
         return {"entries": []}
@@ -506,11 +554,13 @@ def _write_arrays(db, res: dict, tctx: Optional[dict]) -> dict:
                         bytes=vals.nbytes):
             arrays["val_words"] = np.asarray(vals)[:count]
     opts = db.options
+    build_bloom = (
+        _launch_bloom(res["bloom_words"], count, opts.bits_per_key)
+        if place_bloom else _device_bloom_builder(opts.bits_per_key, tctx))
     outputs = write_resolved_lanes(
         arrays, count, db.allocate_sst_path, opts.block_bytes,
         opts.compression, opts.bits_per_key, opts.target_file_bytes,
-        build_bloom=_device_bloom_builder(opts.bits_per_key, tctx),
-        trace=tctx)
+        build_bloom=build_bloom, trace=tctx)
     if outputs is not None:
         return {"files": [os.path.basename(path) for path, _ in outputs]}
     # tuple fallback (non-uniform keys/values)
@@ -519,6 +569,47 @@ def _write_arrays(db, res: dict, tctx: Optional[dict]) -> dict:
         arrays["seq_lo"], arrays["vtype"], arrays["val_words"],
         arrays["val_len"], count,
     )}
+
+
+def _launch_bloom(words: np.ndarray, count: int, bits_per_key: int):
+    """``write_resolved_lanes``' bloom builder for a place of a cut
+    shard: the launch's own filter (built over exactly the place's
+    ``count`` output keys, sized for a full place) where the place is
+    ONE file and the filter gives the DB's ``bits_per_key`` or more a
+    key; else None, which leaves the file to the host's bulk bloom. No
+    program either way: the per-file device builder is a program per
+    exact row count, and the places' counts differ by shard and seed."""
+    def build(sub: dict, n: int) -> Optional[np.ndarray]:
+        if n == count and 32 * len(words) >= n * bits_per_key:
+            return words
+        return None
+
+    return build
+
+
+def _write_places(db, results: List[dict], tctx: Optional[dict]) -> dict:
+    """``_write_arrays`` over every place of one shard, in key order: an
+    uncut shard's one place as it always was; a cut shard's places as
+    key-disjoint file sets that install TOGETHER (``files`` in key
+    order). A place that fails removes the files of the places before it
+    and raises: all of a shard's places are installed or none."""
+    if len(results) == 1:
+        return _write_arrays(db, results[0], tctx)
+    files: List[str] = []
+    try:
+        for res in results:
+            how = _write_arrays(db, res, tctx, place_bloom=True)
+            if how.get("entries"):
+                raise RuntimeError("the planar sink declined a place")
+            files.extend(how.get("files", ()))
+    except BaseException:
+        for name in files:
+            try:
+                os.remove(os.path.join(db.path, name))
+            except OSError:
+                pass
+        raise
+    return {"files": files}
 
 
 def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
@@ -534,6 +625,25 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
     after its decode, ``tpu.readback.values`` before its write): the
     launching thread carries the small lanes alone.
 
+    A launch is ALWAYS the fixed ``(group_size, capacity)`` shape with
+    ``capacity`` at most ``PLACE_ROWS_MAX``. A shard of more rows into
+    its compaction is cut at KEYS (``plan_subcompactions``: from the
+    runs' own rows, the fewest key ranges of at most ``PLACE_ROWS_MAX``
+    rows each, near-equal; a key's whole entry stack lies in one) into
+    several PLACES, packed with other shards' places into full launches
+    (8 shards of 3 places are 3 launches); each place writes a
+    key-disjoint file set, its filter the launch's own, and ONE
+    ``install_full_compaction`` installs all of a shard's places or,
+    where one fails, none (the shard goes to the per-db path). How many
+    places follows from the rows alone: no option. A shard one key
+    group of which is over a place is declined before any program
+    (``tpu.host_fallbacks reason=key_group_over_place``). Per cut shard
+    a ``tpu.range_cut`` span (``rows``, ``places``, ``capacity``) on its
+    pool thread, ``Stats`` ``compact.range_cut.shards`` / ``.places``;
+    ``tpu.compact_stream`` carries ``shards`` = launched places and
+    ``dbs`` = whole shards. ``device_shard_rows_max`` says what the
+    door takes.
+
     Per DB: plan (engine plan_full_compaction: flush + snapshot under the
     compaction mutex), read its runs as lanes, launch the group, install
     each shard's output files (engine install_full_compaction). Values:
@@ -545,8 +655,9 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
     which). DBs the device path can't express (``device_decline_reason``
     says which and why: custom merge operators, values over
     ``device_value_bytes_max``, MERGE records with no operator, keys or
-    values of more than one width; besides >24B keys and oversized
-    shards, which the lane read declines) are declined untouched, before
+    values of more than one width; besides >24B keys and shards of more
+    than ``MAX_BATCHED_DB_ENTRIES`` rows, which the lane read declines)
+    are declined untouched, before
     any program is built; a decline for width counts under
     ``tpu.host_fallbacks reason=value_width``.
 
@@ -615,15 +726,16 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
 
         def value_rows(total, vw):
             # an index-path shard's values are decoded straight into the
-            # padded buffer that goes up: its own capacity bucket
-            if value_path(kind, vw) == "index":
+            # padded buffer that goes up: its own capacity bucket (a
+            # shard that will be cut gets a buffer a place instead)
+            if value_path(kind, vw) == "index" and total <= PLACE_ROWS_MAX:
                 return _next_pow2(total)
             return total
 
         try:
             with start_span("tpu.lanes.decode", remote=tctx) as lsp:
                 # None: nothing to compact, a run the lanes can't
-                # express, or more rows than one place of a launch takes
+                # express, or more rows than the door reads of one shard
                 read = read_runs_as_lanes(
                     plan["runs"], None, max_entries=MAX_BATCHED_DB_ENTRIES,
                     value_rows=value_rows)
@@ -637,7 +749,7 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
         if read is None:
             _abort(db, plan)
             return ("remaining", name, db, None)
-        lanes = read[1]
+        parts, lanes, total, vw = read
         reason = device_decline_reason(lanes, merge_op)
         if reason is not None:
             if reason == "value_width":
@@ -650,29 +762,60 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
             return ("remaining", name, db, None)
         # index-path shards group by their width as well: their values
         # go up as they are, never padded to a wider neighbour's
-        vw = lanes["val_words"].shape[1]
         index = value_path(kind, vw) == "index"
-        dev = None
-        if index:
-            # up from THIS thread, eight shards at once, and not one
-            # after another on the leader's (its ``tpu.h2d``)
-            padded = lanes["val_words"].base
-            try:
+        cut = total > PLACE_ROWS_MAX  # else the shard is its one place
+        try:
+            places = (_range_cut(name, parts, lanes, total, index, tctx)
+                      if cut else [lanes])
+            devs = []
+            for place in places if index else ():
+                # up from THIS thread, eight shards at once, and not one
+                # after another on the leader's (its ``tpu.h2d``)
+                padded = place["val_words"].base
                 with start_span("tpu.h2d.values", remote=tctx,
                                 bytes=padded.nbytes):
-                    dev = jax.block_until_ready(jax.device_put(padded))
-            except BaseException:
-                log.exception(
-                    "value upload failed for %s; declining to per-db", name)
-                _abort(db, plan)
-                return ("remaining", name, db, None)
+                    devs.append(
+                        jax.block_until_ready(jax.device_put(padded)))
+            batches = (_place_batches(places, devs) if cut
+                       else [_LaneBatch(lanes, *devs)])
+        except BaseException:
+            log.exception(
+                "range cut or value upload failed for %s; declining to "
+                "per-db", name)
+            _abort(db, plan)
+            return ("remaining", name, db, None)
         key = (kind, plan["drop_tombstones"], vw if index else 0)
-        return ("grouped", name, db, (key, plan, _LaneBatch(lanes, dev)))
+        return ("grouped", name, db, (key, plan, batches))
+
+    def _range_cut(name, parts, lanes, total, index, tctx):
+        """A shard of more rows than a place holds, as the lanes of its
+        places in key order (the index path's values in a zero-tailed
+        buffer a place, at the place's capacity). Raises where no cut
+        at keys fits (counted: one key group is over a place) or the
+        runs are not sorted."""
+        klen = int(lanes["key_len"][0])  # one width: the rule above
+        with start_span("tpu.range_cut", remote=tctx, rows=total,
+                        capacity=PLACE_ROWS_MAX) as sp:
+            try:
+                bounds = plan_subcompactions(
+                    parts, total, 1, klen, max_slice_rows=PLACE_ROWS_MAX)
+            except KeyGroupOverSlice as e:
+                record_host_fallback("key_group_over_place", f"{name}: {e}")
+                raise
+            if not bounds:  # the planner cuts sorted runs only
+                raise RuntimeError(f"{name}: {total} rows in unsorted runs")
+            places = slice_lanes(
+                parts, bounds, klen,
+                (lambda rows, vw: PLACE_ROWS_MAX) if index else None)
+            sp.annotate(places=len(places))
+        Stats.get().incr("compact.range_cut.shards")
+        Stats.get().incr("compact.range_cut.places", len(places))
+        return places
 
     def _install(args, tctx):
-        name, db, plan, res = args
+        name, db, plan, results = args  # a result a place, in key order
         try:
-            how = _write_arrays(db, res, tctx)
+            how = _write_places(db, results, tctx)
         except BaseException:
             # nothing is installed yet: hand the mutex back, so that the
             # per-db retry via compact_range can take it
@@ -704,12 +847,14 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
             elif verdict == "remaining":
                 remaining.append((name, db))
             else:
-                key, plan, batch = payload
-                groups.setdefault(key, []).append((name, db, plan, batch))
+                key, plan, places = payload
+                groups.setdefault(key, []).append((name, db, plan, places))
 
         svc = TpuCompactionService.instance()
         for (kind, drop, _vw), items in groups.items():
-            batches = [b for _n, _d, _p, b in items]
+            # every shard's places side by side: 8 shards of 3 places
+            # fill 3 launches
+            batches = [b for _n, _d, _p, places in items for b in places]
             vw = max(b.val_words.shape[1] for b in batches)
             for b in batches:  # group-uniform value lanes for np.stack
                 w = b.val_words.shape[1]
@@ -727,7 +872,8 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
                 # 22). H2D of group i+1 overlaps group i's kernel.
                 results = svc.compact_shard_stream(
                     batches, merge_kind=kind, drop_tombstones=drop,
-                    group_size=group_size, return_arrays=True)
+                    group_size=group_size, return_arrays=True,
+                    dbs=len(items))
             except Exception:
                 record_host_fallback(
                     "batched_launch",
@@ -737,8 +883,9 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
                     _abort(db, plan)
                     remaining.append((name, db))
                 continue
-            installs = [(name, db, plan, res) for (name, db, plan, _b), res
-                        in zip(items, results)]
+            results = iter(results)
+            installs = [(name, db, plan, [next(results) for _ in places])
+                        for name, db, plan, places in items]
             with start_span("admin.compact_install", shards=len(installs)):
                 installed = _pmap(_install, installs)
             for verdict, name, db in installed:

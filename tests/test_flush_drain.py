@@ -628,3 +628,94 @@ def test_block_cache_disabled_by_zero_capacity(tmp_path):
         assert db.get(b"key00000017") == pack64(17)  # reads still work
     finally:
         db.close()
+
+
+# ---------------------------------------------------------------------------
+# the memtable as columns (PR 33): a key's stack is a chain of rows; the
+# same answers as a list of entry tuples a key, and nothing on the heap
+# for the collector to walk
+# ---------------------------------------------------------------------------
+
+
+def _stack_ops(seed: int, keys: int, n: int):
+    rng = np.random.default_rng(seed)
+    kinds = (OpType.PUT, OpType.MERGE, OpType.DELETE)
+    ops = []
+    for i in range(n):
+        kind = kinds[rng.integers(3)]
+        ops.append((b"k%03d" % rng.integers(keys), i + 1, kind,
+                    b"" if kind == OpType.DELETE
+                    else pack64(int(rng.integers(100)))))
+    return ops
+
+
+def _model_get(stack, key, merge_op):
+    """``MemTable.get`` over a plain newest-first list of entries."""
+    operands = []
+    for _seq, vtype, value in stack:
+        if vtype in (OpType.PUT, OpType.DELETE):
+            base = value if vtype == OpType.PUT else None
+            if operands and merge_op:
+                return True, merge_op.merge(key, base, operands[::-1]), []
+            return True, base, []
+        operands.append(value)
+    return False, None, operands[::-1]
+
+
+@pytest.mark.parametrize("keys,n", [(400, 300), (40, 300), (1, 50)],
+                         ids=["mostly_single", "stacks", "one_key"])
+@pytest.mark.parametrize("absorbed", [False, True])
+def test_memtable_columns_answer_as_a_list_of_entries_a_key(keys, n,
+                                                            absorbed):
+    ops = _stack_ops(33, keys, n)
+    model = {}
+    for key, seq, vtype, value in ops:
+        model.setdefault(key, []).insert(0, (seq, int(vtype), value))
+    if absorbed:
+        # the flush-failure path: the older half folded beneath the newer
+        older, mem = MemTable(), MemTable()
+        for op in ops[:n // 2]:
+            older.apply(*op)
+        for op in ops[n // 2:]:
+            mem.apply(*op)
+        mem.absorb_older(older)
+        arrival = ops[n // 2:] + ops[:n // 2]
+    else:
+        mem = MemTable()
+        for op in ops:
+            mem.apply(*op)
+        arrival = ops
+    assert len(mem) == len(model)
+    assert (mem.min_seq, mem.max_seq) == (1, n)
+    assert list(mem.entries()) == [
+        (k, *e) for k in sorted(model) for e in model[k]]
+    for op in (UInt64AddOperator(), None):
+        for key, stack in model.items():
+            assert mem.get(key, op) == _model_get(stack, key, op)
+        assert mem.get(b"absent", op) == (False, None, [])
+    # the drain reads the columns: every op, in arrival order
+    lanes, key_mat = mem.drain_lanes()
+    assert lanes["vtype"].tolist() == [int(v) for _k, _s, v, _x in arrival]
+    assert lanes["seq_lo"].tolist() == [s for _k, s, _v, _x in arrival]
+    assert [bytes(r) for r in key_mat] == [k for k, _s, _v, _x in arrival]
+    assert [bytes(r[:8]) if v != OpType.DELETE else b""
+            for r, (_k, _s, v, _x) in zip(lanes["val_words"].view(np.uint8),
+                                          arrival)] \
+        == [x for _k, _s, _v, x in arrival]
+    mem.apply(b"k000", n + 1, OpType.PUT, pack64(7))  # not pinned by a drain
+    assert mem.get(b"k000", None) == (True, pack64(7), [])
+
+
+def test_memtable_adds_no_container_a_key_or_an_entry():
+    """Eight memtables fill before every bulk load: what they hold must
+    not lengthen a full collection (PERF.md section 6, PR 33)."""
+    import gc
+
+    gc.collect()
+    before = len(gc.get_objects())
+    mem = MemTable()
+    for i in range(2000):
+        mem.apply(b"k%05d" % (i % 1800), i + 1, OpType.MERGE, pack64(i))
+    gc.collect()
+    assert len(mem) == 1800 and not gc.is_tracked(mem._newest)
+    assert len(gc.get_objects()) - before < 50

@@ -36,6 +36,13 @@ the run's own and the last):
   ``tpu.h2d.values`` / ``tpu.readback.values`` spans and the leader's
   ``tpu.h2d`` / ``tpu.readback``: how many and their mean.
   ``--trace 0`` too.
+- **which shards were cut by key range**: the process's
+  ``compact.range_cut.shards`` / ``.places`` counters (shards of more
+  rows than one place of the served door's launch holds, and the places
+  they were cut into; ``tpu/compaction_service.py``) beside the window's
+  ``tpu.range_cut`` spans (how many, their mean) and the places and
+  whole shards its ``tpu.compact_stream`` spans launched (``shards``,
+  ``dbs``). ``--trace 0`` too.
 
 Arguments are ``chipbench/run.py``'s own.
 """
@@ -159,6 +166,18 @@ def main(argv=None) -> int:
             {"window_spans_count_mean_ms": seam},
             **{"process_" + k: Stats.get().get_counter("seam.values." + k)
                for k in ("prestaged", "restaged")})))
+        ms = span_ms(run, "tpu.range_cut")
+        streams = [s["annotations"] for s in run.spans
+                   if s["name"] == "tpu.compact_stream"]
+        harness.say("shards cut by key range: " + json.dumps(dict(
+            {"window_spans": len(ms),
+             "window_mean_ms": round(sum(ms) / len(ms), 2) if ms else None,
+             "window_launched_places": sum(
+                 int(a["shards"]) for a in streams),
+             "window_launched_dbs": sum(
+                 int(a.get("dbs", a["shards"])) for a in streams)},
+            **{"process_" + k: Stats.get().get_counter(
+                "compact.range_cut." + k) for k in ("shards", "places")})))
         return real_read_metrics(bench, group, package, cell, run)
 
     tr.reduce, harness.reduce_trace = reduce, reduce_trace
