@@ -128,7 +128,7 @@ class ApplicationDB:
                     pass  # timeout accounting lives in the ack window
             seq = waiters[0].seq
         else:
-            seq = self.db.write_many([(b, None) for b in batches])
+            seq = self.db.write_many(batches)
         self._stats.incr(
             tagged("applicationdb.writes", db=self.name), len(batches))
         return seq

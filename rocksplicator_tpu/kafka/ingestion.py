@@ -310,10 +310,8 @@ class IngestionWatcher:
 
     def _write_many(self, batches: List[WriteBatch]) -> None:
         target = self._app_db
-        if hasattr(target, "db"):  # ApplicationDB: replication-aware
+        if hasattr(target, "write_many"):  # ApplicationDB or a raw engine DB
             target.write_many(batches)
-        elif hasattr(target, "write_many"):  # raw engine DB
-            target.write_many([(b, None) for b in batches])
         else:
             for b in batches:
                 target.write(b)

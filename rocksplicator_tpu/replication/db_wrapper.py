@@ -128,7 +128,7 @@ class StorageDbWrapper(DbWrapper):
         return self.db.write(batch)
 
     def write_to_leader_many(self, batches) -> int:
-        return self.db.write_many([(b, None) for b in batches])
+        return self.db.write_many(batches)
 
     def get_updates_from_leader(
         self, since_seq: int
@@ -149,22 +149,16 @@ class StorageDbWrapper(DbWrapper):
         # The raw batch still carries the leader's LOG_DATA timestamp, so
         # applying it verbatim preserves the stamp for chained downstream
         # followers (reference re-stamps explicitly; here the bytes already
-        # contain it). Passing the raw bytes through skips the WAL
-        # re-encode — decode + encode per applied update was pure waste on
-        # the follower apply hot path.
-        batch = decode_batch(raw_data)
-        self.db.write(batch, encoded=bytes(raw_data))
+        # contain it). The decoded batch keeps the leader's frame, so the
+        # WAL logs those bytes as they came: no re-encode on the apply path.
+        self.db.write(decode_batch(raw_data))
 
     def handle_replicate_updates(self, updates) -> None:
         """Batched apply: one engine write_many per pull response — one
         storage-lock pass and ONE WAL flush for the whole group (the
         per-record flush syscall dominated the apply hot path once
         leader writes pipelined)."""
-        items = []
-        for u in updates:
-            raw = bytes(u["raw_data"])
-            items.append((decode_batch(raw), raw))
-        self.db.write_many(items)
+        self.db.write_many([decode_batch(u["raw_data"]) for u in updates])
 
     def get(self, key: bytes) -> Optional[bytes]:
         return self.db.get(key)

@@ -502,6 +502,13 @@ class ReplicatedDB:
                 return self._register_ack_wait(end_seq, seq, sp)
         return resolved_waiter(seq)
 
+    def _write_encoded(self, raw_batch) -> AckWaiter:
+        """The ``write`` RPC's executor half: the client's frame is
+        parsed ONCE, here and not on the loop (a frame that is not a
+        batch raises ``Corruption`` before anything is logged), and
+        stays the frame down to the WAL."""
+        return self.write_async(decode_batch(raw_batch))
+
     def write_async_many(self, batches: List[WriteBatch]) -> List[AckWaiter]:
         """Pipelined GROUP write: commit every batch with one storage
         lock pass and ONE WAL flush (engine ``write_many``), one
@@ -1256,14 +1263,13 @@ class ReplicatedDB:
                 f"{self.name}: {self._acked.depth}/{self._acked.capacity} "
                 f"writes in flight — retry with backoff",
             )
-        batch = decode_batch(bytes(raw_batch))
         # server-side latency per op class (the write sibling of
         # reads.latency_ms): the fleet p50/p99 the spectator merge
         # reports for puts, measured commit → ack condition; recorded on
         # COMPLETED writes only (same served-only contract as reads)
         t0 = time.monotonic()
         waiter = await self._loop.run_in_executor(
-            self._executor, self.write_async, batch)
+            self._executor, self._write_encoded, raw_batch)
         await asyncio.wrap_future(waiter.future)
         self._stats.add_metric(tagged("writes.latency_ms", op="put"),
                                (time.monotonic() - t0) * 1e3)
@@ -1629,7 +1635,7 @@ class ReplicatedDB:
                         f"{expected}, got {got} — rebuild required"
                     )
                 expected += int(u.get("count")
-                                or decode_batch(bytes(u["raw_data"])).count())
+                                or scan_batch_meta(u["raw_data"])[0])
                 total_bytes += len(u["raw_data"])
             # Apply: consecutive UNTRACED updates flow through the
             # wrapper's batched group path (one storage-lock pass + one

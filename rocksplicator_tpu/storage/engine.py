@@ -39,7 +39,7 @@ from .compaction import (CompactionBackend, CpuCompactionBackend,
 from .errors import Corruption, InvalidArgument, StorageError
 from .memtable import MemTable
 from .merge import MERGE_OPERATORS, MergeOperator
-from .records import OpType, WriteBatch, decode_batch
+from .records import BatchColumns, OpType, WriteBatch, decode_batch
 from .sst import COMPRESSION_NONE, COMPRESSION_ZLIB, SSTReader, SSTWriter
 
 import bisect
@@ -399,7 +399,7 @@ class DB:
             end_seq = start_seq + batch.count() - 1
             if end_seq <= self._persisted_seq:
                 continue
-            self._apply_to_memtable(batch, start_seq)
+            self._apply_to_memtable(batch.columns(), start_seq)
             self._last_seq = max(self._last_seq, end_seq)
         self._wal = wal_mod.WalWriter(
             self._wal_dir, self.options.wal_segment_bytes
@@ -458,14 +458,12 @@ class DB:
     # writes
     # ------------------------------------------------------------------
 
-    def write(self, batch: WriteBatch, sync: bool = False,
-              encoded: Optional[bytes] = None) -> int:
+    def write(self, batch: WriteBatch, sync: bool = False) -> int:
         """Apply a batch atomically; returns the batch's start seq.
 
-        ``encoded`` lets a caller that already HOLDS the batch's encoded
-        bytes (a follower applying a replicated update ships the raw
-        leader bytes) skip the re-encode — the bytes must be exactly
-        ``batch.encode()``.
+        A batch that ARRIVED encoded (``decode_batch``: a follower
+        applying a replicated update, the leader's ``write`` RPC) logs
+        the frame it came as; only a built batch is encoded here.
 
         Sync durability is GROUP-COMMITTED: the fsync runs OUTSIDE the
         DB lock (readers and other writers never block on the disk) and
@@ -474,21 +472,20 @@ class DB:
         mode, a concurrent reader may observe a sync write in the
         memtable shortly before its fsync returns; write() itself does
         not return until the batch is durable."""
-        count = batch.count()
+        encoded = batch.encode()
+        cols = batch.columns()
         with self._lock:
             self._check_open()
             self._check_flush_health_locked()
-            self._admission_stall_locked(batch.byte_size())
+            self._admission_stall_locked(len(encoded))
             self._check_open()
             self._check_flush_health_locked()
             start_seq = self._last_seq + 1
             self._last_write_mono = time.monotonic()
-            if encoded is None:
-                encoded = batch.encode()
             assert self._wal is not None
             token = self._wal.append(start_seq, encoded)
-            self._apply_to_memtable(batch, start_seq)
-            self._last_seq += count
+            self._apply_to_memtable(cols, start_seq)
+            self._last_seq += cols.count
             if self._mem.approximate_bytes() >= self.options.memtable_bytes:
                 if self._bg_thread is not None:
                     self._swap_to_imm_locked()
@@ -499,11 +496,7 @@ class DB:
             wal.sync_to(token)
         return start_seq
 
-    def write_many(
-        self,
-        items: List[Tuple[WriteBatch, Optional[bytes]]],
-        sync: bool = False,
-    ) -> int:
+    def write_many(self, batches: List[WriteBatch], sync: bool = False) -> int:
         """Apply a GROUP of batches in order with one lock pass and one
         WAL flush — the follower apply path commits a whole replication
         pull response per call instead of paying the per-record flush
@@ -511,21 +504,14 @@ class DB:
         still gets its own sequence range (identical numbering to N
         ``write`` calls — replication continuity depends on it); the
         group is NOT atomic against a crash mid-flush, which matches N
-        separate non-sync writes. Returns the FIRST batch's start seq.
-
-        ``items`` pairs each batch with its encoded bytes when the
-        caller already holds them (replicated updates ship the leader's
-        raw bytes), else None to encode here."""
-        if not items:
+        separate non-sync writes. Returns the FIRST batch's start seq."""
+        if not batches:
             raise ValueError("write_many: empty group")
-        total_bytes = sum(
-            len(enc) if enc is not None else b.byte_size()
-            for b, enc in items
-        )
+        group = [(batch.encode(), batch.columns()) for batch in batches]
         with self._lock:
             self._check_open()
             self._check_flush_health_locked()
-            self._admission_stall_locked(total_bytes)
+            self._admission_stall_locked(sum(len(enc) for enc, _ in group))
             self._check_open()
             self._check_flush_health_locked()
             assert self._wal is not None
@@ -533,16 +519,14 @@ class DB:
             self._last_write_mono = time.monotonic()
             records = []
             seq = first_seq
-            for batch, encoded in items:
-                if encoded is None:
-                    encoded = batch.encode()
+            for encoded, cols in group:
                 records.append((seq, encoded))
-                seq += batch.count()
+                seq += cols.count
             token = self._wal.append_many(records)
             seq = first_seq
-            for batch, _ in items:
-                self._apply_to_memtable(batch, seq)
-                seq += batch.count()
+            for _, cols in group:
+                self._apply_to_memtable(cols, seq)
+                seq += cols.count
                 self._last_seq = seq - 1
             if self._mem.approximate_bytes() >= self.options.memtable_bytes:
                 if self._bg_thread is not None:
@@ -717,13 +701,10 @@ class DB:
             self._cond.wait(0.05)
         self._check_open()
 
-    def _apply_to_memtable(self, batch: WriteBatch, start_seq: int) -> None:
-        seq = start_seq
-        for op, key, value in batch.ops():
-            if op is OpType.LOG_DATA:
-                continue
-            self._mem.apply(key, seq, op, value)
-            seq += 1
+    def _apply_to_memtable(self, cols: BatchColumns, start_seq: int) -> None:
+        self._mem.apply_batch(cols, start_seq)
+        if cols.frame_pass:  # an arrived frame: which pass it took
+            Stats.get().incr("write.apply." + cols.frame_pass)
 
     def put(self, key: bytes, value: bytes) -> int:
         return self.write(WriteBatch().put(key, value))

@@ -8,10 +8,11 @@ entries in (key asc, seq desc) order — the SST writer's required order.
 from __future__ import annotations
 
 from array import array
+from itertools import repeat
 from typing import Dict, Iterator, List, Optional, Tuple
 
 from .merge import MergeOperator
-from .records import OpType
+from .records import BatchColumns, OpType
 
 # entry: (seq, vtype, value), newest first
 _Entry = Tuple[int, int, bytes]
@@ -59,6 +60,38 @@ class MemTable:
         if self.min_seq is None:
             self.min_seq = seq
         self.max_seq = max(self.max_seq, seq)
+
+    def apply_batch(self, cols: BatchColumns, start_seq: int) -> None:
+        """A whole batch, rows ``start_seq`` onward in the batch's order:
+        what ``apply`` op by op leaves, from one extend a column. The
+        values are the batch's own objects; ``_newest`` goes LAST, so a
+        reader finds whole rows only."""
+        n = cols.count
+        if not n:
+            return
+        base = len(self._seqs)
+        newest = self._newest
+        prev = array("q", map(newest.get, cols.keys, repeat(-1)))
+        mine = dict(zip(cols.keys, range(base, base + n)))
+        if len(mine) != n:  # a key twice in the batch: its own older row
+            seen: Dict[bytes, int] = {}
+            for i, key in enumerate(cols.keys):
+                older = seen.get(key)
+                if older is not None:
+                    prev[i] = older
+                seen[key] = base + i
+        self._prev.extend(prev)
+        self._key_buf += cols.key_bytes
+        self._klens.extend(cols.klens)
+        self._vals.extend(cols.vals)
+        self._vlens.extend(cols.vlens)
+        self._seqs.extend(range(start_seq, start_seq + n))
+        self._vtypes += cols.vtypes
+        newest.update(mine)
+        self._bytes += len(cols.key_bytes) + sum(cols.vlens) + 16 * n
+        if self.min_seq is None:
+            self.min_seq = start_seq
+        self.max_seq = max(self.max_seq, start_seq + n - 1)
 
     def _stack(self, key: bytes) -> Iterator[_Entry]:
         """The key's entries, newest first."""

@@ -23,7 +23,8 @@ from __future__ import annotations
 import enum
 import struct
 import time
-from typing import Iterator, List, Optional, Tuple
+from array import array
+from typing import Iterator, List, NamedTuple, Optional, Sequence, Tuple
 
 from .errors import Corruption
 
@@ -42,27 +43,92 @@ class OpType(enum.IntEnum):
 # wall-clock milliseconds (replicated_db.cpp stamps ms for the lag metric).
 _TS = struct.Struct("<Q")
 
+_Op = Tuple[OpType, bytes, bytes]
+
+
+class BatchColumns(NamedTuple):
+    """A batch's sequence-consuming operations as the memtable's columns
+    (``MemTable.apply_batch``); ``LOG_DATA`` is not among them."""
+
+    count: int
+    keys: Sequence[bytes]
+    vals: Sequence[bytes]
+    vtypes: bytes       # an OpType a row
+    key_bytes: bytes    # the keys back to back
+    klens: array        # "I"
+    vlens: array        # "I"
+    # how an ARRIVED frame became columns: "bulk" (read column-wise off
+    # one stride) or "general" (walked op by op); None for a built batch
+    frame_pass: Optional[str]
+
+
+def _columns_of(ops: List[_Op], frame_pass: Optional[str]) -> BatchColumns:
+    """The general pass: any batch, one walk over its tuples."""
+    live = [t for t in ops if t[0] is not OpType.LOG_DATA]
+    keys = [t[1] for t in live]
+    vals = [t[2] for t in live]
+    return BatchColumns(
+        len(live), keys, vals, bytes(t[0] for t in live), b"".join(keys),
+        array("I", map(len, keys)), array("I", map(len, vals)), frame_pass)
+
 
 class WriteBatch:
-    __slots__ = ("_ops",)
+    """Built (``put`` / ``merge`` / ``delete``: a list of tuples) or
+    ARRIVED (``decode_batch``: the encoded frame, kept as it came). A
+    frame of one stride — every operation a PUT / DELETE / MERGE of one
+    key width and one value width, then nothing but ``LOG_DATA`` — was
+    read column-wise once and never becomes tuples unless someone asks
+    for ``ops()``; ``put_log_data`` (the leader's time stamp) appends to
+    the frame, so ``encode()`` hands back the client's own bytes plus
+    the stamp. Any other mutation thaws the batch into its tuples."""
+
+    __slots__ = ("_built", "_raw", "_cols")
 
     def __init__(self) -> None:
-        self._ops: List[Tuple[OpType, bytes, bytes]] = []
+        self._built: Optional[List[_Op]] = []
+        self._raw: Optional[bytes] = None
+        # set by decode_batch's column-wise read alone
+        self._cols: Optional[BatchColumns] = None
+
+    def _thaw(self) -> List[_Op]:
+        """An arrived batch gives up its frame for its tuples."""
+        self._built = self._tuples()
+        self._raw = self._cols = None
+        return self._built
+
+    def _set_ops(self, ops: List[_Op]) -> None:
+        self._built, self._raw, self._cols = ops, None, None
+
+    # the tuple list under its old name (chipbench's rehearsal cuts a
+    # decoded batch in half through it); to read it is to mutate
+    _ops = property(_thaw, _set_ops)
+
+    def _append(self, op: _Op) -> "WriteBatch":
+        if self._raw is not None:
+            self._thaw()
+        self._built.append(op)
+        return self
 
     def put(self, key: bytes, value: bytes) -> "WriteBatch":
-        self._ops.append((OpType.PUT, bytes(key), bytes(value)))
-        return self
+        return self._append((OpType.PUT, bytes(key), bytes(value)))
 
     def delete(self, key: bytes) -> "WriteBatch":
-        self._ops.append((OpType.DELETE, bytes(key), b""))
-        return self
+        return self._append((OpType.DELETE, bytes(key), b""))
 
     def merge(self, key: bytes, operand: bytes) -> "WriteBatch":
-        self._ops.append((OpType.MERGE, bytes(key), bytes(operand)))
-        return self
+        return self._append((OpType.MERGE, bytes(key), bytes(operand)))
 
     def put_log_data(self, blob: bytes) -> "WriteBatch":
-        self._ops.append((OpType.LOG_DATA, b"", bytes(blob)))
+        blob = bytes(blob)
+        if self._raw is None:
+            self._built.append((OpType.LOG_DATA, b"", blob))
+            return self
+        # an arrived batch stays a frame: one more op behind the others
+        self._raw = b"".join((
+            _U32.pack(len(self) + 1), memoryview(self._raw)[_U32.size:],
+            _OPHEAD.pack(OpType.LOG_DATA, 0), _U32.pack(len(blob)), blob))
+        if self._built is not None:
+            self._built.append((OpType.LOG_DATA, b"", blob))
         return self
 
     # -- replication timestamp helpers ------------------------------------
@@ -74,7 +140,9 @@ class WriteBatch:
 
     def extract_timestamp_ms(self) -> Optional[int]:
         """Last LOG_DATA 8-byte timestamp, if any (follower lag metric)."""
-        for op, _key, val in reversed(self._ops):
+        if self._raw is not None:
+            return scan_batch_meta(self._raw)[1]
+        for op, _key, val in reversed(self._built):
             if op is OpType.LOG_DATA and len(val) == _TS.size:
                 return _TS.unpack(val)[0]
         return None
@@ -82,31 +150,54 @@ class WriteBatch:
     def strip_log_data(self) -> "WriteBatch":
         """Copy without LOG_DATA ops (follower re-stamps its own)."""
         out = WriteBatch()
-        out._ops = [t for t in self._ops if t[0] is not OpType.LOG_DATA]
+        out._built = [
+            t for t in self._tuples() if t[0] is not OpType.LOG_DATA]
         return out
 
     # -- introspection ----------------------------------------------------
 
+    def _tuples(self) -> List[_Op]:
+        """Read-only: an arrived batch keeps its frame."""
+        if self._built is None:
+            self._built = _decode_ops(self._raw)
+        return self._built
+
     def count(self) -> int:
         """Number of sequence-number-consuming ops."""
-        return sum(1 for op, _k, _v in self._ops if op is not OpType.LOG_DATA)
+        if self._cols is not None:
+            return self._cols.count
+        return sum(1 for op, _k, _v in self._built if op is not OpType.LOG_DATA)
 
     def __len__(self) -> int:
-        return len(self._ops)
+        if self._built is not None:
+            return len(self._built)
+        return _U32.unpack_from(self._raw, 0)[0]
 
-    def ops(self) -> Iterator[Tuple[OpType, bytes, bytes]]:
-        return iter(self._ops)
+    def ops(self) -> Iterator[_Op]:
+        return iter(self._tuples())
+
+    def columns(self) -> BatchColumns:
+        """What ``MemTable.apply_batch`` takes."""
+        if self._cols is not None:
+            return self._cols
+        return _columns_of(
+            self._built, None if self._raw is None else "general")
 
     def byte_size(self) -> int:
+        if self._raw is not None:
+            return len(self._raw)
         return _U32.size + sum(
-            _OPHEAD.size + _U32.size + len(k) + len(v) for _op, k, v in self._ops
+            _OPHEAD.size + _U32.size + len(k) + len(v)
+            for _op, k, v in self._built
         )
 
     # -- serialization ----------------------------------------------------
 
     def encode(self) -> bytes:
-        parts = [_U32.pack(len(self._ops))]
-        for op, key, val in self._ops:
+        if self._raw is not None:
+            return self._raw
+        parts = [_U32.pack(len(self._built))]
+        for op, key, val in self._built:
             parts.append(_OPHEAD.pack(op, len(key)))
             parts.append(key)
             parts.append(_U32.pack(len(val)))
@@ -114,17 +205,50 @@ class WriteBatch:
         return b"".join(parts)
 
 
-def scan_batch_meta(data) -> Tuple[int, Optional[int]]:
-    """(count, timestamp_ms) by skimming op HEADERS only — no key/value
-    slicing, no WriteBatch construction. The replication serve path needs
-    exactly these two facts per shipped update; a full decode_batch +
-    extract_timestamp_ms pair cost two O(bytes) passes per update on the
-    hot serve path."""
-    buf = bytes(data)
+def _uniform_rows(buf: bytes, num_ops: int):
+    """The frame's leading ops of ONE stride (the first op's key and
+    value widths, any of PUT / DELETE / MERGE) as a structured view
+    ``(t, kl, k, vl)`` a row, no byte copied; None where there is none
+    (an empty key, as LOG_DATA's, starts none)."""
+    import numpy as np
+
+    try:
+        _t, klen = _OPHEAD.unpack_from(buf, _U32.size)
+        (vlen,) = _U32.unpack_from(buf, _U32.size + _OPHEAD.size + klen)
+    except struct.error:
+        return None
+    stride = _OPHEAD.size + klen + _U32.size + vlen
+    rows = min(num_ops, (len(buf) - _U32.size) // stride)
+    if klen == 0 or rows <= 0:
+        return None
+    rec = np.frombuffer(buf, np.dtype({
+        "names": ["t", "kl", "k", "vl"],
+        "formats": ["u1", "<u4", f"V{klen}", "<u4"],
+        "offsets": [0, 1, _OPHEAD.size, _OPHEAD.size + klen],
+        "itemsize": stride}), rows, _U32.size)
+    # u1 wraps: 0 - 1 = 255, so this is 1 <= t <= 3
+    ok = (rec["kl"] == klen) & (rec["vl"] == vlen) & (rec["t"] - 1 < 3)
+    if not ok.all():
+        rows = int(ok.argmin())
+        if rows == 0:
+            return None
+        rec = rec[:rows]
+    return rec, klen, vlen, stride
+
+
+def _skim(buf: bytes):
+    """A frame by its op HEADERS alone: (its leading rows of one stride
+    or None, the sequence-consuming ops behind them, the last 8-byte
+    LOG_DATA behind them as a time stamp, the position behind the last
+    op)."""
     if len(buf) < _U32.size:
         raise Corruption("batch too short")
     (num_ops,) = _U32.unpack_from(buf, 0)
+    uniform = _uniform_rows(buf, num_ops)
     pos = _U32.size
+    if uniform is not None:
+        num_ops -= len(uniform[0])
+        pos += len(uniform[0]) * uniform[3]
     count = 0
     ts: Optional[int] = None
     try:
@@ -139,20 +263,30 @@ def scan_batch_meta(data) -> Tuple[int, Optional[int]]:
             else:
                 count += 1
             pos += val_len
-        if pos > len(buf):
-            raise Corruption("truncated batch")
     except struct.error as e:
         raise Corruption(f"bad batch: {e}") from e
-    return count, ts
+    return uniform, count, ts, pos
 
 
-def decode_batch(data) -> WriteBatch:
+def scan_batch_meta(data) -> Tuple[int, Optional[int]]:
+    """(count, timestamp_ms) from op HEADERS only — no key/value slicing,
+    no WriteBatch construction. The replication serve path and the WAL's
+    straddler checks need exactly these facts per update: a frame of one
+    stride gives them from one array comparison, any other from a skim."""
     buf = bytes(data)
+    uniform, count, ts, end = _skim(buf)
+    if end > len(buf):
+        raise Corruption("truncated batch")
+    return count + (0 if uniform is None else len(uniform[0])), ts
+
+
+def _decode_ops(buf: bytes) -> List[_Op]:
+    """Every op of a frame as a tuple, validated to the last byte."""
     if len(buf) < _U32.size:
         raise Corruption("batch too short")
     (num_ops,) = _U32.unpack_from(buf, 0)
     pos = _U32.size
-    batch = WriteBatch()
+    ops: List[_Op] = []
     try:
         for _ in range(num_ops):
             op_raw, key_len = _OPHEAD.unpack_from(buf, pos)
@@ -167,9 +301,43 @@ def decode_batch(data) -> WriteBatch:
             if len(val) != val_len:
                 raise Corruption("truncated value")
             pos += val_len
-            batch._ops.append((OpType(op_raw), key, val))
+            ops.append((OpType(op_raw), key, val))
     except (struct.error, ValueError) as e:
         raise Corruption(f"bad batch encoding: {e}") from e
     if pos != len(buf):
         raise Corruption("trailing bytes in batch")
+    return ops
+
+
+def _bulk_columns(buf: bytes) -> Optional[BatchColumns]:
+    """A frame of ONE stride with nothing but ``LOG_DATA`` behind it,
+    read column-wise: numpy over the stride for the headers, the types
+    and the key bytes, every key and value object sliced once by one
+    ``iter_unpack``. None for any other frame, a broken one too."""
+    try:
+        uniform, behind, _ts, tail_end = _skim(buf)
+    except Corruption:
+        return None
+    if uniform is None or behind or tail_end != len(buf):
+        return None
+    rec, klen, vlen, stride = uniform
+    rows = len(rec)
+    end = _U32.size + rows * stride
+    keys, vals = zip(*struct.iter_unpack(
+        f"{_OPHEAD.size}x{klen}s{_U32.size}x{vlen}s",
+        memoryview(buf)[_U32.size:end]))
+    return BatchColumns(
+        rows, keys, vals, rec["t"].tobytes(), rec["k"].tobytes(),
+        array("I", (klen,)) * rows, array("I", (vlen,)) * rows, "bulk")
+
+
+def decode_batch(data) -> WriteBatch:
+    """The ONE parse of an arrived frame: validated to the last byte
+    (``Corruption`` otherwise), and the batch keeps the frame. What
+    cannot be read column-wise is walked op by op into tuples, as any
+    built batch is."""
+    batch = WriteBatch()
+    batch._raw = bytes(data)
+    batch._cols = _bulk_columns(batch._raw)
+    batch._built = None if batch._cols else _decode_ops(batch._raw)
     return batch

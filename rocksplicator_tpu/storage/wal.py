@@ -32,6 +32,7 @@ from typing import Iterator, List, Optional, Tuple
 
 from ..testing import failpoints as fp
 from .errors import Corruption, StorageError
+from .records import scan_batch_meta
 
 _REC_HEAD = struct.Struct("<QII")
 
@@ -435,8 +436,6 @@ def iter_updates(
     contract holds regardless). Safe against concurrent append (active
     segment tail tolerated) and concurrent purge (missing segments skipped).
     """
-    from .records import decode_batch
-
     segs = _segments(wal_dir)
     yielded_any = False
     for i, (first_seq, path) in enumerate(segs):
@@ -458,7 +457,7 @@ def iter_updates(
                 yield start_seq, body
             elif not yielded_any:
                 # Possible straddler: include iff its range reaches since_seq.
-                if start_seq + decode_batch(body).count() - 1 >= since_seq:
+                if start_seq + scan_batch_meta(body)[0] - 1 >= since_seq:
                     yielded_any = True
                     yield start_seq, body
 
@@ -583,9 +582,7 @@ class WalTailCursor:
                     p_seq, p_blen, _ = _REC_HEAD.unpack(p_hdr)
                     body = self._read_at(prev_off + _REC_HEAD.size, p_blen)
                     if len(body) == p_blen:
-                        from .records import decode_batch
-
-                        if p_seq + decode_batch(body).count() - 1 >= self._since:
+                        if p_seq + scan_batch_meta(body)[0] - 1 >= self._since:
                             self._offset = prev_off
                 break
             if self._offset + _REC_HEAD.size + blen > size:
@@ -751,7 +748,5 @@ def latest_seq(wal_dir: str) -> int:
     """Highest sequence number present in the WAL (0 if empty)."""
     last = 0
     for start_seq, body in iter_updates(wal_dir, 0, truncate_torn=False):
-        from .records import decode_batch
-
-        last = start_seq + decode_batch(body).count() - 1
+        last = start_seq + scan_batch_meta(body)[0] - 1
     return last

@@ -86,7 +86,7 @@ RANKS = {
     "rocksplicator_tpu/cluster/participant.py:75": ('Participant._state_lock', 66),
     "rocksplicator_tpu/utils/stats.py:218": ('Stats._lock', 67),
     "rocksplicator_tpu/storage/compaction_scheduler.py:123": ('IoBudget._lock', 68),
-    "rocksplicator_tpu/storage/wal.py:68": ('WalWriter._sync_lock', 69),
+    "rocksplicator_tpu/storage/wal.py:69": ('WalWriter._sync_lock', 69),
 }
 
 # static partial order: (acquired-first, acquired-second)
@@ -95,11 +95,11 @@ ORDER = {
     ("rocksplicator_tpu/cluster/coordinator.py:303", "rocksplicator_tpu/cluster/coordinator.py:296"),
     ("rocksplicator_tpu/cluster/participant.py:76", "rocksplicator_tpu/cluster/participant.py:75"),
     ("rocksplicator_tpu/storage/engine.py:248", "rocksplicator_tpu/storage/compaction_scheduler.py:123"),
-    ("rocksplicator_tpu/storage/engine.py:248", "rocksplicator_tpu/storage/wal.py:68"),
+    ("rocksplicator_tpu/storage/engine.py:248", "rocksplicator_tpu/storage/wal.py:69"),
     ("rocksplicator_tpu/storage/engine.py:277", "rocksplicator_tpu/storage/compaction_scheduler.py:123"),
     ("rocksplicator_tpu/storage/engine.py:277", "rocksplicator_tpu/storage/engine.py:248"),
     ("rocksplicator_tpu/storage/engine.py:277", "rocksplicator_tpu/storage/engine.py:284"),
-    ("rocksplicator_tpu/storage/engine.py:277", "rocksplicator_tpu/storage/wal.py:68"),
+    ("rocksplicator_tpu/storage/engine.py:277", "rocksplicator_tpu/storage/wal.py:69"),
     ("rocksplicator_tpu/utils/dbconfig.py:48", "rocksplicator_tpu/utils/file_watcher.py:40"),
     ("rocksplicator_tpu/utils/stats.py:240", "rocksplicator_tpu/utils/stats.py:218"),
 }

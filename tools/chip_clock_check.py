@@ -43,6 +43,16 @@ the run's own and the last):
   ``tpu.range_cut`` spans (how many, their mean) and the places and
   whole shards its ``tpu.compact_stream`` spans launched (``shards``,
   ``dbs``). ``--trace 0`` too.
+- **which pass a write batch took into the memtable**: the process's
+  ``write.apply.bulk`` / ``write.apply.general`` counters (batches whose
+  frame was of one stride and went into the memtable's columns as
+  columns / batches walked op by op: ``storage/records.py``,
+  ``storage/engine.py``) beside the process's served ``write`` RPCs
+  (``rpc.write.success``) and the window's ``rpc.server.write`` roots
+  (how many, their mean). Every batch a cell's client sends is of one
+  stride, so ``bulk`` equals the served ``write`` RPCs and ``general``
+  is 0 (a BUILT batch, as the admin plane's own metadata puts, is no
+  frame and counts under neither). ``--trace 0`` too.
 
 Arguments are ``chipbench/run.py``'s own.
 """
@@ -178,6 +188,13 @@ def main(argv=None) -> int:
                  int(a.get("dbs", a["shards"])) for a in streams)},
             **{"process_" + k: Stats.get().get_counter(
                 "compact.range_cut." + k) for k in ("shards", "places")})))
+        ms = span_ms(run, "rpc.server.write")
+        harness.say("write batches by pass: " + json.dumps(dict(
+            {"window_spans": len(ms),
+             "window_mean_ms": round(sum(ms) / len(ms), 2) if ms else None},
+            **{"process_" + k: Stats.get().get_counter(k)
+               for k in ("write.apply.bulk", "write.apply.general",
+                         "rpc.write.success")})))
         return real_read_metrics(bench, group, package, cell, run)
 
     tr.reduce, harness.reduce_trace = reduce, reduce_trace
