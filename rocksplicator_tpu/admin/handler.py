@@ -19,6 +19,7 @@ object-store URI (local dir or s3://).
 from __future__ import annotations
 
 import asyncio
+import gc
 import json
 import logging
 import os
@@ -51,6 +52,26 @@ from .ingest_pipeline import (BatchCompactor, IngestGate,
                               default_sst_loading_concurrency)
 
 log = logging.getLogger(__name__)
+
+_base_frozen = False
+
+
+def _freeze_process_base() -> None:
+    """Once a process, when its first handler is built: what lives now
+    (every module the node runs, its servers and pools) lives as long as
+    the process, and a full garbage collection that walks it stops the
+    world for its ~55 ms in the middle of whatever the node is serving.
+    Eight lockstep ingests that meet one are the slowest cycle of a
+    window, and how many cycles of a window meet one decides its p95
+    (PERF.md section 6, PRs 33 and 35). ``gc.freeze()`` moves what
+    lives now to the permanent generation, which no collection walks;
+    what is frozen still dies by reference count."""
+    global _base_frozen
+    if not _base_frozen:
+        _base_frozen = True
+        gc.collect()
+        gc.freeze()
+
 
 # Reference gflag parity: direct-IO SST downloads keep a restore/ingest
 # storm from evicting the serving working set (s3util.h:82-103)
@@ -173,12 +194,16 @@ class AdminHandler:
             from ..tpu.compile_cache import configure_compile_cache
 
             configure_compile_cache()
+            # the device stack's modules, which the first dispatch would
+            # import: part of the base that is frozen below
+            from ..tpu import compaction_service  # noqa: F401
         self._batch_compactor = BatchCompactor(
             use_tpu=tpu_compaction, compact_parallelism=compact_parallelism)
         self._meta_db = DB(os.path.join(self.rocksdb_dir, "meta_db"))
         # db_name -> message-ingestion watcher (kafka-equivalent stack)
         self._ingestion: Dict[str, object] = {}
         self._stats = Stats.get()
+        _freeze_process_base()
 
     # ------------------------------------------------------------------
     # helpers

@@ -205,8 +205,9 @@ class _MergedMemView:
         """Concatenated unsorted lanes across every memtable (see
         MemTable.drain_lanes) — the caller's single lexsort restores the
         global (key asc, seq desc) order. None when any memtable can't
-        express its entries as lanes; cross-memtable width mismatches
-        are caught by the caller's planar_widths check."""
+        express its entries as lanes; cross-memtable value width
+        mismatches are caught here and by the caller's planar_widths
+        check (key lengths may differ, within a memtable and across)."""
         import numpy as np
 
         parts = [m.drain_lanes() for m in self._imms]
@@ -217,8 +218,6 @@ class _MergedMemView:
         # O(parts) instead of after a giant transient concatenation
         # (the same round-2 lesson MemTable.drain_lanes applies within
         # one memtable).
-        if len({km.shape[1] for _l, km in parts}) != 1:
-            return None  # mixed key widths across memtables
         part_vlens = set()
         for lanes, _km in parts:
             live = lanes["val_len"][lanes["vtype"] != 2]
@@ -236,7 +235,11 @@ class _MergedMemView:
             f: np.concatenate([l[f] for l, _km in parts])
             for f in parts[0][0]
         }
-        return lanes, np.concatenate([km for _l, km in parts])
+        kmax = max(km.shape[1] for _l, km in parts)
+        return lanes, np.concatenate([
+            km if km.shape[1] == kmax
+            else np.pad(km, [(0, 0), (0, kmax - km.shape[1])])
+            for _l, km in parts])
 
 
 class DB:
@@ -1216,6 +1219,7 @@ class DB:
         from ..tpu.format import planar_stride, planar_widths, \
             write_sst_from_arrays
         from .bloom import BloomFilter
+        from .planar import key_shape
 
         with start_span("flush.drain"):
             drained = mem.drain_lanes()
@@ -1226,28 +1230,36 @@ class DB:
         widths = planar_widths(lanes, n)
         if widths is None:
             return False  # cross-memtable width mismatch
-        klen, vlen = widths
+        klen, _vlen, mixed = widths
+        # the rows' own key lengths, as they were drained (the bloom is
+        # order-independent and takes the pre-sort key matrix)
+        key_lens = lanes["key_len"].astype(np.uint64)
         with start_span("flush.sort", entries=n):
             # np.lexsort: last column has highest priority → key words
-            # ascending (uniform klen ⇒ BE word order == byte order),
-            # inverted seq as the descending tiebreak
+            # ascending (the zero-padded BE words, then the key's length
+            # where lengths differ: the bytewise order; one length ⇒ BE
+            # word order == byte order), inverted seq as the descending
+            # tiebreak
             seq = (
                 lanes["seq_hi"].astype(np.uint64) << np.uint64(32)
             ) | lanes["seq_lo"].astype(np.uint64)
             kw = lanes["key_words_be"]
             kwc = (klen + 3) // 4
             order = np.lexsort(
-                (~seq,) + tuple(kw[:, w] for w in range(kwc - 1, -1, -1)))
+                (~seq,) + ((lanes["key_len"],) if mixed else ())
+                + tuple(kw[:, w] for w in range(kwc - 1, -1, -1)))
             if not np.array_equal(order, np.arange(n)):
                 lanes = {f: a[order] for f, a in lanes.items()}
-        with start_span("flush.encode", entries=n):
+        shape = key_shape(key_lens)
+        if mixed:
+            Stats.get().incr("flush.key_widths.mixed")
+        with start_span("flush.encode", entries=n, **shape):
             # bulk bloom (order-independent — built from the pre-sort key
             # matrix) instead of a per-key Python loop
             bloom = BloomFilter.build_from_arrays(
-                key_mat, np.full(n, klen, dtype=np.uint64),
-                self.options.bits_per_key,
+                key_mat, key_lens, self.options.bits_per_key,
             )
-            stride = planar_stride(klen, vlen)
+            stride = planar_stride(*widths)
             props = write_sst_from_arrays(
                 lanes, n, path,
                 bloom_words=bloom.words,

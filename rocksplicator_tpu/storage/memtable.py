@@ -170,15 +170,18 @@ class MemTable:
         words, replacing the pure-Python ``sorted(self._data)`` +
         per-entry repack). Returns ``(lanes, key_bytes_matrix)`` or
         None when the planar lane representation can't express this
-        memtable: non-uniform or zero/over-wide key length, non-uniform
+        memtable: an empty or over-wide key (keys of DIFFERING length,
+        1 to 24 bytes, are lanes like any other), non-uniform
         non-DELETE value widths, value wider than the planar u16 vlen
         field, or a DELETE carrying a value. Width checks run inline
         during collection so a disqualifying entry bails before any
         large buffer is built.
 
         ``lanes`` is the kernel lane dict (key_words_be, key_len,
-        seq_hi/lo, vtype, val_words, val_len); the (n, klen) u8 key
-        matrix rides along for bulk bloom construction. The columns are
+        seq_hi/lo, vtype, val_words, val_len); the (n, widest key) u8
+        key matrix (a shorter key zero-padded; ``lanes["key_len"]`` has
+        each row's length) rides along for bulk bloom construction. The
+        columns are
         read as arrays where they lie (the key bytes copied once: a
         view that outlives this call would pin the bytearray against
         the next apply), the values joined."""
@@ -194,11 +197,10 @@ class MemTable:
         # million small ones bails here, not after a giant transient
         # allocation.
         klens = np.frombuffer(self._klens, dtype=np.uint32)
-        klen = int(klens[0])
-        if not (0 < klen <= PLANAR_MAX_KLEN):
+        kmin, klen = int(klens.min()), int(klens.max())  # klen: the widest
+        if not 0 < kmin <= klen <= PLANAR_MAX_KLEN:
             return None
-        if not bool((klens == klen).all()):
-            return None
+        mixed = kmin != klen
         vtype_arr = np.frombuffer(self._vtypes, dtype=np.uint8).astype(
             np.uint32)
         vlens = np.frombuffer(self._vlens, dtype=np.uint32)
@@ -209,11 +211,19 @@ class MemTable:
         vlen = int(live_vlens[0]) if len(live_vlens) else 0
         if vlen > PLANAR_MAX_VLEN or not bool((live_vlens == vlen).all()):
             return None
-        key_mat = np.frombuffer(
-            bytes(self._key_buf), dtype=np.uint8).reshape(n, klen)
         seq = np.frombuffer(self._seqs, dtype=np.uint64)
         key_buf = np.zeros((n, 24), dtype=np.uint8)
-        key_buf[:, :klen] = key_mat
+        flat = np.frombuffer(bytes(self._key_buf), dtype=np.uint8)
+        if mixed:
+            # each key's bytes to the head of its own row: byte j of the
+            # buffer lies in row r at column j - (where r's key starts)
+            starts = np.cumsum(klens, dtype=np.int64) - klens
+            row = np.repeat(np.arange(n), klens)
+            key_buf[row, np.arange(len(flat)) - starts[row]] = flat
+            key_mat = key_buf[:, :klen]
+        else:
+            key_mat = flat.reshape(n, klen)
+            key_buf[:, :klen] = key_mat
         vw = max(2, (vlen + 3) // 4)
         val_buf = np.zeros((n, vw * 4), dtype=np.uint8)
         if vlen:
@@ -224,7 +234,7 @@ class MemTable:
         lanes = {
             "key_words_be": key_buf.view(">u4").astype(
                 np.uint32).reshape(n, 6),
-            "key_len": np.full(n, klen, dtype=np.uint32),
+            "key_len": klens.copy(),
             "seq_hi": (seq >> np.uint64(32)).astype(np.uint32),
             "seq_lo": (seq & np.uint64(0xFFFFFFFF)).astype(np.uint32),
             "vtype": vtype_arr,

@@ -145,7 +145,7 @@ class NativeLib:
                 _u32p, ctypes.c_uint32,
                 ctypes.c_uint64, ctypes.c_uint32, ctypes.c_uint32,
                 ctypes.c_int32, ctypes.c_uint32, ctypes.c_int32,
-                _u8p, ctypes.c_uint64, _u64p, _u32p, _u8p, _u32p,
+                _u8p, ctypes.c_uint64, _u64p, _u32p, _u8p, _u32p, _u32p,
             ]
             lib.tsst_decode_file_lanes.restype = ctypes.c_int64
             lib.tsst_decode_file_lanes.argtypes = [
@@ -415,14 +415,16 @@ class NativeLib:
 
     def planar_encode_file(self, arrays, count: int, klen: int, vlen: int,
                            seq32: bool, block_entries: int,
-                           compression: int):
+                           compression: int, mixed: bool = False):
         """Every PLANAR block of one file from lanes ``[0, count)`` in
         ONE call (tsst_planar_encode_file; the GIL is released for all
         of it). Returns ``(payload, offsets, sizes, codecs, checksums)``:
         the blocks' bytes back to back in a u8 array, and per block its
         offset there, size, index codec nibble and ``poly1w`` value.
         None when the lanes are narrower than the widths ask (the Python
-        sink says what is wrong with them)."""
+        sink says what is wrong with them). ``mixed``: the rows' keys
+        differ in length (``klen`` their widest): each block's header
+        and key-length plane follow from its own rows."""
         kw, vw = (klen + 3) // 4, (vlen + 3) // 4
         kw_be = np.ascontiguousarray(
             arrays["key_words_be"][:count], dtype=np.uint32)
@@ -440,6 +442,11 @@ class NativeLib:
         per_entry = 4 * (kw + 1 + (0 if seq32 else 1) + vw)
         # a block's payload is never larger than its uncompressed bytes
         cap = count * per_entry + nblocks * (16 + 4) + count
+        key_len = None
+        if mixed:
+            key_len = np.ascontiguousarray(
+                arrays["key_len"][:count], dtype=np.uint32)
+            cap += count + 4 * nblocks  # the key-length planes
         out = np.empty(cap, dtype=np.uint8)
         offs = np.empty(nblocks, dtype=np.uint64)
         sizes = np.empty(nblocks, dtype=np.uint32)
@@ -454,6 +461,7 @@ class NativeLib:
             self._u8(out), cap, self._u64(offs),
             sizes.ctypes.data_as(_u32p), self._u8(codecs),
             chks.ctypes.data_as(_u32p),
+            None if key_len is None else key_len.ctypes.data_as(_u32p),
         )
         if wrote < 0:
             raise ValueError(f"tsst_planar_encode_file failed ({wrote})")
@@ -465,8 +473,10 @@ class NativeLib:
         """Every block of one file -> the eight kernel lanes in ONE call
         (tsst_decode_file_lanes: pread, inflate, transpose; the GIL is
         released for all of it). ``index``: (nblocks, 3) u64 of offset,
-        size, codec nibble. ``klen == 0`` (row format only): infer the
-        uniform widths from block 0. ``chk_mode`` 1 / 2: also each
+        size, codec nibble. ``klen == 0`` (row format only): the value
+        width is block 0's first entry's, and the keys have whatever
+        lengths they have (1 to 24 bytes; ``lanes["key_len"]``). A
+        PLANAR file's ``klen`` is its widest key. ``chk_mode`` 1 / 2: also each
         block's poly1 / poly1w value over ``chk_len``.
 
         Returns ``(status, lanes, checksums, blocks)``: status >= 0 is

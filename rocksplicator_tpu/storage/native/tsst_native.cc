@@ -402,10 +402,14 @@ int64_t rlz_decompress(const uint8_t* src, uint64_t n,
 // Block: u32 n | u8 klen | u8 vlen_lo | u8 flags | u8 vlen_hi | u64 0
 // (vlen = vlen_lo | vlen_hi<<8 — u16, byte 7 was reserved-zero so old
 // files read back unchanged), then u32
-// planes: key words (BE values, ceil(klen/4) x n), seq_lo (n), seq_hi
+// planes: key words (BE values, ceil(klen/4) x n), key lengths (only
+// when flags&2: ceil(n/4), 4 packed/word; klen is then the block's
+// widest key and a shorter key's tail bytes are zero), seq_lo (n), seq_hi
 // (n, absent when flags&1), vtype (ceil(n/4), 4 packed/word), value
-// words (LE values, ceil(vlen/4) x n). Keys ascending -> binary search,
-// then the contiguous match run (MERGE stacks). -2 = malformed.
+// words (LE values, ceil(vlen/4) x n). Keys ascending bytewise, which on
+// the planes is the zero-padded big-endian words, then the length ->
+// binary search, then the contiguous match run (MERGE stacks).
+// -2 = malformed.
 
 static inline int planar_cmp_key(
     const uint32_t* kw_planes, uint64_t n, uint64_t i,
@@ -434,14 +438,16 @@ extern "C" int64_t tsst_planar_get_entries(
   uint16_t bvlen = (uint16_t)data[5] | ((uint16_t)data[7] << 8);
   if (bklen == 0 || bklen > 24) return -2;
   uint64_t kw = (bklen + 3) / 4, vw = ((uint64_t)bvlen + 3) / 4;
-  int seq32 = flags & 1;
+  int seq32 = flags & 1, klens = flags & 2;
   uint64_t words = (uint64_t)n * (kw + 1 + (seq32 ? 0 : 1) + vw)
-                 + (n + 3) / 4;
+                 + (klens ? 2 : 1) * (((uint64_t)n + 3) / 4);
   if (len != 16 + 4 * words) return -2;
   if (n == 0) return 0;
   const uint32_t* planes = (const uint32_t*)(data + 16);
   const uint32_t* kwp = planes;
-  const uint32_t* seq_lo = planes + kw * n;
+  // an entry's own key length: its byte of the plane, else the block's
+  const uint8_t* klp = klens ? (const uint8_t*)(planes + kw * n) : nullptr;
+  const uint32_t* seq_lo = planes + kw * n + (klens ? (n + 3) / 4 : 0);
   const uint32_t* seq_hi = seq32 ? nullptr : seq_lo + n;
   const uint8_t* vtp = (const uint8_t*)(seq_lo + n + (seq32 ? 0 : n));
   const uint32_t* vvp = (const uint32_t*)(vtp + 4 * ((n + 3) / 4));
@@ -450,12 +456,16 @@ extern "C" int64_t tsst_planar_get_entries(
   uint64_t lo = 0, hi = n;
   while (lo < hi) {
     uint64_t mid = (lo + hi) / 2;
-    if (planar_cmp_key(kwp, n, mid, bklen, key, klen) < 0) lo = mid + 1;
+    uint32_t ek = klp ? klp[mid] : bklen;
+    if (ek > bklen) return -2;
+    if (planar_cmp_key(kwp, n, mid, ek, key, klen) < 0) lo = mid + 1;
     else hi = mid;
   }
   uint64_t found = 0;
   for (uint64_t i = lo; i < n; i++) {
-    int c = planar_cmp_key(kwp, n, i, bklen, key, klen);
+    uint32_t ek = klp ? klp[i] : bklen;
+    if (ek > bklen) return -2;
+    int c = planar_cmp_key(kwp, n, i, ek, key, klen);
     if (c != 0) { if (c > 0) *past_end = 1; break; }
     if (found >= max_matches) return -1;
     uint64_t s = seq_lo[i];
@@ -836,8 +846,10 @@ static uint32_t chk_bytes(const uint8_t* data, uint64_t n, uint64_t length,
 }
 
 static inline uint64_t planar_plane_words(uint64_t n, uint64_t kw,
-                                          uint64_t vw, bool seq32) {
-  return n * (kw + 1 + (seq32 ? 0 : 1) + vw) + (n + 3) / 4;
+                                          uint64_t vw, bool seq32,
+                                          bool klens = false) {
+  return n * (kw + 1 + (seq32 ? 0 : 1) + vw)
+       + (klens ? 2 : 1) * ((n + 3) / 4);
 }
 
 static bool pread_all(int fd, uint8_t* dst, uint64_t size, uint64_t off) {
@@ -919,9 +931,14 @@ struct BlockFetcher {
 // in `out` (capacity: the blocks' uncompressed bytes; a block is kept
 // compressed only when that is smaller). Per block: offset in `out`,
 // size, codec nibble, and the poly1w checksum over the uncompressed
-// plane words padded to a full block's. Returns the bytes written, -1
-// when `out_cap` is short, -2 on arguments the layout can't take, -3
-// when zlib fails.
+// plane words padded to a full block's. `key_len` (null: every key is
+// `klen` bytes): each row's own key length, `klen` their widest; a block
+// whose rows differ in length gets the key-length plane and flag 2, its
+// header's klen and its key planes at the block's own widest key; a
+// block whose rows share one length is the block it always was, at that
+// length. Returns the bytes written, -1 when `out_cap` is short, -2 on
+// arguments the layout can't take (a key length of 0 or over `klen`
+// among them), -3 when zlib fails.
 extern "C" int64_t tsst_planar_encode_file(
     const uint32_t* kw_be, uint32_t kw_cols,
     const uint32_t* seq_lo, const uint32_t* seq_hi, const uint8_t* vtype,
@@ -930,12 +947,14 @@ extern "C" int64_t tsst_planar_encode_file(
     uint32_t block_entries, int32_t compression,
     uint8_t* out, uint64_t out_cap,
     uint64_t* blk_off, uint32_t* blk_size, uint8_t* blk_codec,
-    uint32_t* blk_chk) {
-  uint64_t kw = (klen + 3) / 4, vw = ((uint64_t)vlen + 3) / 4;
+    uint32_t* blk_chk, const uint32_t* key_len) {
+  uint64_t vw = ((uint64_t)vlen + 3) / 4;
   if (klen == 0 || klen > 24 || vlen > 0xFFFFu || block_entries == 0
-      || kw > kw_cols || vw > val_cols || (!seq32 && seq_hi == nullptr))
+      || (klen + 3) / 4 > kw_cols || vw > val_cols
+      || (!seq32 && seq_hi == nullptr))
     return -2;
-  uint64_t full_words = planar_plane_words(block_entries, kw, vw, seq32);
+  uint64_t full_words = planar_plane_words(
+      block_entries, (klen + 3) / 4, vw, seq32, key_len != nullptr);
   ChkPowers pw;
   z_stream zs;
   memset(&zs, 0, sizeof(zs));
@@ -948,18 +967,36 @@ extern "C" int64_t tsst_planar_encode_file(
   for (uint64_t start = 0, bi = 0; start < count;
        start += block_entries, bi++) {
     uint64_t n = std::min<uint64_t>(block_entries, count - start);
-    uint64_t words = planar_plane_words(n, kw, vw, seq32);
+    uint32_t bklen = klen;   // this block's header klen: its widest key
+    bool klens = false;      // and whether its rows differ in length
+    if (key_len != nullptr) {
+      uint32_t lo = key_len[start], hi = lo;
+      for (uint64_t i = 1; i < n; i++) {
+        uint32_t l = key_len[start + i];
+        lo = std::min(lo, l); hi = std::max(hi, l);
+      }
+      if (lo == 0 || hi > klen) { rc = -2; break; }
+      bklen = hi; klens = lo != hi;
+    }
+    uint64_t kw = (bklen + 3) / 4;
+    uint64_t words = planar_plane_words(n, kw, vw, seq32, klens);
     uint64_t raw_len = 16 + 4 * words;
     if (pos + raw_len > out_cap) { rc = -1; break; }
     uint8_t* raw = out + pos;
     put_u32(raw, (uint32_t)n);
-    raw[4] = (uint8_t)klen; raw[5] = (uint8_t)(vlen & 0xFF);
-    raw[6] = seq32 ? 1 : 0; raw[7] = (uint8_t)(vlen >> 8);
+    raw[4] = (uint8_t)bklen; raw[5] = (uint8_t)(vlen & 0xFF);
+    raw[6] = (seq32 ? 1 : 0) | (klens ? 2 : 0); raw[7] = (uint8_t)(vlen >> 8);
     put_u64(raw + 8, 0);
     uint8_t* w = raw + 16;
     for (uint64_t k = 0; k < kw; k++)
       for (uint64_t i = 0; i < n; i++, w += 4)
         put_u32(w, kw_be[(start + i) * kw_cols + k]);
+    if (klens) {
+      uint64_t kl_bytes = 4 * ((n + 3) / 4);
+      for (uint64_t i = 0; i < n; i++) w[i] = (uint8_t)key_len[start + i];
+      memset(w + n, 0, kl_bytes - n);
+      w += kl_bytes;
+    }
     memcpy(w, seq_lo + start, 4 * n); w += 4 * n;
     if (!seq32) { memcpy(w, seq_hi + start, 4 * n); w += 4 * n; }
     uint64_t vt_bytes = 4 * ((n + 3) / 4);
@@ -1000,20 +1037,27 @@ extern "C" int64_t tsst_planar_encode_file(
 // Lane source: pread + inflate every block of one file and fill the
 // eight kernel lanes (the arrays tpu/format.py's Python decoders
 // return). `index` is (nblocks, 3) u64: offset, size, codec nibble.
-// planar != 0: PLANAR blocks, widths as the file's props give them.
-// planar == 0: entry-stream blocks of ONE uniform stride; *klen_io == 0
-// asks for the widths to be inferred from block 0, as
-// _infer_uniform_widths does. chk_mode 1 / 2: each block's poly1 (bytes)
+// planar != 0: PLANAR blocks, widths as the file's props give them (the
+// key width is the file's WIDEST key: a block's own may be narrower, and
+// with flag 2 each row has its own; key_len says it row by row).
+// planar == 0: entry-stream blocks, walked entry by entry, of ONE value
+// width. With *klen_io given (the sink's "uniform" prop) every key has
+// that length; *klen_io == 0 (no prop: a flush-written or foreign file)
+// takes the value width from block 0's first entry and keys of ANY
+// length from 1 to 24 bytes, each row its own (key_len says it).
+// chk_mode 1 / 2: each block's poly1 (bytes)
 // / poly1w (plane words) value over `chk_len`, into blk_chk: the caller
 // holds them against the file's block_chk prop.
 //
 // Returns the rows decoded, or
 //   -1  a read failed or came up short          (*err_block: which)
 //   -2  a block is corrupt: inflate, layout, codec
-//   -3  widths drift / no uniform stride: not lanes, the tuple path's
+//   -3  widths drift (a value width other than the file's, a key that
+//       the lanes cannot hold or the prop did not promise, an entry
+//       that runs past its block): not lanes, the tuple path's
 //   -4  more rows than row_cap
-//   -5  inferred widths need another val_cols: *klen_io / *vlen_io are
-//       filled, call again with them
+//   -5  the inferred value width needs another val_cols: *vlen_io is
+//       filled, call again with it
 extern "C" int64_t tsst_decode_file_lanes(
     int32_t fd, const uint64_t* index, uint64_t nblocks, int32_t planar,
     uint32_t* klen_io, uint32_t* vlen_io, uint64_t row_cap,
@@ -1025,6 +1069,7 @@ extern "C" int64_t tsst_decode_file_lanes(
     int64_t* err_block) {
   uint32_t klen = *klen_io, vlen = *vlen_io;
   bool infer = !planar && klen == 0;
+  bool one_klen = !infer;  // the prop's promise: every key klen bytes
   if (!infer && (klen == 0 || klen > 24)) return -2;
   ChkPowers pw;
   BlockFetcher fetcher;
@@ -1043,38 +1088,42 @@ extern "C" int64_t tsst_decode_file_lanes(
       uint64_t n = get_u32(raw);
       uint32_t bklen = raw[4];
       uint32_t bvlen = (uint32_t)raw[5] | ((uint32_t)raw[7] << 8);
-      bool seq32 = raw[6] & 1;
+      bool seq32 = raw[6] & 1, klens = raw[6] & 2;
       if (bklen == 0 || bklen > 24) { rc = -2; break; }
       uint64_t kw = (bklen + 3) / 4, vw = ((uint64_t)bvlen + 3) / 4;
-      uint64_t words = planar_plane_words(n, kw, vw, seq32);
+      uint64_t words = planar_plane_words(n, kw, vw, seq32, klens);
       if (raw_len != 16 + 4 * words) { rc = -2; break; }
       if (chk_mode == 2)
         blk_chk[bi] = chk_words(raw + 16, words, chk_len, &pw);
-      if (bklen != klen || bvlen != vlen || vw > val_cols) {
+      if (bklen > klen || bvlen != vlen || vw > val_cols) {
         rc = -3; break;
       }
       if (row + n > row_cap) { rc = -4; break; }
       const uint8_t* kwp = raw + 16;
-      const uint8_t* slo = kwp + 4 * kw * n;
+      const uint8_t* klp = klens ? kwp + 4 * kw * n : nullptr;
+      const uint8_t* slo = kwp + 4 * kw * n + (klens ? 4 * ((n + 3) / 4) : 0);
       const uint8_t* shi = seq32 ? nullptr : slo + 4 * n;
       const uint8_t* vtp = slo + 4 * n * (seq32 ? 1 : 2);
       const uint8_t* vvp = vtp + 4 * ((n + 3) / 4);
-      // bytes of the last key word past klen are not key: zeroed, as
-      // decode_planar_block's key_buf[:, :klen] leaves them
-      uint32_t tail_mask = (klen % 4)
-          ? 0xFFFFFFFFu << (8 * (4 - klen % 4)) : 0xFFFFFFFFu;
       for (uint64_t i = 0; i < n; i++) {
         uint64_t r = row + i;
+        uint32_t ek = klp ? klp[i] : bklen;  // the row's own key length
+        if (ek == 0 || ek > bklen) { rc = -2; break; }
+        // bytes of a key word past the key's length are not key: zeroed,
+        // as decode_planar_block leaves them
+        uint64_t ekw = (ek + 3) / 4;
+        uint32_t tail_mask = (ek % 4)
+            ? 0xFFFFFFFFu << (8 * (4 - ek % 4)) : 0xFFFFFFFFu;
         for (uint64_t k = 0; k < 6; k++) {
           uint32_t be = 0;
-          if (k < kw) {
+          if (k < ekw) {
             be = get_u32(kwp + 4 * (k * n + i));
-            if (k == kw - 1) be &= tail_mask;
+            if (k == ekw - 1) be &= tail_mask;
           }
           kw_be[r * 6 + k] = be;
           kw_le[r * 6 + k] = __builtin_bswap32(be);
         }
-        key_len[r] = klen;
+        key_len[r] = ek;
         seq_lo[r] = get_u32(slo + 4 * i);
         seq_hi[r] = shi ? get_u32(shi + 4 * i) : 0u;
         uint32_t vt = vtp[i];
@@ -1084,54 +1133,55 @@ extern "C" int64_t tsst_decode_file_lanes(
           val_words[r * val_cols + k] =
               k < vw ? get_u32(vvp + 4 * (k * n + i)) : 0u;
       }
+      if (rc < 0) break;
       row += n;
       continue;
     }
 
     if (chk_mode == 1) blk_chk[bi] = chk_bytes(raw, raw_len, chk_len, &pw);
     if (infer) {
-      // _infer_uniform_widths over block 0
+      // the value width of block 0's first entry is the file's
       if (raw_len < 17) { rc = -3; break; }
-      klen = get_u32(raw);
-      if (klen == 0 || klen > 24 || raw_len < 17 + (uint64_t)klen) {
+      uint32_t k0 = get_u32(raw);
+      if (k0 == 0 || k0 > 24 || raw_len < 17 + (uint64_t)k0) {
         rc = -3; break;
       }
-      vlen = get_u32(raw + klen + 13);
-      if (raw_len % (17 + (uint64_t)klen + vlen)) { rc = -3; break; }
+      vlen = get_u32(raw + k0 + 13);
       infer = false;
-      *klen_io = klen; *vlen_io = vlen;
+      *vlen_io = vlen;
     }
     uint64_t need_cols = std::max<uint64_t>(2, ((uint64_t)vlen + 3) / 4);
     if (need_cols != val_cols) { rc = -5; break; }
-    uint64_t stride = 17 + (uint64_t)klen + vlen;
-    if (raw_len % stride) { rc = -3; break; }
-    uint64_t n = raw_len / stride;
-    if (row + n > row_cap) { rc = -4; break; }
-    for (uint64_t i = 0; i < n; i++) {
-      const uint8_t* e = raw + i * stride;
-      if (get_u32(e) != klen || get_u32(e + klen + 13) != vlen) {
+    // entry: u32 klen | key | u64 seq | u8 vtype | u32 vlen | value
+    for (uint64_t pos = 0; pos < raw_len; row++) {
+      const uint8_t* e = raw + pos;
+      if (raw_len - pos < 17) { rc = -3; break; }
+      uint32_t ek = get_u32(e);
+      if (ek == 0 || ek > 24 || (one_klen && ek != klen)
+          || raw_len - pos < 17 + (uint64_t)ek + vlen
+          || get_u32(e + ek + 13) != vlen) {
         rc = -3; break;
       }
-      uint64_t r = row + i;
+      if (row >= row_cap) { rc = -4; break; }
       uint8_t key[24];
       memset(key, 0, 24);
-      memcpy(key, e + 4, klen);
+      memcpy(key, e + 4, ek);
       for (int k = 0; k < 6; k++) {
         uint32_t le = get_u32(key + 4 * k);
-        kw_le[r * 6 + k] = le;
-        kw_be[r * 6 + k] = __builtin_bswap32(le);
+        kw_le[row * 6 + k] = le;
+        kw_be[row * 6 + k] = __builtin_bswap32(le);
       }
-      key_len[r] = klen;
-      uint64_t seq = get_u64(e + 4 + klen);
-      seq_hi[r] = (uint32_t)(seq >> 32);
-      seq_lo[r] = (uint32_t)seq;
-      vtype[r] = e[klen + 12];
-      val_len[r] = vlen;
-      uint32_t* vdst = val_words + r * val_cols;
+      key_len[row] = ek;
+      uint64_t seq = get_u64(e + 4 + ek);
+      seq_hi[row] = (uint32_t)(seq >> 32);
+      seq_lo[row] = (uint32_t)seq;
+      vtype[row] = e[ek + 12];
+      val_len[row] = vlen;
+      uint32_t* vdst = val_words + row * val_cols;
       memset(vdst, 0, 4 * (size_t)val_cols);
-      memcpy(vdst, e + klen + 17, vlen);
+      memcpy(vdst, e + ek + 17, vlen);
+      pos += 17 + (uint64_t)ek + vlen;
     }
-    row += n;
   }
   return rc < 0 ? rc : (int64_t)row;
 }

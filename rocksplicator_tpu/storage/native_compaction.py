@@ -8,7 +8,8 @@ The engine's default backend. Two faces:
   (measured: tuple-interface numpy path 4× slower than heapq).
 - ``merge_runs_to_files``: the DIRECT sink. When every input run reads
   as lanes (sink-written planar/uniform TSSTs decode straight to
-  arrays) and widths are uniform, the merge runs as
+  arrays) and the values are of one width (keys of 1 to 24 bytes, of
+  one length or mixed: ``lanes_decline_reason``), the merge runs as
   ``cpu_merge_resolve`` (storage/native C when loaded, numpy
   otherwise), blooms build in bulk with no per-key Python, and outputs
   write as PLANAR files via the vectorized array writer — no per-entry
@@ -32,6 +33,7 @@ import numpy as np
 
 from .compaction import CpuCompactionBackend
 from .merge import MergeOperator, UInt64AddOperator
+from .planar import PLANAR_MAX_KLEN, key_shape
 
 log = logging.getLogger(__name__)
 
@@ -187,6 +189,8 @@ class NativeCompactionBackend(CpuCompactionBackend):
 
     @staticmethod
     def _bulk_bloom(sub: dict, n: int, klen0: int, bits_per_key: int):
+        """One file's host bloom from its rows, each key at its own
+        length (``klen0``: the rows' widest)."""
         from .bloom import BloomFilter
 
         kb = (
@@ -297,9 +301,16 @@ def lanes_decline_reason(lanes: dict,
 
     - ``merge_without_operator``: MERGE records and no operator (only
       the tuple path keeps an unresolved operand chain);
-    - ``key_width`` / ``value_width_mixed``: the PLANAR sink needs one
-      key width and one non-delete value width (kept tombstones are
-      fine: the layout derives val_len from vtype);
+    - ``key_width``: a key the lanes cannot hold (empty, or over
+      ``PLANAR_MAX_KLEN`` = 24 bytes). Keys of DIFFERING length, 1 to 24
+      bytes mixed in any proportion, are taken: the lanes carry each
+      row's ``key_len``, the order everywhere is the zero-padded
+      big-endian key words and then the length (the bytewise order),
+      and a PLANAR block whose rows differ carries a key-length plane
+      (storage/planar.py);
+    - ``value_width_mixed``: the PLANAR sink needs one non-delete value
+      width (kept tombstones are fine: the layout derives val_len from
+      vtype);
     - ``uint64add_width``: the uint64-add RESOLUTION assumes 8-byte
       values: the fold rewrites every PUT segment to the operand sum,
       and a non-8-byte PUT parses as 0 (stream semantics only invoke
@@ -308,7 +319,7 @@ def lanes_decline_reason(lanes: dict,
     if merge_op is None and bool((lanes["vtype"] == _MERGE).any()):
         return "merge_without_operator"
     kl = lanes["key_len"]
-    if len(kl) and not (kl == kl[0]).all():
+    if len(kl) and not 0 < int(kl.min()) <= int(kl.max()) <= PLANAR_MAX_KLEN:
         return "key_width"
     non_del_vlens = lanes["val_len"][lanes["vtype"] != _DELETE]
     if len(non_del_vlens) and not (
@@ -333,9 +344,12 @@ def write_resolved_lanes(
     own ``n`` rows (default, and where it gives None: the host bulk
     bloom; the device doors build theirs on the device). ``io_budget``
     (compaction callers only) throttles after each output file so
-    compaction IO yields to foreground fsyncs. Each file's write is a ``tpu.planar.write`` span
-    (``rows``), under ``trace`` where the caller's thread carries no
-    trace context of its own (a pool thread)."""
+    compaction IO yields to foreground fsyncs. Each file's write is a
+    ``tpu.planar.write`` span (``rows``; ``key_widths`` ``uniform`` /
+    ``mixed`` and ``key_bytes_max`` of the file's own rows), under
+    ``trace`` where the caller's thread carries no trace context of its
+    own (a pool thread). Rows whose keys differ in length size their
+    files and blocks by their widest key."""
     from ..observability.span import start_span
     from ..tpu.format import planar_stride, planar_widths, \
         write_sst_from_arrays
@@ -343,8 +357,8 @@ def write_resolved_lanes(
     widths = planar_widths(arrays, count)
     if widths is None:
         return None
-    klen0, vlen0 = widths
-    stride = planar_stride(klen0, vlen0)
+    klen0, vlen0, _mixed = widths
+    stride = planar_stride(*widths)
     entries_per_file = max(1024, target_file_bytes // max(1, stride))
     block_entries = max(64, block_bytes // max(1, stride))
 
@@ -372,7 +386,7 @@ def write_resolved_lanes(
                 bloom_words = host_bloom(sub, end - start)
             path = path_factory()
             with start_span("tpu.planar.write", remote=trace,
-                            rows=end - start):
+                            rows=end - start, **key_shape(sub["key_len"])):
                 props = write_sst_from_arrays(
                     sub, end - start, path,
                     bloom_words=bloom_words,
@@ -415,10 +429,18 @@ def write_resolved_lanes(
 # in boundary order and install atomically as ONE generation.
 
 
+def shard_klen(lanes: dict) -> int:
+    """The ``klen`` every key-range cut below takes: the rows' one key
+    length, or 0 where their keys differ in length (each row then has
+    its own: ``key_len``)."""
+    kl = lanes["key_len"]
+    return int(kl[0]) if len(kl) and bool((kl == kl[0]).all()) else 0
+
+
 def _part_key(part: dict, i: int, klen: int) -> bytes:
-    """Key bytes of row ``i`` (uniform width ``klen`` — guaranteed by
-    lanes_decline_reason before slicing is attempted)."""
-    return part["key_words_be"][i].astype(">u4").tobytes()[:klen]
+    """Key bytes of row ``i`` (``klen`` as ``shard_klen`` gives it)."""
+    return part["key_words_be"][i].astype(">u4").tobytes()[
+        :klen or int(part["key_len"][i])]
 
 
 def _first_row_ge(part: dict, key: bytes, klen: int) -> int:
@@ -481,9 +503,7 @@ def _bounded_boundaries(parts: List[dict], total: int, nslices: int,
     the k-way merge's own check (``_run_is_sorted``) is fifty calls a
     run over strided lanes, each handing the GIL round, which with
     eight shards cut at once costs more than the cut."""
-    words = (klen + 3) // 4
-    runs = [np.ascontiguousarray(p["key_words_be"][:, :words].astype(">u4"))
-            .view(f"S{4 * words}").ravel() for p in parts]
+    runs = [_order_strings(p, klen) for p in parts]
     if not all(bool((r[1:] >= r[:-1]).all()) for r in runs):
         return []
     keys = np.sort(np.concatenate(runs), kind="stable")
@@ -513,7 +533,29 @@ def _bounded_boundaries(parts: List[dict], total: int, nslices: int,
         want = int(np.searchsorted(pos, (i * total) // n, side="right")) - 1
         prev = min(max(want, lo), hi)
         cuts.append(prev)
-    return [keys[pos[g]:pos[g] + 1].tobytes()[:klen] for g in cuts]
+    # a string of differing lengths ends in its key's length
+    return [raw[:klen or raw[-1]] for raw in (
+        keys[pos[g]:pos[g] + 1].tobytes() for g in cuts)]
+
+
+def _order_strings(part: dict, klen: int) -> np.ndarray:
+    """A run's keys as fixed-size byte strings whose order (numpy's: the
+    zero-padded bytes) is the keys' bytewise order. One key length
+    (``klen``): the key words it fills. Differing lengths (``klen`` 0):
+    all 24 zero-padded key bytes and then the length as one byte more,
+    which breaks the tie between a key and the same key with NUL bytes
+    behind it."""
+    if klen:
+        words = (klen + 3) // 4
+        return np.ascontiguousarray(
+            part["key_words_be"][:, :words].astype(">u4")
+        ).view(f"S{4 * words}").ravel()
+    n = part["key_len"].shape[0]
+    out = np.empty((n, 4 * 6 + 1), dtype=np.uint8)
+    out[:, :24] = np.ascontiguousarray(
+        part["key_words_be"].astype(">u4")).view(np.uint8).reshape(n, 24)
+    out[:, 24] = part["key_len"]
+    return out.view("S25").ravel()
 
 
 def plan_subcompactions(parts: List[dict], total: int,
@@ -521,7 +563,10 @@ def plan_subcompactions(parts: List[dict], total: int,
                         max_slice_rows: Optional[int] = None) -> List[bytes]:
     """Boundary keys for this compaction, or [] to run unsliced: THE
     planner of every key-range cut (the CPU sink's subcompactions and
-    both device doors). Two rules, either or both:
+    both device doors). ``klen`` is ``shard_klen``'s: the rows' one key
+    length, or 0 where they differ; a boundary is a KEY of whatever
+    length its row has, and the order is the bytewise one either way.
+    Two rules, either or both:
 
     - parallelism (``max_subcompactions`` > 1): that many slices where
       every slice would clear MIN_SLICE_ENTRIES, at sampled quantiles of
@@ -714,8 +759,7 @@ def direct_merge_runs_to_files(
         mem_tracker.add(inram_bytes)
     try:
         if max_subcompactions > 1:
-            kl = lanes["key_len"]
-            klen = int(kl[0]) if len(kl) else 0
+            klen = shard_klen(lanes)
             bounds = plan_subcompactions(
                 parts, total, max_subcompactions, klen)
             if bounds:

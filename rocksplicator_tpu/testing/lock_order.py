@@ -18,7 +18,7 @@ runtime's dynamic cycle detection.
 # construction site (repo-relative file:line) -> (name, rank)
 RANKS = {
     "rocksplicator_tpu/replication/ack_window.py:127": ('AckWindow._cond', 0),
-    "rocksplicator_tpu/admin/handler.py:161": ('AdminHandler._db_admin_lock', 1),
+    "rocksplicator_tpu/admin/handler.py:182": ('AdminHandler._db_admin_lock', 1),
     "rocksplicator_tpu/admin/ingest_pipeline.py:157": ('BatchCompactor._lock', 2),
     "rocksplicator_tpu/storage/sst.py:99": ('BlockCache._instance_lock', 3),
     "rocksplicator_tpu/storage/sst.py:103": ('BlockCache._lock', 4),
@@ -29,7 +29,7 @@ RANKS = {
     "rocksplicator_tpu/storage/stream_merge.py:131": ('CompactionMemoryBudget._lock', 9),
     "rocksplicator_tpu/utils/rate_limiter.py:25": ('ConcurrentRateLimiter._lock', 10),
     "rocksplicator_tpu/cluster/coordinator.py:303": ('CoordinatorServer._snapshot_mutex', 11),
-    "rocksplicator_tpu/storage/engine.py:277": ('DB._compaction_mutex', 12),
+    "rocksplicator_tpu/storage/engine.py:280": ('DB._compaction_mutex', 12),
     "rocksplicator_tpu/utils/dbconfig.py:48": ('DBConfigManager._instance_lock', 13),
     "rocksplicator_tpu/cluster/publishers.py:69": ('DedupPublisher._lock', 14),
     "rocksplicator_tpu/utils/concurrent_map.py:22": ('FastReadMap._write_lock', 15),
@@ -70,18 +70,18 @@ RANKS = {
     "rocksplicator_tpu/rpc/admission.py:115": ('TenantAdmission._instance_lock', 50),
     "rocksplicator_tpu/rpc/admission.py:125": ('TenantAdmission._lock', 51),
     "rocksplicator_tpu/rpc/admission.py:67": ('TokenBucket._lock', 52),
-    "rocksplicator_tpu/tpu/compaction_service.py:72": ('TpuCompactionService._instance_lock', 53),
+    "rocksplicator_tpu/tpu/compaction_service.py:73": ('TpuCompactionService._instance_lock', 53),
     "rocksplicator_tpu/storage/archive.py:63": ('WalArchiver._mutex', 54),
     "rocksplicator_tpu/testing/failpoints.py:129": ('_Site.lock', 55),
     "rocksplicator_tpu/utils/stats.py:200": ('_ThreadBuffer.lock', 56),
     "rocksplicator_tpu/kafka/broker.py:204": ('kafka.broker:_clusters_lock', 57),
-    "rocksplicator_tpu/storage/native/binding.py:646": ('storage.native.binding:_native_lock', 58),
+    "rocksplicator_tpu/storage/native/binding.py:656": ('storage.native.binding:_native_lock', 58),
     "rocksplicator_tpu/testing/failpoints.py:161": ('testing.failpoints:_lock', 59),
     "rocksplicator_tpu/utils/objectstore.py:379": ('utils.objectstore:_store_cache_lock', 60),
     "rocksplicator_tpu/admin/db_manager.py:20": ('ApplicationDBManager._lock', 61),
     "rocksplicator_tpu/cluster/coordinator.py:296": ('CoordinatorServer._lock', 62),
-    "rocksplicator_tpu/storage/engine.py:248": ('DB._lock', 63),
-    "rocksplicator_tpu/storage/engine.py:284": ('DB._manifest_mutex', 64),
+    "rocksplicator_tpu/storage/engine.py:251": ('DB._lock', 63),
+    "rocksplicator_tpu/storage/engine.py:287": ('DB._manifest_mutex', 64),
     "rocksplicator_tpu/utils/file_watcher.py:40": ('FileWatcher._instance_lock', 65),
     "rocksplicator_tpu/cluster/participant.py:75": ('Participant._state_lock', 66),
     "rocksplicator_tpu/utils/stats.py:218": ('Stats._lock', 67),
@@ -91,15 +91,15 @@ RANKS = {
 
 # static partial order: (acquired-first, acquired-second)
 ORDER = {
-    ("rocksplicator_tpu/admin/handler.py:161", "rocksplicator_tpu/admin/db_manager.py:20"),
+    ("rocksplicator_tpu/admin/handler.py:182", "rocksplicator_tpu/admin/db_manager.py:20"),
     ("rocksplicator_tpu/cluster/coordinator.py:303", "rocksplicator_tpu/cluster/coordinator.py:296"),
     ("rocksplicator_tpu/cluster/participant.py:76", "rocksplicator_tpu/cluster/participant.py:75"),
-    ("rocksplicator_tpu/storage/engine.py:248", "rocksplicator_tpu/storage/compaction_scheduler.py:123"),
-    ("rocksplicator_tpu/storage/engine.py:248", "rocksplicator_tpu/storage/wal.py:69"),
-    ("rocksplicator_tpu/storage/engine.py:277", "rocksplicator_tpu/storage/compaction_scheduler.py:123"),
-    ("rocksplicator_tpu/storage/engine.py:277", "rocksplicator_tpu/storage/engine.py:248"),
-    ("rocksplicator_tpu/storage/engine.py:277", "rocksplicator_tpu/storage/engine.py:284"),
-    ("rocksplicator_tpu/storage/engine.py:277", "rocksplicator_tpu/storage/wal.py:69"),
+    ("rocksplicator_tpu/storage/engine.py:251", "rocksplicator_tpu/storage/compaction_scheduler.py:123"),
+    ("rocksplicator_tpu/storage/engine.py:251", "rocksplicator_tpu/storage/wal.py:69"),
+    ("rocksplicator_tpu/storage/engine.py:280", "rocksplicator_tpu/storage/compaction_scheduler.py:123"),
+    ("rocksplicator_tpu/storage/engine.py:280", "rocksplicator_tpu/storage/engine.py:251"),
+    ("rocksplicator_tpu/storage/engine.py:280", "rocksplicator_tpu/storage/engine.py:287"),
+    ("rocksplicator_tpu/storage/engine.py:280", "rocksplicator_tpu/storage/wal.py:69"),
     ("rocksplicator_tpu/utils/dbconfig.py:48", "rocksplicator_tpu/utils/file_watcher.py:40"),
     ("rocksplicator_tpu/utils/stats.py:240", "rocksplicator_tpu/utils/stats.py:218"),
 }

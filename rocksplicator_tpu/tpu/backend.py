@@ -78,8 +78,9 @@ def device_decline_reason(lanes: Optional[dict],
     - ``value_width``: a value wider than ``device_value_bytes_max``
       (each door counts it under ``tpu.host_fallbacks reason=value_width``);
     - what no array path expresses (``lanes_decline_reason``):
-      ``merge_without_operator``, ``key_width``, ``value_width_mixed``,
-      ``uint64add_width``.
+      ``merge_without_operator``, ``key_width`` (a key over 24 bytes;
+      keys of differing length up to that are taken),
+      ``value_width_mixed``, ``uint64add_width``.
 
     ``lanes=None`` asks about the operator alone (a plan costs a flush)."""
     limit = device_value_bytes_max(merge_operator)
@@ -357,11 +358,10 @@ class TpuCompactionBackend(CompactionBackend):
         boundary order — identical logical output to the single-shot
         kernel — or None to take the unsliced path."""
         from ..storage.native_compaction import (plan_subcompactions,
-                                                 slice_lanes)
+                                                 shard_klen, slice_lanes)
         from .compaction_service import resolve_slices_batched
 
-        kl = lanes["key_len"]
-        klen = int(kl[0]) if len(kl) else 0
+        klen = shard_klen(lanes)
         bounds = plan_subcompactions(parts, total, max_subcompactions, klen)
         if not bounds:
             return None

@@ -43,8 +43,9 @@ from ..ops.kv_format import KEY_WORDS, KVBatch, fast_flags, unpack_entries
 from ..storage.compaction import record_host_fallback
 from ..storage.native_compaction import (KeyGroupOverSlice,
                                          plan_subcompactions,
-                                         read_runs_as_lanes, slice_lanes,
-                                         write_resolved_lanes)
+                                         read_runs_as_lanes, shard_klen,
+                                         slice_lanes, write_resolved_lanes)
+from ..storage.planar import PLANAR_MAX_KLEN, key_shape
 from ..testing import failpoints as fp
 from ..utils.stats import Stats
 from .backend import (TpuCompactionBackend, _device_bloom_builder,
@@ -227,7 +228,15 @@ class TpuCompactionService:
         index = path == "index"
         span = current_span()  # tpu.compact_stream / tpu.compact_batch
         if span is not None:
-            span.annotate(val_words=val_words, value_path=path)
+            # key_widths: ``mixed`` where some place's keys differ in
+            # length (the launch then carries the key-length lane:
+            # ``uniform_klen=False``), key_bytes_max the longest key
+            span.annotate(
+                val_words=val_words, value_path=path,
+                key_widths="uniform" if uniform_klen else "mixed",
+                key_bytes_max=max(
+                    (int(b.key_len.max()) for b in batches if b.capacity),
+                    default=0))
         Stats.get().incr("compact.value_path." + path, len(batches))
         fn = self._pipeline(merge_kind, drop_tombstones, num_words,
                             uniform_klen, seq32, key_words, val_words)
@@ -494,6 +503,19 @@ MAX_BATCHED_DB_ENTRIES = 1 << 20
 PLACE_ROWS_MAX = 1 << 15
 
 
+def device_mixed_key_bytes_max(merge_operator) -> int:
+    """The longest key, in bytes, of a shard of DIFFERING key lengths
+    that the served door (``compact_dbs_batched``) compacts on the
+    device for a DB with this merge operator: the lanes' key width
+    (``lanes_decline_reason`` declines a longer key with ``key_width``);
+    0 where the door takes no shard with this operator. Asked by a
+    deployment's driver before it builds anything
+    (chipbench/drivers/refresh_names.py)."""
+    if device_decline_reason(None, merge_operator) is not None:
+        return 0
+    return PLANAR_MAX_KLEN
+
+
 def device_shard_rows_max(merge_operator) -> int:
     """The most rows of ONE shard that the served door
     (``compact_dbs_batched``) compacts on the device for a DB with this
@@ -652,14 +674,21 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
     ``device_value_bytes_max(None)`` bytes take the index path (one
     row-index lane rides, the values are moved once inside the same
     module; ``value_path`` on the ``tpu.compact_stream`` span says
-    which). DBs the device path can't express (``device_decline_reason``
-    says which and why: custom merge operators, values over
-    ``device_value_bytes_max``, MERGE records with no operator, keys or
-    values of more than one width; besides >24B keys and shards of more
-    than ``MAX_BATCHED_DB_ENTRIES`` rows, which the lane read declines)
-    are declined untouched, before
-    any program is built; a decline for width counts under
-    ``tpu.host_fallbacks reason=value_width``.
+    which). Keys: 1 to 24 bytes, of one length or mixed in any
+    proportion (``device_mixed_key_bytes_max``): a launch any place of
+    which has keys of differing length carries the key-length lane
+    (``uniform_klen=False``: a sort key after the key words, a boundary
+    compare, an output lane; one more program a capacity bucket), and
+    ``key_widths`` on ``tpu.compact_stream`` / ``tpu.lanes.decode`` /
+    ``tpu.planar.write`` says ``uniform`` or ``mixed``, ``Stats``
+    ``compact.key_widths.uniform`` / ``.mixed`` count the shards. DBs
+    the device path can't express (``device_decline_reason`` says which
+    and why: custom merge operators, values over
+    ``device_value_bytes_max``, MERGE records with no operator, values
+    of more than one width; besides >24B keys and shards of more than
+    ``MAX_BATCHED_DB_ENTRIES`` rows, which the lane read declines) are
+    declined untouched, before any program is built; a decline for
+    width counts under ``tpu.host_fallbacks reason=value_width``.
 
     Returns ``(handled, remaining)``: db names compacted here, and the
     (name, db) pairs the caller must compact per-db (compact_range).
@@ -740,7 +769,8 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
                     plan["runs"], None, max_entries=MAX_BATCHED_DB_ENTRIES,
                     value_rows=value_rows)
                 if read is not None:
-                    lsp.annotate(rows=read[2])
+                    shape = key_shape(read[1]["key_len"])
+                    lsp.annotate(rows=read[2], **shape)
         except BaseException:
             log.exception(
                 "lane read failed for %s; declining to per-db", name)
@@ -760,6 +790,9 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
                     f"{name}: {int(lanes['val_len'].max())}-byte values")
             _abort(db, plan)
             return ("remaining", name, db, None)
+        # shards through the door by key shape: one key length, or
+        # lengths that differ (``tpu.compact_stream`` says which launch)
+        Stats.get().incr("compact.key_widths." + shape["key_widths"])
         # index-path shards group by their width as well: their values
         # go up as they are, never padded to a wider neighbour's
         index = value_path(kind, vw) == "index"
@@ -793,7 +826,7 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
         buffer a place, at the place's capacity). Raises where no cut
         at keys fits (counted: one key group is over a place) or the
         runs are not sorted."""
-        klen = int(lanes["key_len"][0])  # one width: the rule above
+        klen = shard_klen(lanes)  # 0: the keys differ in length
         with start_span("tpu.range_cut", remote=tctx, rows=total,
                         capacity=PLACE_ROWS_MAX) as sp:
             try:

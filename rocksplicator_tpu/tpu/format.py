@@ -6,6 +6,15 @@ end-to-end time. For uniform-width rows (the counter workload and most
 fixed-schema KV), the block bytes assemble as ONE numpy matrix fill — no
 per-entry Python — and the TPU-built bloom bitmap writes straight into the
 file (byte-identical format, so readers can't tell).
+
+The PLANAR sink and source (``write_sst_from_arrays(planar=True)``,
+``read_sst_arrays``) also take rows whose KEYS differ in length, 1 to
+``PLANAR_MAX_KLEN`` bytes mixed in any proportion (counter names
+``counter-<n>``): ``planar_widths`` then says ``mixed``, a block whose
+rows differ carries a key-length plane behind a header flag
+(storage/planar.py), the file's ``planar`` prop gives its widest key
+and a fourth member 1. Rows of one key length write the file they
+always wrote, byte for byte. Values stay of one width a file.
 """
 
 from __future__ import annotations
@@ -19,8 +28,9 @@ from ..observability.context import current_span
 from ..storage.bloom import BloomFilter
 from ..storage.errors import Corruption
 from ..storage.native.binding import get_file_codecs
-from ..storage.planar import (PLANAR_MAX_VLEN, decode_planar_block,
-                              encode_planar_block, plane_words, planar_props)
+from ..storage.planar import (PLANAR_MAX_KLEN, PLANAR_MAX_VLEN,
+                              decode_planar_block, encode_planar_block,
+                              plane_words, planar_props, planar_props_mixed)
 from ..storage import rlz
 from ..storage.sst import (BLOCK_PLANAR, BLOCK_PLANAR_RLZ,
                            BLOCK_PLANAR_ZLIB, COMPRESSION_NONE,
@@ -130,7 +140,9 @@ def _read_lanes_native(reader, planar: bool):
     into the lanes, each block's checksum). ``_NOT_TAKEN`` when there is
     no library or the file is not one it reads (unknown block codec,
     props it does not understand): the Python source decides about
-    those. None on width drift (the tuple path's file). Raises
+    those. None on width drift (a value width other than the file's,
+    a block key wider than the file's widest: the tuple path's file;
+    keys of differing length are lanes like any other). Raises
     Corruption for a block that does not inflate, does not fit its
     layout, or fails its ``block_chk`` value."""
     lib = get_file_codecs()
@@ -257,6 +269,10 @@ class SstBlockLaneSource:
                 return None
             if not (0 < klen <= 24) or vlen < 0:
                 return None
+            if planar_props_mixed(p):
+                # the chunked merge cuts its windows at keys of ONE
+                # width: a file of differing key lengths is read whole
+                return None
             return cls(reader, "planar", klen, vlen)
         widths = props.get("uniform")
         if widths:
@@ -375,21 +391,25 @@ def _decode_uniform_rows(raw: bytes, klen: int,
     }
 
 
-def planar_stride(klen: int, vlen: int) -> int:
+def planar_stride(klen: int, vlen: int, mixed: bool = False) -> int:
     """Approximate PLANAR bytes per entry (seq32 layout: key + seq_lo +
-    vtype + value) — block/file sizing only, shared by every sink."""
-    return klen + vlen + 9
+    vtype + value; ``mixed``: the key at the rows' widest, and its
+    length byte) — block/file sizing only, shared by every sink."""
+    return klen + vlen + 9 + bool(mixed)
 
 
 def planar_widths(arrays: Dict[str, np.ndarray], count: int):
-    """(klen, vlen) for the PLANAR sink. Laxer than uniform_widths:
-    DELETE rows carry no value in the planar layout (val_len derives from
-    vtype on read), so kept tombstones coexist with fixed-width values."""
+    """(klen, vlen, mixed) for the PLANAR sink, or None where the layout
+    can't express the rows. ``klen`` is the rows' widest key, ``mixed``
+    whether their keys differ in length (1 to ``PLANAR_MAX_KLEN`` bytes
+    each, in any proportion). Laxer than uniform_widths: DELETE rows
+    carry no value in the planar layout (val_len derives from vtype on
+    read), so kept tombstones coexist with fixed-width values."""
     if count == 0:
         return None
     kl = arrays["key_len"][:count]
-    k0 = int(kl[0])
-    if not ((kl == k0).all() and 0 < k0 <= 24):
+    k0, k1 = int(kl.min()), int(kl.max())
+    if not 0 < k0 <= k1 <= PLANAR_MAX_KLEN:
         return None
     vt = arrays["vtype"][:count]
     vl = arrays["val_len"][:count]
@@ -404,33 +424,50 @@ def planar_widths(arrays: Dict[str, np.ndarray], count: int):
     # with values >= 256 B died in the header packer (VERDICT r2 #1).
     if v0 > PLANAR_MAX_VLEN:
         return None
-    return k0, v0
+    return k1, v0, k0 != k1
 
 
 def _key_rows(arrays: Dict[str, np.ndarray], rows, klen: int) -> np.ndarray:
-    """(len(rows), klen) u8: the key bytes of the given rows."""
+    """(len(rows), klen) u8: the key bytes of the given rows (a key
+    shorter than ``klen`` zero-padded, as its lanes are)."""
     kw = np.ascontiguousarray(arrays["key_words_be"][rows].astype(">u4"))
     return kw.view(np.uint8).reshape(len(kw), 24)[:, :klen]
+
+
+def _keys_of(arrays: Dict[str, np.ndarray], rows, klen: int,
+             mixed: bool) -> List[bytes]:
+    """The given rows' keys as bytes, each at its own length."""
+    flat = _key_rows(arrays, rows, klen).tobytes()
+    if not mixed:
+        return [flat[i:i + klen] for i in range(0, len(flat), klen)]
+    return [flat[i * klen:i * klen + n]
+            for i, n in enumerate(arrays["key_len"][rows].tolist())]
 
 
 def _write_planar(
     arrays: Dict[str, np.ndarray], count: int, path: str,
     bloom_words: Optional[np.ndarray], block_entries: int,
     compression: int, bits_per_key: int, klen: int, vlen: int,
+    mixed: bool,
     device_words: Optional[np.ndarray],
     device_checksums: Optional[np.ndarray],
 ) -> Optional[dict]:
     """PLANAR sink body: per-block plane bytes + word-domain checksums.
     Without device-encoded words the native library, where it is, makes
     every block in one call; else ``_planar_blocks`` does, block by
-    block. The file is the same."""
+    block. The file is the same. ``mixed``: the rows' keys differ in
+    length (``klen`` their widest); the device's block encoder knows
+    one key width, so its words are not taken then."""
     seq32 = bool((arrays["seq_hi"][:count] == 0).all())
-    full_words = plane_words(block_entries, klen, vlen, seq32)
+    full_words = plane_words(block_entries, klen, vlen, seq32, mixed)
+    if mixed:
+        device_words = device_checksums = None
     lib = get_file_codecs() if device_words is None else None
     encoded = None
     if lib is not None:
         encoded = lib.planar_encode_file(
-            arrays, count, klen, vlen, seq32, block_entries, compression)
+            arrays, count, klen, vlen, seq32, block_entries, compression,
+            mixed)
     _count_codec(encoded is not None)
     writer = SSTWriter(path, compression=compression,
                        bits_per_key=bits_per_key)
@@ -438,22 +475,21 @@ def _write_planar(
         if encoded is None:
             encoded = _planar_blocks(
                 arrays, count, block_entries, compression, klen, vlen,
-                seq32, full_words, device_words, device_checksums)
+                seq32, full_words, device_words, device_checksums, mixed)
         payload, offs, sizes, codecs, chks = encoded
         ends = np.minimum(
             np.arange(1, len(offs) + 1) * block_entries, count)
-        last_keys = _key_rows(arrays, ends - 1, klen).tobytes()
-        first_key = _key_rows(arrays, slice(0, 1), klen).tobytes()
+        last_keys = _keys_of(arrays, ends - 1, klen, mixed)
+        (first_key,) = _keys_of(arrays, np.arange(1), klen, mixed)
         seqs = (
             arrays["seq_hi"][:count].astype(np.uint64) << np.uint64(32)
         ) | arrays["seq_lo"][:count].astype(np.uint64)
         writer.add_encoded_blocks(
             payload,
-            [(last_keys[i * klen:(i + 1) * klen], off, size, codec)
-             for i, (off, size, codec) in enumerate(
-                 zip(offs.tolist(), sizes.tolist(), codecs.tolist()))],
+            list(zip(last_keys, offs.tolist(), sizes.tolist(),
+                     codecs.tolist())),
             num_entries=count, keys=[],
-            min_key=first_key, max_key=last_keys[-klen:],
+            min_key=first_key, max_key=last_keys[-1],
             min_seq=int(seqs.min()), max_seq=int(seqs.max()),
         )
         if bloom_words is not None:
@@ -463,10 +499,10 @@ def _write_planar(
         else:
             bloom = BloomFilter.build_from_arrays(
                 _key_rows(arrays, slice(0, count), klen),
-                np.full(count, klen, dtype=np.uint64), bits_per_key)
+                arrays["key_len"][:count].astype(np.uint64), bits_per_key)
         extra_props = {
             "num_keys": int(count),
-            "planar": planar_props(klen, vlen, seq32),
+            "planar": planar_props(klen, vlen, seq32, mixed),
             "block_chk": {
                 "algo": "poly1w",
                 "block_words": int(full_words),
@@ -484,7 +520,7 @@ def _planar_blocks(
     arrays: Dict[str, np.ndarray], count: int, block_entries: int,
     compression: int, klen: int, vlen: int, seq32: bool, full_words: int,
     device_words: Optional[np.ndarray],
-    device_checksums: Optional[np.ndarray],
+    device_checksums: Optional[np.ndarray], mixed: bool = False,
 ):
     """The Python block loop of the PLANAR sink, in the shape the native
     encoder returns: ``(payload, offsets, sizes, codecs, checksums)``.
@@ -511,7 +547,7 @@ def _planar_blocks(
                 chks.append(poly_checksum_words(words, full_words))
         else:
             raw = encode_planar_block(
-                arrays, start, end, klen, vlen, seq32)
+                arrays, start, end, klen, vlen, seq32, mixed)
             words = np.frombuffer(
                 raw, dtype="<u4", offset=PLANAR_HEADER.size)
             chks.append(poly_checksum_words(words, full_words))
@@ -574,7 +610,8 @@ def write_sst_from_arrays(
 
     ``planar=True`` writes PLANAR blocks (storage/planar.py): u32 planes
     in kernel lane order — smaller files and no byte interleaving on
-    either side. ``device_words`` optionally carries the device planar
+    either side; keys of 1 to 24 bytes, of one length or mixed
+    (``planar_widths``). ``device_words`` optionally carries the device planar
     encoder's (nblocks, words) matrix for full blocks (the tail block is
     host-packed: its plane lengths differ from the fixed device shape)."""
     if planar:
@@ -583,8 +620,7 @@ def write_sst_from_arrays(
             return None
         return _write_planar(
             arrays, count, path, bloom_words, block_entries, compression,
-            bits_per_key, widths[0], widths[1], device_words,
-            device_checksums)
+            bits_per_key, *widths, device_words, device_checksums)
     widths = uniform_widths(arrays, count)
     if widths is None:
         return None

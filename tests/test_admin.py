@@ -675,3 +675,25 @@ def test_backup_manager_wal_archive_and_admin_pitr(nodes, tmp_path, call):
     assert call(n, "get_sequence_number",
                 db_name="seg00002")["seq_num"] == mid_seq
     assert rdb.get(b"late0") is None  # beyond the restore point
+
+
+def test_the_process_base_is_frozen_once(tmp_path, monkeypatch):
+    """The first handler of a process moves what lives then to the
+    permanent generation (a full collection no longer walks the node's
+    modules: PERF.md section 6, PR 35); a second one freezes nothing."""
+    import gc
+
+    from rocksplicator_tpu.admin import handler as handler_mod
+
+    calls = []
+    monkeypatch.setattr(handler_mod, "_base_frozen", False)
+    monkeypatch.setattr(handler_mod.gc, "freeze", lambda: calls.append(1))
+    replicator = Replicator(port=0)
+    try:
+        for n in range(2):
+            handler_mod.AdminHandler(
+                str(tmp_path / f"h{n}"), replicator).close()
+    finally:
+        replicator.stop()
+    assert calls == [1] and handler_mod._base_frozen is True
+    assert gc.isenabled()

@@ -33,6 +33,11 @@ U32 = jnp.uint32
 # what fast_flags() gives for the counter data: one key length (16 B =
 # 4 BE words), every seq below 2^32
 FAST = dict(uniform_klen=True, seq32=True, key_words=4)
+# counter_names_64x15k's (PR 35): keys ``counter-<n>`` of 9 to 15 bytes in
+# one shard, so ``fast_flags`` gives ``uniform_klen=False`` (the key-length
+# lane: a sort key after the four key words, a boundary compare, an output
+# lane)
+NAMES = dict(uniform_klen=False, seq32=True, key_words=4)
 BITS_PER_KEY = 10  # examples/counter_service/options.py
 
 
@@ -84,16 +89,20 @@ def test_merge_resolve_lax_compiles(shape):
     assert compiled.memory_analysis() is not None
 
 
-def test_service_pipeline_group8_compiles(shape, monkeypatch):
+@pytest.mark.parametrize("flags", [FAST, NAMES],
+                         ids=["one_key_length", "key_length_lane"])
+def test_service_pipeline_group8_compiles(shape, monkeypatch, flags):
     """The batched post-load program (compact_dbs_batched): the service's
-    own vmapped merge-resolve + bloom pipeline at its group size 8."""
+    own vmapped merge-resolve + bloom pipeline at its group size 8, for
+    keys of one length and with the key-length lane (keys of differing
+    length: ``NAMES`` below)."""
     from rocksplicator_tpu.tpu.compaction_service import TpuCompactionService
 
     # the platform rule reads the env; conftest set it, keep it explicit
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     n = 2048
     fn = TpuCompactionService()._pipeline(
-        MergeKind.UINT64_ADD, True, num_words_for(n, BITS_PER_KEY), **FAST)
+        MergeKind.UINT64_ADD, True, num_words_for(n, BITS_PER_KEY), **flags)
     compiled = fn.lower(*_kernel_lanes(shape, n, lead=(8,))).compile()
     assert compiled.memory_analysis() is not None
 
@@ -126,18 +135,23 @@ def test_service_index_pipeline_group8_compiles(shape, monkeypatch):
     assert mem.output_size_in_bytes < values + values // 4
 
 
-# sha256 of the two cells' programs as lowered (the text the persistent
+# sha256 of the cells' programs as lowered (the text the persistent
 # compile cache is keyed on) at the cells' own shapes, group 8, capacity
 # 32,768: PR 31's readings, which PR 32 (values cross the seam on the pool
 # threads) had to leave as they were, to the byte. jax 0.9.0; after an
 # upgrade of jax, or a PR that MEANS to change a program, read them anew.
+# The third (PR 35): counter_names_64x15k's, with the key-length lane
+# (``NAMES`` above), values riding.
 CELL_PROGRAMS = {
     "counter_64x20k": (
-        MergeKind.UINT64_ADD, 2,
+        MergeKind.UINT64_ADD, 2, FAST,
         "86313af6e2a4f28e227ffa9171c4e8429c83d307fcfec4f7a552d2667a2d3827"),
     "rec1k_32x20k": (
-        MergeKind.NONE, 256,
+        MergeKind.NONE, 256, FAST,
         "b83ab464fc243d531ca85f7da0acfd0585f815ccfed7c0cff93dc1639083672b"),
+    "counter_names_64x15k": (
+        MergeKind.UINT64_ADD, 2, NAMES,
+        "93019a9146520f0b6b49f5f795dd92e609f1bdf421ea76b3199b8adc96908651"),
 }
 
 
@@ -153,7 +167,7 @@ def test_cell_program_text_is_the_accepted_one(cell, monkeypatch):
     from rocksplicator_tpu.tpu.compaction_service import TpuCompactionService
 
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    kind, words, accepted = CELL_PROGRAMS[cell]
+    kind, words, flags, accepted = CELL_PROGRAMS[cell]
     n, group = 32768, 8
 
     def lane(*dims, dtype=U32):
@@ -163,7 +177,7 @@ def test_cell_program_text_is_the_accepted_one(cell, monkeypatch):
     if value_path(kind, words) == "index":
         values = tuple(lane(n, words) for _ in range(group))
     fn = TpuCompactionService()._pipeline(
-        kind, True, num_words_for(n, BITS_PER_KEY), **FAST, val_words=words)
+        kind, True, num_words_for(n, BITS_PER_KEY), **flags, val_words=words)
     text = fn.lower(
         lane(group, n, 6), lane(group, n), lane(group, n), lane(group, n),
         lane(group, n), values, lane(group, n),

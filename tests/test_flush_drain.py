@@ -168,16 +168,20 @@ def test_flush_fallback_vlen_over_u16(tmp_path):
 
 
 def test_flush_fallback_non_uniform_widths(tmp_path):
+    """A value of another width falls back to the per-entry sink; a key
+    of another LENGTH does not any more (tests/test_mixed_key_widths.py):
+    the array flush takes it, and the file is the per-entry sink's
+    entries all the same."""
     for mutate in ("klen", "vlen"):
         mem = _mixed_mem(64)
         if mutate == "klen":
             mem.apply(b"short", 10_000, OpType.PUT, pack64(1))
         else:
             mem.apply(b"key%05d" % 1, 10_000, OpType.PUT, b"wide-value-16b!!")
-        assert mem.drain_lanes() is None
+        assert (mem.drain_lanes() is None) == (mutate == "vlen")
         sub = tmp_path / mutate
         sub.mkdir()
-        got_a, got_b = _flush_both(sub, mem, expect_planar=False)
+        got_a, got_b = _flush_both(sub, mem, expect_planar=mutate == "klen")
         assert got_a == got_b
 
 
@@ -226,12 +230,15 @@ def test_merged_memview_drain_parity(tmp_path):
     assert view.drain_lanes() is not None
     got_a, got_b = _flush_both(tmp_path, view, expect_planar=True)
     assert got_a == got_b
-    # a width mismatch in ANY memtable declines the whole view — both
-    # the key-width and the value-width flavor (each checked per-part
-    # BEFORE any pad/concat, so the bail is O(parts) not O(entries))
-    bad_k = MemTable()
-    bad_k.apply(b"odd-width-key", 9999, OpType.PUT, pack64(1))
-    assert _MergedMemView(mems + [bad_k]).drain_lanes() is None
+    # a VALUE width mismatch in ANY memtable declines the whole view
+    # (checked per-part BEFORE any pad/concat, so the bail is O(parts)
+    # not O(entries))
+    odd_k = MemTable()  # a key of another length is lanes like any other
+    odd_k.apply(b"odd-width-key", 9999, OpType.PUT, pack64(1))
+    lanes, key_mat = _MergedMemView(mems + [odd_k]).drain_lanes()
+    assert lanes["key_len"].tolist() == [11] * 300 + [13]
+    assert key_mat.shape == (301, 13) and bytes(key_mat[0]) == (
+        b"key00000000\0\0")
     bad_v = MemTable()
     bad_v.apply(b"key00000000", 9999, OpType.PUT, b"sixteen-byte-val")
     assert _MergedMemView(mems + [bad_v]).drain_lanes() is None
@@ -430,7 +437,7 @@ def test_install_full_compaction_arrays_empty_and_invalid(tmp_path):
         plan = db.plan_full_compaction()
         lanes = read_sst_arrays(db._readers[plan["inputs"][0]])
         lanes["key_len"] = lanes["key_len"].copy()
-        lanes["key_len"][0] = 5  # non-uniform → not planar-expressible
+        lanes["key_len"][0] = 25  # over the lanes' 24 bytes: no planar row
         with pytest.raises(InvalidArgument):
             db.install_full_compaction(
                 plan, arrays=(lanes, int(lanes["key_len"].shape[0])))

@@ -138,7 +138,7 @@ def test_planar_checksum_detects_corruption(tmp_path):
 
 def test_planar_widths_allows_tombstones_rejects_mixed():
     arrays, n = _arrays(_entries(50, with_deletes=True))
-    assert planar_widths(arrays, n) == (16, 8)
+    assert planar_widths(arrays, n) == (16, 8, False)
     # mixed non-delete value widths are not planar-expressible
     mixed, m = _arrays([
         (b"k" * 16, 2, OpType.PUT, b"12345678"),

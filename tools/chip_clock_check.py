@@ -52,7 +52,17 @@ the run's own and the last):
   (how many, their mean). Every batch a cell's client sends is of one
   stride, so ``bulk`` equals the served ``write`` RPCs and ``general``
   is 0 (a BUILT batch, as the admin plane's own metadata puts, is no
-  frame and counts under neither). ``--trace 0`` too.
+  frame and counts under neither). ``--trace 0`` too. (A batch of
+  counter NAMES has several strides: ``counter_names_64x15k.refresh``
+  reads ``general`` = every frame.)
+- **which key shape the shards had**: the process's
+  ``compact.key_widths.uniform`` / ``.mixed`` counters (shards through
+  the served door whose keys have one length / differ in length:
+  ``tpu/compaction_service.py``) and ``flush.key_widths.mixed``
+  (memtable flushes of differing key lengths: ``storage/engine.py``)
+  beside the places the window's ``tpu.compact_stream`` spans launched
+  by their ``key_widths`` annotation and the longest key any of them
+  carried (``key_bytes_max``). ``--trace 0`` too.
 
 Arguments are ``chipbench/run.py``'s own.
 """
@@ -195,6 +205,17 @@ def main(argv=None) -> int:
             **{"process_" + k: Stats.get().get_counter(k)
                for k in ("write.apply.bulk", "write.apply.general",
                          "rpc.write.success")})))
+        harness.say("shards by key shape: " + json.dumps(dict(
+            {"window_launched_" + w: sum(
+                int(a["shards"]) for a in streams
+                if a.get("key_widths") == w) for w in ("uniform", "mixed")},
+            window_key_bytes_max=max(
+                (int(a.get("key_bytes_max", 0)) for a in streams),
+                default=0),
+            **{"process_" + k: Stats.get().get_counter(k)
+               for k in ("compact.key_widths.uniform",
+                         "compact.key_widths.mixed",
+                         "flush.key_widths.mixed")})))
         return real_read_metrics(bench, group, package, cell, run)
 
     tr.reduce, harness.reduce_trace = reduce, reduce_trace
