@@ -172,6 +172,11 @@ class NativeLib:
         ]
         lib.wal_count_records.restype = ctypes.c_int64
         lib.wal_count_records.argtypes = [_u8p, ctypes.c_uint64]
+        held.batch_index_ops.restype = ctypes.c_int64
+        held.batch_index_ops.argtypes = [
+            ctypes.c_char_p, ctypes.c_uint64, ctypes.c_uint64,
+            ctypes.c_uint64, ctypes.c_void_p, ctypes.c_void_p,
+        ]
         lib.bloom_add_many.restype = None
         lib.bloom_add_many.argtypes = [
             _u32p, ctypes.c_uint32, _u8p, _u64p, ctypes.c_uint64,
@@ -596,6 +601,26 @@ class NativeLib:
             [(int(seqs[i]), int(offs[i]), int(lens[i])) for i in range(n)],
             int(bad.value),
         )
+
+    def batch_index(self, frame: bytes, pos: int, num_ops: int):
+        """The headers of ``num_ops`` ops of a WriteBatch frame from
+        ``pos`` on (``storage/records.py`` ``_index_ops``, whose caller
+        has bounded ``num_ops`` by the frame's length): ``(types, cols,
+        end)`` — a u8 type an op; the (4, num_ops) int64 columns key
+        offset, key length, value offset, value length; the position
+        behind the last op. ``Corruption`` for a type outside 1-4 or an
+        op that runs past the frame. GIL kept: microseconds of C on a
+        ``write`` RPC's path."""
+        types = np.empty(num_ops, np.uint8)
+        cols = np.empty((4, num_ops), np.int64)
+        end = self._held.batch_index_ops(
+            frame, len(frame), pos, num_ops,
+            types.ctypes.data, cols.ctypes.data)
+        if end < 0:
+            raise Corruption(
+                "bad batch: " + ("op type outside 1-4" if end == -1
+                                 else "an op runs past the frame"))
+        return types, cols, end
 
     def bloom_add_many(self, words: np.ndarray, keys: List[bytes]) -> None:
         n = len(keys)

@@ -202,6 +202,45 @@ int64_t wal_scan(
 }
 
 // ---------------------------------------------------------------------------
+// write-batch frame index (format: storage/records.py)
+// ---------------------------------------------------------------------------
+
+// The headers of `num_ops` ops from `pos` on, in frame order:
+//   op : u8 type | u32 key_len | key | u32 val_len | val
+// types[i] is op i's type; cols holds four columns of num_ops each: key
+// offset, key length, value offset, value length. Returns the position
+// behind the last op; -1 for a type outside 1..4, -2 for an op that runs
+// past the frame. Lengths are the frame's own words: every sum stays in
+// 64 bits and is compared as a remainder, so none can wrap.
+int64_t batch_index_ops(
+    const uint8_t* data, uint64_t len, uint64_t pos, uint64_t num_ops,
+    uint8_t* types, int64_t* cols) {
+  int64_t* key_off = cols;
+  int64_t* key_len = cols + num_ops;
+  int64_t* val_off = cols + 2 * num_ops;
+  int64_t* val_len = cols + 3 * num_ops;
+  if (pos > len) return -2;
+  for (uint64_t i = 0; i < num_ops; i++) {
+    if (len - pos < 5) return -2;
+    uint8_t t = data[pos];
+    if (t < 1 || t > 4) return -1;
+    uint64_t kl = get_u32(data + pos + 1);
+    pos += 5;
+    if (len - pos < kl || len - pos - kl < 4) return -2;
+    uint64_t vl = get_u32(data + pos + kl);
+    types[i] = t;
+    key_off[i] = (int64_t)pos;
+    key_len[i] = (int64_t)kl;
+    pos += kl + 4;
+    if (len - pos < vl) return -2;
+    val_off[i] = (int64_t)pos;
+    val_len[i] = (int64_t)vl;
+    pos += vl;
+  }
+  return (int64_t)pos;
+}
+
+// ---------------------------------------------------------------------------
 // bloom (format-identical to storage/bloom.py)
 // ---------------------------------------------------------------------------
 

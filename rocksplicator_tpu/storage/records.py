@@ -30,6 +30,7 @@ from .errors import Corruption
 
 _U32 = struct.Struct("<I")
 _OPHEAD = struct.Struct("<BI")
+_OP_MIN = _OPHEAD.size + _U32.size  # an op of empty key and empty value
 
 
 class OpType(enum.IntEnum):
@@ -44,6 +45,8 @@ class OpType(enum.IntEnum):
 _TS = struct.Struct("<Q")
 
 _Op = Tuple[OpType, bytes, bytes]
+_LOG_DATA = int(OpType.LOG_DATA)
+_LOG_DATA_BYTE = bytes((_LOG_DATA,))
 
 
 class BatchColumns(NamedTuple):
@@ -57,37 +60,47 @@ class BatchColumns(NamedTuple):
     key_bytes: bytes    # the keys back to back
     klens: array        # "I"
     vlens: array        # "I"
-    # how an ARRIVED frame became columns: "bulk" (read column-wise off
-    # one stride) or "general" (walked op by op); None for a built batch
+    # the pass that made the columns. An ARRIVED frame (``decode_batch``)
+    # is read column-wise, by what its own strides allow: "bulk", a frame
+    # of ONE stride, off a structured view of that stride with no per-op
+    # step at all; "indexed", any other frame, off one index of its op
+    # headers (``_index_ops``). None: a BUILT batch, walked tuple by
+    # tuple (``_columns_of``), which no arrived frame ever is.
     frame_pass: Optional[str]
 
 
-def _columns_of(ops: List[_Op], frame_pass: Optional[str]) -> BatchColumns:
-    """The general pass: any batch, one walk over its tuples."""
+def _columns_of(ops: List[_Op]) -> BatchColumns:
+    """The general pass, a BUILT batch's: one walk over its tuples."""
     live = [t for t in ops if t[0] is not OpType.LOG_DATA]
     keys = [t[1] for t in live]
     vals = [t[2] for t in live]
     return BatchColumns(
         len(live), keys, vals, bytes(t[0] for t in live), b"".join(keys),
-        array("I", map(len, keys)), array("I", map(len, vals)), frame_pass)
+        array("I", map(len, keys)), array("I", map(len, vals)), None)
 
 
 class WriteBatch:
     """Built (``put`` / ``merge`` / ``delete``: a list of tuples) or
-    ARRIVED (``decode_batch``: the encoded frame, kept as it came). A
-    frame of one stride — every operation a PUT / DELETE / MERGE of one
-    key width and one value width, then nothing but ``LOG_DATA`` — was
-    read column-wise once and never becomes tuples unless someone asks
-    for ``ops()``; ``put_log_data`` (the leader's time stamp) appends to
-    the frame, so ``encode()`` hands back the client's own bytes plus
-    the stamp. Any other mutation thaws the batch into its tuples."""
+    ARRIVED (``decode_batch``: the encoded frame, kept as it came). An
+    arrived frame of any shape was read column-wise once and never
+    becomes tuples unless someone asks for ``ops()``. Three passes make
+    ``columns()``, and what the batch is decides which: a frame of ONE
+    stride — every operation a PUT / DELETE / MERGE of one key width
+    and one value width, then nothing but ``LOG_DATA`` — is read off a
+    structured view of that stride (``"bulk"``); any other frame off
+    one index of its op headers (``"indexed"``); a built batch is
+    walked tuple by tuple (``None``). ``put_log_data`` (the leader's
+    time stamp) appends to the frame, so ``encode()`` hands back the
+    client's own bytes plus the stamp. Any other mutation thaws the
+    batch into its tuples."""
 
     __slots__ = ("_built", "_raw", "_cols")
 
     def __init__(self) -> None:
         self._built: Optional[List[_Op]] = []
         self._raw: Optional[bytes] = None
-        # set by decode_batch's column-wise read alone
+        # an arrived frame's columns (decode_batch), as long as it has
+        # its frame
         self._cols: Optional[BatchColumns] = None
 
     def _thaw(self) -> List[_Op]:
@@ -180,8 +193,7 @@ class WriteBatch:
         """What ``MemTable.apply_batch`` takes."""
         if self._cols is not None:
             return self._cols
-        return _columns_of(
-            self._built, None if self._raw is None else "general")
+        return _columns_of(self._built)
 
     def byte_size(self) -> int:
         if self._raw is not None:
@@ -236,48 +248,103 @@ def _uniform_rows(buf: bytes, num_ops: int):
     return rec, klen, vlen, stride
 
 
-def _skim(buf: bytes):
-    """A frame by its op HEADERS alone: (its leading rows of one stride
-    or None, the sequence-consuming ops behind them, the last 8-byte
-    LOG_DATA behind them as a time stamp, the position behind the last
-    op)."""
+def _walk_ops(buf: bytes, pos: int, num_ops: int):
+    """``_index_ops`` in Python, the native call's reference: ONE loop
+    over the headers for where each op starts, the columns read off
+    those places by numpy."""
+    import numpy as np
+
+    ophead, u32 = _OPHEAD.unpack_from, _U32.unpack_from
+    starts = []
+    try:
+        for _ in range(num_ops):
+            starts.append(pos)
+            pos += _OP_MIN + ophead(buf, pos)[1]
+            pos += u32(buf, pos - _U32.size)[0]
+    except struct.error as e:
+        raise Corruption(f"bad batch: {e}") from e
+    if pos > len(buf):
+        raise Corruption("bad batch: an op runs past the frame")
+    at = np.array(starts, np.int64)
+    data = np.frombuffer(buf, np.uint8)
+    types = data[at]
+    # u1 wraps: 0 - 1 = 255, so this is 1 <= t <= 4
+    if not (types - 1 < 4).all():
+        raise Corruption("bad batch: op type outside 1-4")
+    cols = np.empty((4, num_ops), np.int64)
+    cols[0] = at + _OPHEAD.size
+    cols[1] = data[(at + 1)[:, None] + np.arange(_U32.size)].view("<u4")[:, 0]
+    cols[2] = cols[0] + cols[1] + _U32.size
+    cols[3] = np.append(at[1:], pos) - cols[2]  # up to where the next starts
+    return types, cols, pos
+
+
+def _index_ops(buf: bytes, pos: int, num_ops: int):
+    """The headers of ``num_ops`` ops from ``pos`` on, indexed ONCE, in
+    frame order: ``(types, cols, end)`` — a u8 op type an op; the
+    (4, num_ops) int64 columns key offset, key length, value offset,
+    value length; the position behind the last op. ``Corruption`` for a
+    type outside 1-4, an op that runs past the frame, a frame too short
+    for its op count. One native call where the library is loaded (it
+    keeps the GIL: microseconds of C), else the same walk in Python."""
+    if num_ops > (len(buf) - pos) // _OP_MIN:
+        raise Corruption("bad batch: shorter than its op count")
+    from .native.binding import get_native
+
+    lib = get_native()
+    if lib is not None:
+        return lib.batch_index(buf, pos, num_ops)
+    return _walk_ops(buf, pos, num_ops)
+
+
+def _read_frame(buf: bytes):
+    """An arrived frame by its op headers, validated to its last byte:
+    ``(uniform, index)``. A frame of ONE stride gives its rows
+    (``_uniform_rows``) and the index of the ``LOG_DATA`` behind them
+    (None where nothing is); any other frame None and the index of
+    every op."""
     if len(buf) < _U32.size:
         raise Corruption("batch too short")
     (num_ops,) = _U32.unpack_from(buf, 0)
     uniform = _uniform_rows(buf, num_ops)
-    pos = _U32.size
+    index, end = None, _U32.size
     if uniform is not None:
-        num_ops -= len(uniform[0])
-        pos += len(uniform[0]) * uniform[3]
-    count = 0
-    ts: Optional[int] = None
-    try:
-        for _ in range(num_ops):
-            op_raw, key_len = _OPHEAD.unpack_from(buf, pos)
-            pos += _OPHEAD.size + key_len
-            (val_len,) = _U32.unpack_from(buf, pos)
-            pos += _U32.size
-            if op_raw == OpType.LOG_DATA:
-                if val_len == _TS.size:
-                    ts = _TS.unpack_from(buf, pos)[0]
-            else:
-                count += 1
-            pos += val_len
-    except struct.error as e:
-        raise Corruption(f"bad batch: {e}") from e
-    return uniform, count, ts, pos
+        end += len(uniform[0]) * uniform[3]
+        behind = num_ops - len(uniform[0])
+        # what stands behind the rows is indexed alone only where it can
+        # be the stamp (a follower's update, a WAL record)
+        if behind and buf[end:end + 1] == _LOG_DATA_BYTE:
+            index = _index_ops(buf, end, behind)
+            if index[0].tobytes() != _LOG_DATA_BYTE * behind:
+                uniform = None
+        elif behind:
+            uniform = None
+    if uniform is None:
+        index = _index_ops(buf, _U32.size, num_ops)
+    if index is not None:
+        end = index[2]
+    if end != len(buf):
+        raise Corruption("trailing bytes in batch")
+    return uniform, index
 
 
 def scan_batch_meta(data) -> Tuple[int, Optional[int]]:
     """(count, timestamp_ms) from op HEADERS only — no key/value slicing,
     no WriteBatch construction. The replication serve path and the WAL's
     straddler checks need exactly these facts per update: a frame of one
-    stride gives them from one array comparison, any other from a skim."""
+    stride gives them from one array comparison, any other from the
+    index of its headers. Validates as ``decode_batch`` does."""
+    import numpy as np
+
     buf = bytes(data)
-    uniform, count, ts, end = _skim(buf)
-    if end > len(buf):
-        raise Corruption("truncated batch")
-    return count + (0 if uniform is None else len(uniform[0])), ts
+    uniform, index = _read_frame(buf)
+    count = 0 if uniform is None else len(uniform[0])
+    if index is None:
+        return count, None
+    types, cols, _end = index
+    stamps = np.flatnonzero((types == _LOG_DATA) & (cols[3] == _TS.size))
+    ts = _TS.unpack_from(buf, cols[2, stamps[-1]])[0] if len(stamps) else None
+    return count + int(np.count_nonzero(types != _LOG_DATA)), ts
 
 
 def _decode_ops(buf: bytes) -> List[_Op]:
@@ -309,17 +376,16 @@ def _decode_ops(buf: bytes) -> List[_Op]:
     return ops
 
 
-def _bulk_columns(buf: bytes) -> Optional[BatchColumns]:
-    """A frame of ONE stride with nothing but ``LOG_DATA`` behind it,
-    read column-wise: numpy over the stride for the headers, the types
-    and the key bytes, every key and value object sliced once by one
-    ``iter_unpack``. None for any other frame, a broken one too."""
-    try:
-        uniform, behind, _ts, tail_end = _skim(buf)
-    except Corruption:
-        return None
-    if uniform is None or behind or tail_end != len(buf):
-        return None
+def _u32s(col) -> array:
+    out = array("I")
+    out.frombytes(col.astype("<u4").tobytes())
+    return out
+
+
+def _bulk_columns(buf: bytes, uniform) -> BatchColumns:
+    """The rows of a frame of ONE stride, read column-wise: numpy over
+    the stride for the headers, the types and the key bytes, every key
+    and value object sliced once by one ``iter_unpack``."""
     rec, klen, vlen, stride = uniform
     rows = len(rec)
     end = _U32.size + rows * stride
@@ -331,13 +397,34 @@ def _bulk_columns(buf: bytes) -> Optional[BatchColumns]:
         array("I", (klen,)) * rows, array("I", (vlen,)) * rows, "bulk")
 
 
+def _indexed_columns(buf: bytes, index) -> BatchColumns:
+    """Any frame's columns off the index of its op headers: the types by
+    one take, the lengths as they stand, the key and value objects (the
+    memtable's dict and value list hold objects) by one slice each, the
+    key bytes by one join. ``LOG_DATA`` is dropped."""
+    types, cols, _end = index
+    live = types != _LOG_DATA
+    if not live.all():
+        types, cols = types[live], cols[:, live]
+    key_off, key_len, val_off, val_len = cols
+    keys = [buf[a:b] for a, b in zip(
+        key_off.tolist(), (key_off + key_len).tolist())]
+    vals = [buf[a:b] for a, b in zip(
+        val_off.tolist(), (val_off + val_len).tolist())]
+    return BatchColumns(
+        len(keys), keys, vals, types.tobytes(), b"".join(keys),
+        _u32s(key_len), _u32s(val_len), "indexed")
+
+
 def decode_batch(data) -> WriteBatch:
     """The ONE parse of an arrived frame: validated to the last byte
-    (``Corruption`` otherwise), and the batch keeps the frame. What
-    cannot be read column-wise is walked op by op into tuples, as any
-    built batch is."""
+    (``Corruption`` otherwise), read column-wise whatever its shape
+    (``BatchColumns.frame_pass`` says how), and the batch keeps the
+    frame: tuples exist only once someone asks for ``ops()``."""
     batch = WriteBatch()
-    batch._raw = bytes(data)
-    batch._cols = _bulk_columns(batch._raw)
-    batch._built = None if batch._cols else _decode_ops(batch._raw)
+    batch._raw = raw = bytes(data)
+    batch._built = None
+    uniform, index = _read_frame(raw)
+    batch._cols = (_indexed_columns(raw, index) if uniform is None
+                   else _bulk_columns(raw, uniform))
     return batch

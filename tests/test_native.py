@@ -958,3 +958,74 @@ def test_native_short_calls_keep_the_gil():
     for data in (b"", b"abc" * 1000, big):
         packed = NATIVE.rlz_compress(data)
         assert NATIVE.rlz_decompress(packed, len(data) + 1) == data
+
+
+def _random_frame(seed: int, num_ops: int) -> bytes:
+    """A WriteBatch frame of all four op types, keys and values of 0 to
+    300 bytes, built straight from the format."""
+    import random
+
+    r = random.Random(seed)
+    parts = [struct.pack("<I", num_ops)]
+    for _ in range(num_ops):
+        key = r.randbytes(r.choice((0, 1, 9, 16, r.randint(0, 300))))
+        val = r.randbytes(r.choice((0, 8, 8, r.randint(0, 300))))
+        parts.append(struct.pack("<BI", r.randint(1, 4), len(key)) + key
+                     + struct.pack("<I", len(val)) + val)
+    return b"".join(parts)
+
+
+@pytest.mark.parametrize("seed,num_ops", [
+    (1, 0), (2, 1), (3, 2), (4, 17), (5, 512), (6, 1999), (7, 2000)])
+def test_batch_index_matches_the_python_walk(seed, num_ops):
+    """One native call over a frame's op headers gives what the Python
+    walk gives: types, the four columns, the end; from any op on."""
+    from rocksplicator_tpu.storage.records import _walk_ops
+
+    raw = _random_frame(seed, num_ops)
+    types, cols, end = NATIVE.batch_index(raw, 4, num_ops)
+    ref_types, ref_cols, ref_end = _walk_ops(raw, 4, num_ops)
+    assert end == ref_end == len(raw)
+    assert types.dtype == ref_types.dtype == np.uint8
+    assert cols.dtype == ref_cols.dtype == np.int64
+    assert cols.shape == ref_cols.shape == (4, num_ops)
+    np.testing.assert_array_equal(types, ref_types)
+    np.testing.assert_array_equal(cols, ref_cols)
+    # the columns say where the frame's own bytes lie
+    for i in range(0, num_ops, 97):
+        ko, kl, vo, vl = (int(c) for c in cols[:, i])
+        assert raw[ko - 5] == types[i]
+        assert struct.unpack_from("<I", raw, ko - 4)[0] == kl
+        assert struct.unpack_from("<I", raw, ko + kl) == (vl,)
+        assert vo == ko + kl + 4
+    if num_ops > 2:  # from the third op on, as behind a frame's rows
+        start = int(cols[2, 1] + cols[3, 1])
+        tail = NATIVE.batch_index(raw, start, num_ops - 2)
+        ref_tail = _walk_ops(raw, start, num_ops - 2)
+        np.testing.assert_array_equal(tail[0], types[2:])
+        np.testing.assert_array_equal(tail[1], cols[:, 2:])
+        np.testing.assert_array_equal(ref_tail[1], cols[:, 2:])
+        assert tail[2] == ref_tail[2] == end
+
+
+@pytest.mark.parametrize("seed,num_ops", [(11, 0), (12, 3), (13, 512),
+                                          (14, 2000)])
+def test_decode_batch_is_the_same_with_the_library_hidden(
+        seed, num_ops, monkeypatch):
+    from rocksplicator_tpu.storage.native import binding
+    from rocksplicator_tpu.storage.records import (
+        decode_batch, scan_batch_meta)
+
+    raw = _random_frame(seed, num_ops)
+    with_lib = decode_batch(raw)
+    meta = scan_batch_meta(raw)
+    monkeypatch.setattr(binding, "_native", None)
+    assert not binding.native_available()
+    hidden = decode_batch(raw)
+    assert hidden.columns() == with_lib.columns()
+    assert hidden.columns().frame_pass == "indexed"
+    assert scan_batch_meta(raw) == meta == (
+        with_lib.count(), with_lib.extract_timestamp_ms())
+    assert hidden._built is None and with_lib._built is None
+    assert list(hidden.ops()) == list(with_lib.ops())
+    assert len(list(hidden.ops())) == num_ops

@@ -15,6 +15,11 @@ what the path did before, which the builder API and the per-operation
   at re-open, end equal;
 - a served ``write`` RPC parses on an executor thread, takes the
   column pass and answers as before.
+
+Since PR 36 EVERY arrived frame is read column-wise: a frame of one
+stride off a structured view (``"bulk"``), any other off one index of
+its op headers (``"indexed"``: the native call, or the same walk in
+Python); tuples exist only once someone asks for ``ops()``.
 """
 
 import random
@@ -32,7 +37,9 @@ from rocksplicator_tpu.storage import (
     DB, DBOptions, OpType, UInt64AddOperator, WriteBatch, decode_batch)
 from rocksplicator_tpu.storage.errors import Corruption
 from rocksplicator_tpu.storage.memtable import MemTable
-from rocksplicator_tpu.storage.records import scan_batch_meta
+from rocksplicator_tpu.storage.native import binding
+from rocksplicator_tpu.storage.records import (
+    _columns_of, _decode_ops, _index_ops, _indexed_columns, scan_batch_meta)
 from rocksplicator_tpu.utils.stats import Stats
 
 PUT, DELETE, MERGE, LOG = (
@@ -80,28 +87,60 @@ def _with_log(at):
     return make
 
 
-# name -> (ops from a Random, does the frame take the column pass)
+def _names(r, n=512, twice=False):
+    """Counter names ``counter-<n>`` in arrival order: seven key lengths
+    (9 to 15 bytes), a PUT among twenty MERGEs, the leader's stamp
+    behind them."""
+    ops = []
+    for i in range(n):
+        digits = i % 7 + 1 if i < 7 else r.choice((5, 6, 6, 6, 6, 7, 7))
+        name = b"counter-%d" % r.randrange(10 ** (digits - 1), 10 ** digits)
+        ops.append((PUT if r.random() < 0.05 else MERGE, name, _val(r)))
+    r.shuffle(ops)
+    assert {len(k) for _t, k, _v in ops} == set(range(9, 16))
+    if twice:
+        ops[400] = (MERGE, ops[17][1], _val(r))
+    return ops + [(LOG, b"", TS.to_bytes(8, "little"))]
+
+
+def _widths_and_deletes(r):
+    ops = []
+    for _ in range(300):
+        t = r.choice((PUT, MERGE, DELETE))
+        ops.append((t, _key(r, r.choice((12, 16, 16, 20))),
+                    b"" if t is DELETE else _val(r, r.choice((0, 8, 8, 40)))))
+    return ops
+
+
+# name -> (ops from a Random, the pass the frame takes into columns)
 CASES = {
-    "counters_512": (_counters, True),
-    "one_key_many_times": (_repeated, True),
+    "counters_512": (_counters, "bulk"),
+    "one_key_many_times": (_repeated, "bulk"),
     "one_key_only": (lambda r: [(MERGE, b"k" * 16, _val(r))
-                                for _ in range(33)], True),
-    "records_1kb": (lambda r: _counters(r, 128, (PUT,), vlen=1024), True),
-    "deletes_only": (lambda r: _counters(r, 40, (DELETE,), vlen=0), True),
-    "single_put": (lambda r: _counters(r, 1), True),
-    "log_data_last": (_with_log("last"), True),
-    "log_data_first": (_with_log("first"), False),
-    "log_data_middle": (_with_log("middle"), False),
-    "put_merge_delete": (_mixed, False),
+                                for _ in range(33)], "bulk"),
+    "records_1kb": (lambda r: _counters(r, 128, (PUT,), vlen=1024), "bulk"),
+    "deletes_only": (lambda r: _counters(r, 40, (DELETE,), vlen=0), "bulk"),
+    "single_put": (lambda r: _counters(r, 1), "bulk"),
+    "log_data_last": (_with_log("last"), "bulk"),
+    "log_data_first": (_with_log("first"), "indexed"),
+    "log_data_middle": (_with_log("middle"), "indexed"),
+    "put_merge_delete": (_mixed, "indexed"),
     "varying_key_widths": (lambda r: [
-        (PUT, _key(r, r.randint(1, 24)), _val(r)) for _ in range(100)], False),
+        (PUT, _key(r, r.randint(1, 24)), _val(r)) for _ in range(100)],
+        "indexed"),
     "varying_value_widths": (lambda r: [
-        (PUT, _key(r), _val(r, r.randint(0, 40))) for _ in range(100)], False),
+        (PUT, _key(r), _val(r, r.randint(0, 40))) for _ in range(100)],
+        "indexed"),
     "width_changes_late": (lambda r: _counters(r, 50) + [
-        (PUT, _key(r, 12), _val(r))], False),
-    "empty_keys": (lambda r: _counters(r, 20, klen=0), False),
-    "empty": (lambda r: [], False),
-    "log_data_only": (lambda r: [(LOG, b"", b"x")], False),
+        (PUT, _key(r, 12), _val(r))], "indexed"),
+    "empty_keys": (lambda r: _counters(r, 20, klen=0), "indexed"),
+    "empty": (lambda r: [], "indexed"),
+    "log_data_only": (lambda r: [(LOG, b"", b"x")], "indexed"),
+    "names_512": (_names, "indexed"),
+    "names_512_one_key_twice": (lambda r: _names(r, twice=True), "indexed"),
+    "value_widths_and_deletes": (_widths_and_deletes, "indexed"),
+    "log_data_between_two_strides": (lambda r: _counters(r, 40) + [
+        (LOG, b"", b"\x07" * 8)] + _counters(r, 40, klen=11), "indexed"),
 }
 
 
@@ -146,7 +185,7 @@ def _assert_same_memtable(a: MemTable, b: MemTable, uint64: bool):
                          ids=["fresh", "half_full"])
 @pytest.mark.parametrize("case", CASES)
 def test_whole_batch_apply_equals_per_operation_apply(case, half_full):
-    make, bulk = CASES[case]
+    make, frame_pass = CASES[case]
     r = random.Random(case)
     ops = make(r)
     raw = _build(ops).encode()
@@ -160,7 +199,7 @@ def test_whole_batch_apply_equals_per_operation_apply(case, half_full):
         whole.apply_batch(_build(before).columns(), 1)
     arrived = decode_batch(raw)
     cols = arrived.columns()
-    assert cols.frame_pass == ("bulk" if bulk else "general")
+    assert cols.frame_pass == frame_pass
     assert arrived.count() == cols.count == sum(o[0] is not LOG for o in ops)
     assert len(arrived) == len(ops)
     assert arrived.byte_size() == len(raw)
@@ -171,10 +210,53 @@ def test_whole_batch_apply_equals_per_operation_apply(case, half_full):
     widths = {len(v) for t, _k, v in before + ops if t in (PUT, MERGE)}
     _assert_same_memtable(one_by_one, whole, uint64=widths <= {8})
     # the frame itself was never taken apart: tuples only when asked for
-    if bulk:
-        assert arrived._built is None
+    assert arrived._built is None
     assert arrived.encode() == raw
     assert list(arrived.ops()) == ops
+
+
+def _hide_library(monkeypatch):
+    """What a process without the native library runs."""
+    monkeypatch.setattr(binding, "_native", None)
+    assert not binding.native_available()
+
+
+WALKERS = ["native", "python"]
+
+
+@pytest.mark.parametrize("walker", WALKERS)
+@pytest.mark.parametrize("case", CASES)
+def test_columns_off_the_index_equal_the_tuple_walks(case, walker, monkeypatch):
+    """Whatever the frame's shape, the columns read off the index of its
+    op headers are, field by field, the ones the walk over its tuples
+    gives; so are the ones ``decode_batch`` chose for it."""
+    if walker == "python":
+        _hide_library(monkeypatch)
+    make, frame_pass = CASES[case]
+    ops = make(random.Random(case))
+    raw = _build(ops).encode()
+    want = _columns_of(_decode_ops(raw))
+    index = _index_ops(raw, 4, len(ops))
+    assert index[2] == len(raw)
+    arrived = decode_batch(raw)
+    for got, said in ((_indexed_columns(raw, index), "indexed"),
+                      (arrived.columns(), frame_pass)):
+        assert got.frame_pass == said
+        for name in want._fields[:-1]:
+            field, ref = getattr(got, name), getattr(want, name)
+            if name in ("keys", "vals"):
+                field, ref = list(field), list(ref)
+            assert field == ref, name
+            assert type(field) is type(ref), name
+    assert arrived._built is None
+    assert arrived.encode() == raw
+    assert (arrived.count(), len(arrived), arrived.byte_size()) == (
+        want.count, len(ops), len(raw))
+    # the stamp is the last 8-byte LOG_DATA, wherever it stands
+    stamp = _build(ops).extract_timestamp_ms()
+    assert scan_batch_meta(raw) == (want.count, stamp)
+    assert arrived.extract_timestamp_ms() == stamp
+    assert arrived._built is None
 
 
 @pytest.mark.parametrize("case", CASES)
@@ -208,12 +290,26 @@ def test_stamped_frame_is_the_built_batchs_encoding(case, tmp_path):
         db.close()
 
 
+def _small_mixed():
+    """Four ops of four shapes: a stamp in the middle, an empty value."""
+    return _build([(PUT, b"k1", b"v"), (LOG, b"", TS.to_bytes(8, "little")),
+                   (MERGE, b"key-two", b"vv"), (DELETE, b"k", b"")]).encode()
+
+
+def _with_u32(frame: bytes, at: int, value: int) -> bytes:
+    return frame[:at] + value.to_bytes(4, "little") + frame[at + 4:]
+
+
 def _frames():
     r = random.Random(34)
     good = _build(_counters(r, 32)).encode()
     uneven = _build(_mixed(r)).encode()
-    count = len(good).to_bytes(4, "little")
-    return {
+    small = _small_mixed()
+    # small's third op: its type, its key's length, its value's length
+    at = small.index(b"key-two") - 5
+    klen_at, vlen_at = at + 1, at + 12
+    assert small[at] == MERGE and small[vlen_at + 4:vlen_at + 6] == b"vv"
+    frames = {
         "three_bytes": good[:3],
         "cut_in_a_header": good[:4 + 33 * 7 + 3],
         "cut_in_a_key": good[:4 + 33 * 20 + 9],
@@ -223,44 +319,97 @@ def _frames():
         # nine bytes that would read as an op's header once a stamp's
         # 17 bytes stand behind them
         "an_op_header_over": good + b"\x01" + bytes(4) + b"\x11" + bytes(3),
-        "count_too_high": (33).to_bytes(4, "little") + good[4:],
-        "count_too_low": (31).to_bytes(4, "little") + good[4:],
-        "count_is_a_length": count + good[4:],
+        "count_too_high": _with_u32(good, 0, 33),
+        "count_too_low": _with_u32(good, 0, 31),
+        "count_is_a_length": _with_u32(good, 0, len(good)),
+        "count_is_the_largest": _with_u32(good, 0, 0xFFFFFFFF),
         "op_type_0": good[:4 + 33 * 5] + b"\x00" + good[4 + 33 * 5 + 1:],
         "op_type_5": good[:4 + 33 * 5] + b"\x05" + good[4 + 33 * 5 + 1:],
         "uneven_cut": uneven[:-1],
         "uneven_over": uneven + b"\x04",
+        "uneven_count_too_high": _with_u32(uneven, 0, 201),
+        "small_over": small + b"\x00",
+        "small_op_type_0": small[:at] + b"\x00" + small[at + 1:],
+        "small_op_type_5": small[:at] + b"\x05" + small[at + 1:],
+        "small_op_type_255": small[:at] + b"\xff" + small[at + 1:],
+        "small_key_runs_past": _with_u32(small, klen_at, len(small)),
+        "small_key_runs_to_the_end": _with_u32(
+            small, klen_at, len(small) - klen_at - 4),
+        "small_key_is_the_largest": _with_u32(small, klen_at, 0xFFFFFFFF),
+        "small_key_wraps_32_bits": _with_u32(
+            small, klen_at, 0xFFFFFFFF - klen_at - 3),
+        "small_value_runs_past": _with_u32(small, vlen_at, len(small)),
+        "small_value_is_the_largest": _with_u32(small, vlen_at, 0xFFFFFFFF),
+        "small_value_wraps_32_bits": _with_u32(
+            small, vlen_at, 0xFFFFFFFF - vlen_at - 3),
+        "small_last_value_runs_past": _with_u32(small, len(small) - 4, 1),
+        "small_last_value_is_the_largest": _with_u32(
+            small, len(small) - 4, 0xFFFFFFFF),
     }
+    for cut in range(len(small)):  # truncated at every byte
+        frames[f"small_cut_at_{cut:02d}"] = small[:cut]
+    return frames
+
+
+def _open_leader(path):
+    rep = Replicator(port=0)
+    db = DB(str(path), DBOptions(
+        memtable_bytes=1 << 30, merge_operator=UInt64AddOperator()))
+    rdb = rep.add_db("seg00000", StorageDbWrapper(db), ReplicaRole.LEADER,
+                     replication_mode=0)
+    return rep, db, rdb
 
 
 @pytest.fixture()
 def leader(tmp_path):
-    rep = Replicator(port=0)
-    db = DB(str(tmp_path / "leader"), DBOptions(
-        memtable_bytes=1 << 30, merge_operator=UInt64AddOperator()))
-    rdb = rep.add_db("seg00000", StorageDbWrapper(db), ReplicaRole.LEADER,
-                     replication_mode=0)
+    rep, db, rdb = _open_leader(tmp_path / "leader")
     yield rep, db, rdb
     rep.stop()
     db.close()
 
 
+@pytest.fixture(scope="module")
+def refusing_leader(tmp_path_factory):
+    """One leader for every frame it refuses: nothing of them stays."""
+    rep, db, rdb = _open_leader(tmp_path_factory.mktemp("refusing"))
+    rdb.write(_build(_counters(random.Random(1), 8)))
+    yield rep, db, rdb
+    rep.stop()
+    db.close()
+
+
+@pytest.mark.parametrize("walker", WALKERS)
 @pytest.mark.parametrize("frame", _frames())
 def test_a_frame_that_is_no_batch_is_refused_before_it_is_logged(
-        frame, leader):
-    _rep, db, rdb = leader
-    rdb.write(_build(_counters(random.Random(1), 8)))
+        frame, walker, refusing_leader, monkeypatch):
+    """``Corruption`` from the parse and from the header scan alike,
+    from the native index and from the Python walk alike."""
+    if walker == "python":
+        _hide_library(monkeypatch)
+    _rep, db, rdb = refusing_leader
     seq = db.latest_sequence_number()
+    assert seq == 8
     wal = [(s, bytes(b)) for s, b in db.get_updates_since(1)]
     entries = list(db._mem.entries())
     bad = _frames()[frame]
     with pytest.raises(Corruption):
         decode_batch(bad)
     with pytest.raises(Corruption):
+        scan_batch_meta(bad)
+    with pytest.raises(Corruption):
         rdb._write_encoded(memoryview(bad))
     assert db.latest_sequence_number() == seq
     assert [(s, bytes(b)) for s, b in db.get_updates_since(1)] == wal
     assert list(db._mem.entries()) == entries
+
+
+def test_the_small_frame_itself_is_a_batch():
+    """What the corrupt frames above were cut from."""
+    small = _small_mixed()
+    assert scan_batch_meta(small) == (3, TS)
+    assert decode_batch(small).columns().frame_pass == "indexed"
+    assert [t for t, _k, _v in decode_batch(small).ops()] == [
+        PUT, LOG, MERGE, DELETE]
 
 
 @pytest.mark.parametrize("cases", [
@@ -308,13 +457,23 @@ def test_a_built_batch_still_builds_after_it_arrived():
         (PUT, b"late", b"op")]
 
 
+@pytest.mark.parametrize("shape", ["counters", "names"])
 def test_served_write_parses_off_the_loop_and_takes_the_column_pass(
-        leader, monkeypatch):
+        shape, leader, monkeypatch):
+    """A frame of 16-byte keys takes the bulk pass, a frame of counter
+    names the index: both are parsed on an executor thread, counted
+    under their pass alone, and answered the same."""
     rep, db, rdb = leader
     r = random.Random(512)
-    keys = [_key(r) for _ in range(200)]
+    if shape == "counters":
+        keys = [_key(r) for _ in range(200)]
+    else:
+        keys = [b"counter-%d" % r.randrange(10 ** d)
+                for d in range(1, 8) for _ in range(30)]
     ops = [(PUT, keys[0], (5).to_bytes(8, "little"))] + [
         (MERGE, r.choice(keys), _val(r, 4) + bytes(4)) for _ in range(511)]
+    took, other = (("bulk", "indexed") if shape == "counters"
+                   else ("indexed", "bulk"))
     folded = {}
     for _op, key, val in ops:
         folded[key] = folded.get(key, 0) + int.from_bytes(val, "little")
@@ -336,8 +495,8 @@ def test_served_write_parses_off_the_loop_and_takes_the_column_pass(
         return threading.get_ident()
 
     stats = Stats.get()
-    bulk = stats.get_counter("write.apply.bulk")
-    general = stats.get_counter("write.apply.general")
+    before = {k: stats.get_counter("write.apply." + k)
+              for k in ("bulk", "indexed", "general")}
     try:
         raw = _build(ops).encode()
         reply = ioloop.run_sync(
@@ -346,8 +505,11 @@ def test_served_write_parses_off_the_loop_and_takes_the_column_pass(
         reply = ioloop.run_sync(
             call("write", db_name="seg00000", raw_batch=raw), timeout=10)
         assert reply == {"seq": 513, "acked": True, "epoch": rdb.epoch}
-        assert stats.get_counter("write.apply.bulk") == bulk + 2
-        assert stats.get_counter("write.apply.general") == general
+        assert stats.get_counter("write.apply." + took) == before[took] + 2
+        assert stats.get_counter("write.apply." + other) == before[other]
+        # no arrived frame takes the tuple pass: the counter is gone
+        assert stats.get_counter("write.apply.general") == 0 == before[
+            "general"]
         assert len(parsed_on) == 2
         assert ioloop.run_sync(loop_thread(), timeout=10) not in parsed_on
         assert threading.get_ident() not in parsed_on

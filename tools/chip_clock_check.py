@@ -44,17 +44,18 @@ the run's own and the last):
   whole shards its ``tpu.compact_stream`` spans launched (``shards``,
   ``dbs``). ``--trace 0`` too.
 - **which pass a write batch took into the memtable**: the process's
-  ``write.apply.bulk`` / ``write.apply.general`` counters (batches whose
-  frame was of one stride and went into the memtable's columns as
-  columns / batches walked op by op: ``storage/records.py``,
-  ``storage/engine.py``) beside the process's served ``write`` RPCs
-  (``rpc.write.success``) and the window's ``rpc.server.write`` roots
-  (how many, their mean). Every batch a cell's client sends is of one
-  stride, so ``bulk`` equals the served ``write`` RPCs and ``general``
-  is 0 (a BUILT batch, as the admin plane's own metadata puts, is no
-  frame and counts under neither). ``--trace 0`` too. (A batch of
-  counter NAMES has several strides: ``counter_names_64x15k.refresh``
-  reads ``general`` = every frame.)
+  ``write.apply.bulk`` / ``write.apply.indexed`` counters (arrived
+  frames of one stride, read column-wise off a view of that stride /
+  arrived frames of any other shape, read column-wise off one index of
+  their op headers: ``storage/records.py``, ``storage/engine.py``)
+  beside the process's served ``write`` RPCs (``rpc.write.success``) and
+  the window's ``rpc.server.write`` roots (how many, their mean). In a
+  cell of keys of one length every batch the client sends is of one
+  stride, so ``bulk`` equals the served ``write`` RPCs and ``indexed``
+  is 0; a batch of counter NAMES has several strides, so
+  ``counter_names_64x15k.refresh`` reads ``indexed`` = every frame and
+  ``bulk`` 0 (a BUILT batch, as the admin plane's own metadata puts, is
+  no frame and counts under neither). ``--trace 0`` too.
 - **which key shape the shards had**: the process's
   ``compact.key_widths.uniform`` / ``.mixed`` counters (shards through
   the served door whose keys have one length / differ in length:
@@ -203,7 +204,7 @@ def main(argv=None) -> int:
             {"window_spans": len(ms),
              "window_mean_ms": round(sum(ms) / len(ms), 2) if ms else None},
             **{"process_" + k: Stats.get().get_counter(k)
-               for k in ("write.apply.bulk", "write.apply.general",
+               for k in ("write.apply.bulk", "write.apply.indexed",
                          "rpc.write.success")})))
         harness.say("shards by key shape: " + json.dumps(dict(
             {"window_launched_" + w: sum(
