@@ -30,8 +30,8 @@ from concurrent.futures import ThreadPoolExecutor
 from dataclasses import asdict, dataclass
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-from ..observability.context import wire_context
-from ..observability.span import start_span
+from ..observability.hop import run_in_executor
+from ..observability.span import phase, start_span
 from ..replication.replicated_db import LeaderResolver
 from ..replication.replicator import Replicator
 from ..replication.wire import ReplicaRole
@@ -210,8 +210,11 @@ class AdminHandler:
     # ------------------------------------------------------------------
 
     async def _run(self, fn: Callable, *args):
-        loop = asyncio.get_running_loop()
-        return await loop.run_in_executor(self._executor, fn, *args)
+        """The one funnel of every admin RPC onto the pool: the hop is
+        three phases of the RPC's root (``hop_in``, ``exec``,
+        ``hop_out``), and ``phase(...)`` inside ``fn`` stamps that root."""
+        return await run_in_executor(
+            asyncio.get_running_loop(), self._executor, fn, *args)
 
     def _db_path(self, db_name: str) -> str:
         return os.path.join(self.rocksdb_dir, db_name)
@@ -283,33 +286,38 @@ class AdminHandler:
     ) -> ApplicationDB:
         path = self._db_path(db_name)
         if overwrite:
-            destroy_db(path)
+            with phase("db.destroy"):
+                destroy_db(path)
         options = self._options_for(db_name)
-        # a split child's retain range is durable identity (DBMetaData),
-        # not dbconfig: reapply it on every reopen so scheduled
-        # compactions keep trimming the inherited other-half keys
-        meta = self.get_meta_data(db_name)
-        if meta.retain_lo or meta.retain_hi:
-            options.retain_lo = meta.retain_lo or None
-            options.retain_hi = meta.retain_hi or None
-        db = DB(path, options)
-        app_db = ApplicationDB(
-            db_name, db, role,
-            replicator=self.replicator,
-            upstream_addr=upstream,
-            replication_mode=replication_mode,
-            epoch=epoch,
-            # late-bound: set_leader_resolver (called once the participant
-            # exists — it is constructed after the handler) must reach DBs
-            # that are already open, so the wrapper defers the lookup
-            leader_resolver=lambda name: (
-                self._leader_resolver(name) if self._leader_resolver
-                else None
-            ),
-        )
-        if not self.db_manager.add_db(db_name, app_db):
-            app_db.close()
-            raise RpcApplicationError(DB_ALREADY_EXISTS, db_name)
+        with phase("db.open"):
+            # a split child's retain range is durable identity
+            # (DBMetaData), not dbconfig: reapply it on every reopen so
+            # scheduled compactions keep trimming the inherited
+            # other-half keys
+            meta = self.get_meta_data(db_name)
+            if meta.retain_lo or meta.retain_hi:
+                options.retain_lo = meta.retain_lo or None
+                options.retain_hi = meta.retain_hi or None
+            db = DB(path, options)
+        with phase("db.register"):
+            app_db = ApplicationDB(
+                db_name, db, role,
+                replicator=self.replicator,
+                upstream_addr=upstream,
+                replication_mode=replication_mode,
+                epoch=epoch,
+                # late-bound: set_leader_resolver (called once the
+                # participant exists — it is constructed after the
+                # handler) must reach DBs that are already open, so the
+                # wrapper defers the lookup
+                leader_resolver=lambda name: (
+                    self._leader_resolver(name) if self._leader_resolver
+                    else None
+                ),
+            )
+            if not self.db_manager.add_db(db_name, app_db):
+                app_db.close()
+                raise RpcApplicationError(DB_ALREADY_EXISTS, db_name)
         return app_db
 
     # ------------------------------------------------------------------
@@ -435,9 +443,12 @@ class AdminHandler:
                     epoch = _current_epoch(app_db)
                     if app_db.replicated_db is not None:
                         upstream = app_db.replicated_db.upstream_addr
-                    self.db_manager.remove_db(db_name)
-                destroy_db(self._db_path(db_name))
-                self.clear_meta_data(db_name)
+                    with phase("db.close"):
+                        self.db_manager.remove_db(db_name)
+                with phase("db.destroy"):
+                    destroy_db(self._db_path(db_name))
+                with phase("db.meta"):
+                    self.clear_meta_data(db_name)
                 if reopen_db:
                     self._open_app_db(db_name, role, upstream,
                                       replication_mode=mode, epoch=epoch)
@@ -693,12 +704,11 @@ class AdminHandler:
         app_db = self._get_app_db(db_name)
         store = self._store(store_uri)
         prefix = sub_path or db_name
-        # run_in_executor drops contextvars: carry the rpc.server span's
-        # context across the hop so the backup phases join the RPC trace.
         # always=True: control-plane ops are rare enough to trace
         # unconditionally — the 45 s backup round trip gets a per-phase
-        # breakdown (checkpoint → upload batches → dbmeta) every time.
-        tctx = wire_context()
+        # breakdown (checkpoint → upload batches → dbmeta) every time,
+        # as children of the RPC's root (``_run`` carries it across the
+        # hop).
 
         def do():
             # The per-db admin lock covers ONLY the checkpoint (fast,
@@ -708,8 +718,7 @@ class AdminHandler:
             # db for its whole duration (rstpu-check blocking-under-lock;
             # same narrowing as the round-7 ingest pipeline).
             with Timer("admin.backup_ms"), \
-                    start_span("admin.backup_db", always=True, remote=tctx,
-                               db=db_name):
+                    start_span("admin.backup_db", always=True, db=db_name):
                 meta = self.get_meta_data(db_name)
                 # stage INSIDE rocksdb_dir: same filesystem as the db,
                 # so the checkpoint's os.link fast path works — on /tmp
@@ -755,11 +764,10 @@ class AdminHandler:
                     INVALID_UPSTREAM, f"{role.value} requires upstream")
         else:
             role = ReplicaRole.FOLLOWER if upstream else ReplicaRole.NOOP
-        tctx = wire_context()
 
         def do():
             with Timer("admin.restore_ms"), \
-                    start_span("admin.restore_db", always=True, remote=tctx,
+                    start_span("admin.restore_db", always=True,
                                db=db_name, to_seq=to_seq):
                 if to_seq > 0:
                     # PITR: checkpoint download + WAL-archive replay must
@@ -860,10 +868,9 @@ class AdminHandler:
         ingest that will end there, so that the first to arrive waits
         for the rest (one dispatch for the N, not 1 then N - 1)."""
         store = self._store(s3_bucket)
-        tctx = wire_context()
 
         def do():
-            with start_span("admin.add_s3_sst", always=True, remote=tctx,
+            with start_span("admin.add_s3_sst", always=True,
                             db=db_name, path=s3_path) as sp:
                 return self._add_s3_sst(
                     sp, db_name, store, s3_bucket, s3_path, ingest_behind,
@@ -1044,8 +1051,6 @@ class AdminHandler:
         return {}
 
     async def handle_compact_db(self, db_name: str = "") -> dict:
-        tctx = wire_context()
-
         def do():
             # per-db lock: a concurrent clearDB/closeDB must not destroy the
             # directory under a running compaction
@@ -1053,7 +1058,7 @@ class AdminHandler:
                 app_db = self._get_app_db(db_name)
                 with Timer("admin.compact_ms"), \
                         start_span("admin.compact_db", always=True,
-                                   remote=tctx, db=db_name):
+                                   db=db_name):
                     app_db.compact_range()
 
         await self._run(do)

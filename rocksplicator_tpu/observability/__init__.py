@@ -13,7 +13,8 @@ round trip (checkpoint → upload batches → download), and compaction
 
 from .collector import SpanCollector, render_trace
 from .context import TRACE_KEY, current_span, wire_context
-from .span import NOOP_SPAN, Span, start_span
+from .hop import run_in_executor
+from .span import NOOP_SPAN, Span, phase, request_phases, start_span
 
 __all__ = [
     "NOOP_SPAN",
@@ -21,7 +22,10 @@ __all__ = [
     "SpanCollector",
     "TRACE_KEY",
     "current_span",
+    "phase",
     "render_trace",
+    "request_phases",
+    "run_in_executor",
     "start_span",
     "wire_context",
 ]

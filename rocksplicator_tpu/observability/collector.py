@@ -139,8 +139,19 @@ class SpanCollector:
         return self.enabled and rate > 0.0 and random.random() < rate
 
     def record(self, span) -> None:
-        """Called once per finished SAMPLED span (span.py __exit__)."""
-        self._put(next(self._seq), span.to_dict(self.process))
+        """Called once per finished SAMPLED span (span.py __exit__). A
+        sampled boundary root's phases go in beside it, as the children
+        ``snapshot`` makes of a kept root's (a sampled trace is one in
+        ~1,000: dicts, made now)."""
+        d = span.to_dict(self.process)
+        # (the children first: making them adds the root's
+        # ``<phase>_ms`` annotations, and a record in the ring is read
+        # by other threads)
+        children = _phase_children(d, span.phases, span._t0,
+                                   attached=True) if span.phases else ()
+        self._put(next(self._seq), d)
+        for child in children:
+            self._put(next(self._seq), child)
 
     def _put(self, i: int, record) -> None:
         ring = self._ring
@@ -159,11 +170,14 @@ class SpanCollector:
 
         The record is one FLAT tuple of strings and numbers,
         ``(name, start_ms, duration_ms, error, trace_id, span_id, seq,
-        key, value, ...)``; ``_root_dict`` turns it into a span's dict
-        for a reader. Flat, because the ring keeps one for every served
-        RPC and a tuple of atoms leaves the garbage collector's lists at
-        its first pass: tens of thousands of kept dicts (or objects)
-        lengthen every full collection, which stops all threads."""
+        n, t0, <n atoms of phases: name, begin, end, ...>, key, value,
+        ...)`` (``t0``, ``begin``, ``end``: the root's start and a
+        phase's two ends as ``time.perf_counter()`` read them); ``_root_dict`` turns it into a span's dict
+        for a reader, ``snapshot`` the phases into its children. Flat,
+        because the ring keeps one for every served RPC and a tuple of
+        atoms leaves the garbage collector's lists at its first pass:
+        tens of thousands of kept dicts (or objects) lengthen every full
+        collection, which stops all threads."""
         if tail:
             root.annotations["tail_kept"] = True
         if root.boundary:
@@ -171,9 +185,10 @@ class SpanCollector:
             trace_id, span_id = root.minted_ids()
         else:  # kept for its slowness alone: nobody's parent until now
             seq, trace_id, span_id = -1, new_id(), new_id()
+        phases = root.phases or ()
         rec = (root.name, time.time() * 1000.0 - duration_ms, duration_ms,
-               error, trace_id, span_id, seq,
-               *chain.from_iterable(root.annotations.items()))
+               error, trace_id, span_id, seq, len(phases), root._t0,
+               *phases, *chain.from_iterable(root.annotations.items()))
         if root.boundary:
             self._put(seq, rec)
         if not tail:
@@ -193,7 +208,8 @@ class SpanCollector:
         """A kept root's record as ``Span.to_dict`` shapes one. A root
         that nothing asked for its ids gets them from its place in the
         ring's sequence: the same on every call, unique in the process."""
-        name, start_ms, duration_ms, error, trace_id, span_id, seq = rec[:7]
+        name, start_ms, duration_ms, error, trace_id, span_id, seq, n = \
+            rec[:8]
         return {
             "trace_id": trace_id or f"{self._id_salt ^ (2 * seq):016x}",
             "span_id": span_id or f"{self._id_salt ^ (2 * seq + 1):016x}",
@@ -202,9 +218,18 @@ class SpanCollector:
             "process": self.process,
             "start_ms": round(start_ms, 3),
             "duration_ms": round(duration_ms, 3),
-            "annotations": dict(zip(rec[7::2], rec[8::2])),
+            "annotations": dict(zip(rec[9 + n::2], rec[10 + n::2])),
             "error": error,
         }
+
+    def _root_spans(self, rec: tuple) -> List[dict]:
+        """A kept root's record as its span and its phases' (``<root
+        name>:<phase>``). A root something real attached to (its ids
+        were minted: an ``always=True`` child asked for them) keeps
+        ``exec`` as an annotation alone."""
+        root = self._root_dict(rec)
+        return [root, *_phase_children(root, rec[9:9 + rec[7]], rec[8],
+                                       attached=bool(rec[4]))]
 
     # -- cold read path ---------------------------------------------------
 
@@ -233,8 +258,14 @@ class SpanCollector:
         held = {id(e) for e in kept}  # a slow boundary root is in both
         kept.extend(e for e in list(self._tail_ring)
                     if e is not None and id(e) not in held)
-        # a span's record is a dict; a kept root's becomes one here
-        spans = [e if type(e) is dict else self._root_dict(e) for e in kept]
+        # a span's record is a dict; a kept root's becomes one here,
+        # and its phases its children
+        spans: List[dict] = []
+        for e in kept:
+            if type(e) is dict:
+                spans.append(e)
+            else:
+                spans.extend(self._root_spans(e))
         spans.sort(key=lambda d: d["start_ms"])
         return spans
 
@@ -323,6 +354,50 @@ class SpanCollector:
             )
             lines.extend(render_trace(tr["spans"], tr["start_ms"]))
         return "\n".join(lines) + "\n"
+
+
+def _phase_children(root: dict, phases, t0: float,
+                    attached: bool) -> List[dict]:
+    """The flat ``phases`` (name, begin, end, ...: ``perf_counter``
+    readings, as the root's start ``t0`` is) of a boundary root, given
+    as its span's dict ``root``: ADDS the ``<phase>_ms`` annotations the
+    readers read to ``root`` (a root of several hops sums a name's
+    durations) and returns one child record a phase, named ``<root
+    name>:<phase>``, each over its own interval. Ids derive from the
+    root's; the parent is the root, or the innermost phase that holds
+    the child (``parse`` under ``exec``). ``attached``: the root has
+    real descendants, which ``exec`` would stand beside as a second
+    leaf: it stays an annotation alone."""
+    ann = root["annotations"]
+    if "exec_cpu_ms" in ann:
+        ann["exec_cpu_ms"] = round(ann["exec_cpu_ms"], 3)
+    triples = sorted(  # (offset ms, duration ms, name) by start; outer
+        # before inner where two tie
+        (((a - t0) * 1000.0, (b - a) * 1000.0, name) for name, a, b in
+         zip(phases[0::3], phases[1::3], phases[2::3])),
+        key=lambda p: (p[0], -p[1]))
+    children: List[dict] = []
+    open_: List[tuple] = []  # (end offset, span_id) of enclosing phases
+    for j, (off, dur, name) in enumerate(triples):
+        ann[name + "_ms"] = round(ann.get(name + "_ms", 0.0) + dur, 3)
+        if attached and name == "exec":
+            continue
+        while open_ and off + dur > open_[-1][0] + 1e-6:
+            open_.pop()
+        span_id = f"{root['span_id']}p{j}"
+        children.append({
+            "trace_id": root["trace_id"],
+            "span_id": span_id,
+            "parent_id": open_[-1][1] if open_ else root["span_id"],
+            "name": f"{root['name']}:{name}",
+            "process": root["process"],
+            "start_ms": round(root["start_ms"] + off, 3),
+            "duration_ms": round(dur, 3),
+            "annotations": {},
+            "error": None,
+        })
+        open_.append((off + dur, span_id))
+    return children
 
 
 def render_trace(spans: List[dict], t0_ms: Optional[float] = None

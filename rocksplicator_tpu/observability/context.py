@@ -13,7 +13,11 @@ propagation models this codebase needs for free:
 The one seam contextvars do NOT cross is ``loop.run_in_executor`` (asyncio
 submits the bare callable). Callers that hop onto the executor capture
 :func:`wire_context` on the event-loop side and reattach it via
-``start_span(..., remote=ctx)`` executor-side (see admin/handler.py).
+``start_span(..., remote=ctx)`` executor-side. A served request's hops
+go through :func:`~.hop.run_in_executor`, which carries the request's
+ROOT across on a contextvar of its own (and times the hop as the root's
+phases): an ``always=True`` span opened there joins it with no
+``remote=`` (see admin/handler.py).
 
 Cross-process propagation uses the same dict: a sampled caller injects
 ``{"trace_id", "span_id", "sampled"}`` into the RPC message's JSON frame
@@ -33,6 +37,17 @@ from typing import Any, Dict, Optional
 # None = no tracing decision made yet at this point.
 _current: contextvars.ContextVar = contextvars.ContextVar(
     "rstpu_active_span", default=None
+)
+
+# The served request's boundary root (``start_span(..., boundary=True)``:
+# the RPC server's dispatch), whatever span is current below it: where
+# ``span.phase()`` and the executor hop (hop.py) find the root, and an
+# ``always=True`` span its parent where nothing is current (the pool
+# thread the hop carried the root to). It is NOT the current span: an
+# ordinary span never sees it, and one that has ended (``phases`` None)
+# is nobody's root, whoever still holds a copy of this context.
+_root: contextvars.ContextVar = contextvars.ContextVar(
+    "rstpu_request_root", default=None
 )
 
 TRACE_KEY = "trace"  # reserved top-level key in the RPC message header

@@ -26,6 +26,21 @@ reattaches a wire/executor context captured via
 dispatch, and nothing else): head-unsampled it is still recorded, ALONE
 (:class:`_TailRoot`), so every request has a named owner on the
 timeline and the always-on operations it starts have a parent.
+
+**Phases of a root.** A boundary root also takes phases: three atoms
+each (name, begin, end) on the root's own flat record, and nothing else
+kept. The request's root rides its own contextvar, so ``with
+phase("db.open"):`` stamps it from anywhere in the request (also below
+a head-sampled child, and on a pool thread that
+:func:`~.hop.run_in_executor` carried the root to); with no root, or
+under the kill switch, it is the shared no-op. A hot path asks
+``request_phases()`` once, reads the clock only where that is a list,
+and extends it with its own ``(name, begin, end)``. That contextvar
+parents nothing but an ``always=True`` span opened where no span is
+current (the pool thread), and a root that has ended (``phases`` is
+None again) is no root: a task spawned under a request (a follower's
+pull loop, by ``add_db``) keeps a copy of the context, and must trace
+as if it had none.
 """
 
 from __future__ import annotations
@@ -33,7 +48,38 @@ from __future__ import annotations
 import time
 from typing import Any, Dict, Optional
 
-from .context import _current, new_id, valid_wire_context
+from .context import _current, _root, new_id, valid_wire_context
+
+
+class _NoopPhase:
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        return False
+
+
+_NOOP_PHASE = _NoopPhase()
+
+
+class _Phase:
+    """``with phase(name):`` — both ends on one thread."""
+
+    __slots__ = ("_phases", "_name", "_t0")
+
+    def __init__(self, phases: list, name: str):
+        self._phases = phases
+        self._name = name
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self._phases.extend((self._name, self._t0, time.perf_counter()))
+        return False
 
 
 class Span:
@@ -41,7 +87,7 @@ class Span:
 
     __slots__ = (
         "trace_id", "span_id", "parent_id", "name",
-        "start_ms", "_t0", "duration_ms", "annotations", "error",
+        "start_ms", "_t0", "duration_ms", "annotations", "error", "phases",
     )
 
     sampled = True
@@ -62,6 +108,11 @@ class Span:
         self.duration_ms: Optional[float] = None
         self.annotations = annotations or {}
         self.error: Optional[str] = None
+        # a boundary root's alone, while it is open: FLAT and raw
+        # (name, begin, end, name, ...: ``time.perf_counter()`` readings,
+        # turned into an offset from the root's start and a duration
+        # only when a reader asks)
+        self.phases: Optional[list] = None
 
     def annotate(self, **kv: Any) -> None:
         self.annotations.update(kv)
@@ -103,6 +154,7 @@ class _NoopSpan:
     boundary = False
     trace_id = ""
     span_id = ""
+    phases = None
 
     def annotate(self, **kv: Any) -> None:
         pass
@@ -133,16 +185,17 @@ class _TailRoot:
       ``wire_context()`` → ``remote=`` across an executor hop — becomes
       its child (the ids are minted when the first one asks) and carries
       a full trace below itself. A slow one is held in the tail ring
-      too: the same record with the same ids, never a second orphan."""
+      too: the same record with the same ids, never a second orphan.
+      Its phases go onto that one record."""
 
-    __slots__ = ("name", "t0", "collector", "tail_ms", "boundary",
-                 "annotations", "_trace_id", "_span_id")
+    __slots__ = ("name", "_t0", "collector", "tail_ms", "boundary",
+                 "annotations", "_trace_id", "_span_id", "phases")
     sampled = False
 
     def __init__(self, name: str, collector, boundary: bool,
                  annotations: Optional[Dict[str, Any]]):
         self.name = name
-        self.t0 = time.perf_counter()
+        self._t0 = time.perf_counter()
         # collector and threshold cached here so the exit never looks
         # the singleton up; wall-clock start is reconstructed at record
         # time (start = now - duration) — one fewer syscall per
@@ -152,6 +205,7 @@ class _TailRoot:
         self.boundary = boundary
         self.annotations = annotations or {}
         self._trace_id = self._span_id = ""
+        self.phases: Optional[list] = None
 
     @property
     def trace_id(self) -> str:
@@ -186,7 +240,7 @@ class start_span:
     contract."""
 
     __slots__ = ("_name", "_always", "_remote", "_boundary", "_ann",
-                 "_span", "_token")
+                 "_span", "_token", "_root_token")
 
     def __init__(self, name: str, always: bool = False,
                  remote: Optional[dict] = None, boundary: bool = False,
@@ -198,6 +252,7 @@ class start_span:
         self._ann = annotations
         self._span = NOOP_SPAN
         self._token = None
+        self._root_token = None
 
     def __enter__(self):
         remote = self._remote
@@ -219,6 +274,13 @@ class start_span:
                         remote["span_id"], self._ann)
         else:
             parent = _current.get()
+            if parent is None and self._always:
+                # on the pool thread a served request's hop carried its
+                # root to, nothing is current: the always-on operation
+                # joins the request's root while that is open
+                parent = _root.get()
+                if parent is not None and parent.phases is None:
+                    parent = None
             if parent is not None:
                 if not parent.sampled and not (
                         self._always and parent.boundary):
@@ -241,6 +303,12 @@ class start_span:
                                      self._ann)
                     self._span = root
                     self._token = _current.set(root)
+                    if self._boundary:
+                        # its request's root: it takes phases, and
+                        # phase() / the executor hop find it on its own
+                        # contextvar whatever span is current below it
+                        root.phases = []
+                        self._root_token = _root.set(root)
                     return root
                 else:
                     # unsampled ROOT: park the sentinel so descendants
@@ -250,6 +318,9 @@ class start_span:
                     return NOOP_SPAN
         self._span = span
         self._token = _current.set(span)
+        if self._boundary:  # a sampled request's root: as above
+            span.phases = []
+            self._root_token = _root.set(span)
         return span
 
     def __exit__(self, exc_type, exc, tb):
@@ -258,8 +329,10 @@ class start_span:
         span = self._span
         if span is NOOP_SPAN:
             return False
+        if self._root_token is not None:
+            _root.reset(self._root_token)
         if type(span) is _TailRoot:
-            duration_ms = (time.perf_counter() - span.t0) * 1000.0
+            duration_ms = (time.perf_counter() - span._t0) * 1000.0
             # tail_exempt: the operation declared its slowness is BY
             # DESIGN (a parked long-poll serve, a long-poll pull RTT) —
             # keeping those would fill the tail ring with waits and
@@ -272,6 +345,9 @@ class start_span:
                     col.record_root(
                         span, duration_ms, tail,
                         repr(exc) if exc_type is not None else None)
+            # ended: no root to whoever still holds a copy of the
+            # request's context (a task it spawned)
+            span.phases = None
             return False
         if exc_type is not None and span.error is None:
             span.error = repr(exc)
@@ -279,6 +355,7 @@ class start_span:
         from .collector import SpanCollector
 
         SpanCollector.get().record(span)
+        span.phases = None  # ended, as above
         return False
 
 
@@ -297,6 +374,28 @@ def detached_span(name: str, parent, **annotations: Any):
     if parent is None or not parent.sampled:
         return None
     return Span(name, parent.trace_id, parent.span_id, dict(annotations))
+
+
+def request_phases() -> Optional[list]:
+    """The open served request's phases (the root's own flat list), or
+    None: outside a served request, under the kill switch, or once the
+    request has ended. What a hot path asks before it reads the clock::
+
+        ph = request_phases()
+        ...
+        if ph is not None:
+            ph.extend(("parse", t0, t1))
+    """
+    root = _root.get()
+    return None if root is None else root.phases
+
+
+def phase(name: str):
+    """``with phase("db.open"):`` — a phase of the served request's
+    root, from anywhere in the request; the shared no-op where there is
+    no open root."""
+    phases = request_phases()
+    return _NOOP_PHASE if phases is None else _Phase(phases, name)
 
 
 def _enabled() -> bool:

@@ -32,7 +32,8 @@ from dataclasses import dataclass
 from typing import Callable, Iterator, List, Optional, Tuple
 
 from ..observability.context import current_span, wire_context
-from ..observability.span import detached_span, start_span
+from ..observability.hop import run_in_executor
+from ..observability.span import detached_span, request_phases, start_span
 from ..rpc.client_pool import RpcClientPool
 from ..rpc.errors import (RpcApplicationError, RpcConnectionError, RpcError,
                           RpcTransportConfigError)
@@ -507,7 +508,15 @@ class ReplicatedDB:
         parsed ONCE, here and not on the loop (a frame that is not a
         batch raises ``Corruption`` before anything is logged), and
         stays the frame down to the WAL."""
-        return self.write_async(decode_batch(raw_batch))
+        phases = request_phases()
+        if phases is None:
+            return self.write_async(decode_batch(raw_batch))
+        t0 = time.perf_counter()
+        batch = decode_batch(raw_batch)
+        t1 = time.perf_counter()
+        waiter = self.write_async(batch)  # stamp + WAL + memtable + notify
+        phases.extend(("parse", t0, t1, "commit", t1, time.perf_counter()))
+        return waiter
 
     def write_async_many(self, batches: List[WriteBatch]) -> List[AckWaiter]:
         """Pipelined GROUP write: commit every batch with one storage
@@ -1153,8 +1162,9 @@ class ReplicatedDB:
                     # probe's answer — the chaos invariant's foundation
                     await self._probe_upstream_seq()
             gate = self.read_gate(max_lag=max_lag, epoch=epoch)
-            values = await self._loop.run_in_executor(
-                self._executor, self._do_read, op, keys, start, count)
+            values = await run_in_executor(
+                self._loop, self._executor, self._do_read, op, keys,
+                start, count)
             if op in ("multi_get", "scan"):
                 # round-19 tail armor: re-check the request deadline
                 # before a potentially large response is serialized —
@@ -1268,9 +1278,16 @@ class ReplicatedDB:
         # reports for puts, measured commit → ack condition; recorded on
         # COMPLETED writes only (same served-only contract as reads)
         t0 = time.monotonic()
-        waiter = await self._loop.run_in_executor(
-            self._executor, self._write_encoded, raw_batch)
-        await asyncio.wrap_future(waiter.future)
+        waiter = await run_in_executor(
+            self._loop, self._executor, self._write_encoded, raw_batch)
+        # at RF 1 the future is done: still one trip through the loop
+        phases = request_phases()
+        if phases is None:
+            await asyncio.wrap_future(waiter.future)
+        else:
+            t_ack = time.perf_counter()
+            await asyncio.wrap_future(waiter.future)
+            phases.extend(("ack_wait", t_ack, time.perf_counter()))
         self._stats.add_metric(tagged("writes.latency_ms", op="put"),
                                (time.monotonic() - t0) * 1e3)
         return {"seq": waiter.seq, "acked": waiter.acked,

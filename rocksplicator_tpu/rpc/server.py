@@ -410,6 +410,11 @@ class RpcServer:
                 }
                 sp.annotate(error_code="INTERNAL")
                 stats.incr(f"rpc.{method}.internal_error")
+            # phase ``reply`` of the root: the JSON header and the
+            # coalesced send, on the loop (NOOP_SPAN: no list, no clock)
+            phases = sp.phases
+            if phases is not None:
+                t_reply = time.perf_counter()
             header, chunks = encode_message(reply)
             if armored and tenant is not None:
                 # response bytes are only known after encode: post-hoc
@@ -423,6 +428,8 @@ class RpcServer:
                 await conn.send_frames([(header, chunks)])
             except (ConnectionError, OSError):
                 pass
+            if phases is not None:
+                phases.extend(("reply", t_reply, time.perf_counter()))
 
     async def _admission_check(self, method: str, msg: Dict[str, Any],
                          tenant: Optional[str], queue_wait_ms: float,

@@ -54,10 +54,10 @@ RANKS = {
     "rocksplicator_tpu/utils/file_watcher.py:173": ('MultiFilePoller._lock', 34),
     "rocksplicator_tpu/utils/object_lock.py:18": ('ObjectLock._guard', 35),
     "rocksplicator_tpu/cluster/participant.py:76": ('Participant._publish_lock', 36),
-    "rocksplicator_tpu/replication/replicated_db.py:175": ('ReplicatedDB._ack_state_lock', 37),
-    "rocksplicator_tpu/replication/replicated_db.py:152": ('ReplicatedDB._epoch_lock', 38),
-    "rocksplicator_tpu/replication/replicated_db.py:181": ('ReplicatedDB._expiry_lock', 39),
-    "rocksplicator_tpu/replication/replicated_db.py:272": ('ReplicatedDB._write_traces_lock', 40),
+    "rocksplicator_tpu/replication/replicated_db.py:176": ('ReplicatedDB._ack_state_lock', 37),
+    "rocksplicator_tpu/replication/replicated_db.py:153": ('ReplicatedDB._epoch_lock', 38),
+    "rocksplicator_tpu/replication/replicated_db.py:182": ('ReplicatedDB._expiry_lock', 39),
+    "rocksplicator_tpu/replication/replicated_db.py:273": ('ReplicatedDB._write_traces_lock', 40),
     "rocksplicator_tpu/replication/replicator.py:46": ('Replicator._instance_lock', 41),
     "rocksplicator_tpu/utils/retry_policy.py:77": ('RetryBudget._lock', 42),
     "rocksplicator_tpu/utils/s3_stub.py:48": ('S3StubServer.lock', 43),

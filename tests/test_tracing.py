@@ -14,6 +14,7 @@ import json
 import os
 import subprocess
 import sys
+import threading
 import time
 import urllib.request
 
@@ -55,6 +56,17 @@ def wait_until(pred, timeout=15.0, interval=0.05):
 
 def _spans_by_name(name):
     return [s for s in SpanCollector.get().snapshot() if s["name"] == name]
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _time_every_hop():
+    """``exec_cpu_ms`` is taken on one root in eight (hop.py): here on
+    every one, so that a test can ask any root for it."""
+    from rocksplicator_tpu.observability import hop
+
+    every, hop.CPU_TIMED_EVERY = hop.CPU_TIMED_EVERY, 1
+    yield
+    hop.CPU_TIMED_EVERY = every
 
 
 # ---------------------------------------------------------------------------
@@ -450,6 +462,8 @@ class _RootHandler:
 
     def __init__(self):
         self.seen = {}
+        self.release = threading.Event()
+        self.done = threading.Event()
 
     async def handle_plain(self):
         with start_span("plain.child") as c:  # ordinary: stays free
@@ -476,6 +490,77 @@ class _RootHandler:
         await asyncio.get_running_loop().run_in_executor(None, hop)
         if sleep_ms:
             await asyncio.sleep(sleep_ms / 1000.0)
+        return {}
+
+    async def handle_hop(self, hops=1, always=False, under_child=False):
+        """A handler that hops through the observability helper, as the
+        read / write / admin handlers do, and stamps phases below."""
+        from rocksplicator_tpu.observability import (phase, request_phases,
+                                                     run_in_executor)
+
+        def work():
+            # the root rides its own contextvar to the pool thread:
+            # nothing is CURRENT there, as on any pool thread
+            self.seen["pool_current"] = current_span()
+            self.seen["pool_finds_root"] = (
+                request_phases() is not None
+                and request_phases() is self.seen["root_phases"])
+            with phase("parse"):
+                time.sleep(0.002)
+            with phase("commit"):
+                if always:
+                    with start_span("pool.always", always=True):
+                        pass
+                with start_span("pool.plain") as p:
+                    self.seen["pool_plain_sampled"] = p.sampled
+            return 7
+
+        loop = asyncio.get_running_loop()
+        self.seen["root_phases"] = request_phases()
+        for _ in range(hops):
+            if under_child:
+                with start_span("loop.child"):
+                    got = await run_in_executor(loop, None, work)
+            else:
+                got = await run_in_executor(loop, None, work)
+        return {"got": got}
+
+
+    async def handle_spawn(self):
+        """A handler whose pool half spawns a task that outlives the
+        request, as ``add_db`` of a follower spawns its pull loop
+        (``run_coroutine_threadsafe`` copies the pool thread's context,
+        the request's root in it)."""
+        from rocksplicator_tpu.observability import (phase, request_phases,
+                                                     run_in_executor)
+
+        loop = asyncio.get_running_loop()
+        seen = self.seen
+
+        async def outlives():
+            seen["bg_root_while_open"] = request_phases() is not None
+            while not self.release.is_set():
+                await asyncio.sleep(0.005)
+            seen["bg_phases"] = request_phases()
+            seen["bg_phase_is_noop"] = phase("a") is phase("b")
+            with phase("late"):
+                with start_span("bg.plain") as p:
+                    seen["bg_plain"] = type(p).__name__
+                    await asyncio.sleep(0.003)
+            with start_span("bg.always", always=True):
+                pass
+
+            def work():
+                seen["bg_pool_phases"] = request_phases()
+
+            await run_in_executor(loop, None, work)
+            self.done.set()
+
+        def work():
+            asyncio.run_coroutine_threadsafe(outlives(), loop)
+
+        await run_in_executor(loop, None, work)
+        await asyncio.sleep(0.02)  # the task runs under the open request
         return {}
 
 
@@ -511,9 +596,17 @@ def test_unsampled_rpc_leaves_one_root_named_by_method():
     assert wait_until(lambda: col.recorded == 3)
     assert handler.seen["child_sampled"] is False
     snap = col.snapshot()
-    assert [s["name"] for s in snap] == [
+    roots = [s for s in snap if ":" not in s["name"]]
+    assert [s["name"] for s in roots] == [
         "rpc.server.plain", "rpc.server.nope", "rpc.server.invalid"]
-    plain, nope, _bad = snap
+    # beside each root its one phase (no hop: the reply alone), a child
+    for root in roots:
+        (reply,) = [s for s in snap if s["parent_id"] == root["span_id"]]
+        assert reply["name"] == root["name"] + ":reply"
+        assert reply["trace_id"] == root["trace_id"]
+        assert root["annotations"]["reply_ms"] == reply["duration_ms"]
+    assert len(snap) == 6
+    plain, nope, _bad = roots
     assert plain["parent_id"] is None and plain["error"] is None
     assert plain["annotations"]["method"] == "plain"
     assert plain["annotations"]["queue_wait_ms"] >= 0.0
@@ -540,6 +633,11 @@ def test_always_span_joins_root_only_root_directly_and_across_a_hop():
     assert grandchild["trace_id"] == root["trace_id"]
     assert not _spans_by_name("ctl.hop.plain")
     assert {s["trace_id"] for s in col.snapshot()} == {root["trace_id"]}
+    # the root's phases are children beside the real ones (this handler
+    # hops by the bare loop call: a reply, and no hop to show)
+    assert sorted(s["name"] for s in col.snapshot()
+                  if s["parent_id"] == root["span_id"]) == [
+        "ctl.direct", "ctl.hop", "rpc.server.control:reply"]
 
 
 def test_slow_root_is_kept_once_with_the_ids_its_children_carry():
@@ -558,10 +656,13 @@ def test_slow_root_is_kept_once_with_the_ids_its_children_carry():
     assert _spans_by_name("ctl.direct")[0]["parent_id"] == root["span_id"]
     assert "tail_kept" not in _spans_by_name(
         "rpc.server.plain")[0]["annotations"]
-    # /traces shows it as one trace, the root with its children
+    # /traces shows it as one trace: the root, its three real
+    # descendants and its reply phase
     (trace,) = [t for t in json.loads(col.to_json_text())["traces"]
                 if t["trace_id"] == root["trace_id"]]
-    assert trace["span_count"] == 4
+    assert trace["span_count"] == 5
+    assert "rpc.server.control:reply" in col.waterfall_text(
+        trace_id=root["trace_id"])
 
 
 def test_kill_switch_silences_roots_and_what_runs_under_them():
@@ -569,11 +670,15 @@ def test_kill_switch_silences_roots_and_what_runs_under_them():
     col.configure(sample_rate=1.0, tail_ms=1.0)
     col.enabled = False  # RSTPU_TRACING=0
     try:
-        _serve_calls([("control", {"sleep_ms": 5}), ("plain", {})])
+        handler = _serve_calls([("control", {"sleep_ms": 5}), ("plain", {}),
+                                ("hop", {"always": True})])
     finally:
         col.enabled = True
     assert col.recorded == 0 and col.tail_kept == 0
     assert col.snapshot() == []
+    # and the hop was the bare run_in_executor: no root on either side
+    assert handler.seen["root_phases"] is None
+    assert handler.seen["pool_finds_root"] is False
 
 
 def test_kept_root_records_leave_the_garbage_collectors_lists():
@@ -586,15 +691,265 @@ def test_kept_root_records_leave_the_garbage_collectors_lists():
 
     col = SpanCollector.get()
     col.configure(sample_rate=0.0, tail_ms=1.0)
-    _serve_calls([("plain", {}), ("control", {"sleep_ms": 5}), ("nope", {})])
-    assert wait_until(lambda: col.recorded >= 3)
+    _serve_calls([("plain", {}), ("control", {"sleep_ms": 5}), ("nope", {}),
+                  ("hop", {"hops": 2})])
+    assert wait_until(lambda: col.recorded >= 4)
     gc.collect()
     roots = [e for e in col._ring + col._tail_ring if type(e) is tuple]
-    assert len(roots) >= 4  # three in the ring, the slow one kept twice
+    assert len(roots) >= 5  # four in the ring, the slow one kept twice
     assert not any(gc.is_tracked(e) for e in roots)
+    # with its phases on it (two hops: eleven of them) a record is
+    # still ONE tuple of strings and numbers, nothing nested
+    (hop,) = {e for e in roots if e[0] == "rpc.server.hop"}
+    assert hop[7] == 3 * 11
+    assert all(type(a) in (str, int, float, bool, type(None))
+               for e in roots for a in e)
     # and a reader still gets a span's dict, the same on every call
     assert _spans_by_name("rpc.server.nope") == _spans_by_name(
         "rpc.server.nope")
+
+
+# ---------------------------------------------------------------------------
+# inside a served RPC: phases of its root
+# ---------------------------------------------------------------------------
+
+
+def _children_of(snap, parent):
+    return [s for s in snap if s["parent_id"] == parent["span_id"]]
+
+
+def test_served_rpc_that_hops_carries_its_phases_in_order():
+    col = SpanCollector.get()
+    col.configure(sample_rate=0.0)
+    handler = _serve_calls([("hop", {})])
+    assert wait_until(lambda: _spans_by_name("rpc.server.hop"))
+    assert handler.seen["pool_finds_root"] is True
+    assert handler.seen["pool_current"] is None
+    assert handler.seen["pool_plain_sampled"] is False  # its own roll
+    snap = col.snapshot()
+    (root,) = _spans_by_name("rpc.server.hop")
+    top = _children_of(snap, root)
+    assert [s["name"].split(":")[1] for s in top] == [
+        "hop_in", "exec", "hop_out", "reply"]
+    ann = root["annotations"]
+    for s in top:
+        assert s["duration_ms"] >= 0.0
+        assert s["start_ms"] >= root["start_ms"]
+        assert ann[s["name"].split(":")[1] + "_ms"] == s["duration_ms"]
+    starts = [s["start_ms"] for s in top]
+    assert starts == sorted(starts) and len(set(starts)) == 4
+    assert sum(s["duration_ms"] for s in top) <= root["duration_ms"] + 0.01
+    assert 0.0 <= ann["exec_cpu_ms"] <= ann["exec_ms"] + 1.0
+    assert ann["exec_ms"] >= 2.0  # the pool thread slept 2 ms in parse
+    assert ann["exec_cpu_ms"] < ann["exec_ms"]  # ... off the CPU
+    assert ann["parse_ms"] >= 2.0 and "commit_ms" in ann
+
+
+def test_snapshot_yields_phases_as_children_with_stable_ids():
+    col = SpanCollector.get()
+    col.configure(sample_rate=0.0)
+    _serve_calls([("hop", {}), ("hop", {"hops": 2})])
+    assert wait_until(lambda: len(_spans_by_name("rpc.server.hop")) == 2)
+    snap = col.snapshot()
+    assert snap == col.snapshot()  # the same ids on every call
+    assert len({s["span_id"] for s in snap}) == len(snap)
+    for root in _spans_by_name("rpc.server.hop"):
+        for exec_ in _children_of(snap, root):
+            inside = _children_of(snap, exec_)
+            assert [s["name"] for s in inside] == (
+                ["rpc.server.hop:parse", "rpc.server.hop:commit"]
+                if exec_["name"] == "rpc.server.hop:exec" else [])
+            assert {s["trace_id"] for s in inside} <= {root["trace_id"]}
+    # a root of several hops: a child a phase over its own interval,
+    # the annotation the sum of a name's durations
+    one, two = _spans_by_name("rpc.server.hop")
+    assert len(_children_of(snap, one)) == 4
+    assert [s["name"].split(":")[1] for s in _children_of(snap, two)] == [
+        "hop_in", "exec", "hop_out", "hop_in", "exec", "hop_out", "reply"]
+    execs = [s for s in snap if s["name"] == "rpc.server.hop:exec"
+             and s["parent_id"] == two["span_id"]]
+    assert two["annotations"]["exec_ms"] == pytest.approx(
+        sum(s["duration_ms"] for s in execs), abs=0.002)
+    assert two["annotations"]["parse_ms"] >= 4.0
+    # /traces.txt shows the RPC with its phases, indented under it
+    text = col.waterfall_text(trace_id=one["trace_id"])
+    assert "\n    rpc.server.hop:hop_in" in text
+    assert "\n      rpc.server.hop:parse" in text
+
+
+def test_root_with_a_real_child_yields_no_exec_child():
+    """``exec`` would stand beside the real descendants as a second
+    leaf (and take half their blame): it stays an annotation."""
+    col = SpanCollector.get()
+    col.configure(sample_rate=0.0)
+    _serve_calls([("hop", {"always": True})])
+    assert wait_until(lambda: _spans_by_name("rpc.server.hop"))
+    snap = col.snapshot()
+    (root,) = _spans_by_name("rpc.server.hop")
+    (real,) = _spans_by_name("pool.always")
+    assert real["parent_id"] == root["span_id"]  # no remote= needed
+    assert sorted(s["name"] for s in _children_of(snap, root)) == [
+        "pool.always", "rpc.server.hop:commit", "rpc.server.hop:hop_in",
+        "rpc.server.hop:hop_out", "rpc.server.hop:parse",
+        "rpc.server.hop:reply"]
+    assert not _spans_by_name("rpc.server.hop:exec")
+    assert root["annotations"]["exec_ms"] >= 2.0
+
+
+def test_sampled_root_takes_its_phases_below_a_sampled_child():
+    """Head-sampled, the root is a ``Span`` and a child of it is the
+    current span on the loop: the hop still stamps the ROOT."""
+    col = SpanCollector.get()
+    col.configure(sample_rate=1.0)
+    handler = _serve_calls([("hop", {"under_child": True})])
+    assert wait_until(lambda: _spans_by_name("rpc.server.hop"))
+    snap = col.snapshot()
+    (root,) = _spans_by_name("rpc.server.hop")
+    assert root["parent_id"] is not None  # joined the caller's trace
+    assert sorted(s["name"] for s in _children_of(snap, root)) == [
+        "loop.child", "rpc.server.hop:commit",
+        "rpc.server.hop:hop_in", "rpc.server.hop:hop_out",
+        "rpc.server.hop:parse", "rpc.server.hop:reply"]
+    # an ORDINARY span on the pool thread is nobody's child, as before
+    # there were phases (the hop carries the root for phases and for
+    # always-on spans alone): it rolled its own sampling
+    assert handler.seen["pool_plain_sampled"] is True
+    (plain,) = _spans_by_name("pool.plain")
+    assert plain["parent_id"] is None
+    assert plain["trace_id"] != root["trace_id"]
+    assert not _spans_by_name("rpc.server.hop:exec")  # a real trace
+    assert root["annotations"]["exec_ms"] >= 2.0
+    assert 0.0 <= root["annotations"]["exec_cpu_ms"]
+
+
+def test_exec_cpu_is_taken_on_one_root_in_eight_whole_or_not(monkeypatch):
+    """Two reads of the thread's clock cost as much as the hop's other
+    stamps together: one root in ``CPU_TIMED_EVERY`` pays for them, and a
+    root of several hops is timed in all of them or in none."""
+    from rocksplicator_tpu.observability import hop
+
+    monkeypatch.setattr(hop, "CPU_TIMED_EVERY", 8)
+    col = SpanCollector.get()
+    col.configure(sample_rate=0.0)
+    _serve_calls([("hop", {"hops": 2})] * 16)
+    assert wait_until(lambda: len(_spans_by_name("rpc.server.hop")) == 16)
+    roots = _spans_by_name("rpc.server.hop")
+    timed = [r for r in roots if "exec_cpu_ms" in r["annotations"]]
+    assert len(timed) == 2
+    assert all("exec_ms" in r["annotations"] for r in roots)
+    for r in timed:  # both hops slept 2 ms in parse, off the CPU
+        ann = r["annotations"]
+        assert ann["exec_ms"] >= 4.0 and ann["exec_cpu_ms"] < ann["exec_ms"]
+
+
+def test_phase_outside_a_served_request_is_the_shared_noop():
+    from rocksplicator_tpu.observability import phase, request_phases
+
+    assert request_phases() is None
+    assert phase("parse") is phase("commit")
+    with phase("parse"):
+        with start_span("not.a.boundary", always=True) as sp:
+            assert request_phases() is None and sp.phases is None
+    assert [s["name"] for s in SpanCollector.get().snapshot()] == [
+        "not.a.boundary"]
+
+
+def test_task_that_outlives_its_request_has_no_root():
+    """A task spawned from the pool half of a served request keeps a
+    copy of the request's context. Once the request has ended its root
+    is nobody's: no phase lands on the finished record, an ordinary span
+    rolls its own sampling (tail-kept when slow), an always-on one is a
+    root of its own, the hop is the bare ``run_in_executor``."""
+    col = SpanCollector.get()
+    col.configure(sample_rate=0.0, tail_ms=1.0)
+    handler = _RootHandler()
+    _serve_calls([("spawn", {})], handler)
+    assert wait_until(lambda: _spans_by_name("rpc.server.spawn"))
+    (root,) = _spans_by_name("rpc.server.spawn")
+    before = col.snapshot()
+    handler.release.set()
+    assert handler.done.wait(10)
+    seen = handler.seen
+    assert seen["bg_root_while_open"] is True  # a copy, while it is open
+    assert seen["bg_phases"] is None and seen["bg_pool_phases"] is None
+    assert seen["bg_phase_is_noop"] is True
+    assert seen["bg_plain"] == "_TailRoot"  # not the NOOP of a dead root
+    (plain,) = _spans_by_name("bg.plain")
+    assert plain["annotations"]["tail_kept"] is True
+    (always,) = _spans_by_name("bg.always")
+    assert always["parent_id"] is None
+    assert always["trace_id"] != root["trace_id"]
+    # the finished request's record is as it was: nobody minted its ids
+    # (``exec`` is still its child), no phase was added
+    assert [s for s in col.snapshot()
+            if s["trace_id"] == root["trace_id"]] == [
+        s for s in before if s["trace_id"] == root["trace_id"]]
+    assert _spans_by_name("rpc.server.spawn:exec")
+    assert not _spans_by_name("rpc.server.spawn:late")
+
+
+@pytest.mark.parametrize("sample_rate,tail_ms", [(1.0, 0.0), (0.0, 0.5)],
+                         ids=["head_sampled", "tail_kept"])
+def test_follower_added_by_a_served_add_db_still_traces_its_pulls(
+        tmp_path, sample_rate, tail_ms):
+    """``add_db`` opens the follower on the pool thread, where its pull
+    loop is spawned with a copy of the RPC's context: ``repl.pull`` must
+    go on tracing as on a follower added in-process (head-sampled, or
+    kept for its slowness), not fall silent under a finished root."""
+    from rocksplicator_tpu.admin.handler import AdminHandler
+
+    col = SpanCollector.get()
+    col.configure(sample_rate=sample_rate, tail_ms=tail_ms)
+    # no long poll: a pull is not ``tail_exempt``, and a slow one is kept
+    flags = ReplicationFlags(server_long_poll_ms=0,
+                             pull_error_delay_min_ms=50,
+                             pull_error_delay_max_ms=120)
+    nodes = []
+    for name in ("leader", "follower"):
+        repl = Replicator(port=0, flags=flags)
+        handler = AdminHandler(str(tmp_path / name), repl)
+        server = RpcServer(port=0, ioloop=repl.ioloop)
+        server.add_handler(handler)
+        server.start()
+        nodes.append((repl, handler, server))
+    ioloop = IoLoop.default()
+    pool = RpcClientPool()
+
+    def call(port, method, **args):
+        async def go():
+            return await pool.call("127.0.0.1", port, method, args,
+                                   timeout=30)
+        return ioloop.run_sync(go())
+
+    try:
+        (lrepl, lhandler, lserver), (_, fhandler, fserver) = nodes
+        call(lserver.port, "add_db", db_name="seg00001", role="LEADER")
+        call(fserver.port, "add_db", db_name="seg00001", role="FOLLOWER",
+             upstream_ip="127.0.0.1", upstream_port=lrepl.port)
+        assert wait_until(
+            lambda: len(_spans_by_name("rpc.server.add_db")) == 2)
+        lhandler.db_manager.get_db("seg00001").write(
+            WriteBatch().put(b"k", b"v"))
+        assert wait_until(
+            lambda: fhandler.db_manager.get_db("seg00001").get(b"k")
+            == b"v")
+        assert wait_until(lambda: _spans_by_name("repl.pull"))
+        add_db_traces = {s["trace_id"]
+                         for s in _spans_by_name("rpc.server.add_db")}
+        pulls = _spans_by_name("repl.pull")
+        assert all(s["parent_id"] is None for s in pulls)
+        assert not add_db_traces & {s["trace_id"] for s in pulls}
+        if sample_rate:
+            (seq_read,) = _spans_by_name("repl.seq_read")  # the cold pull's
+            assert seq_read["parent_id"] in {s["span_id"] for s in pulls}
+        else:
+            assert all(s["annotations"]["tail_kept"] for s in pulls)
+    finally:
+        ioloop.run_sync(pool.close())
+        for repl, handler, server in nodes:
+            server.stop()
+            handler.close()
+            repl.stop()
 
 
 # ---------------------------------------------------------------------------
@@ -839,6 +1194,134 @@ def test_clock_check_pairs_module_events_with_their_launch_spans():
         "largest_violation_us": 1000.0}
     assert clock_check(recording, (0.0, 1000 * ms), spans[:1]) == {
         "module_events": 2, "launch_pairs": 0}
+
+
+@pytest.fixture(scope="module")
+def served_node_roots(tmp_path_factory):
+    """The roots a real node records for one ``add_db``, ``write``,
+    ``read`` and ``clear_db`` (the three call sites of the hop helper
+    and every stamp below them), head-unsampled."""
+    import struct
+
+    from rocksplicator_tpu.admin import AdminHandler
+
+    SpanCollector.reset_for_test()
+    col = SpanCollector.get()
+    col.configure(sample_rate=0.0, capacity=4096)
+    repl = Replicator(port=0, flags=FAST)
+    handler = AdminHandler(
+        str(tmp_path_factory.mktemp("served_node") / "dbs"), repl)
+    server = RpcServer(port=0, ioloop=repl.ioloop)
+    server.add_handler(handler)
+    server.start()
+    batch = WriteBatch()
+    for i in range(64):
+        batch.put(b"k%013d" % i, struct.pack("<q", i))
+    try:
+        async def go():
+            pool = RpcClientPool()
+
+            def call(port, method, args):
+                return pool.call("127.0.0.1", port, method, args)
+
+            await call(server.port, "add_db",
+                       {"db_name": "seg00001", "role": "LEADER"})
+            wrote = await call(repl.port, "write", {
+                "db_name": "seg00001", "raw_batch": batch.encode()})
+            got = await call(repl.port, "read", {
+                "db_name": "seg00001", "op": "get",
+                "keys": [b"k%013d" % 7]})
+            await call(server.port, "clear_db",
+                       {"db_name": "seg00001", "reopen_db": True})
+            await pool.close()
+            return wrote, got
+
+        wrote, got = repl.ioloop.run_sync(go())
+        assert wrote["acked"] is True
+        assert bytes(got["values"][0]) == struct.pack("<q", 7)
+        assert wait_until(lambda: _spans_by_name("rpc.server.clear_db"))
+        return col.snapshot()
+    finally:
+        server.stop()
+        repl.stop()
+
+
+@pytest.mark.parametrize("method,top,under_exec", [
+    ("read", ["hop_in", "exec", "hop_out", "reply"], []),
+    ("write", ["hop_in", "exec", "hop_out", "ack_wait", "reply"],
+     ["parse", "commit"]),
+    ("add_db", ["hop_in", "exec", "hop_out", "reply"],
+     ["db.open", "db.register"]),
+    ("clear_db", ["hop_in", "exec", "hop_out", "reply"],
+     ["db.close", "db.destroy", "db.meta", "db.open", "db.register"]),
+])
+def test_served_node_stamps_the_phases_of_each_method(
+        served_node_roots, method, top, under_exec):
+    snap = served_node_roots
+    name = "rpc.server." + method
+    (root,) = [s for s in snap if s["name"] == name]
+    kids = _children_of(snap, root)
+    assert [s["name"] for s in kids] == [f"{name}:{p}" for p in top]
+    (exec_,) = [s for s in kids if s["name"] == name + ":exec"]
+    assert [s["name"] for s in _children_of(snap, exec_)] == [
+        f"{name}:{p}" for p in under_exec]
+    ann = root["annotations"]
+    assert all(ann[p + "_ms"] >= 0.0 for p in top + under_exec)
+    assert sum(ann[p + "_ms"] for p in top) <= root["duration_ms"] + 0.01
+    assert sum(ann[p + "_ms"] for p in under_exec) <= ann["exec_ms"] + 0.01
+    assert ann["exec_cpu_ms"] <= ann["exec_ms"] + 1.0
+
+
+def test_phase_table_reads_the_roots_a_served_node_records(
+        served_node_roots):
+    """tools/chip_clock_check.py's per-method table off real roots."""
+    from tools.chip_clock_check import phase_table
+
+    table = phase_table(served_node_roots)
+    assert set(table) == {"read", "write", "add_db", "clear_db"}
+    for method, row in table.items():
+        assert row["roots"] == 1 and row["mean_ms"] > 0.0
+        assert 50.0 < row["covered_pct"] <= 100.0
+        assert row["exec_off_cpu_mean_ms"] >= 0.0
+    assert set(table["write"]["phases_count_mean_ms"]) == {
+        "hop_in", "exec", "parse", "commit", "hop_out", "ack_wait", "reply"}
+
+
+def test_phase_table_counts_and_nesting():
+    """The table's arithmetic on roots of a stand-in admin handler: a
+    method with no root has no row; a nested phase (``db.open`` under
+    ``exec``) is not counted twice in the coverage."""
+    from tools.chip_clock_check import TOP_PHASES, phase_table
+
+    col = SpanCollector.get()
+    col.configure(sample_rate=0.0, capacity=4096)
+
+    class Admin:  # AdminHandler._run's shape, without a node
+        async def handle_add_db(self):
+            from rocksplicator_tpu.observability import (phase,
+                                                         run_in_executor)
+
+            def do():
+                with phase("db.open"):
+                    time.sleep(0.002)
+
+            await run_in_executor(asyncio.get_running_loop(), None, do)
+            return {}
+
+    _serve_calls([("add_db", {}), ("add_db", {}), ("nope", {})],
+                 handler=Admin())
+    assert wait_until(lambda: col.recorded >= 3)
+    table = phase_table(col.snapshot())
+    assert set(table) == {"add_db"}  # a method with no root has no row
+    row = table["add_db"]
+    assert row["roots"] == 2
+    counts = {p: n for p, (n, _ms) in row["phases_count_mean_ms"].items()}
+    assert counts == {"hop_in": 2, "exec": 2, "db.open": 2, "hop_out": 2,
+                      "reply": 2}
+    assert row["phases_count_mean_ms"]["db.open"][1] >= 2.0
+    assert row["exec_off_cpu_mean_ms"] >= 1.5  # it slept
+    assert 50.0 < row["covered_pct"] <= 100.0
+    assert "db.open" not in TOP_PHASES  # nested: not counted twice
 
 
 @pytest.mark.parametrize("straggler", [False, True],

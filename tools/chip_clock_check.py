@@ -12,7 +12,19 @@ the run's own and the last):
   matching ``tpu.readback`` span ends. Printed: the events checked, the
   least lead and tail, and the largest violation, in microseconds.
 - **seconds ``reduce_trace`` takes** after the window (its ``blame`` is
-  gaps x spans, and every served RPC records a span).
+  gaps x spans, and every served RPC records a span and, since PR 37, a
+  child a phase), with the records that went into ``blame``: spans, and
+  the phase children among them.
+- **inside a served RPC** (PR 37): per method (``read``, ``write``,
+  ``add_db``, ``clear_db``, the ingest RPC) the window's roots, their
+  mean, the count and mean of every phase on them (``hop_in``, ``exec``,
+  ``hop_out``, ``reply``; a write's ``parse``, ``commit``, ``ack_wait``;
+  the admin plane's ``db.*``: the roots' ``<phase>_ms`` annotations), of
+  ``exec_ms - exec_cpu_ms`` (the pool thread held the request and did
+  not run: GIL wait, locks, blocking IO), and the share of the roots'
+  time that the phases of the root's own level cover (``hop_in + exec +
+  hop_out + ack_wait + reply``; the others lie inside ``exec``). This
+  look needs no recording: it is printed with ``--trace 0`` too.
 - **which host codec ran**: the process's ``codec.native_files`` and
   ``codec.python_files`` counters (set-up and window; ``tpu/format.py``),
   and how many point reads found their block in the block cache.
@@ -128,6 +140,46 @@ def clock_check(recording: dict, slice_ns, host_spans: list) -> dict:
     return out
 
 
+PHASED_METHODS = ("read", "write", "add_db", "clear_db",
+                  "add_s3_sst_files_to_db")
+TOP_PHASES = ("hop_in", "exec", "hop_out", "ack_wait", "reply")
+
+
+def phase_table(spans: list) -> dict:
+    """Per method: the window's roots, their mean, ``{phase: [count,
+    mean ms]}`` off the roots' ``<phase>_ms`` annotations, the mean
+    ``exec_ms - exec_cpu_ms`` and the share of the roots' time that the
+    phases of the root's own level cover."""
+    out = {}
+    for method in PHASED_METHODS:
+        roots = [s for s in spans if s["name"] == "rpc.server." + method]
+        if not roots:
+            continue
+        by_phase: dict = {}
+        off_cpu = []
+        for s in roots:
+            ann = s["annotations"]
+            for key, ms in ann.items():
+                if key.endswith("_ms") and key not in ("queue_wait_ms",
+                                                       "exec_cpu_ms"):
+                    by_phase.setdefault(key[:-3], []).append(ms)
+            if "exec_ms" in ann and "exec_cpu_ms" in ann:
+                off_cpu.append(max(0.0, ann["exec_ms"] - ann["exec_cpu_ms"]))
+        total = sum(s["duration_ms"] for s in roots)
+        covered = sum(sum(by_phase.get(p, ())) for p in TOP_PHASES)
+        out[method] = {
+            "roots": len(roots), "mean_ms": round(total / len(roots), 3),
+            "phases_count_mean_ms": {
+                p: [len(ms), round(sum(ms) / len(ms), 3)]
+                for p, ms in sorted(by_phase.items(),
+                                    key=lambda kv: -sum(kv[1]))},
+            "exec_off_cpu_mean_ms": round(
+                sum(off_cpu) / len(off_cpu), 3) if off_cpu else None,
+            "covered_pct": round(100.0 * covered / total, 2) if total
+            else None}
+    return out
+
+
 def main(argv=None) -> int:
     from chipbench import run as harness
     from chipbench import trace_reduce as tr
@@ -144,7 +196,9 @@ def main(argv=None) -> int:
         t = time.monotonic()
         out = real_reduce_trace(trace_dir, marks, spans, out_dir)
         harness.say(f"reduce_trace: {time.monotonic() - t:.2f} s over "
-                    f"{len(spans)} spans")
+                    f"{len(spans)} records into blame, "
+                    f"{sum(1 for s in spans if ':' in s['name'])} of them "
+                    f"phases of a root")
         return out
 
     real_run_cell = harness.run_cell
@@ -217,6 +271,8 @@ def main(argv=None) -> int:
                for k in ("compact.key_widths.uniform",
                          "compact.key_widths.mixed",
                          "flush.key_widths.mixed")})))
+        harness.say("inside a served RPC, by method: " + json.dumps(
+            phase_table(run.spans)))
         return real_read_metrics(bench, group, package, cell, run)
 
     tr.reduce, harness.reduce_trace = reduce, reduce_trace
