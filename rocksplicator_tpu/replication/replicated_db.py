@@ -32,7 +32,7 @@ from dataclasses import dataclass
 from typing import Callable, Iterator, List, Optional, Tuple
 
 from ..observability.context import current_span, wire_context
-from ..observability.hop import run_in_executor
+from ..observability.hop import run_in_executor, wait_future
 from ..observability.span import detached_span, request_phases, start_span
 from ..rpc.client_pool import RpcClientPool
 from ..rpc.errors import (RpcApplicationError, RpcConnectionError, RpcError,
@@ -41,7 +41,7 @@ from ..storage.records import WriteBatch, decode_batch, scan_batch_meta
 from ..testing import failpoints as fp
 from ..utils.misc import now_ms
 from ..utils.retry_policy import RetryPolicy
-from ..utils.stats import Stats, tagged
+from ..utils.stats import Stats, Tally, tagged
 from .ack_window import AckWaiter, AckWindow, resolved_waiter
 from .cond_var import AsyncNotifier
 from .db_wrapper import DbWrapper
@@ -53,6 +53,12 @@ from .wire import ReplicaRole, ReplicateErrorCode
 log = logging.getLogger(__name__)
 
 LeaderResolver = Callable[[str], Optional[Tuple[str, int]]]
+
+# a served ``write``'s ack, by how it was met: before the executor half
+# returned (at commit: RF 1, mode 0, an ack that beat the registration),
+# or awaited (a follower's ack, a timeout, a fence, a close)
+ACKS_AT_COMMIT = Tally("write.ack.at_commit")
+ACKS_AWAITED = Tally("write.ack.awaited")
 
 
 @dataclass
@@ -1238,8 +1244,9 @@ class ReplicatedDB:
         """Remote entry to the leader write path (the macro-bench's
         full-stack put op class): fence-check the carried epoch, commit
         via write_async OFF the loop (it may block on window flow
-        control), and await the ack condition. Returns the batch's start
-        seq and whether the replication ack condition was met."""
+        control), and await the ack condition where commit did not meet
+        it. Returns the batch's start seq and whether the replication ack
+        condition was met."""
         if self.role not in (ReplicaRole.LEADER, ReplicaRole.NOOP):
             # role check BEFORE any epoch processing: a FOLLOWER must
             # never adopt a client-claimed epoch (_reject_stale_epoch
@@ -1280,13 +1287,15 @@ class ReplicatedDB:
         t0 = time.monotonic()
         waiter = await run_in_executor(
             self._loop, self._executor, self._write_encoded, raw_batch)
-        # at RF 1 the future is done: still one trip through the loop
         phases = request_phases()
-        if phases is None:
-            await asyncio.wrap_future(waiter.future)
-        else:
+        if phases is not None:
             t_ack = time.perf_counter()
-            await asyncio.wrap_future(waiter.future)
+        if waiter.future.done():  # met at commit: nothing to wait for
+            ACKS_AT_COMMIT.n += 1
+        else:
+            ACKS_AWAITED.n += 1
+            await wait_future(self._loop, waiter.future)
+        if phases is not None:
             phases.extend(("ack_wait", t_ack, time.perf_counter()))
         self._stats.add_metric(tagged("writes.latency_ms", op="put"),
                                (time.monotonic() - t0) * 1e3)

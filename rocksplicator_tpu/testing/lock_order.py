@@ -54,18 +54,18 @@ RANKS = {
     "rocksplicator_tpu/utils/file_watcher.py:173": ('MultiFilePoller._lock', 34),
     "rocksplicator_tpu/utils/object_lock.py:18": ('ObjectLock._guard', 35),
     "rocksplicator_tpu/cluster/participant.py:76": ('Participant._publish_lock', 36),
-    "rocksplicator_tpu/replication/replicated_db.py:176": ('ReplicatedDB._ack_state_lock', 37),
-    "rocksplicator_tpu/replication/replicated_db.py:153": ('ReplicatedDB._epoch_lock', 38),
-    "rocksplicator_tpu/replication/replicated_db.py:182": ('ReplicatedDB._expiry_lock', 39),
-    "rocksplicator_tpu/replication/replicated_db.py:273": ('ReplicatedDB._write_traces_lock', 40),
+    "rocksplicator_tpu/replication/replicated_db.py:182": ('ReplicatedDB._ack_state_lock', 37),
+    "rocksplicator_tpu/replication/replicated_db.py:159": ('ReplicatedDB._epoch_lock', 38),
+    "rocksplicator_tpu/replication/replicated_db.py:188": ('ReplicatedDB._expiry_lock', 39),
+    "rocksplicator_tpu/replication/replicated_db.py:279": ('ReplicatedDB._write_traces_lock', 40),
     "rocksplicator_tpu/replication/replicator.py:46": ('Replicator._instance_lock', 41),
     "rocksplicator_tpu/utils/retry_policy.py:77": ('RetryBudget._lock', 42),
     "rocksplicator_tpu/utils/s3_stub.py:48": ('S3StubServer.lock', 43),
     "rocksplicator_tpu/observability/collector.py:54": ('SpanCollector._instance_lock', 44),
     "rocksplicator_tpu/utils/ssl_context_manager.py:57": ('SslContextManager._lock', 45),
-    "rocksplicator_tpu/utils/stats.py:231": ('Stats._buffers_lock', 46),
-    "rocksplicator_tpu/utils/stats.py:240": ('Stats._dump_lock', 47),
-    "rocksplicator_tpu/utils/stats.py:212": ('Stats._instance_lock', 48),
+    "rocksplicator_tpu/utils/stats.py:249": ('Stats._buffers_lock', 46),
+    "rocksplicator_tpu/utils/stats.py:258": ('Stats._dump_lock', 47),
+    "rocksplicator_tpu/utils/stats.py:229": ('Stats._instance_lock', 48),
     "rocksplicator_tpu/utils/status_server.py:31": ('StatusServer._instance_lock', 49),
     "rocksplicator_tpu/rpc/admission.py:115": ('TenantAdmission._instance_lock', 50),
     "rocksplicator_tpu/rpc/admission.py:125": ('TenantAdmission._lock', 51),
@@ -84,7 +84,7 @@ RANKS = {
     "rocksplicator_tpu/storage/engine.py:287": ('DB._manifest_mutex', 64),
     "rocksplicator_tpu/utils/file_watcher.py:40": ('FileWatcher._instance_lock', 65),
     "rocksplicator_tpu/cluster/participant.py:75": ('Participant._state_lock', 66),
-    "rocksplicator_tpu/utils/stats.py:218": ('Stats._lock', 67),
+    "rocksplicator_tpu/utils/stats.py:236": ('Stats._lock', 67),
     "rocksplicator_tpu/storage/compaction_scheduler.py:123": ('IoBudget._lock', 68),
     "rocksplicator_tpu/storage/wal.py:69": ('WalWriter._sync_lock', 69),
 }
@@ -101,5 +101,5 @@ ORDER = {
     ("rocksplicator_tpu/storage/engine.py:280", "rocksplicator_tpu/storage/engine.py:287"),
     ("rocksplicator_tpu/storage/engine.py:280", "rocksplicator_tpu/storage/wal.py:69"),
     ("rocksplicator_tpu/utils/dbconfig.py:48", "rocksplicator_tpu/utils/file_watcher.py:40"),
-    ("rocksplicator_tpu/utils/stats.py:240", "rocksplicator_tpu/utils/stats.py:218"),
+    ("rocksplicator_tpu/utils/stats.py:258", "rocksplicator_tpu/utils/stats.py:236"),
 }

@@ -200,6 +200,23 @@ class _ThreadBuffer(threading.local):
         self.lock = threading.Lock()
 
 
+class Tally:
+    """A counter its owner bumps as a plain integer (``tally.n += 1``: no
+    thread buffer, no lock, no clock, where ``Stats.incr`` holds the GIL
+    for about a microsecond: PERF.md §6's price list), published under
+    ``name`` by every ``Stats`` registry when it is flushed, that is, when
+    it is read. For a count on a served RPC's path. The increment has no call
+    in it, so no thread switch falls inside it; the owner bumps it from
+    the threads of event loops alone."""
+
+    __slots__ = ("name", "n")
+
+    def __init__(self, name: str) -> None:
+        self.name = name
+        self.n = 0
+        Stats._tallies.append(self)
+
+
 class Stats:
     """Process-wide stats registry.
 
@@ -210,6 +227,7 @@ class Stats:
 
     _instance: Optional["Stats"] = None
     _instance_lock = threading.Lock()
+    _tallies: List[Tally] = []  # every Tally of the process
 
     def __init__(self) -> None:
         import os
@@ -240,6 +258,9 @@ class Stats:
         self._dump_lock = threading.Lock()
         self._export_cache: Tuple[float, Optional[Dict]] = (0.0, None)
         self._prom_cache: Tuple[float, Optional[str]] = (0.0, None)
+        # what each Tally read when this registry last published it: a
+        # registry counts from its own start
+        self._tally_seen: Dict[Tally, int] = {t: t.n for t in Stats._tallies}
 
     # -- singleton --------------------------------------------------------
 
@@ -323,6 +344,15 @@ class Stats:
                         h = self._metrics[name] = _Histogram()
                     for v in vals:
                         h.add(v, now)
+            for tally in Stats._tallies:
+                n = tally.n
+                delta = n - self._tally_seen.get(tally, 0)
+                if delta:
+                    self._tally_seen[tally] = n
+                    ts = self._counters.get(tally.name)
+                    if ts is None:
+                        ts = self._counters[tally.name] = _TimeSeries()
+                    ts.add(delta, now)
         if dead:
             # Prune drained buffers of exited threads so _all_buffers does
             # not grow with every short-lived worker thread.

@@ -1270,6 +1270,8 @@ def test_served_node_stamps_the_phases_of_each_method(
     assert sum(ann[p + "_ms"] for p in top) <= root["duration_ms"] + 0.01
     assert sum(ann[p + "_ms"] for p in under_exec) <= ann["exec_ms"] + 0.01
     assert ann["exec_cpu_ms"] <= ann["exec_ms"] + 1.0
+    if method == "write":  # met at commit (RF 1): no trip through the loop
+        assert ann["ack_wait_ms"] < 0.1
 
 
 def test_phase_table_reads_the_roots_a_served_node_records(

@@ -68,6 +68,15 @@ the run's own and the last):
   ``counter_names_64x15k.refresh`` reads ``indexed`` = every frame and
   ``bulk`` 0 (a BUILT batch, as the admin plane's own metadata puts, is
   no frame and counts under neither). ``--trace 0`` too.
+- **how a served write's ack was met**: the process's
+  ``write.ack.at_commit`` / ``write.ack.awaited`` counters (acks met
+  before the executor half returned, which take no trip through the
+  loop / acks the request waited for: a follower's ack, a timeout, a
+  fence; ``replication/replicated_db.py`` ``handle_write_request``)
+  beside ``rpc.write.success``. Every cell runs at RF 1, so ``at_commit``
+  equals the served ``write`` RPCs and ``awaited`` is 0; the
+  ``ack_wait`` phase of ``inside a served RPC`` reads ~0. ``--trace 0``
+  too.
 - **which key shape the shards had**: the process's
   ``compact.key_widths.uniform`` / ``.mixed`` counters (shards through
   the served door whose keys have one length / differ in length:
@@ -260,6 +269,10 @@ def main(argv=None) -> int:
             **{"process_" + k: Stats.get().get_counter(k)
                for k in ("write.apply.bulk", "write.apply.indexed",
                          "rpc.write.success")})))
+        harness.say("write acks by how they were met: " + json.dumps(
+            {"process_" + k: Stats.get().get_counter(k)
+             for k in ("write.ack.at_commit", "write.ack.awaited",
+                       "rpc.write.success")}))
         harness.say("shards by key shape: " + json.dumps(dict(
             {"window_launched_" + w: sum(
                 int(a["shards"]) for a in streams
