@@ -382,10 +382,12 @@ def resolve_sorted_lanes(
     seq32: bool,
     key_words: int,
     val_row=None,               # (N,) u32: the index path's one lane
+    key_cols: int = KEY_WORDS,  # the input key matrix's width: 6 or 12
 ) -> Dict[str, jnp.ndarray]:
     """Phases 2-4 of the kernel on ALREADY merge-ordered lanes
     ((invalid-last, key asc, seq desc) order): boundary detection,
-    segmented LSM resolution, stream compaction.
+    segmented LSM resolution, stream compaction. The output key matrices
+    are ``key_cols`` words wide, as the input's were.
 
     ``val_row`` (the index path, ``vw_lanes`` empty): each row's index
     into the caller's value matrix rides the compaction in the value
@@ -437,9 +439,10 @@ def resolve_sorted_lanes(
     else:
         out_key_len = jnp.where(live, klen_const, jnp.uint32(0))
 
-    # full-width (6-lane) key matrices; lanes >= key_words are zero by the
-    # caller-verified promise, LE lanes are byteswaps of the BE lanes
-    zeros_tail = [jnp.zeros_like(out_seq_lo)] * (KEY_WORDS - key_words)
+    # full-width (the input's 6 or 12 lanes) key matrices; lanes >=
+    # key_words are zero by the caller-verified promise, LE lanes are
+    # byteswaps of the BE lanes
+    zeros_tail = [jnp.zeros_like(out_seq_lo)] * (key_cols - key_words)
     out_kw_be = jnp.stack(out_key_lanes + zeros_tail, axis=1)
     out_kw_le = jnp.stack(
         [bswap32(w) for w in out_key_lanes] + zeros_tail, axis=1)
@@ -468,7 +471,7 @@ def resolve_sorted_lanes(
                      "seq32", "key_words"),
 )
 def merge_resolve_kernel(
-    key_words_be: jnp.ndarray,  # (N, 6) u32
+    key_words_be: jnp.ndarray,  # (N, 6) u32, or (N, 12): keys over 24 B
     key_len: jnp.ndarray,       # (N,) u32
     seq_hi: jnp.ndarray,
     seq_lo: jnp.ndarray,
@@ -531,6 +534,7 @@ def _sort_resolve(key_words_be, key_len, seq_hi, seq_lo, vtype, val_words,
         merge_kind=merge_kind, drop_tombstones=drop_tombstones,
         uniform_klen=uniform_klen, seq32=seq32, key_words=key_words,
         val_row=payload[2] if index else None,
+        key_cols=key_words_be.shape[1],
     )
 
 

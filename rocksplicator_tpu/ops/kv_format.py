@@ -6,8 +6,11 @@ vs XLA's fixed-shape world. Representation chosen:
 - **keys** → 24-byte zero-padded prefixes as 6 *big-endian* u32 lanes plus a
   length lane. For keys ≤ 24 bytes (the counter workload and most sharded-KV
   schemas) the prefix is the whole key, so lexicographic byte order ==
-  ascending (word0..word5, len) tuple order. Longer keys are detected at
-  pack time and routed to the CPU backend.
+  ascending (word0..word5, len) tuple order. The array paths (the lane
+  decoders, the memtable drain: storage/planar.py ``key_word_cols``) carry
+  keys of 25 to 48 bytes as 12 lanes, whose order is the same tuple order
+  over twelve words; ``pack_entries`` (the tuple path's packer) takes 24
+  and routes longer keys to the CPU backend.
 - **values** → zero-padded to a fixed byte width as u32 lanes + a length
   lane. Counter values are 8 bytes. For the uint64-add merge path values
   are additionally exposed as 4×16-bit limbs (in u32 lanes) so segment sums
@@ -15,7 +18,8 @@ vs XLA's fixed-shape world. Representation chosen:
 - **seqs** → (hi, lo) u32 pairs (no x64 dependency).
 
 The same 24-byte-prefix convention is shared with the storage bloom filter
-(storage/bloom.py) so TPU-built blooms are byte-identical.
+(storage/bloom.py) so TPU-built blooms are byte-identical: the bloom hashes
+the first six words and the length, whatever the lanes' width.
 """
 
 from __future__ import annotations

@@ -205,36 +205,24 @@ class _MergedMemView:
         """Concatenated unsorted lanes across every memtable (see
         MemTable.drain_lanes) — the caller's single lexsort restores the
         global (key asc, seq desc) order. None when any memtable can't
-        express its entries as lanes; cross-memtable value width
-        mismatches are caught here and by the caller's planar_widths
-        check (key lengths may differ, within a memtable and across)."""
+        express its entries as lanes (key lengths and value widths may
+        differ, within a memtable and across: the lanes are as wide as
+        the widest)."""
         import numpy as np
+
+        from .native_compaction import concat_lanes
 
         parts = [m.drain_lanes() for m in self._imms]
         if any(p is None for p in parts):
             return None
-        # Cross-memtable width checks BEFORE any pad/concat — scalar
-        # reads off each part's lanes, so a mismatched burst bails in
-        # O(parts) instead of after a giant transient concatenation
-        # (the same round-2 lesson MemTable.drain_lanes applies within
-        # one memtable).
-        part_vlens = set()
+        kc = max(p[0]["key_words_be"].shape[1] for p in parts)
         for lanes, _km in parts:
-            live = lanes["val_len"][lanes["vtype"] != 2]
-            if len(live):  # all-DELETE parts constrain nothing
-                part_vlens.add(int(live[0]))
-        if len(part_vlens) > 1:
-            return None  # mixed value widths across memtables
-        vw = max(p[0]["val_words"].shape[1] for p in parts)
-        for lanes, _km in parts:
-            w = lanes["val_words"].shape[1]
-            if w < vw:
-                lanes["val_words"] = np.pad(
-                    lanes["val_words"], [(0, 0), (0, vw - w)])
-        lanes = {
-            f: np.concatenate([l[f] for l, _km in parts])
-            for f in parts[0][0]
-        }
+            w = lanes["key_words_be"].shape[1]
+            if w < kc:
+                lanes["key_words_be"] = np.pad(
+                    lanes["key_words_be"], [(0, 0), (0, kc - w)])
+        lanes = concat_lanes([l for l, _km in parts],
+                             sum(km.shape[0] for _l, km in parts))
         kmax = max(km.shape[1] for _l, km in parts)
         return lanes, np.concatenate([
             km if km.shape[1] == kmax
@@ -1219,7 +1207,7 @@ class DB:
         from ..tpu.format import planar_stride, planar_widths, \
             write_sst_from_arrays
         from .bloom import BloomFilter
-        from .planar import key_shape
+        from .planar import key_shape, value_shape
 
         with start_span("flush.drain"):
             drained = mem.drain_lanes()
@@ -1229,8 +1217,8 @@ class DB:
         n = key_mat.shape[0]
         widths = planar_widths(lanes, n)
         if widths is None:
-            return False  # cross-memtable width mismatch
-        klen, _vlen, mixed = widths
+            return False
+        klen, _vlen, mixed, vmixed = widths
         # the rows' own key lengths, as they were drained (the bloom is
         # order-independent and takes the pre-sort key matrix)
         key_lens = lanes["key_len"].astype(np.uint64)
@@ -1253,7 +1241,10 @@ class DB:
         shape = key_shape(key_lens)
         if mixed:
             Stats.get().incr("flush.key_widths.mixed")
-        with start_span("flush.encode", entries=n, **shape):
+        if vmixed:
+            Stats.get().incr("flush.value_widths.mixed")
+        with start_span("flush.encode", entries=n, **shape,
+                        **value_shape(lanes["vtype"], lanes["val_len"])):
             # bulk bloom (order-independent — built from the pre-sort key
             # matrix) instead of a per-key Python loop
             bloom = BloomFilter.build_from_arrays(

@@ -171,11 +171,13 @@ class MemTable:
         per-entry repack). Returns ``(lanes, key_bytes_matrix)`` or
         None when the planar lane representation can't express this
         memtable: an empty or over-wide key (keys of DIFFERING length,
-        1 to 24 bytes, are lanes like any other), non-uniform
-        non-DELETE value widths, value wider than the planar u16 vlen
-        field, or a DELETE carrying a value. Width checks run inline
-        during collection so a disqualifying entry bails before any
-        large buffer is built.
+        1 to 48 bytes, are lanes like any other: six key words a row up
+        to 24 bytes, twelve above), a value wider than the planar u16
+        vlen field, or a DELETE carrying a value. Live values may differ
+        in width (each row's ``val_len``; the value lanes are as wide as
+        the widest, a shorter value zero-tailed). Width checks run
+        inline during collection so a disqualifying entry bails before
+        any large buffer is built.
 
         ``lanes`` is the kernel lane dict (key_words_be, key_len,
         seq_hi/lo, vtype, val_words, val_len); the (n, widest key) u8
@@ -187,7 +189,7 @@ class MemTable:
         the next apply), the values joined."""
         import numpy as np
 
-        from .planar import PLANAR_MAX_KLEN, PLANAR_MAX_VLEN
+        from .planar import PLANAR_MAX_KLEN, PLANAR_MAX_VLEN, key_word_cols
 
         n = len(self._seqs)
         if n == 0:
@@ -208,18 +210,16 @@ class MemTable:
         if bool(vlens[is_del].any()):
             return None
         live_vlens = vlens[~is_del]
-        vlen = int(live_vlens[0]) if len(live_vlens) else 0
-        if vlen > PLANAR_MAX_VLEN or not bool((live_vlens == vlen).all()):
+        vlen = int(live_vlens.max()) if len(live_vlens) else 0  # the widest
+        if vlen > PLANAR_MAX_VLEN:
             return None
+        vmixed = bool(len(live_vlens)) and int(live_vlens.min()) != vlen
         seq = np.frombuffer(self._seqs, dtype=np.uint64)
-        key_buf = np.zeros((n, 24), dtype=np.uint8)
+        kc = key_word_cols(klen)
+        key_buf = np.zeros((n, 4 * kc), dtype=np.uint8)
         flat = np.frombuffer(bytes(self._key_buf), dtype=np.uint8)
         if mixed:
-            # each key's bytes to the head of its own row: byte j of the
-            # buffer lies in row r at column j - (where r's key starts)
-            starts = np.cumsum(klens, dtype=np.int64) - klens
-            row = np.repeat(np.arange(n), klens)
-            key_buf[row, np.arange(len(flat)) - starts[row]] = flat
+            key_buf[_ragged_rows(klens, len(flat))] = flat
             key_mat = key_buf[:, :klen]
         else:
             key_mat = flat.reshape(n, klen)
@@ -229,16 +229,32 @@ class MemTable:
         if vlen:
             # a DELETE brought no bytes: the join is the live rows'
             # values back to back, in arrival order
-            val_buf[~is_del, :vlen] = np.frombuffer(
-                b"".join(self._vals), dtype=np.uint8).reshape(-1, vlen)
+            joined = np.frombuffer(b"".join(self._vals), dtype=np.uint8)
+            if vmixed:
+                val_buf[_ragged_rows(vlens, len(joined))] = joined
+            else:
+                val_buf[~is_del, :vlen] = joined.reshape(-1, vlen)
         lanes = {
             "key_words_be": key_buf.view(">u4").astype(
-                np.uint32).reshape(n, 6),
+                np.uint32).reshape(n, kc),
             "key_len": klens.copy(),
             "seq_hi": (seq >> np.uint64(32)).astype(np.uint32),
             "seq_lo": (seq & np.uint64(0xFFFFFFFF)).astype(np.uint32),
             "vtype": vtype_arr,
             "val_words": val_buf.view("<u4").reshape(n, vw),
-            "val_len": np.where(is_del, 0, vlen).astype(np.uint32),
+            "val_len": (vlens.copy() if vmixed
+                        else np.where(is_del, 0, vlen).astype(np.uint32)),
         }
         return lanes, key_mat
+
+
+def _ragged_rows(lens, total: int):
+    """Index arrays (rows, columns) that put byte j of a buffer of
+    ``total`` bytes, rows of ``lens`` bytes back to back, at the head of
+    its own row: byte j lies in row r at column j - (where r's bytes
+    start)."""
+    import numpy as np
+
+    starts = np.cumsum(lens, dtype=np.int64) - lens
+    row = np.repeat(np.arange(len(lens)), lens)
+    return row, np.arange(total) - starts[row]

@@ -146,11 +146,13 @@ class NativeLib:
                 ctypes.c_uint64, ctypes.c_uint32, ctypes.c_uint32,
                 ctypes.c_int32, ctypes.c_uint32, ctypes.c_int32,
                 _u8p, ctypes.c_uint64, _u64p, _u32p, _u8p, _u32p, _u32p,
+                _u32p,
             ]
             lib.tsst_decode_file_lanes.restype = ctypes.c_int64
             lib.tsst_decode_file_lanes.argtypes = [
                 ctypes.c_int32, _u64p, ctypes.c_uint64, ctypes.c_int32,
                 _u32p, _u32p, ctypes.c_uint64, ctypes.c_uint32,
+                ctypes.c_uint32, ctypes.c_int32,
                 _u32p, _u32p, _u32p, _u32p, _u32p, _u32p, _u32p, _u32p,
                 ctypes.c_int32, ctypes.c_uint64, _u32p, _i64p,
             ]
@@ -420,7 +422,8 @@ class NativeLib:
 
     def planar_encode_file(self, arrays, count: int, klen: int, vlen: int,
                            seq32: bool, block_entries: int,
-                           compression: int, mixed: bool = False):
+                           compression: int, mixed: bool = False,
+                           vmixed: bool = False):
         """Every PLANAR block of one file from lanes ``[0, count)`` in
         ONE call (tsst_planar_encode_file; the GIL is released for all
         of it). Returns ``(payload, offsets, sizes, codecs, checksums)``:
@@ -429,7 +432,9 @@ class NativeLib:
         None when the lanes are narrower than the widths ask (the Python
         sink says what is wrong with them). ``mixed``: the rows' keys
         differ in length (``klen`` their widest): each block's header
-        and key-length plane follow from its own rows."""
+        and key-length plane follow from its own rows. ``vmixed``: the
+        same for the values' widths (``vlen`` their widest) and the
+        value-length plane."""
         kw, vw = (klen + 3) // 4, (vlen + 3) // 4
         kw_be = np.ascontiguousarray(
             arrays["key_words_be"][:count], dtype=np.uint32)
@@ -452,6 +457,11 @@ class NativeLib:
             key_len = np.ascontiguousarray(
                 arrays["key_len"][:count], dtype=np.uint32)
             cap += count + 4 * nblocks  # the key-length planes
+        val_len = None
+        if vmixed:
+            val_len = np.ascontiguousarray(
+                arrays["val_len"][:count], dtype=np.uint32)
+            cap += 2 * count + 4 * nblocks  # the value-length planes
         out = np.empty(cap, dtype=np.uint8)
         offs = np.empty(nblocks, dtype=np.uint64)
         sizes = np.empty(nblocks, dtype=np.uint32)
@@ -467,6 +477,7 @@ class NativeLib:
             sizes.ctypes.data_as(_u32p), self._u8(codecs),
             chks.ctypes.data_as(_u32p),
             None if key_len is None else key_len.ctypes.data_as(_u32p),
+            None if val_len is None else val_len.ctypes.data_as(_u32p),
         )
         if wrote < 0:
             raise ValueError(f"tsst_planar_encode_file failed ({wrote})")
@@ -474,15 +485,19 @@ class NativeLib:
 
     def decode_file_lanes(self, fd: int, index: np.ndarray, planar: bool,
                           klen: int, vlen: int, rows: int,
-                          chk_mode: int = 0, chk_len: int = 0):
+                          chk_mode: int = 0, chk_len: int = 0,
+                          key_cols: int = 6, vmixed: bool = False):
         """Every block of one file -> the eight kernel lanes in ONE call
         (tsst_decode_file_lanes: pread, inflate, transpose; the GIL is
         released for all of it). ``index``: (nblocks, 3) u64 of offset,
-        size, codec nibble. ``klen == 0`` (row format only): the value
-        width is block 0's first entry's, and the keys have whatever
-        lengths they have (1 to 24 bytes; ``lanes["key_len"]``). A
-        PLANAR file's ``klen`` is its widest key. ``chk_mode`` 1 / 2: also each
-        block's poly1 / poly1w value over ``chk_len``.
+        size, codec nibble. ``key_cols``: the key lanes' width in words
+        (6, or 12 for keys of 25 to 48 bytes). ``klen == 0`` (row format
+        only): the keys have whatever lengths they have (1 to
+        ``4 * key_cols`` bytes; ``lanes["key_len"]``), and the value
+        width is block 0's first entry's. A PLANAR file's ``klen`` is its
+        widest key. ``vmixed``: the values differ in width, ``vlen`` the
+        widest (``lanes["val_len"]`` has each row's). ``chk_mode`` 1 / 2:
+        also each block's poly1 / poly1w value over ``chk_len``.
 
         Returns ``(status, lanes, checksums, blocks)``: status >= 0 is
         the row count (lanes cut to it); below zero the C routine's code
@@ -499,8 +514,8 @@ class NativeLib:
         val_cols = max(2, (vlen + 3) // 4)
         while True:
             lanes = {
-                "key_words_be": np.empty((rows, 6), dtype=np.uint32),
-                "key_words_le": np.empty((rows, 6), dtype=np.uint32),
+                "key_words_be": np.empty((rows, key_cols), dtype=np.uint32),
+                "key_words_le": np.empty((rows, key_cols), dtype=np.uint32),
                 "key_len": np.empty(rows, dtype=np.uint32),
                 "seq_hi": np.empty(rows, dtype=np.uint32),
                 "seq_lo": np.empty(rows, dtype=np.uint32),
@@ -511,7 +526,7 @@ class NativeLib:
             got = self._lib.tsst_decode_file_lanes(
                 fd, self._u64(index), nblocks, int(planar),
                 ctypes.byref(klen_io), ctypes.byref(vlen_io), rows,
-                val_cols,
+                val_cols, key_cols, int(vmixed),
                 *(lanes[f].ctypes.data_as(_u32p) for f in (
                     "key_words_be", "key_words_le", "key_len", "seq_hi",
                     "seq_lo", "vtype", "val_words", "val_len")),

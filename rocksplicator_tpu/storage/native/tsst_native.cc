@@ -441,13 +441,15 @@ int64_t rlz_decompress(const uint8_t* src, uint64_t n,
 // Block: u32 n | u8 klen | u8 vlen_lo | u8 flags | u8 vlen_hi | u64 0
 // (vlen = vlen_lo | vlen_hi<<8 — u16, byte 7 was reserved-zero so old
 // files read back unchanged), then u32
-// planes: key words (BE values, ceil(klen/4) x n), key lengths (only
-// when flags&2: ceil(n/4), 4 packed/word; klen is then the block's
-// widest key and a shorter key's tail bytes are zero), seq_lo (n), seq_hi
-// (n, absent when flags&1), vtype (ceil(n/4), 4 packed/word), value
-// words (LE values, ceil(vlen/4) x n). Keys ascending bytewise, which on
-// the planes is the zero-padded big-endian words, then the length ->
-// binary search, then the contiguous match run (MERGE stacks).
+// planes: key words (BE values, ceil(klen/4) x n; klen up to 48), key
+// lengths (only when flags&2: ceil(n/4), 4 packed/word; klen is then the
+// block's widest key and a shorter key's tail bytes are zero), seq_lo
+// (n), seq_hi (n, absent when flags&1), vtype (ceil(n/4), 4 packed/word),
+// value lengths (only when flags&4: ceil(n/2), a u16 each, 2 packed/word;
+// vlen is then the block's widest value), value words (LE values,
+// ceil(vlen/4) x n). Keys ascending bytewise, which on the planes is the
+// zero-padded big-endian words, then the length -> binary search, then
+// the contiguous match run (MERGE stacks).
 // -2 = malformed.
 
 static inline int planar_cmp_key(
@@ -475,11 +477,12 @@ extern "C" int64_t tsst_planar_get_entries(
   uint32_t n = get_u32(data);
   uint8_t bklen = data[4], flags = data[6];
   uint16_t bvlen = (uint16_t)data[5] | ((uint16_t)data[7] << 8);
-  if (bklen == 0 || bklen > 24) return -2;
+  if (bklen == 0 || bklen > 48) return -2;
   uint64_t kw = (bklen + 3) / 4, vw = ((uint64_t)bvlen + 3) / 4;
-  int seq32 = flags & 1, klens = flags & 2;
+  int seq32 = flags & 1, klens = flags & 2, vlens = flags & 4;
   uint64_t words = (uint64_t)n * (kw + 1 + (seq32 ? 0 : 1) + vw)
-                 + (klens ? 2 : 1) * (((uint64_t)n + 3) / 4);
+                 + (klens ? 2 : 1) * (((uint64_t)n + 3) / 4)
+                 + (vlens ? ((uint64_t)n + 1) / 2 : 0);
   if (len != 16 + 4 * words) return -2;
   if (n == 0) return 0;
   const uint32_t* planes = (const uint32_t*)(data + 16);
@@ -489,7 +492,10 @@ extern "C" int64_t tsst_planar_get_entries(
   const uint32_t* seq_lo = planes + kw * n + (klens ? (n + 3) / 4 : 0);
   const uint32_t* seq_hi = seq32 ? nullptr : seq_lo + n;
   const uint8_t* vtp = (const uint8_t*)(seq_lo + n + (seq32 ? 0 : n));
-  const uint32_t* vvp = (const uint32_t*)(vtp + 4 * ((n + 3) / 4));
+  // an entry's own value length: its u16 of the plane, else the block's
+  const uint8_t* vlp = vlens ? vtp + 4 * ((n + 3) / 4) : nullptr;
+  const uint32_t* vvp = (const uint32_t*)(
+      vtp + 4 * ((n + 3) / 4) + (vlens ? 4 * ((n + 1) / 2) : 0));
 
   // lower_bound: first index with entry key >= query key
   uint64_t lo = 0, hi = n;
@@ -512,8 +518,9 @@ extern "C" int64_t tsst_planar_get_entries(
     seqs[found] = s;
     uint8_t vt = vtp[i];
     vtypes[found] = vt;
-    uint64_t vlen = (vt == 2) ? 0 : bvlen;
-    if (vlen > vlen_cap) return -2;
+    uint64_t vlen = vlp ? (uint64_t)vlp[2 * i] | ((uint64_t)vlp[2 * i + 1] << 8)
+                        : (vt == 2) ? 0 : bvlen;
+    if (vlen > bvlen || vlen > vlen_cap) return -2;
     for (uint64_t b = 0; b < vlen; b++) {
       uint32_t w; memcpy(&w, (const uint8_t*)(vvp + (b / 4) * n + i), 4);
       out_vals[found * vlen_cap + b] = (uint8_t)(w >> (8 * (b % 4)));
@@ -886,9 +893,11 @@ static uint32_t chk_bytes(const uint8_t* data, uint64_t n, uint64_t length,
 
 static inline uint64_t planar_plane_words(uint64_t n, uint64_t kw,
                                           uint64_t vw, bool seq32,
-                                          bool klens = false) {
+                                          bool klens = false,
+                                          bool vlens = false) {
   return n * (kw + 1 + (seq32 ? 0 : 1) + vw)
-       + (klens ? 2 : 1) * ((n + 3) / 4);
+       + (klens ? 2 : 1) * ((n + 3) / 4)
+       + (vlens ? (n + 1) / 2 : 0);
 }
 
 static bool pread_all(int fd, uint8_t* dst, uint64_t size, uint64_t off) {
@@ -975,9 +984,15 @@ struct BlockFetcher {
 // whose rows differ in length gets the key-length plane and flag 2, its
 // header's klen and its key planes at the block's own widest key; a
 // block whose rows share one length is the block it always was, at that
-// length. Returns the bytes written, -1 when `out_cap` is short, -2 on
-// arguments the layout can't take (a key length of 0 or over `klen`
-// among them), -3 when zlib fails.
+// length. `val_len` (null: every live value is `vlen` bytes) does the
+// same for values, `vlen` their widest: a block whose live rows differ
+// in width gets the value-length plane and flag 4, its header's vlen and
+// its value planes at the block's own widest value; one of one width is
+// the old block at that width (an all-DELETE block: at `vlen`). Keys are
+// up to 48 bytes (`kw_cols` words of lanes a row). Returns the bytes
+// written, -1 when `out_cap` is short, -2 on arguments the layout can't
+// take (a key length of 0 or over `klen`, a value over `vlen`, among
+// them), -3 when zlib fails.
 extern "C" int64_t tsst_planar_encode_file(
     const uint32_t* kw_be, uint32_t kw_cols,
     const uint32_t* seq_lo, const uint32_t* seq_hi, const uint8_t* vtype,
@@ -986,14 +1001,15 @@ extern "C" int64_t tsst_planar_encode_file(
     uint32_t block_entries, int32_t compression,
     uint8_t* out, uint64_t out_cap,
     uint64_t* blk_off, uint32_t* blk_size, uint8_t* blk_codec,
-    uint32_t* blk_chk, const uint32_t* key_len) {
-  uint64_t vw = ((uint64_t)vlen + 3) / 4;
-  if (klen == 0 || klen > 24 || vlen > 0xFFFFu || block_entries == 0
-      || (klen + 3) / 4 > kw_cols || vw > val_cols
+    uint32_t* blk_chk, const uint32_t* key_len, const uint32_t* val_len) {
+  uint64_t fvw = ((uint64_t)vlen + 3) / 4;
+  if (klen == 0 || klen > 48 || vlen > 0xFFFFu || block_entries == 0
+      || (klen + 3) / 4 > kw_cols || fvw > val_cols
       || (!seq32 && seq_hi == nullptr))
     return -2;
   uint64_t full_words = planar_plane_words(
-      block_entries, (klen + 3) / 4, vw, seq32, key_len != nullptr);
+      block_entries, (klen + 3) / 4, fvw, seq32, key_len != nullptr,
+      val_len != nullptr);
   ChkPowers pw;
   z_stream zs;
   memset(&zs, 0, sizeof(zs));
@@ -1017,14 +1033,31 @@ extern "C" int64_t tsst_planar_encode_file(
       if (lo == 0 || hi > klen) { rc = -2; break; }
       bklen = hi; klens = lo != hi;
     }
-    uint64_t kw = (bklen + 3) / 4;
-    uint64_t words = planar_plane_words(n, kw, vw, seq32, klens);
+    uint32_t bvlen = vlen;   // this block's header vlen: its widest value
+    bool vlens = false;      // and whether its live rows differ in width
+    if (val_len != nullptr) {
+      bool any = false;
+      uint32_t lo = 0, hi = 0;
+      for (uint64_t i = 0; i < n; i++) {
+        if (vtype[start + i] == 2) continue;
+        uint32_t l = val_len[start + i];
+        if (!any) { lo = hi = l; any = true; }
+        lo = std::min(lo, l); hi = std::max(hi, l);
+      }
+      if (any) {
+        if (hi > vlen) { rc = -2; break; }
+        bvlen = hi; vlens = lo != hi;
+      }
+    }
+    uint64_t kw = (bklen + 3) / 4, vw = ((uint64_t)bvlen + 3) / 4;
+    uint64_t words = planar_plane_words(n, kw, vw, seq32, klens, vlens);
     uint64_t raw_len = 16 + 4 * words;
     if (pos + raw_len > out_cap) { rc = -1; break; }
     uint8_t* raw = out + pos;
     put_u32(raw, (uint32_t)n);
-    raw[4] = (uint8_t)bklen; raw[5] = (uint8_t)(vlen & 0xFF);
-    raw[6] = (seq32 ? 1 : 0) | (klens ? 2 : 0); raw[7] = (uint8_t)(vlen >> 8);
+    raw[4] = (uint8_t)bklen; raw[5] = (uint8_t)(bvlen & 0xFF);
+    raw[6] = (seq32 ? 1 : 0) | (klens ? 2 : 0) | (vlens ? 4 : 0);
+    raw[7] = (uint8_t)(bvlen >> 8);
     put_u64(raw + 8, 0);
     uint8_t* w = raw + 16;
     for (uint64_t k = 0; k < kw; k++)
@@ -1042,6 +1075,15 @@ extern "C" int64_t tsst_planar_encode_file(
     memcpy(w, vtype + start, n);
     memset(w + n, 0, vt_bytes - n);
     w += vt_bytes;
+    if (vlens) {
+      uint64_t vl_bytes = 4 * ((n + 1) / 2);
+      for (uint64_t i = 0; i < n; i++) {
+        uint32_t l = vtype[start + i] == 2 ? 0u : val_len[start + i];
+        w[2 * i] = (uint8_t)(l & 0xFF); w[2 * i + 1] = (uint8_t)(l >> 8);
+      }
+      memset(w + 2 * n, 0, vl_bytes - 2 * n);
+      w += vl_bytes;
+    }
     for (uint64_t k = 0; k < vw; k++)
       for (uint64_t i = 0; i < n; i++, w += 4)
         put_u32(w, val_words[(start + i) * val_cols + k]);
@@ -1076,14 +1118,22 @@ extern "C" int64_t tsst_planar_encode_file(
 // Lane source: pread + inflate every block of one file and fill the
 // eight kernel lanes (the arrays tpu/format.py's Python decoders
 // return). `index` is (nblocks, 3) u64: offset, size, codec nibble.
+// The key lanes are `kw_cols` words a row (6, or 12 for keys of 25 to
+// 48 bytes), the value lanes `val_cols`.
 // planar != 0: PLANAR blocks, widths as the file's props give them (the
 // key width is the file's WIDEST key: a block's own may be narrower, and
 // with flag 2 each row has its own; key_len says it row by row).
-// planar == 0: entry-stream blocks, walked entry by entry, of ONE value
-// width. With *klen_io given (the sink's "uniform" prop) every key has
-// that length; *klen_io == 0 (no prop: a flush-written or foreign file)
-// takes the value width from block 0's first entry and keys of ANY
-// length from 1 to 24 bytes, each row its own (key_len says it).
+// planar == 0: entry-stream blocks, walked entry by entry. With *klen_io
+// given (the sink's "uniform" prop) every key has that length; *klen_io
+// == 0 (no such prop: a flush-written, bulk-built or foreign file) takes
+// keys of ANY length from 1 to 4 * kw_cols bytes, each row its own
+// (key_len says it), and, unless `vmixed`, the value width from block
+// 0's first entry.
+// vmixed != 0: values of differing widths, up to *vlen_io (the file's
+// widest, as its props give it), each row its own (val_len says it; a
+// PLANAR block may carry the value-length plane, flag 4, or be of one
+// width up to *vlen_io); bytes past a value's length are zero.
+// vmixed == 0: every live value is *vlen_io bytes.
 // chk_mode 1 / 2: each block's poly1 (bytes)
 // / poly1w (plane words) value over `chk_len`, into blk_chk: the caller
 // holds them against the file's block_chk prop.
@@ -1100,16 +1150,19 @@ extern "C" int64_t tsst_planar_encode_file(
 extern "C" int64_t tsst_decode_file_lanes(
     int32_t fd, const uint64_t* index, uint64_t nblocks, int32_t planar,
     uint32_t* klen_io, uint32_t* vlen_io, uint64_t row_cap,
-    uint32_t val_cols,
+    uint32_t val_cols, uint32_t kw_cols, int32_t vmixed,
     uint32_t* kw_be, uint32_t* kw_le, uint32_t* key_len,
     uint32_t* seq_hi, uint32_t* seq_lo, uint32_t* vtype,
     uint32_t* val_words, uint32_t* val_len,
     int32_t chk_mode, uint64_t chk_len, uint32_t* blk_chk,
     int64_t* err_block) {
   uint32_t klen = *klen_io, vlen = *vlen_io;
+  if (kw_cols != 6 && kw_cols != 12) return -2;
+  const uint32_t kmax = 4 * kw_cols;  // the longest key the lanes hold
   bool infer = !planar && klen == 0;
   bool one_klen = !infer;  // the prop's promise: every key klen bytes
-  if (!infer && (klen == 0 || klen > 24)) return -2;
+  if (!infer && (klen == 0 || klen > kmax)) return -2;
+  if (vmixed) infer = false;  // the caller gave the widest value
   ChkPowers pw;
   BlockFetcher fetcher;
   uint64_t row = 0;
@@ -1127,14 +1180,15 @@ extern "C" int64_t tsst_decode_file_lanes(
       uint64_t n = get_u32(raw);
       uint32_t bklen = raw[4];
       uint32_t bvlen = (uint32_t)raw[5] | ((uint32_t)raw[7] << 8);
-      bool seq32 = raw[6] & 1, klens = raw[6] & 2;
-      if (bklen == 0 || bklen > 24) { rc = -2; break; }
+      bool seq32 = raw[6] & 1, klens = raw[6] & 2, vlens = raw[6] & 4;
+      if (bklen == 0 || bklen > 48) { rc = -2; break; }
       uint64_t kw = (bklen + 3) / 4, vw = ((uint64_t)bvlen + 3) / 4;
-      uint64_t words = planar_plane_words(n, kw, vw, seq32, klens);
+      uint64_t words = planar_plane_words(n, kw, vw, seq32, klens, vlens);
       if (raw_len != 16 + 4 * words) { rc = -2; break; }
       if (chk_mode == 2)
         blk_chk[bi] = chk_words(raw + 16, words, chk_len, &pw);
-      if (bklen > klen || bvlen != vlen || vw > val_cols) {
+      if (bklen > klen || vw > val_cols
+          || (vmixed ? bvlen > vlen : (bvlen != vlen || vlens))) {
         rc = -3; break;
       }
       if (row + n > row_cap) { rc = -4; break; }
@@ -1143,7 +1197,9 @@ extern "C" int64_t tsst_decode_file_lanes(
       const uint8_t* slo = kwp + 4 * kw * n + (klens ? 4 * ((n + 3) / 4) : 0);
       const uint8_t* shi = seq32 ? nullptr : slo + 4 * n;
       const uint8_t* vtp = slo + 4 * n * (seq32 ? 1 : 2);
-      const uint8_t* vvp = vtp + 4 * ((n + 3) / 4);
+      const uint8_t* vlp = vlens ? vtp + 4 * ((n + 3) / 4) : nullptr;
+      const uint8_t* vvp = vtp + 4 * ((n + 3) / 4)
+                         + (vlens ? 4 * ((n + 1) / 2) : 0);
       for (uint64_t i = 0; i < n; i++) {
         uint64_t r = row + i;
         uint32_t ek = klp ? klp[i] : bklen;  // the row's own key length
@@ -1153,21 +1209,34 @@ extern "C" int64_t tsst_decode_file_lanes(
         uint64_t ekw = (ek + 3) / 4;
         uint32_t tail_mask = (ek % 4)
             ? 0xFFFFFFFFu << (8 * (4 - ek % 4)) : 0xFFFFFFFFu;
-        for (uint64_t k = 0; k < 6; k++) {
+        for (uint64_t k = 0; k < kw_cols; k++) {
           uint32_t be = 0;
           if (k < ekw) {
             be = get_u32(kwp + 4 * (k * n + i));
             if (k == ekw - 1) be &= tail_mask;
           }
-          kw_be[r * 6 + k] = be;
-          kw_le[r * 6 + k] = __builtin_bswap32(be);
+          kw_be[r * kw_cols + k] = be;
+          kw_le[r * kw_cols + k] = __builtin_bswap32(be);
         }
         key_len[r] = ek;
         seq_lo[r] = get_u32(slo + 4 * i);
         seq_hi[r] = shi ? get_u32(shi + 4 * i) : 0u;
         uint32_t vt = vtp[i];
         vtype[r] = vt;
-        val_len[r] = vt == 2 ? 0u : vlen;
+        if (vlp) {
+          // the row's own value length; bytes past it are not value
+          uint32_t ev = (uint32_t)vlp[2 * i] | ((uint32_t)vlp[2 * i + 1] << 8);
+          if (ev > bvlen) { rc = -2; break; }
+          val_len[r] = ev;
+          uint64_t evw = (ev + 3) / 4;
+          for (uint64_t k = 0; k < val_cols; k++) {
+            uint32_t le = k < evw ? get_u32(vvp + 4 * (k * n + i)) : 0u;
+            if (k + 1 == evw && ev % 4) le &= 0xFFFFFFFFu >> (8 * (4 - ev % 4));
+            val_words[r * val_cols + k] = le;
+          }
+          continue;
+        }
+        val_len[r] = vt == 2 ? 0u : bvlen;
         for (uint64_t k = 0; k < val_cols; k++)
           val_words[r * val_cols + k] =
               k < vw ? get_u32(vvp + 4 * (k * n + i)) : 0u;
@@ -1182,7 +1251,7 @@ extern "C" int64_t tsst_decode_file_lanes(
       // the value width of block 0's first entry is the file's
       if (raw_len < 17) { rc = -3; break; }
       uint32_t k0 = get_u32(raw);
-      if (k0 == 0 || k0 > 24 || raw_len < 17 + (uint64_t)k0) {
+      if (k0 == 0 || k0 > kmax || raw_len < 17 + (uint64_t)k0) {
         rc = -3; break;
       }
       vlen = get_u32(raw + k0 + 13);
@@ -1196,30 +1265,34 @@ extern "C" int64_t tsst_decode_file_lanes(
       const uint8_t* e = raw + pos;
       if (raw_len - pos < 17) { rc = -3; break; }
       uint32_t ek = get_u32(e);
-      if (ek == 0 || ek > 24 || (one_klen && ek != klen)
-          || raw_len - pos < 17 + (uint64_t)ek + vlen
-          || get_u32(e + ek + 13) != vlen) {
+      if (ek == 0 || ek > kmax || (one_klen && ek != klen)
+          || raw_len - pos < 17 + (uint64_t)ek) {
+        rc = -3; break;
+      }
+      uint32_t ev = get_u32(e + ek + 13);
+      if ((vmixed ? ev > vlen : ev != vlen)
+          || raw_len - pos < 17 + (uint64_t)ek + ev) {
         rc = -3; break;
       }
       if (row >= row_cap) { rc = -4; break; }
-      uint8_t key[24];
-      memset(key, 0, 24);
+      uint8_t key[48];
+      memset(key, 0, kmax);
       memcpy(key, e + 4, ek);
-      for (int k = 0; k < 6; k++) {
+      for (uint32_t k = 0; k < kw_cols; k++) {
         uint32_t le = get_u32(key + 4 * k);
-        kw_le[row * 6 + k] = le;
-        kw_be[row * 6 + k] = __builtin_bswap32(le);
+        kw_le[row * kw_cols + k] = le;
+        kw_be[row * kw_cols + k] = __builtin_bswap32(le);
       }
       key_len[row] = ek;
       uint64_t seq = get_u64(e + 4 + ek);
       seq_hi[row] = (uint32_t)(seq >> 32);
       seq_lo[row] = (uint32_t)seq;
       vtype[row] = e[ek + 12];
-      val_len[row] = vlen;
+      val_len[row] = ev;
       uint32_t* vdst = val_words + row * val_cols;
       memset(vdst, 0, 4 * (size_t)val_cols);
-      memcpy(vdst, e + ek + 17, vlen);
-      pos += 17 + (uint64_t)ek + vlen;
+      memcpy(vdst, e + ek + 17, ev);
+      pos += 17 + (uint64_t)ek + ev;
     }
   }
   return rc < 0 ? rc : (int64_t)row;

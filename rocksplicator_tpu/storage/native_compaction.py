@@ -8,8 +8,9 @@ The engine's default backend. Two faces:
   (measured: tuple-interface numpy path 4× slower than heapq).
 - ``merge_runs_to_files``: the DIRECT sink. When every input run reads
   as lanes (sink-written planar/uniform TSSTs decode straight to
-  arrays) and the values are of one width (keys of 1 to 24 bytes, of
-  one length or mixed: ``lanes_decline_reason``), the merge runs as
+  arrays) and the lanes express the rows (keys of 1 to 48 bytes, of one
+  length or mixed; values of one width or, with no merge operator,
+  mixed: ``lanes_decline_reason``), the merge runs as
   ``cpu_merge_resolve`` (storage/native C when loaded, numpy
   otherwise), blooms build in bulk with no per-key Python, and outputs
   write as PLANAR files via the vectorized array writer — no per-entry
@@ -33,7 +34,7 @@ import numpy as np
 
 from .compaction import CpuCompactionBackend
 from .merge import MergeOperator, UInt64AddOperator
-from .planar import PLANAR_MAX_KLEN, key_shape
+from .planar import PLANAR_MAX_KLEN, key_shape, value_shape
 
 log = logging.getLogger(__name__)
 
@@ -218,9 +219,17 @@ def read_runs_as_lanes(
 
     ``value_rows(total, vw)`` (the served device door's alone) gives the
     rows of a zero-tailed buffer that the runs' values are concatenated
-    INTO: ``lanes["val_words"]`` is then its first ``total`` rows and
-    ``lanes["val_words"].base`` the buffer, so that a caller that ships
-    the values padded to a launch's capacity copies them once.
+    INTO, or ``(rows, words)`` where the buffer is also wider than the
+    runs' widest value: ``lanes["val_words"]`` is then its first
+    ``total`` rows and ``lanes["val_words"].base`` the buffer, so that a
+    caller that ships the values padded to a launch's capacity (and
+    value bucket) copies them once.
+
+    Runs whose keys or values are of different widths (a file of keys
+    over 24 bytes, 12 words a row, beside one of shorter keys; values of
+    differing widths) are concatenated at the widest: ``vw`` is the
+    widest value's words, and the key lanes are 12 words wide where any
+    run's are.
 
     Deliberately single-threaded: the per-block Python between the
     GIL-releasing zlib/numpy stretches convoys badly under a thread
@@ -266,11 +275,12 @@ def read_runs_as_lanes(
         return None
     if total == 0:
         return None
-    vw = max(p["val_words"].shape[1] for p in parts)
+    kc = max(p["key_words_be"].shape[1] for p in parts)
     for p in parts:
-        w = p["val_words"].shape[1]
-        if w < vw:
-            p["val_words"] = np.pad(p["val_words"], [(0, 0), (0, vw - w)])
+        w = p["key_words_be"].shape[1]
+        if w < kc:  # keys of one run are wider than another's
+            p["key_words_be"] = np.pad(p["key_words_be"], [(0, 0), (0, kc - w)])
+    vw = max(p["val_words"].shape[1] for p in parts)
     return parts, concat_lanes(parts, total, value_rows), total, vw
 
 
@@ -281,15 +291,28 @@ _LANE_FIELDS = ("key_words_be", "key_len", "seq_hi", "seq_lo", "vtype",
 def concat_lanes(parts: List[dict], total: int,
                  value_rows: Optional[Callable[[int, int], int]] = None,
                  ) -> dict:
-    """The parts' rows (``total`` of them, one value width) as one dict
-    of concatenated lanes; ``value_rows`` as ``read_runs_as_lanes`` says."""
-    into = {}
-    if value_rows is not None:
-        vw = parts[0]["val_words"].shape[1]
-        into["val_words"] = np.zeros(
-            (value_rows(total, vw), vw), dtype=np.uint32)[:total]
-    return {f: np.concatenate([p[f] for p in parts], out=into.get(f))
-            for f in _LANE_FIELDS}
+    """The parts' rows (``total`` of them) as one dict of concatenated
+    lanes, the values at the parts' widest (a narrower part's rows
+    zero-tailed); ``value_rows`` as ``read_runs_as_lanes`` says."""
+    widths = [p["val_words"].shape[1] for p in parts]
+    vw = max(widths)
+
+    def values() -> np.ndarray:
+        if value_rows is None and min(widths) == vw:
+            return np.concatenate([p["val_words"] for p in parts])
+        shape = (total, vw) if value_rows is None else value_rows(total, vw)
+        if isinstance(shape, int):
+            shape = (shape, vw)
+        into = np.zeros(shape, dtype=np.uint32)
+        row = 0
+        for p, w in zip(parts, widths):
+            n = p["val_words"].shape[0]
+            into[row:row + n, :w] = p["val_words"]
+            row += n
+        return into[:total]
+
+    return {f: values() if f == "val_words"
+            else np.concatenate([p[f] for p in parts]) for f in _LANE_FIELDS}
 
 
 def lanes_decline_reason(lanes: dict,
@@ -302,15 +325,18 @@ def lanes_decline_reason(lanes: dict,
     - ``merge_without_operator``: MERGE records and no operator (only
       the tuple path keeps an unresolved operand chain);
     - ``key_width``: a key the lanes cannot hold (empty, or over
-      ``PLANAR_MAX_KLEN`` = 24 bytes). Keys of DIFFERING length, 1 to 24
+      ``PLANAR_MAX_KLEN`` = 48 bytes). Keys of DIFFERING length, 1 to 48
       bytes mixed in any proportion, are taken: the lanes carry each
-      row's ``key_len``, the order everywhere is the zero-padded
+      row's ``key_len`` (6 key words a row up to 24 bytes, 12 above:
+      ``planar.key_word_cols``), the order everywhere is the zero-padded
       big-endian key words and then the length (the bytewise order),
       and a PLANAR block whose rows differ carries a key-length plane
       (storage/planar.py);
-    - ``value_width_mixed``: the PLANAR sink needs one non-delete value
-      width (kept tombstones are fine: the layout derives val_len from
-      vtype);
+    - ``value_width_mixed``: live values of differing widths under a
+      merge operator (whose fold is defined on one width). With no
+      operator they are taken: the lanes carry each row's ``val_len``,
+      the resolve never rewrites a value, and a PLANAR block whose rows
+      differ carries a value-length plane;
     - ``uint64add_width``: the uint64-add RESOLUTION assumes 8-byte
       values: the fold rewrites every PUT segment to the operand sum,
       and a non-8-byte PUT parses as 0 (stream semantics only invoke
@@ -322,7 +348,7 @@ def lanes_decline_reason(lanes: dict,
     if len(kl) and not 0 < int(kl.min()) <= int(kl.max()) <= PLANAR_MAX_KLEN:
         return "key_width"
     non_del_vlens = lanes["val_len"][lanes["vtype"] != _DELETE]
-    if len(non_del_vlens) and not (
+    if merge_op is not None and len(non_del_vlens) and not (
             non_del_vlens == non_del_vlens[0]).all():
         return "value_width_mixed"
     if (merge_op is not None and len(non_del_vlens)
@@ -348,8 +374,9 @@ def write_resolved_lanes(
     ``tpu.planar.write`` span (``rows``; ``key_widths`` ``uniform`` /
     ``mixed`` and ``key_bytes_max`` of the file's own rows), under
     ``trace`` where the caller's thread carries no trace context of its
-    own (a pool thread). Rows whose keys differ in length size their
-    files and blocks by their widest key."""
+    own (a pool thread); ``value_widths`` and ``value_bytes_max`` of the
+    file's own rows beside them. Rows whose keys differ in length, or
+    values in width, size their files and blocks by their widest."""
     from ..observability.span import start_span
     from ..tpu.format import planar_stride, planar_widths, \
         write_sst_from_arrays
@@ -357,7 +384,7 @@ def write_resolved_lanes(
     widths = planar_widths(arrays, count)
     if widths is None:
         return None
-    klen0, vlen0, _mixed = widths
+    klen0 = widths[0]
     stride = planar_stride(*widths)
     entries_per_file = max(1024, target_file_bytes // max(1, stride))
     block_entries = max(64, block_bytes // max(1, stride))
@@ -386,7 +413,8 @@ def write_resolved_lanes(
                 bloom_words = host_bloom(sub, end - start)
             path = path_factory()
             with start_span("tpu.planar.write", remote=trace,
-                            rows=end - start, **key_shape(sub["key_len"])):
+                            rows=end - start, **key_shape(sub["key_len"]),
+                            **value_shape(sub["vtype"], sub["val_len"])):
                 props = write_sst_from_arrays(
                     sub, end - start, path,
                     bloom_words=bloom_words,
@@ -542,20 +570,20 @@ def _order_strings(part: dict, klen: int) -> np.ndarray:
     """A run's keys as fixed-size byte strings whose order (numpy's: the
     zero-padded bytes) is the keys' bytewise order. One key length
     (``klen``): the key words it fills. Differing lengths (``klen`` 0):
-    all 24 zero-padded key bytes and then the length as one byte more,
-    which breaks the tie between a key and the same key with NUL bytes
-    behind it."""
+    all the lanes' zero-padded key bytes (24 or 48) and then the length
+    as one byte more, which breaks the tie between a key and the same
+    key with NUL bytes behind it."""
     if klen:
         words = (klen + 3) // 4
         return np.ascontiguousarray(
             part["key_words_be"][:, :words].astype(">u4")
         ).view(f"S{4 * words}").ravel()
-    n = part["key_len"].shape[0]
-    out = np.empty((n, 4 * 6 + 1), dtype=np.uint8)
-    out[:, :24] = np.ascontiguousarray(
-        part["key_words_be"].astype(">u4")).view(np.uint8).reshape(n, 24)
-    out[:, 24] = part["key_len"]
-    return out.view("S25").ravel()
+    n, kc = part["key_words_be"].shape
+    out = np.empty((n, 4 * kc + 1), dtype=np.uint8)
+    out[:, :4 * kc] = np.ascontiguousarray(
+        part["key_words_be"].astype(">u4")).view(np.uint8).reshape(n, 4 * kc)
+    out[:, 4 * kc] = part["key_len"]
+    return out.view(f"S{4 * kc + 1}").ravel()
 
 
 def plan_subcompactions(parts: List[dict], total: int,
@@ -661,10 +689,8 @@ def _subcompact_to_files(
         if not sub_parts:
             results[si] = []
             return
-        fields = sub_parts[0].keys()
-        sub_lanes = {f: np.concatenate([p[f] for p in sub_parts])
-                     for f in fields}
-        sub_total = sub_lanes["key_len"].shape[0]
+        sub_total = sum(p["key_len"].shape[0] for p in sub_parts)
+        sub_lanes = concat_lanes(sub_parts, sub_total)
         arrays, count = NativeCompactionBackend._resolve(
             sub_parts, sub_lanes, sub_total, vw, merge_op,
             drop_tombstones)

@@ -221,6 +221,11 @@ class SSTWriter:
         self._min_seq: Optional[int] = None
         self._max_seq = 0
         self._raw_bytes = 0
+        # the widest key and value add() took: the "widths_max" prop, by
+        # which an array source sizes its lanes in one pass (a file with
+        # pre-encoded blocks says its widths in its own props)
+        self._key_max = self._value_max = 0
+        self._encoded = False
         self._finished = False
 
     def add(self, key: bytes, seq: int, vtype: int, value: bytes) -> None:
@@ -236,6 +241,10 @@ class SSTWriter:
         # entries buffer as tuples; the whole block encodes in ONE native
         # call at flush (tsst_encode_block) instead of per-entry Python
         esize = ENTRY_FIXED_OVERHEAD + len(key) + len(value)
+        if len(key) > self._key_max:
+            self._key_max = len(key)
+        if len(value) > self._value_max:
+            self._value_max = len(value)
         self._block.append((key, seq, int(vtype), value))
         self._block_size += esize
         self._raw_bytes += esize
@@ -274,6 +283,7 @@ class SSTWriter:
         size, codec)]`` in key order; the keys and seqs are the run's."""
         if self._block:
             self._flush_block()
+        self._encoded = True
         self._file.write(payload)
         base = self._offset
         self._index.extend(
@@ -353,6 +363,8 @@ class SSTWriter:
             "min_seq": self._min_seq or 0,
             "max_seq": self._max_seq,
         }
+        if self._num_entries and not self._encoded:
+            props["widths_max"] = [self._key_max, self._value_max]
         if extra_props:
             props.update(extra_props)
         props_bytes = json.dumps(props).encode("utf-8")

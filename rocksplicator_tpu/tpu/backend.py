@@ -2,14 +2,16 @@
 
 ``TpuCompactionBackend`` implements the storage engine's CompactionBackend
 seam with the ops/compaction_kernel pipeline; anything the fixed-shape
-representation can't express (keys over 24 B, values over
+representation can't express (keys over 48 B, values over
 ``device_value_bytes_max``, custom merge operators) falls back to the CPU
 heap-merge, mirroring the north star's "fall back to CPU on kernel
-inapplicability". Values: 8 bytes under the uint64-add operator (they
-ride both sorts and the fold rewrites them); with no operator, up to
+inapplicability". Keys up to 24 B take six key lanes, longer ones up to
+48 B twelve. Values: 8 bytes under the uint64-add operator (they ride
+both sorts and the fold rewrites them); with no operator, up to
 ``RIDE_MAX_VAL_WORDS`` words ride and wider ones, up to
 ``DEVICE_VALUE_BYTES_MAX`` bytes, take the kernel's index path (a row
-index rides, the values are moved once). The array sink
+index rides, the values are moved once), of one width or of differing
+widths in one shard (each row's ``val_len`` rides). The array sink
 (``merge_runs_to_files``) declines a wider shard before the kernel; the
 tuple path (``merge_runs``) packs 8-byte values only and hands anything
 else to the CPU.
@@ -32,8 +34,8 @@ from ..storage.compaction import (CompactionBackend, CpuCompactionBackend,
 from ..storage.merge import MergeOperator, UInt64AddOperator
 from ..storage.native_compaction import lanes_decline_reason
 from ..ops.compaction_kernel import MergeKind, merge_resolve_kernel
-from ..ops.kv_format import (KVBatch, UnsupportedBatch, fast_flags,
-                             pack_entries, unpack_entries)
+from ..ops.kv_format import (KEY_WORDS, KVBatch, UnsupportedBatch,
+                             fast_flags, pack_entries, unpack_entries)
 from ..utils.stats import Stats, tagged
 
 log = logging.getLogger(__name__)
@@ -78,9 +80,10 @@ def device_decline_reason(lanes: Optional[dict],
     - ``value_width``: a value wider than ``device_value_bytes_max``
       (each door counts it under ``tpu.host_fallbacks reason=value_width``);
     - what no array path expresses (``lanes_decline_reason``):
-      ``merge_without_operator``, ``key_width`` (a key over 24 bytes;
+      ``merge_without_operator``, ``key_width`` (a key over 48 bytes;
       keys of differing length up to that are taken),
-      ``value_width_mixed``, ``uint64add_width``.
+      ``value_width_mixed`` (values of differing widths under the
+      operator; with none they are taken), ``uint64add_width``.
 
     ``lanes=None`` asks about the operator alone (a plan costs a flush)."""
     limit = device_value_bytes_max(merge_operator)
@@ -109,8 +112,10 @@ def _device_bloom_builder(bits_per_key: int, trace: Optional[dict] = None):
 
     def build(sub: dict, n: int) -> np.ndarray:
         with start_span("tpu.bloom", remote=trace, rows=n):
+            # the hash reads a key's first 24 bytes and its length
+            # (storage/bloom.py): six words, whatever the lanes' width
             return np.asarray(bloom_build_tpu(
-                jnp.asarray(sub["key_words_le"]),
+                jnp.asarray(sub["key_words_le"][:, :KEY_WORDS]),
                 jnp.asarray(sub["key_len"]),
                 jnp.asarray(np.ones(n, dtype=bool)),
                 num_words=num_words_for(n, bits_per_key),
@@ -150,7 +155,8 @@ class TpuCompactionBackend(CompactionBackend):
     supports_memory_budget = True
 
     def __init__(self, fallback: Optional[CompactionBackend] = None):
-        # inapplicable batches (custom operators, >24B keys, operand
+        # inapplicable batches (custom operators, >24B keys on the tuple
+        # path's packer, >48B on the array paths, operand
         # chains without an operator) take the VECTORIZED cpu path: the
         # lexsort+reduceat numpy pipeline, itself falling back to the
         # streaming heap-merge for what lanes can't express
@@ -461,11 +467,13 @@ def cpu_merge_resolve(
     (storage/native cpu_merge_resolve — packed-record sort + linear
     segment resolve) when the library is loaded, else the numpy path.
     Both are element-exact with the TPU kernel; parity is pinned in
-    tests/test_native.py."""
+    tests/test_native.py. The native record packs six key words: keys of
+    twelve (over 24 bytes) take the numpy path."""
     from ..storage.native.binding import get_native
 
     lib = get_native()
-    if lib is None or not getattr(lib, "has_merge_resolve", False):
+    if (lib is None or not getattr(lib, "has_merge_resolve", False)
+            or batch.key_words_be.shape[1] > KEY_WORDS):
         return numpy_merge_resolve(batch, uint64_add, drop_tombstones)
     valid_n = batch.num_valid()
     seq = (

@@ -45,11 +45,12 @@ from ..storage.native_compaction import (KeyGroupOverSlice,
                                          plan_subcompactions,
                                          read_runs_as_lanes, shard_klen,
                                          slice_lanes, write_resolved_lanes)
-from ..storage.planar import PLANAR_MAX_KLEN, key_shape
+from ..storage.planar import PLANAR_MAX_KLEN, key_shape, value_shape
 from ..testing import failpoints as fp
 from ..utils.stats import Stats
-from .backend import (TpuCompactionBackend, _device_bloom_builder,
-                      _next_pow2, device_decline_reason, require_accelerator)
+from .backend import (DEVICE_VALUE_BYTES_MAX, TpuCompactionBackend,
+                      _device_bloom_builder, _next_pow2,
+                      device_decline_reason, require_accelerator)
 # what the device path takes is asked HERE by the record deployment's
 # driver (chipbench/drivers/refresh_rec.py)
 from .backend import device_value_bytes_max  # noqa: F401
@@ -223,20 +224,33 @@ class TpuCompactionService:
         uniform_klen = all(u for u, _, _ in flags)
         seq32 = all(s for _, s, _ in flags)
         key_words = max(k for _, _, k in flags)
-        val_words = batches[0].val_words.shape[1]  # group-uniform
+        # the group's value width: its widest place's (a narrower place
+        # is zero-tailed to it); its key lanes' (6 or 12 words) are one
+        val_words = max(b.val_words.shape[1] for b in batches)
+        key_cols = batches[0].key_words_be.shape[1]
         path = value_path(merge_kind, val_words)
         index = path == "index"
+        vmixed = any(_values_mixed(b) for b in batches)
         span = current_span()  # tpu.compact_stream / tpu.compact_batch
         if span is not None:
             # key_widths: ``mixed`` where some place's keys differ in
             # length (the launch then carries the key-length lane:
-            # ``uniform_klen=False``), key_bytes_max the longest key
+            # ``uniform_klen=False``), key_bytes_max the longest key;
+            # key_lanes the key matrix's width (``key_words``: the words
+            # in use); value_widths ``mixed`` where some place's live
+            # values differ in width; value_bytes the places' real value
+            # bytes, value_bytes_padded their rows at the launch's width
             span.annotate(
                 val_words=val_words, value_path=path,
                 key_widths="uniform" if uniform_klen else "mixed",
                 key_bytes_max=max(
                     (int(b.key_len.max()) for b in batches if b.capacity),
-                    default=0))
+                    default=0),
+                key_lanes=key_cols, key_words=key_words,
+                value_widths="mixed" if vmixed else "uniform",
+                value_bytes=sum(int(b.val_len.sum()) for b in batches),
+                value_bytes_padded=4 * val_words * sum(
+                    b.capacity for b in batches))
         Stats.get().incr("compact.value_path." + path, len(batches))
         fn = self._pipeline(merge_kind, drop_tombstones, num_words,
                             uniform_klen, seq32, key_words, val_words)
@@ -258,12 +272,14 @@ class TpuCompactionService:
                         ups, prestaged, restaged = [], 0, 0
                         for b in group:
                             dev = getattr(b, "val_words_dev", None)
-                            if dev is not None and dev.shape[0] == capacity:
+                            if dev is not None and dev.shape == (
+                                    capacity, val_words):
                                 prestaged += 1
                             else:  # none came, or one of another bucket
                                 restaged += dev is not None
-                                dev = jax.device_put(
-                                    _pad_to(b.val_words, capacity))
+                                dev = jax.device_put(_pad_to(
+                                    _pad_cols(b.val_words, val_words),
+                                    capacity))
                             ups.append(dev)
                         Stats.get().incr("seam.values.prestaged", prestaged)
                         Stats.get().incr("seam.values.restaged", restaged)
@@ -271,8 +287,10 @@ class TpuCompactionService:
                         stacked[name] = tuple(ups) + (
                             self._zeros(capacity, val_words),) * pad_shards
                         continue
-                    arr = np.stack([_pad_to(getattr(b, name), capacity)
-                                    for b in group])
+                    arr = np.stack([_pad_to(
+                        _pad_cols(b.val_words, val_words)
+                        if name == "val_words" else getattr(b, name),
+                        capacity) for b in group])
                     if pad_shards:
                         arr = np.pad(
                             arr,
@@ -401,6 +419,31 @@ def _pad_to(arr: np.ndarray, capacity: int) -> np.ndarray:
     return np.pad(arr, pad)
 
 
+def _pad_cols(arr: np.ndarray, cols: int) -> np.ndarray:
+    """``arr`` (rows, words) zero-tailed to ``cols`` words a row."""
+    if arr.shape[1] == cols:
+        return arr
+    return np.pad(arr, [(0, 0), (0, cols - arr.shape[1])])
+
+
+def _values_mixed(batch) -> bool:
+    """Whether a place's live values differ in width (read on the
+    launching thread: one width, the common case, costs two reductions
+    and no mask)."""
+    vl = batch.val_len
+    if not len(vl) or int(vl.min()) == int(vl.max()):
+        return False
+    live = vl[batch.vtype != 2]
+    return bool(len(live)) and int(live.min()) != int(live.max())
+
+
+def value_bucket(words: int) -> int:
+    """The value width, in words, an index-path launch is built for: the
+    power of two at or above the shard's widest value (1,024 B: 256), so
+    that shards whose widest values differ share one program."""
+    return _next_pow2(max(1, words))
+
+
 # lane names carried through the arrays-native result path (matches
 # tpu/chunked.FIELDS; redeclared to avoid importing chunked at call time)
 _LANES = (
@@ -506,14 +549,29 @@ PLACE_ROWS_MAX = 1 << 15
 def device_mixed_key_bytes_max(merge_operator) -> int:
     """The longest key, in bytes, of a shard of DIFFERING key lengths
     that the served door (``compact_dbs_batched``) compacts on the
-    device for a DB with this merge operator: the lanes' key width
-    (``lanes_decline_reason`` declines a longer key with ``key_width``);
+    device for a DB with this merge operator: the lanes' key width, 48
+    bytes (twelve key words a row for a shard with a key over 24 bytes;
+    ``lanes_decline_reason`` declines a longer key with ``key_width``);
     0 where the door takes no shard with this operator. Asked by a
     deployment's driver before it builds anything
-    (chipbench/drivers/refresh_names.py)."""
+    (chipbench/drivers/refresh_names.py, refresh_mixgraph.py)."""
     if device_decline_reason(None, merge_operator) is not None:
         return 0
     return PLANAR_MAX_KLEN
+
+
+def device_mixed_value_bytes_max(merge_operator) -> int:
+    """The widest value, in bytes, of a shard whose values DIFFER in
+    width that the served door (``compact_dbs_batched``) compacts on the
+    device for a DB with this merge operator: ``DEVICE_VALUE_BYTES_MAX``
+    with no operator (the index path moves each row whole and its
+    ``val_len`` rides the sorts), 0 where the door takes no such shard
+    (the uint64-add fold is defined on 8-byte values alone). Asked by a
+    deployment's driver before it builds anything
+    (chipbench/drivers/refresh_mixgraph.py)."""
+    if device_decline_reason(None, merge_operator) is not None:
+        return 0
+    return DEVICE_VALUE_BYTES_MAX if merge_operator is None else 0
 
 
 def device_shard_rows_max(merge_operator) -> int:
@@ -674,8 +732,15 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
     ``device_value_bytes_max(None)`` bytes take the index path (one
     row-index lane rides, the values are moved once inside the same
     module; ``value_path`` on the ``tpu.compact_stream`` span says
-    which). Keys: 1 to 24 bytes, of one length or mixed in any
-    proportion (``device_mixed_key_bytes_max``): a launch any place of
+    which); values of differing widths in one shard (no operator: each
+    row's ``val_len`` rides; ``device_mixed_value_bytes_max``) launch at
+    the power-of-two bucket of the group's widest (``value_bucket``), and
+    ``value_widths`` / ``value_bytes`` / ``value_bytes_padded`` on
+    ``tpu.compact_stream`` say what that costs. Keys: 1 to 48 bytes, of
+    one length or mixed in any proportion
+    (``device_mixed_key_bytes_max``; over 24 bytes the key lanes are
+    twelve words, ``key_lanes`` 12, and such shards launch as groups of
+    their own: ``Stats`` ``compact.key_lanes.wide``): a launch any place of
     which has keys of differing length carries the key-length lane
     (``uniform_klen=False``: a sort key after the key words, a boundary
     compare, an output lane; one more program a capacity bucket), and
@@ -685,7 +750,8 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
     the device path can't express (``device_decline_reason`` says which
     and why: custom merge operators, values over
     ``device_value_bytes_max``, MERGE records with no operator, values
-    of more than one width; besides >24B keys and shards of more than
+    of more than one width under the operator; besides >48B keys and
+    shards of more than
     ``MAX_BATCHED_DB_ENTRIES`` rows, which the lane read declines) are
     declined untouched, before any program is built; a decline for
     width counts under ``tpu.host_fallbacks reason=value_width``.
@@ -755,11 +821,13 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
 
         def value_rows(total, vw):
             # an index-path shard's values are decoded straight into the
-            # padded buffer that goes up: its own capacity bucket (a
-            # shard that will be cut gets a buffer a place instead)
-            if value_path(kind, vw) == "index" and total <= PLACE_ROWS_MAX:
-                return _next_pow2(total)
-            return total
+            # padded buffer that goes up: its own capacity bucket and
+            # value bucket (a shard that will be cut gets a buffer a
+            # place instead)
+            if value_path(kind, vw) != "index":
+                return total
+            rows = _next_pow2(total) if total <= PLACE_ROWS_MAX else total
+            return rows, value_bucket(vw)
 
         try:
             with start_span("tpu.lanes.decode", remote=tctx) as lsp:
@@ -770,7 +838,9 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
                     value_rows=value_rows)
                 if read is not None:
                     shape = key_shape(read[1]["key_len"])
-                    lsp.annotate(rows=read[2], **shape)
+                    vshape = value_shape(read[1]["vtype"],
+                                         read[1]["val_len"])
+                    lsp.annotate(rows=read[2], **shape, **vshape)
         except BaseException:
             log.exception(
                 "lane read failed for %s; declining to per-db", name)
@@ -791,10 +861,18 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
             _abort(db, plan)
             return ("remaining", name, db, None)
         # shards through the door by key shape: one key length, or
-        # lengths that differ (``tpu.compact_stream`` says which launch)
-        Stats.get().incr("compact.key_widths." + shape["key_widths"])
-        # index-path shards group by their width as well: their values
-        # go up as they are, never padded to a wider neighbour's
+        # lengths that differ (``tpu.compact_stream`` says which launch);
+        # by the key lanes' width (twelve words: a key over 24 bytes);
+        # by value shape: one width, or widths that differ
+        key_cols = lanes["key_words_be"].shape[1]
+        stats = Stats.get()
+        stats.incr("compact.key_widths." + shape["key_widths"])
+        if key_cols > KEY_WORDS:
+            stats.incr("compact.key_lanes.wide")
+        stats.incr("compact.value_widths." + vshape["value_widths"])
+        # index-path shards launch together at the bucket of the group's
+        # widest value; a shard whose own bucket is narrower is padded
+        # and put up again by the leader (``seam.values.restaged``)
         index = value_path(kind, vw) == "index"
         cut = total > PLACE_ROWS_MAX  # else the shard is its one place
         try:
@@ -817,7 +895,7 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
                 "per-db", name)
             _abort(db, plan)
             return ("remaining", name, db, None)
-        key = (kind, plan["drop_tombstones"], vw if index else 0)
+        key = (kind, plan["drop_tombstones"], index, key_cols)
         return ("grouped", name, db, (key, plan, batches))
 
     def _range_cut(name, parts, lanes, total, index, tctx):
@@ -839,7 +917,8 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
                 raise RuntimeError(f"{name}: {total} rows in unsorted runs")
             places = slice_lanes(
                 parts, bounds, klen,
-                (lambda rows, vw: PLACE_ROWS_MAX) if index else None)
+                (lambda rows, vw: (PLACE_ROWS_MAX, value_bucket(vw)))
+                if index else None)
             sp.annotate(places=len(places))
         Stats.get().incr("compact.range_cut.shards")
         Stats.get().incr("compact.range_cut.places", len(places))
@@ -884,16 +963,10 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
                 groups.setdefault(key, []).append((name, db, plan, places))
 
         svc = TpuCompactionService.instance()
-        for (kind, drop, _vw), items in groups.items():
+        for (kind, drop, _index, _kc), items in groups.items():
             # every shard's places side by side: 8 shards of 3 places
             # fill 3 launches
             batches = [b for _n, _d, _p, places in items for b in places]
-            vw = max(b.val_words.shape[1] for b in batches)
-            for b in batches:  # group-uniform value lanes for np.stack
-                w = b.val_words.shape[1]
-                if w < vw:
-                    b.val_words = np.pad(
-                        b.val_words, [(0, 0), (0, vw - w)])
             try:
                 # ALWAYS the fixed (group_size, capacity) launch shape,
                 # short groups padded with empty shards: how many shards

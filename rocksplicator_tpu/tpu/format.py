@@ -9,12 +9,15 @@ file (byte-identical format, so readers can't tell).
 
 The PLANAR sink and source (``write_sst_from_arrays(planar=True)``,
 ``read_sst_arrays``) also take rows whose KEYS differ in length, 1 to
-``PLANAR_MAX_KLEN`` bytes mixed in any proportion (counter names
+``PLANAR_MAX_KLEN`` (48) bytes mixed in any proportion (counter names
 ``counter-<n>``): ``planar_widths`` then says ``mixed``, a block whose
 rows differ carries a key-length plane behind a header flag
 (storage/planar.py), the file's ``planar`` prop gives its widest key
-and a fourth member 1. Rows of one key length write the file they
-always wrote, byte for byte. Values stay of one width a file.
+and a fourth member 1. Rows whose VALUES differ in width (db_bench
+MixGraph's 10 to 1,024 bytes) do the same with a value-length plane and
+a fifth member (``vmixed``). Keys longer than 24 bytes take key lanes of
+12 words (``key_word_cols``). Rows of one key length and one value
+width write the file they always wrote, byte for byte.
 """
 
 from __future__ import annotations
@@ -28,9 +31,10 @@ from ..observability.context import current_span
 from ..storage.bloom import BloomFilter
 from ..storage.errors import Corruption
 from ..storage.native.binding import get_file_codecs
-from ..storage.planar import (PLANAR_MAX_KLEN, PLANAR_MAX_VLEN,
+from ..storage.planar import (KEY_COLS, PLANAR_MAX_KLEN, PLANAR_MAX_VLEN,
                               decode_planar_block, encode_planar_block,
-                              plane_words, planar_props, planar_props_mixed)
+                              key_word_cols, plane_words, planar_props,
+                              planar_props_mixed, planar_props_vmixed)
 from ..storage import rlz
 from ..storage.sst import (BLOCK_PLANAR, BLOCK_PLANAR_RLZ,
                            BLOCK_PLANAR_ZLIB, COMPRESSION_NONE,
@@ -142,22 +146,38 @@ def _read_lanes_native(reader, planar: bool):
     props it does not understand): the Python source decides about
     those. None on width drift (a value width other than the file's,
     a block key wider than the file's widest: the tuple path's file;
-    keys of differing length are lanes like any other). Raises
-    Corruption for a block that does not inflate, does not fit its
-    layout, or fails its ``block_chk`` value."""
+    keys of differing length are lanes like any other). The lanes' key
+    width (6 or 12 words) and value width follow from the file's props:
+    a PLANAR file's widest key and value, a row-format file's
+    ``widths_max`` (its values may then differ in width: one pass, each
+    row its own ``val_len``). Raises Corruption for a block that does
+    not inflate, does not fit its layout, or fails its ``block_chk``
+    value."""
     lib = get_file_codecs()
     if lib is None or not reader._index or not reader.num_entries:
         return _NOT_TAKEN
     props = reader.props
     widths = props.get("planar") if planar else props.get("uniform")
     klen = vlen = 0  # row format without the sink's prop: inferred
-    if planar or widths:
-        try:
+    key_cols, vmixed = KEY_COLS, False
+    try:
+        if planar or widths:
             klen, vlen = int(widths[0]), int(widths[1])
-        except (TypeError, ValueError, IndexError, KeyError):
-            return _NOT_TAKEN
-        if not (0 < klen <= 24) or not (0 <= vlen <= PLANAR_MAX_VLEN):
-            return _NOT_TAKEN
+            key_cols = key_word_cols(klen)
+            vmixed = planar and planar_props_vmixed(widths)
+        elif props.get("widths_max"):
+            # a row-format file that says its widest key and value (the
+            # plain writer's): keys and values of any width up to them
+            kmax, vlen = (int(w) for w in props["widths_max"][:2])
+            if not 0 < kmax <= PLANAR_MAX_KLEN:
+                return _NOT_TAKEN
+            key_cols, vmixed = key_word_cols(kmax), True
+    except (TypeError, ValueError, IndexError, KeyError):
+        return _NOT_TAKEN
+    if (planar or widths) and not 0 < klen <= PLANAR_MAX_KLEN:
+        return _NOT_TAKEN
+    if not 0 <= vlen <= PLANAR_MAX_VLEN:
+        return _NOT_TAKEN
     index = np.array([e[1:] for e in reader._index], dtype=np.uint64)
     if not np.isin(index[:, 2],
                    _PLANAR_CODECS if planar else _ROW_CODECS).all():
@@ -171,7 +191,7 @@ def _read_lanes_native(reader, planar: bool):
         chk_mode = 2 if planar else 1
     got, lanes, chks, done = lib.decode_file_lanes(
         reader._fd, index, planar, klen, vlen, int(reader.num_entries),
-        chk_mode, chk_len)
+        chk_mode, chk_len, key_cols, vmixed)
     if got in (-1, -2):
         raise Corruption(
             f"{reader._path}: block {done - 1} "
@@ -268,10 +288,13 @@ class SstBlockLaneSource:
             except (TypeError, ValueError, IndexError, KeyError):
                 return None
             if not (0 < klen <= 24) or vlen < 0:
+                # the chunked merge's windows are six key words a row: a
+                # file of longer keys is read whole
                 return None
-            if planar_props_mixed(p):
+            if planar_props_mixed(p) or planar_props_vmixed(p):
                 # the chunked merge cuts its windows at keys of ONE
-                # width: a file of differing key lengths is read whole
+                # width, of values of one width: a file whose keys differ
+                # in length, or values in width, is read whole
                 return None
             return cls(reader, "planar", klen, vlen)
         widths = props.get("uniform")
@@ -391,20 +414,25 @@ def _decode_uniform_rows(raw: bytes, klen: int,
     }
 
 
-def planar_stride(klen: int, vlen: int, mixed: bool = False) -> int:
+def planar_stride(klen: int, vlen: int, mixed: bool = False,
+                  vmixed: bool = False) -> int:
     """Approximate PLANAR bytes per entry (seq32 layout: key + seq_lo +
     vtype + value; ``mixed``: the key at the rows' widest, and its
-    length byte) — block/file sizing only, shared by every sink."""
-    return klen + vlen + 9 + bool(mixed)
+    length byte; ``vmixed``: the value at the rows' widest, and its two
+    length bytes) — block/file sizing only, shared by every sink."""
+    return klen + vlen + 9 + bool(mixed) + 2 * bool(vmixed)
 
 
 def planar_widths(arrays: Dict[str, np.ndarray], count: int):
-    """(klen, vlen, mixed) for the PLANAR sink, or None where the layout
-    can't express the rows. ``klen`` is the rows' widest key, ``mixed``
-    whether their keys differ in length (1 to ``PLANAR_MAX_KLEN`` bytes
-    each, in any proportion). Laxer than uniform_widths: DELETE rows
-    carry no value in the planar layout (val_len derives from vtype on
-    read), so kept tombstones coexist with fixed-width values."""
+    """(klen, vlen, mixed, vmixed) for the PLANAR sink, or None where the
+    layout can't express the rows. ``klen`` is the rows' widest key,
+    ``mixed`` whether their keys differ in length (1 to
+    ``PLANAR_MAX_KLEN`` bytes each, in any proportion); ``vlen`` the
+    widest live value, ``vmixed`` whether the live values differ in
+    width. Laxer than uniform_widths: DELETE rows carry no value in the
+    planar layout (val_len derives from vtype on read, or is 0 in the
+    value-length plane), so kept tombstones coexist with values of any
+    width; a DELETE that carries a value is not expressible."""
     if count == 0:
         return None
     kl = arrays["key_len"][:count]
@@ -414,24 +442,24 @@ def planar_widths(arrays: Dict[str, np.ndarray], count: int):
     vt = arrays["vtype"][:count]
     vl = arrays["val_len"][:count]
     non_del = vl[vt != 2]
-    v0 = int(non_del[0]) if len(non_del) else 0
-    if len(non_del) and not (non_del == v0).all():
-        return None
+    v0 = v1 = 0
+    if len(non_del):
+        v0, v1 = int(non_del.min()), int(non_del.max())
     if not (vl[vt == 2] == 0).all():
         return None
     # Header bound (u16 vlen): wider values take the entry-stream sink.
     # The round-2 crash was this check missing — every uniform workload
     # with values >= 256 B died in the header packer (VERDICT r2 #1).
-    if v0 > PLANAR_MAX_VLEN:
+    if v1 > PLANAR_MAX_VLEN:
         return None
-    return k1, v0, k0 != k1
+    return k1, v1, k0 != k1, v0 != v1
 
 
 def _key_rows(arrays: Dict[str, np.ndarray], rows, klen: int) -> np.ndarray:
     """(len(rows), klen) u8: the key bytes of the given rows (a key
     shorter than ``klen`` zero-padded, as its lanes are)."""
     kw = np.ascontiguousarray(arrays["key_words_be"][rows].astype(">u4"))
-    return kw.view(np.uint8).reshape(len(kw), 24)[:, :klen]
+    return kw.view(np.uint8).reshape(len(kw), -1)[:, :klen]
 
 
 def _keys_of(arrays: Dict[str, np.ndarray], rows, klen: int,
@@ -448,7 +476,7 @@ def _write_planar(
     arrays: Dict[str, np.ndarray], count: int, path: str,
     bloom_words: Optional[np.ndarray], block_entries: int,
     compression: int, bits_per_key: int, klen: int, vlen: int,
-    mixed: bool,
+    mixed: bool, vmixed: bool,
     device_words: Optional[np.ndarray],
     device_checksums: Optional[np.ndarray],
 ) -> Optional[dict]:
@@ -456,18 +484,19 @@ def _write_planar(
     Without device-encoded words the native library, where it is, makes
     every block in one call; else ``_planar_blocks`` does, block by
     block. The file is the same. ``mixed``: the rows' keys differ in
-    length (``klen`` their widest); the device's block encoder knows
-    one key width, so its words are not taken then."""
+    length (``klen`` their widest); ``vmixed``: their live values differ
+    in width (``vlen`` the widest); the device's block encoder knows one
+    key width and one value width, so its words are not taken then."""
     seq32 = bool((arrays["seq_hi"][:count] == 0).all())
-    full_words = plane_words(block_entries, klen, vlen, seq32, mixed)
-    if mixed:
+    full_words = plane_words(block_entries, klen, vlen, seq32, mixed, vmixed)
+    if mixed or vmixed:
         device_words = device_checksums = None
     lib = get_file_codecs() if device_words is None else None
     encoded = None
     if lib is not None:
         encoded = lib.planar_encode_file(
             arrays, count, klen, vlen, seq32, block_entries, compression,
-            mixed)
+            mixed, vmixed)
     _count_codec(encoded is not None)
     writer = SSTWriter(path, compression=compression,
                        bits_per_key=bits_per_key)
@@ -475,7 +504,8 @@ def _write_planar(
         if encoded is None:
             encoded = _planar_blocks(
                 arrays, count, block_entries, compression, klen, vlen,
-                seq32, full_words, device_words, device_checksums, mixed)
+                seq32, full_words, device_words, device_checksums, mixed,
+                vmixed)
         payload, offs, sizes, codecs, chks = encoded
         ends = np.minimum(
             np.arange(1, len(offs) + 1) * block_entries, count)
@@ -502,7 +532,7 @@ def _write_planar(
                 arrays["key_len"][:count].astype(np.uint64), bits_per_key)
         extra_props = {
             "num_keys": int(count),
-            "planar": planar_props(klen, vlen, seq32, mixed),
+            "planar": planar_props(klen, vlen, seq32, mixed, vmixed),
             "block_chk": {
                 "algo": "poly1w",
                 "block_words": int(full_words),
@@ -521,6 +551,7 @@ def _planar_blocks(
     compression: int, klen: int, vlen: int, seq32: bool, full_words: int,
     device_words: Optional[np.ndarray],
     device_checksums: Optional[np.ndarray], mixed: bool = False,
+    vmixed: bool = False,
 ):
     """The Python block loop of the PLANAR sink, in the shape the native
     encoder returns: ``(payload, offsets, sizes, codecs, checksums)``.
@@ -547,7 +578,7 @@ def _planar_blocks(
                 chks.append(poly_checksum_words(words, full_words))
         else:
             raw = encode_planar_block(
-                arrays, start, end, klen, vlen, seq32, mixed)
+                arrays, start, end, klen, vlen, seq32, mixed, vmixed)
             words = np.frombuffer(
                 raw, dtype="<u4", offset=PLANAR_HEADER.size)
             chks.append(poly_checksum_words(words, full_words))
@@ -570,10 +601,14 @@ def _planar_blocks(
 
 def _read_planar_arrays(reader) -> Optional[Dict[str, np.ndarray]]:
     """PLANAR source path: per-block plane decode (views + reshapes),
-    lanes concatenated across blocks."""
+    lanes concatenated across blocks, each at the file's widths (its
+    widest key and value, from its props)."""
     try:
+        p = reader.props["planar"]
+        klen, vlen = int(p[0]), int(p[1])
         parts = [
-            decode_planar_block(reader._read_block(i, fill_cache=False))
+            decode_planar_block(reader._read_block(i, fill_cache=False),
+                                key_word_cols(klen), max(2, (vlen + 3) // 4))
             for i in range(len(reader._index))
         ]
     except Exception:
@@ -610,8 +645,8 @@ def write_sst_from_arrays(
 
     ``planar=True`` writes PLANAR blocks (storage/planar.py): u32 planes
     in kernel lane order — smaller files and no byte interleaving on
-    either side; keys of 1 to 24 bytes, of one length or mixed
-    (``planar_widths``). ``device_words`` optionally carries the device planar
+    either side; keys of 1 to 48 bytes, of one length or mixed, values
+    of one width or mixed (``planar_widths``). ``device_words`` optionally carries the device planar
     encoder's (nblocks, words) matrix for full blocks (the tail block is
     host-packed: its plane lengths differ from the fixed device shape)."""
     if planar:

@@ -38,6 +38,10 @@ FAST = dict(uniform_klen=True, seq32=True, key_words=4)
 # lane: a sort key after the four key words, a boundary compare, an output
 # lane)
 NAMES = dict(uniform_klen=False, seq32=True, key_words=4)
+# mixgraph_64x20k's: db_bench MixGraph keys of 48 bytes (one length), so
+# twelve key lanes, all in use; values of differing widths in the index
+# path's bucket of 256 words
+MIXGRAPH = dict(uniform_klen=True, seq32=True, key_words=12)
 BITS_PER_KEY = 10  # examples/counter_service/options.py
 
 
@@ -183,6 +187,53 @@ def test_cell_program_text_is_the_accepted_one(cell, monkeypatch):
         lane(group, n), values, lane(group, n),
         lane(group, n, dtype=jnp.bool_)).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == accepted
+
+
+def test_mixgraph_index_program_lowers_with_twelve_key_words(monkeypatch):
+    """The MixGraph deployment's program at its own shape, group 8,
+    capacity 32,768, 256 value words a row: the index pipeline over key
+    lanes of twelve words (keys of 48 bytes), lowered here for the CPU.
+    The key matrices in and out are twelve words wide; the four pinned
+    programs above do not change (six key lanes build them)."""
+    from rocksplicator_tpu.tpu.compaction_service import (
+        PIPELINE_PROGRAM_INDEX, TpuCompactionService)
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    n, group, words = 32768, 8, 256
+
+    def lane(*dims, dtype=U32):
+        return jax.ShapeDtypeStruct(dims, dtype)
+
+    fn = TpuCompactionService()._pipeline(
+        MergeKind.NONE, True, num_words_for(n, BITS_PER_KEY), **MIXGRAPH,
+        val_words=words)
+    text = fn.lower(
+        lane(group, n, 12), lane(group, n), lane(group, n), lane(group, n),
+        lane(group, n), tuple(lane(n, words) for _ in range(group)),
+        lane(group, n), lane(group, n, dtype=jnp.bool_)).as_text()
+    assert "@jit_" + PIPELINE_PROGRAM_INDEX in text[:200]
+    (main,) = [ln for ln in text.splitlines() if "func.func public @main" in ln]
+    # the key matrix in, the BE and LE key matrices out
+    assert main.count(f"tensor<{group}x{n}x12xui32>") == 3
+
+
+def test_mixgraph_index_program_compiles_for_the_chip(shape, monkeypatch):
+    """The same program, small, compiled for a described v5e: twelve
+    key-word sort operands and the row gather of each shard's values."""
+    from rocksplicator_tpu.tpu.compaction_service import TpuCompactionService
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    n, words = 512, 256
+    fn = TpuCompactionService()._pipeline(
+        MergeKind.NONE, True, num_words_for(n, BITS_PER_KEY), **MIXGRAPH,
+        val_words=words)
+    lanes = list(_kernel_lanes(shape, n, lead=(8,)))
+    lanes[0] = shape((8, n, 12))
+    lanes[5] = tuple(shape((n, words)) for _ in range(8))
+    compiled = fn.lower(*lanes).compile()
+    text = compiled.as_text()
+    assert " gather(" in text and "u32[8,512,12]" in text
+    assert compiled.memory_analysis() is not None
 
 
 def test_bloom_build_compiles(shape):

@@ -101,10 +101,12 @@ DECLINES = {
     # ("key_width": keys that only DIFFER in length are taken, and one of
     # over 24 bytes is declined by the lane read before the rule sees
     # lanes: both doors' cases are in tests/test_mixed_key_widths.py)
+    # (values of differing widths are taken with no operator:
+    # tests/test_mixgraph_records.py; under uint64-add they are not)
     "value_width_mixed": (
-        None,
-        [_put(i, b"v" * 8) for i in range(20)],
-        [_put(i, b"w" * 12) for i in range(10, 30)]),
+        UInt64AddOperator(),
+        [_put(i, pack64(i)) for i in range(20)],
+        [_put(i, b"\x02\x00\x00\x00") for i in range(10, 30)]),
     "value_width": (
         None,
         [_put(i, bytes([65 + i]) * (tb.DEVICE_VALUE_BYTES_MAX + 4))

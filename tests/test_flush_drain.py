@@ -168,20 +168,20 @@ def test_flush_fallback_vlen_over_u16(tmp_path):
 
 
 def test_flush_fallback_non_uniform_widths(tmp_path):
-    """A value of another width falls back to the per-entry sink; a key
-    of another LENGTH does not any more (tests/test_mixed_key_widths.py):
-    the array flush takes it, and the file is the per-entry sink's
-    entries all the same."""
+    """Neither a key of another LENGTH (tests/test_mixed_key_widths.py)
+    nor, any more, a value of another width falls back to the per-entry
+    sink (tests/test_mixgraph_records.py): the array flush takes both,
+    and the file is the per-entry sink's entries all the same."""
     for mutate in ("klen", "vlen"):
         mem = _mixed_mem(64)
         if mutate == "klen":
             mem.apply(b"short", 10_000, OpType.PUT, pack64(1))
         else:
             mem.apply(b"key%05d" % 1, 10_000, OpType.PUT, b"wide-value-16b!!")
-        assert (mem.drain_lanes() is None) == (mutate == "vlen")
+        assert mem.drain_lanes() is not None
         sub = tmp_path / mutate
         sub.mkdir()
-        got_a, got_b = _flush_both(sub, mem, expect_planar=mutate == "klen")
+        got_a, got_b = _flush_both(sub, mem, expect_planar=True)
         assert got_a == got_b
 
 
@@ -191,7 +191,7 @@ def test_drain_lanes_rejects_inexpressible_shapes():
     m.apply(b"k" * 8, 1, OpType.DELETE, b"oops")  # DELETE carrying a value
     assert m.drain_lanes() is None
     m = MemTable()
-    m.apply(b"k" * 25, 1, OpType.PUT, pack64(0))  # klen > PLANAR_MAX_KLEN
+    m.apply(b"k" * 49, 1, OpType.PUT, pack64(0))  # klen > PLANAR_MAX_KLEN
     assert m.drain_lanes() is None
 
 
@@ -230,10 +230,9 @@ def test_merged_memview_drain_parity(tmp_path):
     assert view.drain_lanes() is not None
     got_a, got_b = _flush_both(tmp_path, view, expect_planar=True)
     assert got_a == got_b
-    # a VALUE width mismatch in ANY memtable declines the whole view
-    # (checked per-part BEFORE any pad/concat, so the bail is O(parts)
-    # not O(entries))
-    odd_k = MemTable()  # a key of another length is lanes like any other
+    # a key of another length is lanes like any other, and so is a value
+    # of another width: the view's lanes are as wide as its widest
+    odd_k = MemTable()
     odd_k.apply(b"odd-width-key", 9999, OpType.PUT, pack64(1))
     lanes, key_mat = _MergedMemView(mems + [odd_k]).drain_lanes()
     assert lanes["key_len"].tolist() == [11] * 300 + [13]
@@ -241,7 +240,17 @@ def test_merged_memview_drain_parity(tmp_path):
         b"key00000000\0\0")
     bad_v = MemTable()
     bad_v.apply(b"key00000000", 9999, OpType.PUT, b"sixteen-byte-val")
-    assert _MergedMemView(mems + [bad_v]).drain_lanes() is None
+    lanes, _km = _MergedMemView(mems + [bad_v]).drain_lanes()
+    assert lanes["val_words"].shape == (301, 4)
+    assert lanes["val_len"][-1] == 16 and set(
+        lanes["val_len"][:300].tolist()) == {0, 8}
+    assert bytes(lanes["val_words"][-1].view(np.uint8)) == b"sixteen-byte-val"
+    assert not lanes["val_words"][:300, 2:].any()  # narrower rows: zero tails
+    sub = tmp_path / "wide_value"
+    sub.mkdir()
+    got_a, got_b = _flush_both(sub, _MergedMemView(mems + [bad_v]),
+                               expect_planar=True)
+    assert got_a == got_b
     # ...and an all-DELETE memtable constrains neither width
     all_del = MemTable()
     all_del.apply(b"key00000000", 10_000, OpType.DELETE, b"")
@@ -437,7 +446,7 @@ def test_install_full_compaction_arrays_empty_and_invalid(tmp_path):
         plan = db.plan_full_compaction()
         lanes = read_sst_arrays(db._readers[plan["inputs"][0]])
         lanes["key_len"] = lanes["key_len"].copy()
-        lanes["key_len"][0] = 25  # over the lanes' 24 bytes: no planar row
+        lanes["key_len"][0] = 49  # over the lanes' 48 bytes: no planar row
         with pytest.raises(InvalidArgument):
             db.install_full_compaction(
                 plan, arrays=(lanes, int(lanes["key_len"].shape[0])))

@@ -694,17 +694,18 @@ def test_compact_dbs_batched_tpu_parity(tmp_path):
 
 
 def test_compact_dbs_batched_declines_unsupported(tmp_path):
-    """A DB the lane format can't express (>24B keys) is declined
-    UNTOUCHED (plan aborted, compact_range still works on it)."""
+    """A DB the lane format can't express (>48B keys; >24B until keys of
+    up to 48 bytes took twelve key words) is declined UNTOUCHED (plan
+    aborted, compact_range still works on it)."""
     from rocksplicator_tpu.tpu.compaction_service import compact_dbs_batched
 
     db = DB(str(tmp_path / "wide"))
-    db.write(WriteBatch().put(b"k" * 40, b"v"))
+    db.write(WriteBatch().put(b"k" * 49, b"v"))
     db.flush()
     handled, remaining = compact_dbs_batched([("wide", db)])
     assert handled == [] and [n for n, _ in remaining] == ["wide"]
     db.compact_range()  # mutex was released by the abort
-    assert db.get(b"k" * 40) == b"v"
+    assert db.get(b"k" * 49) == b"v"
     db.close()
 
 

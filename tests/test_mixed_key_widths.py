@@ -12,7 +12,8 @@ tuple path.
 - the served door (``compact_dbs_batched``) riding, on the index path and
   cut by key range; the engine-seam door; the host array path, whole and
   in key-range slices; point reads and scans after each;
-- a key of 25 bytes: ``key_width``, and both doors decline it to the host;
+- a key over the lanes' 48 bytes: ``key_width``, and both doors decline
+  it to the host (keys of 25 to 48 bytes: tests/test_mixgraph_records.py);
 - files of ONE key length are byte for byte what the parent commit wrote
   (and so files written before this PR read back); an old-layout block
   reads back;
@@ -124,7 +125,7 @@ def test_planar_codecs_agree_block_for_block(kind, compression, tmp_path,
     arrays = lanes_of(entries)
     n, be = len(entries), 64
     assert fmt.planar_widths(arrays, n) == (
-        max(map(len, keys)), 8, kind != "uniform")
+        max(map(len, keys)), 8, kind != "uniform", False)
     assert get_file_codecs() is not None
     native, python = str(tmp_path / "n.tsst"), str(tmp_path / "p.tsst")
     was = Stats.get().get_counter("codec.python_files")
@@ -200,9 +201,12 @@ def test_row_format_file_decodes_to_lanes_natively(kind, tmp_path):
 
 
 def test_native_row_source_declines_what_the_lanes_cannot_hold(tmp_path):
-    """A key over 24 bytes, or a value of another width: not lanes."""
-    for name, odd in (("key", (b"k" * 25, 0, P, pack64(1))),
-                      ("value", (b"zz", 0, P, b"four"))):
+    """A key over 48 bytes (the lanes held 24 until keys of up to 48 were
+    taken), or a value wider than the planar layout's u16 width (a value
+    of another width is lanes since values of differing widths are):
+    not lanes."""
+    for name, odd in (("key", (b"k" * 49, 0, P, pack64(1))),
+                      ("value", (b"zz", 0, P, b"v" * (1 << 16)))):
         path = str(tmp_path / f"{name}.tsst")
         w = SSTWriter(path)
         for e in [(b"a%02d" % i, 0, P, pack64(i)) for i in range(50)] + [odd]:
@@ -665,7 +669,7 @@ def test_the_rule_keeps_key_width_for_a_key_the_lanes_cannot_hold():
              "val_len": np.array([8, 0, 8, 8], np.uint32)}
     assert nc.lanes_decline_reason(lanes, UInt64AddOperator()) is None
     assert tb.device_decline_reason(lanes, UInt64AddOperator()) is None
-    for bad in (25, 0):
+    for bad in (49, 0):  # 25 until keys of up to 48 bytes were taken
         lanes["key_len"][1] = bad
         op = UInt64AddOperator()
         assert nc.lanes_decline_reason(lanes, op) == "key_width"
@@ -676,9 +680,11 @@ def test_the_rule_keeps_key_width_for_a_key_the_lanes_cannot_hold():
 @pytest.mark.parametrize("door", ["engine_seam", "batched"])
 def test_a_key_of_25_bytes_goes_to_the_host_path(door, tmp_path,
                                                  monkeypatch):
+    """Named when the lanes held 24 bytes: the key is now one byte over
+    the 48 they hold (keys of 25 to 48 bytes take twelve key words)."""
     monkeypatch.setattr(sm, "STREAM_MODE_OVERRIDE", None)
-    keys = sorted(keys_of("names", 80) + [b"counter-" + b"9" * 17])
-    assert max(map(len, keys)) == 25
+    keys = sorted(keys_of("names", 80) + [b"counter-" + b"9" * 41])
+    assert max(map(len, keys)) == 49
     db, model = make_db(tmp_path / "db", keys, 4, counters=True)
     if door == "engine_seam":
         db._backend = tb.TpuCompactionBackend()
@@ -695,8 +701,8 @@ def test_a_key_of_25_bytes_goes_to_the_host_path(door, tmp_path,
 
 
 def test_the_door_says_how_long_a_key_of_a_mixed_shard_may_be():
-    assert cs.device_mixed_key_bytes_max(UInt64AddOperator()) == 24
-    assert cs.device_mixed_key_bytes_max(None) == 24
+    assert cs.device_mixed_key_bytes_max(UInt64AddOperator()) == 48
+    assert cs.device_mixed_key_bytes_max(None) == 48
 
     from rocksplicator_tpu.storage.merge import MergeOperator
 

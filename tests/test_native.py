@@ -772,8 +772,10 @@ def test_native_row_source_verifies_poly1_block_chk(tmp_path, monkeypatch):
 
 def test_native_row_source_width_drift_is_not_lanes(tmp_path, monkeypatch):
     """Width drift still yields None (the tuple path's file), decided by
-    the native source itself; a prop it cannot read is the Python
-    source's to judge."""
+    the native source itself, in a file with no width prop (as every
+    row-format file was before the plain writer said its ``widths_max``;
+    with it, values of differing widths are lanes); a prop it cannot
+    read is the Python source's to judge."""
     from rocksplicator_tpu.storage.sst import SSTReader, SSTWriter
     from rocksplicator_tpu.tpu import format as fmt
     from rocksplicator_tpu.utils.stats import Stats
@@ -784,14 +786,14 @@ def test_native_row_source_width_drift_is_not_lanes(tmp_path, monkeypatch):
     w.add(b"a" * 16, 3, 1, b"12345678")
     w.add(b"b" * 16, 2, 1, b"1234")
     w.add(b"c" * 16, 1, 1, b"123456789012")
-    w.finish()
+    w.finish(extra_props={"widths_max": None})
     # drift in a later block only
     late = str(tmp_path / "late.tsst")
     w = SSTWriter(late, block_bytes=41 * 50)
     for i in range(120):
         w.add(f"key{i:013d}".encode(), 1, 1, b"12345678" if i < 110
               else b"1234")
-    w.finish()
+    w.finish(extra_props={"widths_max": None})
     before = Stats.get().get_counter("codec.python_files")
     with monkeypatch.context() as m:
         m.setattr(fmt, "_read_uniform_arrays", None)
@@ -803,8 +805,8 @@ def test_native_row_source_width_drift_is_not_lanes(tmp_path, monkeypatch):
     assert Stats.get().get_counter("codec.python_files") == before + 2
     foreign = str(tmp_path / "foreign.tsst")
     w = SSTWriter(foreign)
-    w.add(b"k" * 30, 1, 1, b"v")
-    w.finish(extra_props={"uniform": [30, 1]})
+    w.add(b"k" * 49, 1, 1, b"v")  # over the key lanes' 48 bytes
+    w.finish(extra_props={"uniform": [49, 1]})
     r = SSTReader(foreign)
     assert fmt._read_lanes_native(r, False) is fmt._NOT_TAKEN
     assert fmt.read_sst_arrays(r) is None

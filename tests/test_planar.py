@@ -138,12 +138,16 @@ def test_planar_checksum_detects_corruption(tmp_path):
 
 def test_planar_widths_allows_tombstones_rejects_mixed():
     arrays, n = _arrays(_entries(50, with_deletes=True))
-    assert planar_widths(arrays, n) == (16, 8, False)
-    # mixed non-delete value widths are not planar-expressible
+    assert planar_widths(arrays, n) == (16, 8, False, False)
+    # mixed non-delete value widths are planar-expressible (the widest,
+    # and a value-length plane where a block's live rows differ) ...
     mixed, m = _arrays([
         (b"k" * 16, 2, OpType.PUT, b"12345678"),
         (b"m" * 16, 1, OpType.PUT, b"1234"),
     ])
+    assert planar_widths(mixed, m) == (16, 8, False, True)
+    # ... a DELETE that carries a value is not
+    mixed["vtype"][1] = int(OpType.DELETE)
     assert planar_widths(mixed, m) is None
 
 
@@ -250,6 +254,18 @@ def test_read_sst_arrays_infers_uniform_flush_files(tmp_path):
     w.add(b"b" * 16, 1, 1, b"123")  # different value width
     w.finish()
     r = SSTReader(mixed)
+    # a file that says its widest value (``widths_max``) decodes with each
+    # row's own value width; one written before that prop existed (no
+    # width prop at all) is not lanes
+    lanes = read_sst_arrays(r)
+    assert lanes["val_len"].tolist() == [8, 3]
+    assert bytes(lanes["val_words"][1].view(np.uint8)) == b"123" + bytes(5)
+    r.close()
+    w = SSTWriter(mixed, compression=0)
+    w.add(b"a" * 16, 2, 1, b"12345678")
+    w.add(b"b" * 16, 1, 1, b"123")
+    w.finish(extra_props={"widths_max": None})
+    r = SSTReader(mixed)
     assert read_sst_arrays(r) is None
     r.close()
 
@@ -262,7 +278,7 @@ def test_read_sst_arrays_infers_uniform_flush_files(tmp_path):
     w.add(b"a" * 16, 3, 1, b"12345678")
     w.add(b"b" * 16, 2, 1, b"1234")
     w.add(b"c" * 16, 1, 1, b"123456789012")
-    w.finish()
+    w.finish(extra_props={"widths_max": None})  # no width prop: inferred
     r = SSTReader(tricky)
     assert read_sst_arrays(r) is None
     r.close()
@@ -291,15 +307,17 @@ def test_engine_flush_writes_planar_files(tmp_path):
     assert db.get(b"k" + b"0" * 14 + b"3") == pack64(3)
     db.close()
 
-    # variable widths: entry-stream fallback, still fully readable
+    # variable widths: planar too (the widest, a fifth prop member), and
+    # fully readable
     db2 = DB(str(tmp_path / "db2"), DBOptions(compression=0))
     db2.put(b"a" * 16, b"12345678")
     db2.put(b"b" * 16, b"123")
     db2.flush()
     names = list(db2._levels[0])
     r2 = db2._readers[names[0]]
-    assert not r2.props.get("planar")
+    assert r2.props.get("planar") == [16, 8, 1, 0, 1]
     assert db2.get(b"b" * 16) == b"123"
+    assert db2.get(b"a" * 16) == b"12345678"
     db2.close()
 
 
@@ -348,13 +366,14 @@ def test_planar_widths_bounds_vlen():
     with pytest.raises(ValueError):
         pack_planar_header(1, 8, PLANAR_MAX_VLEN + 1, 0)
     with pytest.raises(ValueError):
-        pack_planar_header(1, 25, 8, 0)  # klen beyond the TPU key lanes
+        pack_planar_header(1, 49, 8, 0)  # klen beyond the TPU key lanes
 
 
 def test_decode_planar_block_bad_klen_raises_corruption():
-    """A length-self-consistent block with klen > 24 must raise Corruption
-    (not a numpy broadcast error) on the generic reader path."""
-    n, klen, vlen = 4, 30, 8
+    """A length-self-consistent block with klen > 48 (the key lanes'
+    width; 24 before keys of up to 48 bytes were taken) must raise
+    Corruption (not a numpy broadcast error) on the generic reader path."""
+    n, klen, vlen = 4, 49, 8
     words = plane_words(n, klen, vlen, seq32=False)
     raw = PLANAR_HEADER.pack(n, klen, vlen, 0, 0, 0) + b"\x00" * (4 * words)
     with pytest.raises(Corruption):

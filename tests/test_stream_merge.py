@@ -441,8 +441,10 @@ def test_streaming_compaction_does_not_evict_hot_blocks(tmp_path):
 
 def test_mid_stream_width_drift_declines_cleanly(tmp_path):
     """A file whose later blocks violate the probed uniform stride
-    declines streaming mid-flight: written outputs are swept and the
-    whole direct path hands off to the tuple merge (None)."""
+    declines streaming mid-flight: written outputs are swept, and the
+    in-RAM path (which takes values of differing widths, with no merge
+    operator) writes the result whole; with an operator it would hand
+    off to the tuple merge (None)."""
     path = os.path.join(str(tmp_path), "drift.tsst")
     entries = [(b"d%05d" % i, i + 1, P, b"v" * 8) for i in range(600)]
     entries += [(b"e%05d" % i, i + 1, P, b"w" * 16) for i in range(600)]
@@ -458,11 +460,14 @@ def test_mid_stream_width_drift_declines_cleanly(tmp_path):
     base = counter("compaction.stream_declines")
     outs = nc.direct_merge_runs_to_files(
         [SSTReader(path)], None, True, pf, 4096, 0, 10, 8192)
-    assert outs is None  # the in-RAM path declines mixed widths too
     assert counter("compaction.stream_declines") == base + 1
-    leftovers = [f for f in os.listdir(str(tmp_path))
-                 if f.startswith("o")]
-    assert leftovers == [], "decline leaked partial outputs"
+    leftovers = sorted(f for f in os.listdir(str(tmp_path))
+                       if f.startswith("o"))
+    assert leftovers == sorted(os.path.basename(p) for p, _ in outs), (
+        "decline leaked partial outputs")
+    got = [(k, s, t, v) for p, _ in outs
+           for k, s, t, v in SSTReader(p).iterate()]
+    assert got == sorted(entries, key=lambda e: (e[0], -e[1]))
 
 
 def test_block_lane_source_probe_matrix(tmp_path):

@@ -628,8 +628,8 @@ def test_read_sst_arrays_rejects_foreign_uniform_props(tmp_path):
 
     path = str(tmp_path / "f.tsst")
     w = SSTWriter(path)
-    w.add(b"k" * 30, 1, OpType.PUT, b"v")  # 30-byte key (beyond lanes)
-    w.finish(extra_props={"uniform": [30, 1]})
+    w.add(b"k" * 49, 1, OpType.PUT, b"v")  # 49-byte key (beyond lanes)
+    w.finish(extra_props={"uniform": [49, 1]})
     r = SSTReader(path)
     assert read_sst_arrays(r) is None  # falls back, no ValueError
     r.close()

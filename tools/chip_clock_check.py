@@ -85,6 +85,21 @@ the run's own and the last):
   beside the places the window's ``tpu.compact_stream`` spans launched
   by their ``key_widths`` annotation and the longest key any of them
   carried (``key_bytes_max``). ``--trace 0`` too.
+- **which key lanes and value shape the shards had**: the process's
+  ``compact.key_lanes.wide`` (shards through the served door with a key
+  over 24 bytes: twelve key words a row), ``compact.value_widths.uniform``
+  / ``.mixed`` (shards whose live values share one width / differ in
+  width) and ``flush.value_widths.mixed`` (memtable flushes of values of
+  differing width) beside the places the window's ``tpu.compact_stream``
+  spans launched by their ``key_lanes`` (6 / 12) and ``value_widths``
+  annotations, the words of key they use (``key_words``), and their
+  real value bytes against the bytes at the launch's value width
+  (``value_bytes`` / ``value_bytes_padded``: what a ragged value move
+  would save), and the widest value the window's ``tpu.lanes.decode`` /
+  ``tpu.planar.write`` / ``flush.encode`` spans carried
+  (``value_bytes_max``). 0 / ``uniform`` in the four cells of keys of at
+  most 24 bytes and one value width; every shard in
+  ``mixgraph_64x20k.refresh``. ``--trace 0`` too.
 
 Arguments are ``chipbench/run.py``'s own.
 """
@@ -284,6 +299,30 @@ def main(argv=None) -> int:
                for k in ("compact.key_widths.uniform",
                          "compact.key_widths.mixed",
                          "flush.key_widths.mixed")})))
+        vmax = {name: max((int(sp["annotations"].get("value_bytes_max", 0))
+                          for sp in run.spans if sp["name"] == name),
+                         default=0)
+                for name in ("tpu.lanes.decode", "tpu.planar.write",
+                             "flush.encode")}
+        harness.say("shards by key lanes and value shape: " + json.dumps(dict(
+            {"window_launched_key_lanes_" + str(w): sum(
+                int(a["shards"]) for a in streams
+                if a.get("key_lanes") == w) for w in (6, 12)},
+            **{"window_launched_values_" + w: sum(
+                int(a["shards"]) for a in streams
+                if a.get("value_widths") == w) for w in ("uniform", "mixed")},
+            window_key_words=sorted({a.get("key_words") for a in streams
+                                     if "key_words" in a}),
+            window_value_bytes=sum(int(a.get("value_bytes", 0))
+                                   for a in streams),
+            window_value_bytes_padded=sum(
+                int(a.get("value_bytes_padded", 0)) for a in streams),
+            window_value_bytes_max=vmax,
+            **{"process_" + k: Stats.get().get_counter(k)
+               for k in ("compact.key_lanes.wide",
+                         "compact.value_widths.uniform",
+                         "compact.value_widths.mixed",
+                         "flush.value_widths.mixed")})))
         harness.say("inside a served RPC, by method: " + json.dumps(
             phase_table(run.spans)))
         return real_read_metrics(bench, group, package, cell, run)
