@@ -564,3 +564,36 @@ def gather_value_rows(val_words: jnp.ndarray, val_row: jnp.ndarray,
     live = lax.iota(jnp.int32, val_row.shape[0]) < count
     rows = val_words.at[val_row].get(mode="promise_in_bounds")
     return jnp.where(live[:, None], rows, jnp.zeros_like(rows))
+
+
+def gather_value_stream(stream: jnp.ndarray, in_len: jnp.ndarray,
+                        val_row: jnp.ndarray, out_len: jnp.ndarray,
+                        count) -> jnp.ndarray:
+    """The index path's move of values that travel as ONE word stream
+    (``tpu/format.py`` ``ValueStream``: input row j's value is
+    ``ceil(in_len[j] / 4)`` words from the exclusive cumsum of those
+    counts on). Output row i takes ``ceil(out_len[i] / 4)`` words from
+    input row ``val_row[i]``, placed at the exclusive cumsum of the
+    output rows' counts; words past the last of the first ``count`` rows
+    are zero. One word gather: each output word finds its row (the last
+    row starting at or before it: a scatter of the rows' starts and a
+    running max) and reads its source word at that row's shift."""
+    size = stream.shape[0]
+    n = val_row.shape[0]
+    iota = lax.iota(jnp.int32, n)
+    in_words = ((in_len + 3) >> 2).astype(jnp.int32)
+    in_off = jnp.cumsum(in_words) - in_words
+    out_words = jnp.where(iota < count, ((out_len + 3) >> 2).astype(
+        jnp.int32), 0)
+    out_end = jnp.cumsum(out_words)
+    out_off = out_end - out_words
+    starts = jnp.zeros(size, jnp.int32).at[
+        jnp.where(out_words > 0, out_off, size)].max(iota, mode="drop")
+    owner = lax.cummax(starts)
+    shift = in_off.at[val_row].get(mode="promise_in_bounds") - out_off
+    pos = lax.iota(jnp.int32, size)
+    live = pos < out_end[-1]
+    src = jnp.where(live, pos + shift.at[owner].get(
+        mode="promise_in_bounds"), 0)
+    return jnp.where(live, stream.at[src].get(mode="promise_in_bounds"),
+                     jnp.zeros((), stream.dtype))

@@ -146,7 +146,7 @@ class NativeLib:
                 ctypes.c_uint64, ctypes.c_uint32, ctypes.c_uint32,
                 ctypes.c_int32, ctypes.c_uint32, ctypes.c_int32,
                 _u8p, ctypes.c_uint64, _u64p, _u32p, _u8p, _u32p, _u32p,
-                _u32p,
+                _u32p, _u64p,
             ]
             lib.tsst_decode_file_lanes.restype = ctypes.c_int64
             lib.tsst_decode_file_lanes.argtypes = [
@@ -155,6 +155,7 @@ class NativeLib:
                 ctypes.c_uint32, ctypes.c_int32,
                 _u32p, _u32p, _u32p, _u32p, _u32p, _u32p, _u32p, _u32p,
                 ctypes.c_int32, ctypes.c_uint64, _u32p, _i64p,
+                _u32p, ctypes.c_uint64, _u64p,
             ]
             lib.tsst_read_block.restype = ctypes.c_int64
             lib.tsst_read_block.argtypes = [
@@ -434,14 +435,29 @@ class NativeLib:
         differ in length (``klen`` their widest): each block's header
         and key-length plane follow from its own rows. ``vmixed``: the
         same for the values' widths (``vlen`` their widest) and the
-        value-length plane."""
+        value-length plane. Lanes with ``val_off`` (``count + 1`` word
+        offsets) carry their values as ONE word stream, ``val_stream``
+        (``tpu/format.py`` ``ValueStream``): the same blocks."""
         kw, vw = (klen + 3) // 4, (vlen + 3) // 4
         kw_be = np.ascontiguousarray(
             arrays["key_words_be"][:count], dtype=np.uint32)
-        val_words = np.ascontiguousarray(
-            arrays["val_words"][:count], dtype=np.uint32)
-        if (kw_be.ndim != 2 or val_words.ndim != 2
-                or kw_be.shape[1] < kw or val_words.shape[1] < vw):
+        val_off = arrays.get("val_off")
+        if val_off is not None:
+            val_off = np.ascontiguousarray(val_off[:count + 1],
+                                           dtype=np.uint64)
+            val_words = np.ascontiguousarray(arrays["val_stream"],
+                                             dtype=np.uint32)
+            if len(val_off) != count + 1 or int(val_off[-1]) > len(
+                    val_words):
+                return None
+            val_cols = 0
+        else:
+            val_words = np.ascontiguousarray(
+                arrays["val_words"][:count], dtype=np.uint32)
+            if val_words.ndim != 2 or val_words.shape[1] < vw:
+                return None
+            val_cols = val_words.shape[1]
+        if kw_be.ndim != 2 or kw_be.shape[1] < kw:
             return None
         seq_lo = np.ascontiguousarray(
             arrays["seq_lo"][:count], dtype=np.uint32)
@@ -471,13 +487,14 @@ class NativeLib:
             kw_be.ctypes.data_as(_u32p), kw_be.shape[1],
             seq_lo.ctypes.data_as(_u32p), seq_hi.ctypes.data_as(_u32p),
             self._u8(vtype),
-            val_words.ctypes.data_as(_u32p), val_words.shape[1],
+            val_words.ctypes.data_as(_u32p), val_cols,
             count, klen, vlen, int(seq32), block_entries, int(compression),
             self._u8(out), cap, self._u64(offs),
             sizes.ctypes.data_as(_u32p), self._u8(codecs),
             chks.ctypes.data_as(_u32p),
             None if key_len is None else key_len.ctypes.data_as(_u32p),
             None if val_len is None else val_len.ctypes.data_as(_u32p),
+            None if val_off is None else self._u64(val_off),
         )
         if wrote < 0:
             raise ValueError(f"tsst_planar_encode_file failed ({wrote})")
@@ -486,7 +503,8 @@ class NativeLib:
     def decode_file_lanes(self, fd: int, index: np.ndarray, planar: bool,
                           klen: int, vlen: int, rows: int,
                           chk_mode: int = 0, chk_len: int = 0,
-                          key_cols: int = 6, vmixed: bool = False):
+                          key_cols: int = 6, vmixed: bool = False,
+                          stream=None):
         """Every block of one file -> the eight kernel lanes in ONE call
         (tsst_decode_file_lanes: pread, inflate, transpose; the GIL is
         released for all of it). ``index``: (nblocks, 3) u64 of offset,
@@ -498,11 +516,15 @@ class NativeLib:
         widest key. ``vmixed``: the values differ in width, ``vlen`` the
         widest (``lanes["val_len"]`` has each row's). ``chk_mode`` 1 / 2:
         also each block's poly1 / poly1w value over ``chk_len``.
+        ``stream`` (``tpu/format.py`` ``ValueStream``): the values are
+        written into its word stream from its ``pos`` on, which moves past
+        them, and the lanes have no ``val_words``.
 
         Returns ``(status, lanes, checksums, blocks)``: status >= 0 is
         the row count (lanes cut to it); below zero the C routine's code
         (-1 read, -2 corrupt block, -3 width drift, -4 more rows than
-        ``rows``) and lanes is None. ``blocks``: how many blocks it got
+        ``rows``, -6 the values overrun ``stream``) and lanes is None.
+        ``blocks``: how many blocks it got
         through, the one it stopped at included — that many checksums
         are computed."""
         index = np.ascontiguousarray(index, dtype=np.uint64)
@@ -512,6 +534,7 @@ class NativeLib:
         vlen_io = ctypes.c_uint32(vlen)
         err_block = ctypes.c_int64(-1)
         val_cols = max(2, (vlen + 3) // 4)
+        stream_pos = ctypes.c_uint64(0 if stream is None else stream.pos)
         while True:
             lanes = {
                 "key_words_be": np.empty((rows, key_cols), dtype=np.uint32),
@@ -520,18 +543,25 @@ class NativeLib:
                 "seq_hi": np.empty(rows, dtype=np.uint32),
                 "seq_lo": np.empty(rows, dtype=np.uint32),
                 "vtype": np.empty(rows, dtype=np.uint32),
-                "val_words": np.empty((rows, val_cols), dtype=np.uint32),
                 "val_len": np.empty(rows, dtype=np.uint32),
             }
+            if stream is None:
+                lanes["val_words"] = np.empty((rows, val_cols),
+                                              dtype=np.uint32)
             got = self._lib.tsst_decode_file_lanes(
                 fd, self._u64(index), nblocks, int(planar),
                 ctypes.byref(klen_io), ctypes.byref(vlen_io), rows,
                 val_cols, key_cols, int(vmixed),
-                *(lanes[f].ctypes.data_as(_u32p) for f in (
-                    "key_words_be", "key_words_le", "key_len", "seq_hi",
-                    "seq_lo", "vtype", "val_words", "val_len")),
+                *(lanes[f].ctypes.data_as(_u32p) if f in lanes else None
+                  for f in ("key_words_be", "key_words_le", "key_len",
+                            "seq_hi", "seq_lo", "vtype", "val_words",
+                            "val_len")),
                 chk_mode, chk_len, chks.ctypes.data_as(_u32p),
                 ctypes.byref(err_block),
+                None if stream is None
+                else stream.words.ctypes.data_as(_u32p),
+                0 if stream is None else len(stream.words),
+                ctypes.byref(stream_pos),
             )
             if got != -5:
                 break
@@ -539,6 +569,8 @@ class NativeLib:
             val_cols = max(2, (int(vlen_io.value) + 3) // 4)
         if got < 0:
             return int(got), None, chks, int(err_block.value) + 1
+        if stream is not None:
+            stream.pos = int(stream_pos.value)
         if got < rows:
             lanes = {f: a[:got] for f, a in lanes.items()}
         return int(got), lanes, chks, nblocks

@@ -989,10 +989,14 @@ struct BlockFetcher {
 // in width gets the value-length plane and flag 4, its header's vlen and
 // its value planes at the block's own widest value; one of one width is
 // the old block at that width (an all-DELETE block: at `vlen`). Keys are
-// up to 48 bytes (`kw_cols` words of lanes a row). Returns the bytes
-// written, -1 when `out_cap` is short, -2 on arguments the layout can't
-// take (a key length of 0 or over `klen`, a value over `vlen`, among
-// them), -3 when zlib fails.
+// up to 48 bytes (`kw_cols` words of lanes a row). `val_off` (null: the
+// values are rows of `val_cols` words) says the values are ONE word
+// stream: row r's value is the words [val_off[r], val_off[r + 1]) of
+// `val_words`, each from a 4-byte boundary, and its planes read zeros
+// past them, as a zero-tailed row does: the same blocks. Returns the
+// bytes written, -1 when `out_cap` is short, -2 on arguments the layout
+// can't take (a key length of 0 or over `klen`, a value over `vlen`,
+// among them), -3 when zlib fails.
 extern "C" int64_t tsst_planar_encode_file(
     const uint32_t* kw_be, uint32_t kw_cols,
     const uint32_t* seq_lo, const uint32_t* seq_hi, const uint8_t* vtype,
@@ -1001,10 +1005,11 @@ extern "C" int64_t tsst_planar_encode_file(
     uint32_t block_entries, int32_t compression,
     uint8_t* out, uint64_t out_cap,
     uint64_t* blk_off, uint32_t* blk_size, uint8_t* blk_codec,
-    uint32_t* blk_chk, const uint32_t* key_len, const uint32_t* val_len) {
+    uint32_t* blk_chk, const uint32_t* key_len, const uint32_t* val_len,
+    const uint64_t* val_off) {
   uint64_t fvw = ((uint64_t)vlen + 3) / 4;
   if (klen == 0 || klen > 48 || vlen > 0xFFFFu || block_entries == 0
-      || (klen + 3) / 4 > kw_cols || fvw > val_cols
+      || (klen + 3) / 4 > kw_cols || (val_off == nullptr && fvw > val_cols)
       || (!seq32 && seq_hi == nullptr))
     return -2;
   uint64_t full_words = planar_plane_words(
@@ -1084,9 +1089,23 @@ extern "C" int64_t tsst_planar_encode_file(
       memset(w + 2 * n, 0, vl_bytes - 2 * n);
       w += vl_bytes;
     }
-    for (uint64_t k = 0; k < vw; k++)
-      for (uint64_t i = 0; i < n; i++, w += 4)
-        put_u32(w, val_words[(start + i) * val_cols + k]);
+    if (val_off != nullptr) {
+      // zeroed planes, then each row's own words: past them its planes
+      // read zeros, as a zero-tailed row's do (never past the block's
+      // width: a live row is no wider, a DELETE has no word)
+      memset(w, 0, 4 * vw * n);
+      const uint64_t* vo = val_off + start;
+      for (uint64_t i = 0; i < n; i++) {
+        uint64_t nw = std::min<uint64_t>(vo[i + 1] - vo[i], vw);
+        for (uint64_t k = 0; k < nw; k++)
+          put_u32(w + 4 * (k * n + i), val_words[vo[i] + k]);
+      }
+      w += 4 * vw * n;
+    } else {
+      for (uint64_t k = 0; k < vw; k++)
+        for (uint64_t i = 0; i < n; i++, w += 4)
+          put_u32(w, val_words[(start + i) * val_cols + k]);
+    }
     blk_chk[bi] = chk_words(raw + 16, words, full_words, &pw);
     uint64_t size = raw_len;
     uint8_t codec = TSST_BLOCK_PLANAR;
@@ -1134,6 +1153,12 @@ extern "C" int64_t tsst_planar_encode_file(
 // PLANAR block may carry the value-length plane, flag 4, or be of one
 // width up to *vlen_io); bytes past a value's length are zero.
 // vmixed == 0: every live value is *vlen_io bytes.
+// stream != null: the values go to ONE word stream and not to the value
+// lanes (`val_words` is not written, `val_cols` not asked): row r's value
+// is ceil(val_len[r] / 4) words from stream[*stream_pos] on, from a
+// 4-byte boundary, the words a zero-tailed row would hold (a DELETE: no
+// word); *stream_pos is moved past them on success. `stream_cap` words
+// in all.
 // chk_mode 1 / 2: each block's poly1 (bytes)
 // / poly1w (plane words) value over `chk_len`, into blk_chk: the caller
 // holds them against the file's block_chk prop.
@@ -1147,6 +1172,7 @@ extern "C" int64_t tsst_planar_encode_file(
 //   -4  more rows than row_cap
 //   -5  the inferred value width needs another val_cols: *vlen_io is
 //       filled, call again with it
+//   -6  the values overrun the stream's `stream_cap` words
 extern "C" int64_t tsst_decode_file_lanes(
     int32_t fd, const uint64_t* index, uint64_t nblocks, int32_t planar,
     uint32_t* klen_io, uint32_t* vlen_io, uint64_t row_cap,
@@ -1155,8 +1181,10 @@ extern "C" int64_t tsst_decode_file_lanes(
     uint32_t* seq_hi, uint32_t* seq_lo, uint32_t* vtype,
     uint32_t* val_words, uint32_t* val_len,
     int32_t chk_mode, uint64_t chk_len, uint32_t* blk_chk,
-    int64_t* err_block) {
+    int64_t* err_block, uint32_t* stream, uint64_t stream_cap,
+    uint64_t* stream_pos) {
   uint32_t klen = *klen_io, vlen = *vlen_io;
+  uint64_t spos = stream ? *stream_pos : 0;
   if (kw_cols != 6 && kw_cols != 12) return -2;
   const uint32_t kmax = 4 * kw_cols;  // the longest key the lanes hold
   bool infer = !planar && klen == 0;
@@ -1187,7 +1215,7 @@ extern "C" int64_t tsst_decode_file_lanes(
       if (raw_len != 16 + 4 * words) { rc = -2; break; }
       if (chk_mode == 2)
         blk_chk[bi] = chk_words(raw + 16, words, chk_len, &pw);
-      if (bklen > klen || vw > val_cols
+      if (bklen > klen || (!stream && vw > val_cols)
           || (vmixed ? bvlen > vlen : (bvlen != vlen || vlens))) {
         rc = -3; break;
       }
@@ -1223,6 +1251,24 @@ extern "C" int64_t tsst_decode_file_lanes(
         seq_hi[r] = shi ? get_u32(shi + 4 * i) : 0u;
         uint32_t vt = vtp[i];
         vtype[r] = vt;
+        if (stream) {
+          uint32_t ev = vt == 2 ? 0u : bvlen;
+          if (vlp) {
+            ev = (uint32_t)vlp[2 * i] | ((uint32_t)vlp[2 * i + 1] << 8);
+            if (ev > bvlen) { rc = -2; break; }
+          }
+          val_len[r] = ev;
+          uint64_t evw = (ev + 3) / 4;
+          if (spos + evw > stream_cap) { rc = -6; break; }
+          for (uint64_t k = 0; k < evw; k++) {
+            uint32_t le = get_u32(vvp + 4 * (k * n + i));
+            if (vlp && k + 1 == evw && ev % 4)
+              le &= 0xFFFFFFFFu >> (8 * (4 - ev % 4));
+            stream[spos + k] = le;
+          }
+          spos += evw;
+          continue;
+        }
         if (vlp) {
           // the row's own value length; bytes past it are not value
           uint32_t ev = (uint32_t)vlp[2 * i] | ((uint32_t)vlp[2 * i + 1] << 8);
@@ -1259,7 +1305,7 @@ extern "C" int64_t tsst_decode_file_lanes(
       *vlen_io = vlen;
     }
     uint64_t need_cols = std::max<uint64_t>(2, ((uint64_t)vlen + 3) / 4);
-    if (need_cols != val_cols) { rc = -5; break; }
+    if (!stream && need_cols != val_cols) { rc = -5; break; }
     // entry: u32 klen | key | u64 seq | u8 vtype | u32 vlen | value
     for (uint64_t pos = 0; pos < raw_len; row++) {
       const uint8_t* e = raw + pos;
@@ -1289,12 +1335,21 @@ extern "C" int64_t tsst_decode_file_lanes(
       seq_lo[row] = (uint32_t)seq;
       vtype[row] = e[ek + 12];
       val_len[row] = ev;
-      uint32_t* vdst = val_words + row * val_cols;
-      memset(vdst, 0, 4 * (size_t)val_cols);
-      memcpy(vdst, e + ek + 17, ev);
+      if (stream) {
+        uint64_t evw = ((uint64_t)ev + 3) / 4;
+        if (spos + evw > stream_cap) { rc = -6; break; }
+        if (evw) stream[spos + evw - 1] = 0;
+        memcpy(stream + spos, e + ek + 17, ev);
+        spos += evw;
+      } else {
+        uint32_t* vdst = val_words + row * val_cols;
+        memset(vdst, 0, 4 * (size_t)val_cols);
+        memcpy(vdst, e + ek + 17, ev);
+      }
       pos += 17 + (uint64_t)ek + ev;
     }
   }
+  if (rc >= 0 && stream) *stream_pos = spos;
   return rc < 0 ? rc : (int64_t)row;
 }
 

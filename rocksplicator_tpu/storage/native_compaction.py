@@ -208,6 +208,7 @@ def read_runs_as_lanes(
     runs: List, merge_op: Optional[MergeOperator],
     max_entries: int = MAX_DIRECT_ENTRIES,
     value_rows: Optional[Callable[[int, int], int]] = None,
+    value_stream: Optional[Callable[[List], object]] = None,
 ) -> Optional[Tuple[List[dict], dict, int, int]]:
     """Decode input runs (SSTReaders or entry iterables) straight into
     concatenated lane arrays. Returns (parts, lanes, total, vw) or None
@@ -225,6 +226,13 @@ def read_runs_as_lanes(
     caller that ships the values padded to a launch's capacity (and
     value bucket) copies them once.
 
+    ``value_stream(runs)`` (the served device door's too) gives a
+    ``tpu/format.py`` ``ValueStream`` that every run's values are decoded
+    INTO, one after another, or None (the rows above): ``lanes`` then
+    has ``val_stream`` (the stream's buffer, zero past the last value)
+    in ``val_words``' place, the parts have no values, and
+    ``StreamOverrun`` is raised where the values do not fit it.
+
     Runs whose keys or values are of different widths (a file of keys
     over 24 bytes, 12 words a row, beside one of shorter keys; values of
     differing widths) are concatenated at the widest: ``vw`` is the
@@ -239,15 +247,19 @@ def read_runs_as_lanes(
     from ..ops.kv_format import UnsupportedBatch, pack_entries
     from ..tpu.format import read_sst_arrays
 
+    stream = value_stream(runs) if value_stream is not None else None
+
     def decode_one(run) -> Optional[dict]:
+        arr = None
         if hasattr(run, "iterate"):  # an SSTReader
-            arr = read_sst_arrays(run)
+            arr = read_sst_arrays(run, stream)
             if arr is None:
-                arr = NativeCompactionBackend._arrays_from_entries(
-                    list(run.iterate()), pack_entries)
-        else:
+                run = run.iterate()
+        if arr is None:
             arr = NativeCompactionBackend._arrays_from_entries(
                 list(run), pack_entries)
+            if arr is not None and stream is not None:
+                stream.append_rows(arr.pop("val_words"), arr["val_len"])
         return arr
 
     parts: List[dict] = []
@@ -280,6 +292,12 @@ def read_runs_as_lanes(
         w = p["key_words_be"].shape[1]
         if w < kc:  # keys of one run are wider than another's
             p["key_words_be"] = np.pad(p["key_words_be"], [(0, 0), (0, kc - w)])
+    if stream is not None:  # the values are the stream's buffer
+        lanes = {f: np.concatenate([p[f] for p in parts])
+                 for f in _LANE_FIELDS if f != "val_words"}
+        lanes["val_stream"] = stream.finish()
+        vl = lanes["val_len"]
+        return parts, lanes, total, max(2, (int(vl.max()) + 3) // 4)
     vw = max(p["val_words"].shape[1] for p in parts)
     return parts, concat_lanes(parts, total, value_rows), total, vw
 
@@ -376,7 +394,10 @@ def write_resolved_lanes(
     ``trace`` where the caller's thread carries no trace context of its
     own (a pool thread); ``value_widths`` and ``value_bytes_max`` of the
     file's own rows beside them. Rows whose keys differ in length, or
-    values in width, size their files and blocks by their widest."""
+    values in width, size their files and blocks by their widest. The
+    values may be rows (``val_words``) or one word stream
+    (``val_stream`` and its ``count + 1`` word offsets ``val_off``,
+    ``tpu/format.py`` ``ValueStream``): the files are the same."""
     from ..observability.span import start_span
     from ..tpu.format import planar_stride, planar_widths, \
         write_sst_from_arrays
@@ -407,7 +428,8 @@ def write_resolved_lanes(
     try:
         for start in range(0, count, entries_per_file):
             end = min(start + entries_per_file, count)
-            sub = {f: arrays[f][start:end] for f in arrays}
+            sub = {f: a if f == "val_stream" else a[start:end + (
+                f == "val_off")] for f, a in arrays.items()}
             bloom_words = build_bloom(sub, end - start)
             if bloom_words is None:  # the caller's builder has none
                 bloom_words = host_bloom(sub, end - start)

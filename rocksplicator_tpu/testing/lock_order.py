@@ -70,12 +70,12 @@ RANKS = {
     "rocksplicator_tpu/rpc/admission.py:115": ('TenantAdmission._instance_lock', 50),
     "rocksplicator_tpu/rpc/admission.py:125": ('TenantAdmission._lock', 51),
     "rocksplicator_tpu/rpc/admission.py:67": ('TokenBucket._lock', 52),
-    "rocksplicator_tpu/tpu/compaction_service.py:74": ('TpuCompactionService._instance_lock', 53),
+    "rocksplicator_tpu/tpu/compaction_service.py:82": ('TpuCompactionService._instance_lock', 53),
     "rocksplicator_tpu/storage/archive.py:63": ('WalArchiver._mutex', 54),
     "rocksplicator_tpu/testing/failpoints.py:129": ('_Site.lock', 55),
     "rocksplicator_tpu/utils/stats.py:200": ('_ThreadBuffer.lock', 56),
     "rocksplicator_tpu/kafka/broker.py:204": ('kafka.broker:_clusters_lock', 57),
-    "rocksplicator_tpu/storage/native/binding.py:696": ('storage.native.binding:_native_lock', 58),
+    "rocksplicator_tpu/storage/native/binding.py:728": ('storage.native.binding:_native_lock', 58),
     "rocksplicator_tpu/testing/failpoints.py:161": ('testing.failpoints:_lock', 59),
     "rocksplicator_tpu/utils/objectstore.py:379": ('utils.objectstore:_store_cache_lock', 60),
     "rocksplicator_tpu/admin/db_manager.py:20": ('ApplicationDBManager._lock', 61),

@@ -22,6 +22,7 @@ cut by key range into several places of the same launch.
 
 from __future__ import annotations
 
+import functools
 import logging
 import os
 import threading
@@ -37,6 +38,7 @@ from ..storage.bloom import num_words_for
 from ..storage.engine import DBOptions
 from ..ops.bloom_tpu import bloom_build_tpu
 from ..ops.compaction_kernel import (MergeKind, gather_value_rows,
+                                     gather_value_stream,
                                      merge_resolve_kernel,
                                      merge_resolve_rows, value_path)
 from ..ops.kv_format import KEY_WORDS, KVBatch, fast_flags, unpack_entries
@@ -51,6 +53,8 @@ from ..utils.stats import Stats
 from .backend import (DEVICE_VALUE_BYTES_MAX, TpuCompactionBackend,
                       _device_bloom_builder, _next_pow2,
                       device_decline_reason, require_accelerator)
+from .format import (StreamOverrun, ValueStream, stream_may_carry,
+                     stream_offsets, with_value_rows)
 # what the device path takes is asked HERE by the record deployment's
 # driver (chipbench/drivers/refresh_rec.py)
 from .backend import device_value_bytes_max  # noqa: F401
@@ -67,6 +71,10 @@ _GROUP_LANES = (
     "key_words_be", "key_len", "seq_hi",
     "seq_lo", "vtype", "val_words", "val_len", "valid",
 )
+# The words a row of a place's capacity bucket that its value stream
+# holds (``ValueStream``: 524,288 words, 2 MB, at 32,768 rows); a place
+# whose values need more keeps its rows (``compact.value_move.overrun``)
+STREAM_WORDS_PER_ROW = 16
 
 
 class TpuCompactionService:
@@ -77,7 +85,7 @@ class TpuCompactionService:
         require_accelerator()
         self._bits_per_key = bits_per_key
         self._vmapped_cache: Dict[tuple, object] = {}
-        self._zero_rows: Dict[tuple, object] = {}  # (capacity, words)
+        self._zero_rows: Dict[tuple, object] = {}  # by shape
 
     @classmethod
     def instance(cls) -> "TpuCompactionService":
@@ -104,16 +112,18 @@ class TpuCompactionService:
     def _pipeline(self, merge_kind: MergeKind, drop_tombstones: bool,
                   num_words: int, uniform_klen: bool = False,
                   seq32: bool = False, key_words: int = KEY_WORDS,
-                  val_words: int = 0):
+                  val_words: int = 0, stream: bool = False):
         """The jitted pipeline of one launch group. ``val_words`` (the
         group's value width in u32 words) chooses the value path as the
         kernel does (``value_path``). Riding: every lane stacked
         ``(group, N, ...)``. Index: ``val_words`` goes in and comes out
         as a TUPLE of per-shard ``(N, W)`` buffers, so that only real
-        shards' values cross the host-device seam."""
+        shards' values cross the host-device seam; with ``stream`` a
+        tuple of per-shard word streams (``ValueStream``) in, and out as
+        ``val_stream`` (``gather_value_stream``)."""
         index = value_path(merge_kind, val_words) == "index"
         key = (merge_kind, drop_tombstones, num_words, uniform_klen, seq32,
-               key_words, index)
+               key_words, index, index and stream)
         fn = self._vmapped_cache.get(key)
         if fn is None:
             flags = dict(drop_tombstones=drop_tombstones,
@@ -148,13 +158,26 @@ class TpuCompactionService:
                     for s, vw in enumerate(vws))
                 return out
 
+            def one_shard_stream(kwbe, klen, shi, slo, vt, streams, vl,
+                                 valid):
+                out = jax.vmap(one_shard_rows)(
+                    kwbe, klen, shi, slo, vt, vl, valid)
+                moved = jax.vmap(gather_value_stream)(
+                    jax.numpy.stack(streams), vl, out.pop("val_row"),
+                    out["val_len"], out["count"])
+                out["val_stream"] = tuple(moved[s]
+                                          for s in range(len(streams)))
+                return out
+
             # the XLA module's name (jit_<name>), by which a device
             # trace's reader finds the pipeline: fixed here, not left to
             # whatever the closure happens to be called
             one_shard.__name__ = PIPELINE_PROGRAM
             one_shard_index.__name__ = PIPELINE_PROGRAM_INDEX
-            fn = jax.jit(one_shard_index if index
-                         else jax.vmap(one_shard))
+            one_shard_stream.__name__ = PIPELINE_PROGRAM_INDEX
+            fn = jax.jit(
+                (one_shard_stream if stream else one_shard_index) if index
+                else jax.vmap(one_shard))
             self._vmapped_cache[key] = fn
         return fn
 
@@ -225,12 +248,17 @@ class TpuCompactionService:
         seq32 = all(s for _, s, _ in flags)
         key_words = max(k for _, _, k in flags)
         # the group's value width: its widest place's (a narrower place
-        # is zero-tailed to it); its key lanes' (6 or 12 words) are one
-        val_words = max(b.val_words.shape[1] for b in batches)
+        # is zero-tailed to it); its key lanes' (6 or 12 words) are one;
+        # its places' values as rows or as word streams, never both
+        val_words = max(_val_cols(b) for b in batches)
+        stream = getattr(batches[0], "val_stream", None) is not None
         key_cols = batches[0].key_words_be.shape[1]
         path = value_path(merge_kind, val_words)
         index = path == "index"
         vmixed = any(_values_mixed(b) for b in batches)
+        # the shape of one place's values on the device
+        per_place = ((STREAM_WORDS_PER_ROW * capacity,) if stream
+                     else (capacity, val_words))
         span = current_span()  # tpu.compact_stream / tpu.compact_batch
         if span is not None:
             # key_widths: ``mixed`` where some place's keys differ in
@@ -239,7 +267,10 @@ class TpuCompactionService:
             # key_lanes the key matrix's width (``key_words``: the words
             # in use); value_widths ``mixed`` where some place's live
             # values differ in width; value_bytes the places' real value
-            # bytes, value_bytes_padded their rows at the launch's width
+            # bytes; value_move ``stream`` (each place's values one word
+            # stream) or ``rows``; value_bytes_padded the bytes the
+            # launch moves for values: a stream's capacity a place, or
+            # the places' rows at the launch's width
             span.annotate(
                 val_words=val_words, value_path=path,
                 key_widths="uniform" if uniform_klen else "mixed",
@@ -249,20 +280,23 @@ class TpuCompactionService:
                 key_lanes=key_cols, key_words=key_words,
                 value_widths="mixed" if vmixed else "uniform",
                 value_bytes=sum(int(b.val_len.sum()) for b in batches),
-                value_bytes_padded=4 * val_words * sum(
-                    b.capacity for b in batches))
+                value_move="stream" if stream else "rows",
+                value_bytes_padded=4 * (
+                    per_place[0] * len(batches) if stream
+                    else val_words * sum(b.capacity for b in batches)))
         Stats.get().incr("compact.value_path." + path, len(batches))
         fn = self._pipeline(merge_kind, drop_tombstones, num_words,
-                            uniform_klen, seq32, key_words, val_words)
+                            uniform_klen, seq32, key_words, val_words,
+                            stream)
 
         def stage(lo: int) -> Dict[str, object]:
             """Stack one group on host and issue its async H2D. On the
             index path a shard's values go up as a buffer of their own,
             never stacked: the one its batch brought (``val_words_dev``:
             the caller's thread put it up already) where that is of this
-            group's capacity bucket, else the host values padded and put
-            here; an empty place takes the one zero buffer the device
-            already holds."""
+            group's capacity bucket, else the host values (rows or word
+            stream) padded and put here; an empty place takes the one
+            zero buffer the device already holds."""
             group = list(batches[lo:lo + group_size])
             pad_shards = group_size - len(group)
             stacked = {}
@@ -272,20 +306,20 @@ class TpuCompactionService:
                         ups, prestaged, restaged = [], 0, 0
                         for b in group:
                             dev = getattr(b, "val_words_dev", None)
-                            if dev is not None and dev.shape == (
-                                    capacity, val_words):
+                            if dev is not None and dev.shape == per_place:
                                 prestaged += 1
                             else:  # none came, or one of another bucket
                                 restaged += dev is not None
                                 dev = jax.device_put(_pad_to(
-                                    _pad_cols(b.val_words, val_words),
-                                    capacity))
+                                    b.val_stream, per_place[0]) if stream
+                                    else _pad_to(_pad_cols(
+                                        b.val_words, val_words), capacity))
                             ups.append(dev)
                         Stats.get().incr("seam.values.prestaged", prestaged)
                         Stats.get().incr("seam.values.restaged", restaged)
                         sp.annotate(prestaged=prestaged)
                         stacked[name] = tuple(ups) + (
-                            self._zeros(capacity, val_words),) * pad_shards
+                            self._zeros(per_place),) * pad_shards
                         continue
                     arr = np.stack([_pad_to(
                         _pad_cols(b.val_words, val_words)
@@ -320,10 +354,10 @@ class TpuCompactionService:
                 num_words, return_arrays))
         return results
 
-    def _zeros(self, capacity: int, val_words: int):
-        """The device's one all-zero ``(capacity, val_words)`` buffer:
-        what an empty place of an index-path group reads."""
-        key = (capacity, val_words)
+    def _zeros(self, key: tuple):
+        """The device's one all-zero buffer of this shape (``(capacity,
+        val_words)`` rows, or ``(words,)`` of a stream): what an empty
+        place of an index-path group reads."""
         if key not in self._zero_rows:
             self._zero_rows[key] = jax.device_put(
                 np.zeros(key, dtype=np.uint32))
@@ -332,8 +366,9 @@ class TpuCompactionService:
     def _drain(self, lo: int, out, batches, merge_kind, drop_tombstones,
                num_words, return_arrays=False) -> List[dict]:
         """Readback + unpack one group's device outputs. The index
-        path's values come back per shard (``out["val_words"]`` is a
-        tuple), the real shards' only, each as the padded block: here,
+        path's values come back per shard (``out["val_words"]``, or
+        ``out["val_stream"]``, is a tuple), the real shards' only, each as
+        the padded block or stream: here,
         or, for a shard whose batch brought its values up as a device
         buffer, wherever the caller reads the device buffer its result
         carries (the copy is started here, behind the small lanes)."""
@@ -341,9 +376,11 @@ class TpuCompactionService:
         with start_span("tpu.readback"):  # blocked on the device, and D2H
             host = {k: np.asarray(v) for k, v in out.items()
                     if not isinstance(v, tuple)}
-            if isinstance(out["val_words"], tuple):
-                host["val_words"] = vals = []
-                for b, a in zip(group, out["val_words"]):
+            for key, per_shard in out.items():
+                if not isinstance(per_shard, tuple):
+                    continue
+                host[key] = vals = []
+                for b, a in zip(group, per_shard):
                     if (return_arrays and
                             getattr(b, "val_words_dev", None) is not None):
                         a.copy_to_host_async()
@@ -455,16 +492,23 @@ _LANES = (
 def _shard_result(host: Dict[str, np.ndarray], s: int, count: int,
                   return_arrays: bool) -> dict:
     """One shard's result from stacked device outputs: lane views (no
-    per-entry work) or unpacked tuples."""
+    per-entry work) or unpacked tuples. A shard whose values came back
+    as a word stream (``compact_dbs_batched``'s, lane views) has
+    ``val_stream`` (whole) in ``val_words``' place."""
+    lanes = _LANES
+    if "val_stream" in host:
+        lanes = tuple(f for f in _LANES if f != "val_words") + (
+            "val_stream",)
     if return_arrays:
         def rows(f):
             a = host[f][s]
             # a value block left on the device stays whole: the thread
             # that reads it back slices it (``_write_arrays``)
-            return a[:count] if isinstance(a, np.ndarray) else a
+            return (a[:count] if isinstance(a, np.ndarray)
+                    and f != "val_stream" else a)
 
         return {
-            "arrays": {f: rows(f) for f in _LANES},
+            "arrays": {f: rows(f) for f in lanes},
             "bloom_words": host["bloom"][s],
             "count": count,
         }
@@ -595,15 +639,22 @@ class _LaneBatch:
     ``_next_pow2(rows)`` rows (a place of a cut shard: to the place
     capacity), where the thread that decoded the shard put them up
     itself (an index-path shard of the served door); such a shard's
-    resolved values come back as a device buffer too."""
+    resolved values come back as a device buffer too. ``val_stream``:
+    the place's values as one word stream (``ValueStream``'s buffer;
+    ``val_words`` is then None and ``val_words_dev`` the stream on the
+    device), ``val_cols`` the value bucket of its widest value, as its
+    rows would have had."""
 
-    __slots__ = _GROUP_LANES + ("val_words_dev",)
+    __slots__ = _GROUP_LANES + ("val_words_dev", "val_stream", "val_cols")
 
     def __init__(self, lanes: Dict[str, np.ndarray], val_words_dev=None):
         for f in _GROUP_LANES[:-1]:
-            setattr(self, f, lanes[f])
+            setattr(self, f, lanes.get(f))
         self.valid = np.ones(lanes["key_len"].shape[0], dtype=bool)
         self.val_words_dev = val_words_dev
+        self.val_stream = lanes.get("val_stream")
+        self.val_cols = (self.val_words.shape[1] if self.val_stream is None
+                         else value_bucket((int(self.val_len.max()) + 3) // 4))
 
     @property
     def capacity(self) -> int:
@@ -611,6 +662,11 @@ class _LaneBatch:
 
     def num_valid(self) -> int:
         return self.capacity
+
+
+def _val_cols(batch) -> int:
+    """A place's value width in words (a KVBatch's: its value rows')."""
+    return getattr(batch, "val_cols", 0) or batch.val_words.shape[1]
 
 
 def _write_arrays(db, res: dict, tctx: Optional[dict],
@@ -626,13 +682,18 @@ def _write_arrays(db, res: dict, tctx: Optional[dict],
     arrays, count = res["arrays"], int(res["count"])
     if count == 0:
         return {"entries": []}
-    vals = arrays["val_words"]
+    stream = "val_stream" in arrays
+    key = "val_stream" if stream else "val_words"
+    vals = arrays[key]
     if not isinstance(vals, np.ndarray):
-        # the launch left this shard's padded block on the device
-        # (``_drain``): down on THIS thread, eight shards at once
+        # the launch left this shard's padded block (or word stream) on
+        # the device (``_drain``): down on THIS thread, eight at once
         with start_span("tpu.readback.values", remote=tctx,
                         bytes=vals.nbytes):
-            arrays["val_words"] = np.asarray(vals)[:count]
+            vals = np.asarray(vals)
+        arrays[key] = vals if stream else vals[:count]
+    if stream:  # each row's words from the rows before it
+        arrays["val_off"] = stream_offsets(arrays["val_len"][:count])
     opts = db.options
     build_bloom = (
         _launch_bloom(res["bloom_words"], count, opts.bits_per_key)
@@ -646,7 +707,8 @@ def _write_arrays(db, res: dict, tctx: Optional[dict],
     # tuple fallback (non-uniform keys/values)
     return {"entries": unpack_entries(
         arrays["key_words_be"], arrays["key_len"], arrays["seq_hi"],
-        arrays["seq_lo"], arrays["vtype"], arrays["val_words"],
+        arrays["seq_lo"], arrays["vtype"],
+        with_value_rows(arrays, count)["val_words"],
         arrays["val_len"], count,
     )}
 
@@ -736,7 +798,17 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
     row's ``val_len`` rides; ``device_mixed_value_bytes_max``) launch at
     the power-of-two bucket of the group's widest (``value_bucket``), and
     ``value_widths`` / ``value_bytes`` / ``value_bytes_padded`` on
-    ``tpu.compact_stream`` say what that costs. Keys: 1 to 48 bytes, of
+    ``tpu.compact_stream`` say what that costs; an uncut shard whose runs
+    say its values differ in width (a run's own do, or two runs' widest
+    differ) moves them as ONE word
+    stream instead (``ValueStream``: decoded into it, put up, gathered by
+    the merge order inside the same module, read back and written from
+    it, ``STREAM_WORDS_PER_ROW`` words a row of its capacity bucket;
+    ``value_move`` = ``stream`` on ``tpu.compact_stream``; such shards
+    launch as groups of their own; ``Stats``
+    ``compact.value_move.stream`` / ``.rows`` count index-path shards by
+    move, ``.overrun`` the places whose values overran the stream and
+    kept their rows). Keys: 1 to 48 bytes, of
     one length or mixed in any proportion
     (``device_mixed_key_bytes_max``; over 24 bytes the key lanes are
     twelve words, ``key_lanes`` 12, and such shards launch as groups of
@@ -829,18 +901,46 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
             rows = _next_pow2(total) if total <= PLACE_ROWS_MAX else total
             return rows, value_bucket(vw)
 
+        def value_stream(runs):
+            # an uncut index-path place whose runs say its live values
+            # differ in width (a run's own do, or two runs' widest
+            # differ): its values are decoded into ONE word stream of
+            # ``STREAM_WORDS_PER_ROW`` words a row of its capacity bucket
+            # (values of one width keep their rows, which already are
+            # the values)
+            if kind is not MergeKind.NONE:
+                return None
+            said = [stream_may_carry(r) if hasattr(r, "iterate") else None
+                    for r in runs]
+            if None in said or not (any(m for _w, m in said) or len(
+                    {w for w, _m in said if w}) > 1):
+                return None
+            rows = sum(int(r.num_entries) for r in runs)
+            vw = max(2, (max(w for w, _m in said) + 3) // 4)
+            if rows > PLACE_ROWS_MAX or value_path(kind, vw) != "index":
+                return None
+            return ValueStream(STREAM_WORDS_PER_ROW * _next_pow2(rows))
+
         try:
             with start_span("tpu.lanes.decode", remote=tctx) as lsp:
                 # None: nothing to compact, a run the lanes can't
                 # express, or more rows than the door reads of one shard
-                read = read_runs_as_lanes(
-                    plan["runs"], None, max_entries=MAX_BATCHED_DB_ENTRIES,
+                read_runs = functools.partial(
+                    read_runs_as_lanes, plan["runs"], None,
+                    max_entries=MAX_BATCHED_DB_ENTRIES,
                     value_rows=value_rows)
+                try:
+                    read = read_runs(value_stream=value_stream)
+                except StreamOverrun:  # the values need more: rows
+                    Stats.get().incr("compact.value_move.overrun")
+                    read = read_runs()
                 if read is not None:
                     shape = key_shape(read[1]["key_len"])
                     vshape = value_shape(read[1]["vtype"],
                                          read[1]["val_len"])
-                    lsp.annotate(rows=read[2], **shape, **vshape)
+                    lsp.annotate(rows=read[2], **shape, **vshape,
+                                 value_move="stream" if "val_stream"
+                                 in read[1] else "rows")
         except BaseException:
             log.exception(
                 "lane read failed for %s; declining to per-db", name)
@@ -874,6 +974,13 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
         # widest value; a shard whose own bucket is narrower is padded
         # and put up again by the leader (``seam.values.restaged``)
         index = value_path(kind, vw) == "index"
+        # index-path shards by how their values move: one word stream a
+        # place, or rows (``compact.value_move.overrun``: the stream
+        # could not hold them)
+        stream = "val_stream" in lanes
+        if index:
+            stats.incr("compact.value_move." + ("stream" if stream
+                                                else "rows"))
         cut = total > PLACE_ROWS_MAX  # else the shard is its one place
         try:
             places = (_range_cut(name, parts, lanes, total, index, tctx)
@@ -882,7 +989,8 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
             for place in places if index else ():
                 # up from THIS thread, eight shards at once, and not one
                 # after another on the leader's (its ``tpu.h2d``)
-                padded = place["val_words"].base
+                padded = (place["val_stream"] if stream
+                          else place["val_words"].base)
                 with start_span("tpu.h2d.values", remote=tctx,
                                 bytes=padded.nbytes):
                     devs.append(
@@ -895,7 +1003,7 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
                 "per-db", name)
             _abort(db, plan)
             return ("remaining", name, db, None)
-        key = (kind, plan["drop_tombstones"], index, key_cols)
+        key = (kind, plan["drop_tombstones"], index, key_cols, stream)
         return ("grouped", name, db, (key, plan, batches))
 
     def _range_cut(name, parts, lanes, total, index, tctx):
@@ -963,7 +1071,7 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
                 groups.setdefault(key, []).append((name, db, plan, places))
 
         svc = TpuCompactionService.instance()
-        for (kind, drop, _index, _kc), items in groups.items():
+        for (kind, drop, _index, _kc, _stream), items in groups.items():
             # every shard's places side by side: 8 shards of 3 places
             # fill 3 launches
             batches = [b for _n, _d, _p, places in items for b in places]

@@ -18,12 +18,17 @@ MixGraph's 10 to 1,024 bytes) do the same with a value-length plane and
 a fifth member (``vmixed``). Keys longer than 24 bytes take key lanes of
 12 words (``key_word_cols``). Rows of one key length and one value
 width write the file they always wrote, byte for byte.
+
+Values may also travel as ONE word stream instead of zero-tailed rows
+(``ValueStream``: the served device door's move of values that differ
+in width): the source decodes into it, the sink encodes from it with
+each row's word offsets, and the file is the same.
 """
 
 from __future__ import annotations
 
 import zlib
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -56,6 +61,100 @@ _ENTRY_FIXED_OVERHEAD = ENTRY_FIXED_OVERHEAD
 _ROW_CODECS = (COMPRESSION_NONE, COMPRESSION_ZLIB, COMPRESSION_RLZ)
 _PLANAR_CODECS = (BLOCK_PLANAR, BLOCK_PLANAR_ZLIB, BLOCK_PLANAR_RLZ)
 _NOT_TAKEN = object()  # the native source leaves the file to Python
+
+
+class StreamOverrun(Exception):
+    """The values do not fit the ``ValueStream`` they are decoded into."""
+
+
+class ValueStream:
+    """A place's values as ONE word stream: row by row, each value from
+    a 4-byte boundary, ``ceil(val_len / 4)`` words, the bytes past its
+    length in its last word zero (a DELETE: no word). A row's word
+    offset follows from the ``val_len`` of the rows before it
+    (``stream_offsets``), so no offset lane exists. ``words``: the
+    buffer, ``capacity`` words (``np.empty``: ``finish`` zeroes what no
+    value took); ``pos``: the words taken."""
+
+    __slots__ = ("words", "pos")
+
+    def __init__(self, capacity: int):
+        self.words = np.empty(capacity, dtype=np.uint32)
+        self.pos = 0
+
+    def append_rows(self, val_words: np.ndarray,
+                    val_len: np.ndarray) -> None:
+        """Rows of values (zero-tailed ``(n, W)`` words) onto the stream;
+        ``StreamOverrun`` where they do not fit."""
+        nw = (np.asarray(val_len, dtype=np.int64) + 3) // 4
+        need = int(nw.sum())
+        if self.pos + need > len(self.words):
+            raise StreamOverrun(
+                f"{need} words at {self.pos} of {len(self.words)}")
+        taken = np.arange(val_words.shape[1]) < nw[:, None]
+        self.words[self.pos:self.pos + need] = val_words[taken]
+        self.pos += need
+
+    def finish(self) -> np.ndarray:
+        """The buffer, zero past the last value."""
+        self.words[self.pos:] = 0
+        return self.words
+
+
+def stream_offsets(val_len: np.ndarray) -> np.ndarray:
+    """(n + 1,) u64: the word offset of each row's value in its
+    ``ValueStream``, and the words in all."""
+    off = np.zeros(len(val_len) + 1, dtype=np.uint64)
+    np.cumsum((np.asarray(val_len, dtype=np.uint64) + np.uint64(3))
+              // np.uint64(4), out=off[1:])
+    return off
+
+
+def stream_rows(arrays: Dict[str, np.ndarray], count: int) -> np.ndarray:
+    """The first ``count`` rows' values of stream lanes (``val_stream``,
+    ``val_off``) as zero-tailed ``(count, W)`` rows, W the widest value's
+    words (2 at least): what a codec without a stream input takes."""
+    off = arrays["val_off"][:count + 1].astype(np.int64)
+    nw = np.diff(off)
+    rows = np.zeros((count, max(2, int(nw.max(initial=0)))), np.uint32)
+    taken = np.arange(rows.shape[1]) < nw[:, None]
+    rows[taken] = arrays["val_stream"][off[0]:off[-1]]
+    return rows
+
+
+def with_value_rows(arrays: Dict[str, np.ndarray],
+                    count: int) -> Dict[str, np.ndarray]:
+    """``arrays`` as they are where their values are rows, else (stream
+    lanes) with ``val_words`` rows in place of the stream."""
+    if "val_off" not in arrays:
+        return arrays
+    rows = {f: a for f, a in arrays.items()
+            if f not in ("val_stream", "val_off")}
+    rows["val_words"] = stream_rows(arrays, count)
+    return rows
+
+
+def stream_may_carry(reader) -> Optional[Tuple[int, bool]]:
+    """What a file's props say of its values before it is read:
+    ``(widest value bytes, whether its live values differ in width)``,
+    or None where they say nothing the native source would take (no
+    library, foreign props, a row-format file without the sink's or the
+    plain writer's width prop). A PLANAR file says both (its fifth
+    member); the plain writer's ``widths_max`` says the widest alone
+    (not that its values differ)."""
+    if get_file_codecs() is None or not reader.num_entries:
+        return None
+    props = reader.props
+    try:
+        if props.get("planar"):
+            p = props["planar"]
+            return int(p[1]), planar_props_vmixed(p)
+        widths = props.get("uniform") or props.get("widths_max")
+        if widths:
+            return int(widths[1]), False
+    except (TypeError, ValueError, IndexError, KeyError):
+        pass
+    return None
 
 
 def _count_codec(native: bool) -> None:
@@ -117,17 +216,24 @@ def encode_uniform_block(arrays: Dict[str, np.ndarray], start: int, end: int,
     return out.tobytes()
 
 
-def read_sst_arrays(reader) -> Optional[Dict[str, np.ndarray]]:
+def read_sst_arrays(reader, stream: Optional[ValueStream] = None
+                    ) -> Optional[Dict[str, np.ndarray]]:
     """Vectorized SOURCE: decode a sink-written uniform-stride TSST file
     straight into kernel lanes (no per-entry Python). Returns the arrays
     dict (+ implicit count = rows) or None when the file lacks the uniform
-    property (flush-written / foreign files use the tuple path)."""
+    property (flush-written / foreign files use the tuple path).
+    ``stream``: the values go onto it (the native source writes them
+    there; a Python source's rows are appended) and the lanes have no
+    ``val_words``; ``StreamOverrun`` where they do not fit, and a file
+    that gives None leaves the stream as it was."""
     planar = bool(reader.props.get("planar"))
-    lanes = _read_lanes_native(reader, planar)
+    lanes = _read_lanes_native(reader, planar, stream)
     _count_codec(lanes is not _NOT_TAKEN and lanes is not None)
     if lanes is _NOT_TAKEN:
         lanes = (_read_planar_arrays(reader) if planar
                  else _read_uniform_arrays(reader))
+        if lanes is not None and stream is not None:
+            stream.append_rows(lanes.pop("val_words"), lanes["val_len"])
     # ingestion-time global seqno overrides per-entry seqs, same as the
     # reader's _effective_seq
     if lanes is not None and reader.global_seqno is not None:
@@ -139,7 +245,8 @@ def read_sst_arrays(reader) -> Optional[Dict[str, np.ndarray]]:
     return lanes
 
 
-def _read_lanes_native(reader, planar: bool):
+def _read_lanes_native(reader, planar: bool,
+                       stream: Optional[ValueStream] = None):
     """The whole file through ONE native call (pread, inflate, transpose
     into the lanes, each block's checksum). ``_NOT_TAKEN`` when there is
     no library or the file is not one it reads (unknown block codec,
@@ -152,7 +259,7 @@ def _read_lanes_native(reader, planar: bool):
     ``widths_max`` (its values may then differ in width: one pass, each
     row its own ``val_len``). Raises Corruption for a block that does
     not inflate, does not fit its layout, or fails its ``block_chk``
-    value."""
+    value; ``StreamOverrun`` where the values do not fit ``stream``."""
     lib = get_file_codecs()
     if lib is None or not reader._index or not reader.num_entries:
         return _NOT_TAKEN
@@ -191,7 +298,7 @@ def _read_lanes_native(reader, planar: bool):
         chk_mode = 2 if planar else 1
     got, lanes, chks, done = lib.decode_file_lanes(
         reader._fd, index, planar, klen, vlen, int(reader.num_entries),
-        chk_mode, chk_len, key_cols, vmixed)
+        chk_mode, chk_len, key_cols, vmixed, stream)
     if got in (-1, -2):
         raise Corruption(
             f"{reader._path}: block {done - 1} "
@@ -209,6 +316,8 @@ def _read_lanes_native(reader, planar: bool):
                 f"{int(chks[i]):#010x} != {value:#010x}")
     if got == -4:
         return _NOT_TAKEN  # more rows than the footer says
+    if got == -6:
+        raise StreamOverrun(f"{reader._path}: {len(stream.words)} words")
     return lanes  # None on width drift (-3)
 
 
@@ -559,6 +668,7 @@ def _planar_blocks(
     from ..storage.planar import (PLANAR_HEADER, PLANAR_FLAG_SEQ32,
                                   pack_planar_header)
 
+    arrays = with_value_rows(arrays, count)
     payloads: List[bytes] = []
     codecs: List[int] = []
     chks: List[int] = []
@@ -646,7 +756,9 @@ def write_sst_from_arrays(
     ``planar=True`` writes PLANAR blocks (storage/planar.py): u32 planes
     in kernel lane order — smaller files and no byte interleaving on
     either side; keys of 1 to 48 bytes, of one length or mixed, values
-    of one width or mixed (``planar_widths``). ``device_words`` optionally carries the device planar
+    of one width or mixed (``planar_widths``), as rows or as a
+    ``ValueStream``'s words (``val_stream`` and the ``count + 1`` word
+    offsets ``val_off`` in ``val_words``' place: the same file). ``device_words`` optionally carries the device planar
     encoder's (nblocks, words) matrix for full blocks (the tail block is
     host-packed: its plane lengths differ from the fixed device shape)."""
     if planar:
@@ -656,6 +768,7 @@ def write_sst_from_arrays(
         return _write_planar(
             arrays, count, path, bloom_words, block_entries, compression,
             bits_per_key, *widths, device_words, device_checksums)
+    arrays = with_value_rows(arrays, count)
     widths = uniform_widths(arrays, count)
     if widths is None:
         return None

@@ -236,6 +236,61 @@ def test_mixgraph_index_program_compiles_for_the_chip(shape, monkeypatch):
     assert compiled.memory_analysis() is not None
 
 
+def test_mixgraph_stream_program_lowers_and_compiles_for_the_chip(
+        shape, monkeypatch):
+    """The MixGraph deployment's program where each place's values move
+    as ONE word stream (``STREAM_WORDS_PER_ROW`` words a row of the
+    capacity): lowered whole for a described v5e at the deployment's
+    own shape, group 8, capacity 32,768, twelve key words, under the
+    module name by which a device trace's reader finds it; its value
+    move compiled there at that shape (a word gather over each place's
+    524,288 words, no 256-word row anywhere); the whole program
+    compiled small. (Whole and at full size it compiles for minutes
+    here, as the row program does: the chip run's set-up compiles it.)"""
+    from rocksplicator_tpu.ops.compaction_kernel import gather_value_stream
+    from rocksplicator_tpu.tpu.compaction_service import (
+        PIPELINE_PROGRAM_INDEX, STREAM_WORDS_PER_ROW, TpuCompactionService)
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    group, i32 = 8, jnp.int32
+
+    def lanes(n):
+        keys = list(_kernel_lanes(shape, n, lead=(group,)))
+        keys[0] = shape((group, n, 12))
+        keys[5] = tuple(shape((STREAM_WORDS_PER_ROW * n,))
+                        for _ in range(group))
+        return keys
+
+    def program(n):
+        return TpuCompactionService()._pipeline(
+            MergeKind.NONE, True, num_words_for(n, BITS_PER_KEY), **MIXGRAPH,
+            val_words=256, stream=True)
+
+    n = 32768
+    words = STREAM_WORDS_PER_ROW * n
+    assert words == 524288
+    text = program(n).lower(*lanes(n)).as_text()
+    assert "one_shard_index" in PIPELINE_PROGRAM_INDEX
+    assert "@jit_" + PIPELINE_PROGRAM_INDEX in text[:200]
+    (main,) = [ln for ln in text.splitlines() if "func.func public @main" in ln]
+    # eight streams in and eight out; the key matrices as the row program's
+    assert main.count(f"tensor<{words}xui32>") == 2 * group
+    assert main.count(f"tensor<{group}x{n}x12xui32>") == 3
+    assert f"{n}x256xui32>" not in text
+    move = jax.jit(jax.vmap(gather_value_stream)).lower(
+        shape((group, words)), shape((group, n)), shape((group, n), i32),
+        shape((group, n)), shape((group,), i32)).compile()
+    assert " gather(" in move.as_text()
+    # the move holds nothing of the values' size but its output
+    mem = move.memory_analysis()
+    assert mem.output_size_in_bytes == group * words * 4
+    assert mem.temp_size_in_bytes < group * words * 4 * 3
+    n = 512
+    compiled = program(n).lower(*lanes(n)).compile()
+    assert " gather(" in compiled.as_text()
+    assert compiled.memory_analysis() is not None
+
+
 def test_bloom_build_compiles(shape):
     """The per-output-file bloom (one 16,384-key shard = one file)."""
     from rocksplicator_tpu.ops.bloom_tpu import bloom_build_tpu

@@ -46,7 +46,12 @@ the run's own and the last):
   bucket, so that the leader padded and put them again;
   ``tpu/compaction_service.py``) beside the window's per-shard
   ``tpu.h2d.values`` / ``tpu.readback.values`` spans and the leader's
-  ``tpu.h2d`` / ``tpu.readback``: how many and their mean.
+  ``tpu.h2d`` / ``tpu.readback``: how many and their mean; and how they
+  crossed: ``compact.value_move.stream`` / ``.rows`` (index-path shards
+  whose values moved as one word stream / as zero-tailed rows) and
+  ``.overrun`` (places whose values would not fit the stream and kept
+  their rows), beside the places the window's ``tpu.compact_stream``
+  spans launched by their ``value_move`` annotation.
   ``--trace 0`` too.
 - **which shards were cut by key range**: the process's
   ``compact.range_cut.shards`` / ``.places`` counters (shards of more
@@ -261,13 +266,19 @@ def main(argv=None) -> int:
                      "tpu.h2d", "tpu.readback"):
             ms = span_ms(run, name)
             seam[name] = [len(ms), round(sum(ms) / len(ms), 2) if ms else None]
-        harness.say("values across the seam: " + json.dumps(dict(
-            {"window_spans_count_mean_ms": seam},
-            **{"process_" + k: Stats.get().get_counter("seam.values." + k)
-               for k in ("prestaged", "restaged")})))
-        ms = span_ms(run, "tpu.range_cut")
         streams = [s["annotations"] for s in run.spans
                    if s["name"] == "tpu.compact_stream"]
+        harness.say("values across the seam: " + json.dumps(dict(
+            {"window_spans_count_mean_ms": seam},
+            **{"window_launched_move_" + w: sum(
+                int(a["shards"]) for a in streams
+                if a.get("value_move") == w) for w in ("stream", "rows")},
+            **{"process_" + k: Stats.get().get_counter("seam.values." + k)
+               for k in ("prestaged", "restaged")},
+            **{"process_value_move_" + k: Stats.get().get_counter(
+                "compact.value_move." + k)
+               for k in ("stream", "rows", "overrun")})))
+        ms = span_ms(run, "tpu.range_cut")
         harness.say("shards cut by key range: " + json.dumps(dict(
             {"window_spans": len(ms),
              "window_mean_ms": round(sum(ms) / len(ms), 2) if ms else None,
