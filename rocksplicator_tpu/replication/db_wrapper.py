@@ -168,12 +168,7 @@ class StorageDbWrapper(DbWrapper):
 
     def scan(self, start: Optional[bytes], limit: int
              ) -> List[Tuple[bytes, bytes]]:
-        out: List[Tuple[bytes, bytes]] = []
-        for k, v in self.db.new_iterator(start=start):
-            out.append((k, v))
-            if len(out) >= limit:
-                break
-        return out
+        return self.db.scan(start, limit)
 
     def gauge_target(self) -> Optional[DB]:
         return self.db

@@ -2,7 +2,8 @@
 
 API parity targets (what the upper layers use of rocksdb::DB — SURVEY.md):
 - ``write(batch)`` / ``get`` / ``multi_get`` / ``new_iterator``
-  (application_db.cpp delegates these)
+  (application_db.cpp delegates these); ``scan(start, limit)``, the
+  bounded, seeking range read behind the data plane's ``scan``
 - ``latest_sequence_number`` / ``get_updates_since`` (db_wrapper.h seam)
 - ``checkpoint`` (admin_handler.cpp:996-1129 checkpoint backup)
 - ``ingest_external_file`` with ``allow_global_seqno`` / ``ingest_behind``
@@ -35,16 +36,16 @@ from ..utils.misc import write_file_atomic
 from ..utils.stats import Stats
 from . import wal as wal_mod
 from .compaction import (CompactionBackend, CpuCompactionBackend,
-                         record_host_fallback, resolve_stream)
+                         record_host_fallback)
 from .errors import Corruption, InvalidArgument, StorageError
 from .memtable import MemTable
 from .merge import MERGE_OPERATORS, MergeOperator
 from .records import BatchColumns, OpType, WriteBatch, decode_batch
+from .scan import ScanCounts, ScanView
 from .sst import COMPRESSION_NONE, COMPRESSION_ZLIB, SSTReader, SSTWriter
 
 import bisect
 import heapq
-import itertools
 import logging
 
 log = logging.getLogger(__name__)
@@ -912,48 +913,70 @@ class DB:
     def new_iterator(
         self, start: Optional[bytes] = None, end: Optional[bytes] = None
     ) -> Iterator[Tuple[bytes, bytes]]:
-        """Live (key, value) pairs in key order over a point-in-time view.
+        """Live (key, value) pairs in [start, end), in key order, over a
+        point-in-time view, materialized: ``scan`` without a limit, its
+        blocks kept out of the block cache as a compaction's are."""
+        view = self._scan_view(start, end)
+        try:
+            out = view.take(start, end, None, False, ScanCounts())
+        finally:
+            view.release()
+        return iter(out)
 
-        The view is materialized under the DB lock so concurrent flush/
-        compaction file GC cannot invalidate it (the native engine will use
-        refcounted file snapshots instead)."""
-        out: List[Tuple[bytes, bytes]] = []
+    def scan(self, start: Optional[bytes], limit: int
+             ) -> List[Tuple[bytes, bytes]]:
+        """The first ``limit`` live (key, value) pairs at or after
+        ``start``, in key order, over a point-in-time view
+        (``storage/scan.py``): the DB lock is held only to take the view,
+        each source is sought to ``start``, and nothing past the
+        ``limit``-th live key is read. The same answers as
+        ``new_iterator``'s first ``limit``. Traced as ``storage.scan``
+        (rows returned, blocks decoded, files sought, entries taken from
+        memtables) and counted in ``scan.calls`` / ``.rows`` /
+        ``.blocks``."""
+        with start_span("storage.scan", always=True, limit=limit) as sp:
+            counts = ScanCounts()
+            view = self._scan_view(start, None)
+            try:
+                out = view.take(start, None, limit, True, counts)
+            finally:
+                view.release()
+            stats = Stats.get()
+            stats.incr("scan.calls")
+            stats.incr("scan.rows", len(out))
+            stats.incr("scan.blocks", counts.blocks)
+            if sp.sampled:
+                sp.annotate(rows=len(out), blocks=counts.blocks,
+                            files=counts.files, mem_rows=counts.mem_rows)
+        return out
+
+    def _scan_view(self, start: Optional[bytes],
+                   end: Optional[bytes]) -> ScanView:
+        """The view a scan of [start, end) reads, taken under the DB
+        lock: the sequence number, every memtable with its sorted keys,
+        the L0 files whose key range meets [start, end), and of each
+        deeper level the files from the first whose last key is at or
+        after ``start``; every file pinned."""
         with self._lock:
             self._check_open()
-            runs: List[Iterator] = []
-            mems = [self._mem, *self._imms]
-            for mem in mems:
-                runs.append(iter(list(mem.entries())))
+            mems = [(m, m.sorted_keys()) for m in (self._mem, *self._imms)]
+            l0 = []
             for name in self._levels[0]:
-                runs.append(self._readers[name].iterate())
-            for level_files in self._levels[1:]:
-                for name in level_files:
-                    runs.append(self._readers[name].iterate())
-            merge_op = self.options.merge_operator
-            merged = heapq.merge(*runs, key=lambda e: (e[0], -e[1]))
-            resolved = resolve_stream(merged, merge_op, False)
-            # resolve_stream emits one entry per key except for unresolved
-            # MERGE chains (no partial-merge operator), which must be folded
-            # here as a group — newest first in the stream.
-            for key, group in itertools.groupby(resolved, key=lambda e: e[0]):
-                entries = list(group)
-                if start is not None and key < start:
-                    continue
-                if end is not None and key >= end:
-                    break
-                vtype = entries[0][2]
-                if vtype == OpType.DELETE:
-                    continue
-                if vtype == OpType.MERGE:
-                    operands = [e[3] for e in reversed(entries)]  # oldest first
-                    value = (
-                        merge_op.merge(key, None, operands)
-                        if merge_op else entries[0][3]
-                    )
-                else:
-                    value = entries[0][3]
-                out.append((key, value))
-        return iter(out)
+                reader = self._readers[name]
+                lo, hi = reader.min_key(), reader.max_key()
+                if lo is None or (start is not None and hi < start) or (
+                        end is not None and lo >= end):
+                    continue  # empty, or wholly outside [start, end)
+                l0.append((reader, lo))
+            levels = []
+            for level in range(1, len(self._levels)):
+                mins, maxs, names = self._level_fences_locked(level)
+                first = 0 if start is None else bisect.bisect_left(
+                    maxs, start)
+                levels.append([(self._readers[names[i]], mins[i])
+                               for i in range(first, len(names))])
+            return ScanView(self._last_seq, mems, l0, levels,
+                            self.options.merge_operator)
 
     # ------------------------------------------------------------------
     # sequence numbers / replication shipping (db_wrapper.h seam)
@@ -2365,6 +2388,16 @@ class DB:
     def __exit__(self, *exc) -> bool:
         self.close()
         return False
+
+
+def scan_seeks() -> bool:
+    """Whether ``DB.scan`` seeks each source to its start and reads
+    nothing past its limit (``storage/scan.py``): asked by a
+    deployment's driver before it builds anything
+    (chipbench/drivers/refresh_scan.py). A program without it has no
+    ``DB.scan``, and serves a scan from the whole version, materialized
+    under the DB lock."""
+    return True
 
 
 def destroy_db(path: str) -> None:

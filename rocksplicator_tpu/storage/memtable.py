@@ -7,8 +7,9 @@ entries in (key asc, seq desc) order — the SST writer's required order.
 
 from __future__ import annotations
 
+import bisect
 from array import array
-from itertools import repeat
+from itertools import islice, repeat
 from typing import Dict, Iterator, List, Optional, Tuple
 
 from .merge import MergeOperator
@@ -43,6 +44,7 @@ class MemTable:
         self._seqs = array("Q")
         self._vtypes = bytearray()
         self._bytes = 0
+        self._sorted: List[bytes] = []  # sorted_keys(), as last asked
         self.min_seq: Optional[int] = None
         self.max_seq = 0
 
@@ -163,6 +165,40 @@ class MemTable:
         for key in sorted(self._newest):
             for seq, vtype, value in self._stack(key):
                 yield key, seq, vtype, value
+
+    def sorted_keys(self) -> List[bytes]:
+        """Every key in order: the index a scan seeks in. Kept between
+        calls, and only the keys that came since are sorted in: a key is
+        never removed and the dict keeps insertion order, so they are its
+        newest, and one merge of two sorted runs joins them to the kept
+        list (a new list: a scan may hold the old one). The caller holds
+        the DB lock: a writer adding a key would break the walk."""
+        keys = self._sorted
+        if len(keys) != len(self._newest):
+            fresh = sorted(islice(self._newest, len(keys), None))
+            keys = self._sorted = sorted(keys + fresh) if keys else fresh
+        return keys
+
+    def seek(self, keys: List[bytes], start: Optional[bytes],
+             end: Optional[bytes], max_seq: int
+             ) -> Iterator[Tuple[bytes, int, int, bytes]]:
+        """(key, seq, vtype, value) in (key asc, seq desc) order for the
+        keys of ``keys`` (``sorted_keys()`` as a scan's view took it) in
+        [start, end), leaving out every entry above ``max_seq``. Lazy,
+        and safe without the DB lock: what a writer adds meanwhile is
+        newer than ``max_seq``, a row is whole before ``_newest`` names
+        it, and rows are only ever appended."""
+        i = 0 if start is None else bisect.bisect_left(keys, start)
+        for j in range(i, len(keys)):
+            key = keys[j]
+            if end is not None and key >= end:
+                return
+            row = self._newest[key]
+            while row >= 0:
+                seq = self._seqs[row]
+                if seq <= max_seq:
+                    yield key, seq, self._vtypes[row], self._vals[row]
+                row = self._prev[row]
 
     def drain_lanes(self):
         """All entries as UNSORTED fixed-width lane arrays — the

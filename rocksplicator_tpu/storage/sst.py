@@ -402,10 +402,18 @@ class SSTWriter:
 
 class SSTReader:
     """Thread-safe reader: block reads use positioned pread so concurrent
-    gets/iterators never race on a shared file offset."""
+    gets/iterators never race on a shared file offset.
+
+    A reader that a scan holds outside the DB lock is PINNED (``pin`` /
+    ``unpin``): a ``close`` meanwhile (a flush's or a compaction's file
+    GC, the DB's own close) marks it and leaves the descriptor open, and
+    the last ``unpin`` closes it."""
 
     def __init__(self, path: str):
         self._path = path
+        self._pins = 0
+        self._close_pending = False
+        self._pin_lock = threading.Lock()
         self._fd = os.open(path, os.O_RDONLY)
         file_size = os.fstat(self._fd).st_size
         if file_size < _FOOTER.size:
@@ -744,16 +752,24 @@ class SSTReader:
         return out
 
     def iterate(
-        self, start: Optional[bytes] = None, end: Optional[bytes] = None
+        self, start: Optional[bytes] = None, end: Optional[bytes] = None,
+        fill_cache: bool = False, counts=None,
     ) -> Iterator[Tuple[bytes, int, int, bytes]]:
-        """All entries (key, seq, vtype, value) in order, [start, end)."""
-        for i, (last_key, _off, _size, _codec) in enumerate(self._index):
-            if start is not None and last_key < start:
-                continue
-            # bulk scan: probe the cache but don't fill it (a compaction
-            # or full iteration would evict the point-read working set)
+        """All entries (key, seq, vtype, value) in order, [start, end),
+        from the first block whose last key is at or after ``start`` (a
+        bisect of the block index: the blocks before it hold only keys
+        below ``start`` and are never read). A bulk scan (compaction, a
+        full iteration) probes the block cache but does not fill it, so
+        it cannot evict the point reads' working set; a bounded scan
+        passes ``fill_cache`` and caches as a get does. ``counts``, where
+        given, has its ``blocks`` raised by each block decoded."""
+        first = 0 if start is None else bisect.bisect_left(
+            self._last_keys, start)
+        for i in range(first, len(self._index)):
+            if counts is not None:
+                counts.blocks += 1
             for key, seq, vtype, value in self._block_iter(
-                    i, self._read_block(i, fill_cache=False)):
+                    i, self._read_block(i, fill_cache=fill_cache)):
                 if start is not None and key < start:
                     continue
                 if end is not None and key >= end:
@@ -773,7 +789,27 @@ class SSTReader:
             return self.global_seqno
         return self.props.get("max_seq", 0)
 
+    def pin(self) -> None:
+        """Hold the file open for a reader outside the DB lock; the DB
+        pins under its lock, where GC cannot have closed it yet."""
+        with self._pin_lock:
+            self._pins += 1
+
+    def unpin(self) -> None:
+        with self._pin_lock:
+            self._pins -= 1
+            closing = self._pins == 0 and self._close_pending
+        if closing:
+            self._close_fd()
+
     def close(self) -> None:
+        with self._pin_lock:
+            if self._pins:
+                self._close_pending = True
+                return
+        self._close_fd()
+
+    def _close_fd(self) -> None:
         if self._fd >= 0:
             os.close(self._fd)
             self._fd = -1

@@ -29,7 +29,7 @@ RANKS = {
     "rocksplicator_tpu/storage/stream_merge.py:131": ('CompactionMemoryBudget._lock', 9),
     "rocksplicator_tpu/utils/rate_limiter.py:25": ('ConcurrentRateLimiter._lock', 10),
     "rocksplicator_tpu/cluster/coordinator.py:303": ('CoordinatorServer._snapshot_mutex', 11),
-    "rocksplicator_tpu/storage/engine.py:268": ('DB._compaction_mutex', 12),
+    "rocksplicator_tpu/storage/engine.py:269": ('DB._compaction_mutex', 12),
     "rocksplicator_tpu/utils/dbconfig.py:48": ('DBConfigManager._instance_lock', 13),
     "rocksplicator_tpu/cluster/publishers.py:69": ('DedupPublisher._lock', 14),
     "rocksplicator_tpu/utils/concurrent_map.py:22": ('FastReadMap._write_lock', 15),
@@ -61,32 +61,33 @@ RANKS = {
     "rocksplicator_tpu/replication/replicator.py:46": ('Replicator._instance_lock', 41),
     "rocksplicator_tpu/utils/retry_policy.py:77": ('RetryBudget._lock', 42),
     "rocksplicator_tpu/utils/s3_stub.py:48": ('S3StubServer.lock', 43),
-    "rocksplicator_tpu/observability/collector.py:54": ('SpanCollector._instance_lock', 44),
-    "rocksplicator_tpu/utils/ssl_context_manager.py:57": ('SslContextManager._lock', 45),
-    "rocksplicator_tpu/utils/stats.py:249": ('Stats._buffers_lock', 46),
-    "rocksplicator_tpu/utils/stats.py:258": ('Stats._dump_lock', 47),
-    "rocksplicator_tpu/utils/stats.py:229": ('Stats._instance_lock', 48),
-    "rocksplicator_tpu/utils/status_server.py:31": ('StatusServer._instance_lock', 49),
-    "rocksplicator_tpu/rpc/admission.py:115": ('TenantAdmission._instance_lock', 50),
-    "rocksplicator_tpu/rpc/admission.py:125": ('TenantAdmission._lock', 51),
-    "rocksplicator_tpu/rpc/admission.py:67": ('TokenBucket._lock', 52),
-    "rocksplicator_tpu/tpu/compaction_service.py:82": ('TpuCompactionService._instance_lock', 53),
-    "rocksplicator_tpu/storage/archive.py:63": ('WalArchiver._mutex', 54),
-    "rocksplicator_tpu/testing/failpoints.py:129": ('_Site.lock', 55),
-    "rocksplicator_tpu/utils/stats.py:200": ('_ThreadBuffer.lock', 56),
-    "rocksplicator_tpu/kafka/broker.py:204": ('kafka.broker:_clusters_lock', 57),
-    "rocksplicator_tpu/storage/native/binding.py:728": ('storage.native.binding:_native_lock', 58),
-    "rocksplicator_tpu/testing/failpoints.py:161": ('testing.failpoints:_lock', 59),
-    "rocksplicator_tpu/utils/objectstore.py:379": ('utils.objectstore:_store_cache_lock', 60),
-    "rocksplicator_tpu/admin/db_manager.py:20": ('ApplicationDBManager._lock', 61),
-    "rocksplicator_tpu/cluster/coordinator.py:296": ('CoordinatorServer._lock', 62),
-    "rocksplicator_tpu/storage/engine.py:239": ('DB._lock', 63),
-    "rocksplicator_tpu/storage/engine.py:275": ('DB._manifest_mutex', 64),
-    "rocksplicator_tpu/utils/file_watcher.py:40": ('FileWatcher._instance_lock', 65),
-    "rocksplicator_tpu/cluster/participant.py:75": ('Participant._state_lock', 66),
-    "rocksplicator_tpu/utils/stats.py:236": ('Stats._lock', 67),
-    "rocksplicator_tpu/storage/compaction_scheduler.py:123": ('IoBudget._lock', 68),
-    "rocksplicator_tpu/storage/wal.py:69": ('WalWriter._sync_lock', 69),
+    "rocksplicator_tpu/storage/sst.py:416": ('SSTReader._pin_lock', 44),
+    "rocksplicator_tpu/observability/collector.py:54": ('SpanCollector._instance_lock', 45),
+    "rocksplicator_tpu/utils/ssl_context_manager.py:57": ('SslContextManager._lock', 46),
+    "rocksplicator_tpu/utils/stats.py:249": ('Stats._buffers_lock', 47),
+    "rocksplicator_tpu/utils/stats.py:258": ('Stats._dump_lock', 48),
+    "rocksplicator_tpu/utils/stats.py:229": ('Stats._instance_lock', 49),
+    "rocksplicator_tpu/utils/status_server.py:31": ('StatusServer._instance_lock', 50),
+    "rocksplicator_tpu/rpc/admission.py:115": ('TenantAdmission._instance_lock', 51),
+    "rocksplicator_tpu/rpc/admission.py:125": ('TenantAdmission._lock', 52),
+    "rocksplicator_tpu/rpc/admission.py:67": ('TokenBucket._lock', 53),
+    "rocksplicator_tpu/tpu/compaction_service.py:82": ('TpuCompactionService._instance_lock', 54),
+    "rocksplicator_tpu/storage/archive.py:63": ('WalArchiver._mutex', 55),
+    "rocksplicator_tpu/testing/failpoints.py:129": ('_Site.lock', 56),
+    "rocksplicator_tpu/utils/stats.py:200": ('_ThreadBuffer.lock', 57),
+    "rocksplicator_tpu/kafka/broker.py:204": ('kafka.broker:_clusters_lock', 58),
+    "rocksplicator_tpu/storage/native/binding.py:728": ('storage.native.binding:_native_lock', 59),
+    "rocksplicator_tpu/testing/failpoints.py:161": ('testing.failpoints:_lock', 60),
+    "rocksplicator_tpu/utils/objectstore.py:379": ('utils.objectstore:_store_cache_lock', 61),
+    "rocksplicator_tpu/admin/db_manager.py:20": ('ApplicationDBManager._lock', 62),
+    "rocksplicator_tpu/cluster/coordinator.py:296": ('CoordinatorServer._lock', 63),
+    "rocksplicator_tpu/storage/engine.py:240": ('DB._lock', 64),
+    "rocksplicator_tpu/storage/engine.py:276": ('DB._manifest_mutex', 65),
+    "rocksplicator_tpu/utils/file_watcher.py:40": ('FileWatcher._instance_lock', 66),
+    "rocksplicator_tpu/cluster/participant.py:75": ('Participant._state_lock', 67),
+    "rocksplicator_tpu/utils/stats.py:236": ('Stats._lock', 68),
+    "rocksplicator_tpu/storage/compaction_scheduler.py:123": ('IoBudget._lock', 69),
+    "rocksplicator_tpu/storage/wal.py:69": ('WalWriter._sync_lock', 70),
 }
 
 # static partial order: (acquired-first, acquired-second)
@@ -94,12 +95,12 @@ ORDER = {
     ("rocksplicator_tpu/admin/handler.py:182", "rocksplicator_tpu/admin/db_manager.py:20"),
     ("rocksplicator_tpu/cluster/coordinator.py:303", "rocksplicator_tpu/cluster/coordinator.py:296"),
     ("rocksplicator_tpu/cluster/participant.py:76", "rocksplicator_tpu/cluster/participant.py:75"),
-    ("rocksplicator_tpu/storage/engine.py:239", "rocksplicator_tpu/storage/compaction_scheduler.py:123"),
-    ("rocksplicator_tpu/storage/engine.py:239", "rocksplicator_tpu/storage/wal.py:69"),
-    ("rocksplicator_tpu/storage/engine.py:268", "rocksplicator_tpu/storage/compaction_scheduler.py:123"),
-    ("rocksplicator_tpu/storage/engine.py:268", "rocksplicator_tpu/storage/engine.py:239"),
-    ("rocksplicator_tpu/storage/engine.py:268", "rocksplicator_tpu/storage/engine.py:275"),
-    ("rocksplicator_tpu/storage/engine.py:268", "rocksplicator_tpu/storage/wal.py:69"),
+    ("rocksplicator_tpu/storage/engine.py:240", "rocksplicator_tpu/storage/compaction_scheduler.py:123"),
+    ("rocksplicator_tpu/storage/engine.py:240", "rocksplicator_tpu/storage/wal.py:69"),
+    ("rocksplicator_tpu/storage/engine.py:269", "rocksplicator_tpu/storage/compaction_scheduler.py:123"),
+    ("rocksplicator_tpu/storage/engine.py:269", "rocksplicator_tpu/storage/engine.py:240"),
+    ("rocksplicator_tpu/storage/engine.py:269", "rocksplicator_tpu/storage/engine.py:276"),
+    ("rocksplicator_tpu/storage/engine.py:269", "rocksplicator_tpu/storage/wal.py:69"),
     ("rocksplicator_tpu/utils/dbconfig.py:48", "rocksplicator_tpu/utils/file_watcher.py:40"),
     ("rocksplicator_tpu/utils/stats.py:258", "rocksplicator_tpu/utils/stats.py:236"),
 }

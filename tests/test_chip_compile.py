@@ -42,6 +42,10 @@ NAMES = dict(uniform_klen=False, seq32=True, key_words=4)
 # twelve key lanes, all in use; values of differing widths in the index
 # path's bucket of 256 words
 MIXGRAPH = dict(uniform_klen=True, seq32=True, key_words=12)
+# ycsb_e_32x20k's: YCSB's hashed keys "user<n>" of 5 to 24 bytes in one
+# shard, so the key-length lane over all six key words, and 1,140-byte
+# records in the index path's bucket of 512 words
+YCSB_E = dict(uniform_klen=False, seq32=True, key_words=6)
 BITS_PER_KEY = 10  # examples/counter_service/options.py
 
 
@@ -289,6 +293,37 @@ def test_mixgraph_stream_program_lowers_and_compiles_for_the_chip(
     compiled = program(n).lower(*lanes(n)).compile()
     assert " gather(" in compiled.as_text()
     assert compiled.memory_analysis() is not None
+
+
+def test_ycsb_e_index_program_compiles_for_the_chip(shape, monkeypatch):
+    """The YCSB E deployment's program at its own shape, group 8,
+    capacity 32,768: the index pipeline with the key-length lane over
+    six key words, each shard's rows of 512 value words gathered from a
+    buffer of its own, lowered under the module name by which a device
+    trace's reader finds it and compiled for a described v5e. The
+    pinned programs above do not change (none has both the lane and
+    512-word rows)."""
+    from rocksplicator_tpu.tpu.compaction_service import (
+        PIPELINE_PROGRAM_INDEX, TpuCompactionService, value_bucket)
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    n, group = 32768, 8
+    words = value_bucket(-(-1140 // 4))
+    assert words == 512
+    fn = TpuCompactionService()._pipeline(
+        MergeKind.NONE, True, num_words_for(n, BITS_PER_KEY), **YCSB_E,
+        val_words=words)
+    lanes = list(_kernel_lanes(shape, n, lead=(group,)))
+    lanes[5] = tuple(shape((n, words)) for _ in range(group))
+    lowered = fn.lower(*lanes)
+    assert "one_shard_index" in PIPELINE_PROGRAM_INDEX
+    assert "@jit_" + PIPELINE_PROGRAM_INDEX in lowered.as_text()[:200]
+    compiled = lowered.compile()
+    assert f"u32[{n},{words}]" in compiled.as_text()
+    values = group * n * words * 4
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < values // 4
+    assert mem.output_size_in_bytes < values + values // 4
 
 
 def test_bloom_build_compiles(shape):

@@ -105,6 +105,14 @@ the run's own and the last):
   (``value_bytes_max``). 0 / ``uniform`` in the four cells of keys of at
   most 24 bytes and one value width; every shard in
   ``mixgraph_64x20k.refresh``. ``--trace 0`` too.
+- **what the engine read for a scan**: the process's ``scan.calls`` /
+  ``.rows`` / ``.blocks`` counters (``DB.scan``: the data plane's
+  ``scan``, ``storage/engine.py``) beside the window's ``storage.scan``
+  spans (how many, their mean, blocks and files a scan by their
+  annotations), printed after the served RPCs' phases, where the read
+  root's ``exec`` holds them. 0 and none in the five cells whose readers
+  only get; in ``ycsb_e_32x20k.refresh`` one span a served scan and 1
+  to 3 blocks a file. ``--trace 0`` too.
 
 Arguments are ``chipbench/run.py``'s own.
 """
@@ -336,6 +344,17 @@ def main(argv=None) -> int:
                          "flush.value_widths.mixed")})))
         harness.say("inside a served RPC, by method: " + json.dumps(
             phase_table(run.spans)))
+        scans = [s for s in run.spans if s["name"] == "storage.scan"]
+        per = {k: [s["annotations"][k] for s in scans
+                   if k in s["annotations"]] for k in ("blocks", "files")}
+        harness.say("scans in the engine: " + json.dumps(dict(
+            {"window_spans": len(scans),
+             "window_mean_ms": round(sum(s["duration_ms"] for s in scans)
+                                     / len(scans), 3) if scans else None},
+            **{f"window_{k}_per_scan": round(sum(v) / len(v), 3)
+               if v else None for k, v in per.items()},
+            **{"process_" + k: Stats.get().get_counter(k)
+               for k in ("scan.calls", "scan.rows", "scan.blocks")})))
         return real_read_metrics(bench, group, package, cell, run)
 
     tr.reduce, harness.reduce_trace = reduce, reduce_trace
