@@ -6,7 +6,9 @@ the binding layer. Arrays cross the boundary as numpy buffers.
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import fcntl
 import logging
 import os
 import subprocess
@@ -43,6 +45,26 @@ def _so_current() -> bool:
         return so >= os.path.getmtime(_SRC) and so >= os.path.getmtime(_MAKEFILE)
     except OSError:
         return False
+
+
+@contextlib.contextmanager
+def _build_lock():
+    """Held across processes while one checks, builds and loads the
+    library: a process that found the .so current while another was
+    still writing it would load half a library (and take the Python
+    codecs for the rest of its life). Where the lock file cannot be
+    made (a read-only tree), the check and the load go on unlocked:
+    nothing can be built there anyway."""
+    try:
+        fd = os.open(_SO + ".lock", os.O_RDWR | os.O_CREAT, 0o644)
+    except OSError:
+        yield
+        return
+    try:
+        fcntl.flock(fd, fcntl.LOCK_EX)
+        yield
+    finally:
+        os.close(fd)
 
 
 def _build() -> bool:
@@ -435,20 +457,24 @@ class NativeLib:
         differ in length (``klen`` their widest): each block's header
         and key-length plane follow from its own rows. ``vmixed``: the
         same for the values' widths (``vlen`` their widest) and the
-        value-length plane. Lanes with ``val_off`` (``count + 1`` word
-        offsets) carry their values as ONE word stream, ``val_stream``
-        (``tpu/format.py`` ``ValueStream``): the same blocks."""
+        value-length plane. Lanes with ``val_off`` (each row's word
+        offset) carry their values in ONE word stream, ``val_stream``:
+        row r's value is ``ceil(val_len / 4)`` words from ``val_off[r]``
+        on (a DELETE has none; ``tpu/format.py`` ``stream_rows``): the
+        same blocks."""
         kw, vw = (klen + 3) // 4, (vlen + 3) // 4
         kw_be = np.ascontiguousarray(
             arrays["key_words_be"][:count], dtype=np.uint32)
         val_off = arrays.get("val_off")
         if val_off is not None:
-            val_off = np.ascontiguousarray(val_off[:count + 1],
-                                           dtype=np.uint64)
+            val_off = np.ascontiguousarray(val_off[:count], dtype=np.uint64)
             val_words = np.ascontiguousarray(arrays["val_stream"],
                                              dtype=np.uint32)
-            if len(val_off) != count + 1 or int(val_off[-1]) > len(
-                    val_words):
+            nw = (arrays["val_len"][:count].astype(np.int64) + 3) // 4
+            live = arrays["vtype"][:count] != 2
+            if len(val_off) != count or (live.any() and int(
+                    (val_off[live].astype(np.int64) + nw[live]).max())
+                    > len(val_words)):
                 return None
             val_cols = 0
         else:
@@ -703,16 +729,18 @@ def _load() -> Optional[NativeLib]:
     # Never load a .so older than its source: it is either a stale build
     # or a binary of unknown provenance. Rebuild from tsst_native.cc; on
     # build failure fall back to the pure-Python paths, loudly.
-    if not _so_current() and not _build():
-        _no_native("build failed" + (
-            f"; refusing stale/unverified {_SO}"
-            if os.path.isfile(_SO) else ""))
-        return None
-    try:
-        return NativeLib(_SO)
-    except (OSError, AttributeError) as e:
-        _no_native(f"load failed: {e}")
-        return None
+    with _build_lock():
+        if not _so_current() and not _build():
+            why = "build failed" + (
+                f"; refusing stale/unverified {_SO}"
+                if os.path.isfile(_SO) else "")
+        else:
+            try:
+                return NativeLib(_SO)
+            except (OSError, AttributeError) as e:
+                why = f"load failed: {e}"
+    _no_native(why)
+    return None
 
 
 def _no_native(why: str) -> None:
@@ -760,11 +788,12 @@ def rebuild_native() -> NativeLib:
         if _native is not _UNSET:
             raise RuntimeError("native library already resolved in "
                                "this process; rebuild first")
-        if os.path.isfile(_SO):
-            os.remove(_SO)
-        if not _build():
-            raise RuntimeError("native build failed (see the log)")
-        _native = NativeLib(_SO)
+        with _build_lock():
+            if os.path.isfile(_SO):
+                os.remove(_SO)
+            if not _build():
+                raise RuntimeError("native build failed (see the log)")
+            _native = NativeLib(_SO)
         return _native
 
 

@@ -990,9 +990,12 @@ struct BlockFetcher {
 // its value planes at the block's own widest value; one of one width is
 // the old block at that width (an all-DELETE block: at `vlen`). Keys are
 // up to 48 bytes (`kw_cols` words of lanes a row). `val_off` (null: the
-// values are rows of `val_cols` words) says the values are ONE word
-// stream: row r's value is the words [val_off[r], val_off[r + 1]) of
-// `val_words`, each from a 4-byte boundary, and its planes read zeros
+// values are rows of `val_cols` words) says the values lie in ONE word
+// stream: row r's value is ceil(len / 4) words of `val_words` from
+// val_off[r] on, its length `len` its value-length entry (`vlen` where
+// there is none) and a DELETE none, each from a 4-byte boundary; the
+// offsets need not follow one another (a compaction's output rows read
+// their values where its input rows left them). Its planes read zeros
 // past them, as a zero-tailed row does: the same blocks. Returns the
 // bytes written, -1 when `out_cap` is short, -2 on arguments the layout
 // can't take (a key length of 0 or over `klen`, a value over `vlen`,
@@ -1096,7 +1099,9 @@ extern "C" int64_t tsst_planar_encode_file(
       memset(w, 0, 4 * vw * n);
       const uint64_t* vo = val_off + start;
       for (uint64_t i = 0; i < n; i++) {
-        uint64_t nw = std::min<uint64_t>(vo[i + 1] - vo[i], vw);
+        if (vtype[start + i] == 2) continue;
+        uint64_t l = val_len != nullptr ? val_len[start + i] : vlen;
+        uint64_t nw = std::min<uint64_t>((l + 3) / 4, vw);
         for (uint64_t k = 0; k < nw; k++)
           put_u32(w + 4 * (k * n + i), val_words[vo[i] + k]);
       }

@@ -396,8 +396,8 @@ def write_resolved_lanes(
     file's own rows beside them. Rows whose keys differ in length, or
     values in width, size their files and blocks by their widest. The
     values may be rows (``val_words``) or one word stream
-    (``val_stream`` and its ``count + 1`` word offsets ``val_off``,
-    ``tpu/format.py`` ``ValueStream``): the files are the same."""
+    (``val_stream`` and each row's word offset in it, ``val_off``,
+    ``tpu/format.py`` ``stream_rows``): the files are the same."""
     from ..observability.span import start_span
     from ..tpu.format import planar_stride, planar_widths, \
         write_sst_from_arrays
@@ -428,8 +428,8 @@ def write_resolved_lanes(
     try:
         for start in range(0, count, entries_per_file):
             end = min(start + entries_per_file, count)
-            sub = {f: a if f == "val_stream" else a[start:end + (
-                f == "val_off")] for f, a in arrays.items()}
+            sub = {f: a if f == "val_stream" else a[start:end]
+                   for f, a in arrays.items()}
             bloom_words = build_bloom(sub, end - start)
             if bloom_words is None:  # the caller's builder has none
                 bloom_words = host_bloom(sub, end - start)

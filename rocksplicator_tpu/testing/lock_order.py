@@ -76,7 +76,7 @@ RANKS = {
     "rocksplicator_tpu/testing/failpoints.py:129": ('_Site.lock', 56),
     "rocksplicator_tpu/utils/stats.py:200": ('_ThreadBuffer.lock', 57),
     "rocksplicator_tpu/kafka/broker.py:204": ('kafka.broker:_clusters_lock', 58),
-    "rocksplicator_tpu/storage/native/binding.py:728": ('storage.native.binding:_native_lock', 59),
+    "rocksplicator_tpu/storage/native/binding.py:756": ('storage.native.binding:_native_lock', 59),
     "rocksplicator_tpu/testing/failpoints.py:161": ('testing.failpoints:_lock', 60),
     "rocksplicator_tpu/utils/objectstore.py:379": ('utils.objectstore:_store_cache_lock', 61),
     "rocksplicator_tpu/admin/db_manager.py:20": ('ApplicationDBManager._lock', 62),

@@ -38,7 +38,6 @@ from ..storage.bloom import num_words_for
 from ..storage.engine import DBOptions
 from ..ops.bloom_tpu import bloom_build_tpu
 from ..ops.compaction_kernel import (MergeKind, gather_value_rows,
-                                     gather_value_stream,
                                      merge_resolve_kernel,
                                      merge_resolve_rows, value_path)
 from ..ops.kv_format import KEY_WORDS, KVBatch, fast_flags, unpack_entries
@@ -54,7 +53,7 @@ from .backend import (DEVICE_VALUE_BYTES_MAX, TpuCompactionBackend,
                       _device_bloom_builder, _next_pow2,
                       device_decline_reason, require_accelerator)
 from .format import (StreamOverrun, ValueStream, stream_may_carry,
-                     stream_offsets, with_value_rows)
+                     stream_offsets, stream_rows, with_value_rows)
 # what the device path takes is asked HERE by the record deployment's
 # driver (chipbench/drivers/refresh_rec.py)
 from .backend import device_value_bytes_max  # noqa: F401
@@ -71,9 +70,10 @@ _GROUP_LANES = (
     "key_words_be", "key_len", "seq_hi",
     "seq_lo", "vtype", "val_words", "val_len", "valid",
 )
-# The words a row of a place's capacity bucket that its value stream
-# holds (``ValueStream``: 524,288 words, 2 MB, at 32,768 rows); a place
-# whose values need more keeps its rows (``compact.value_move.overrun``)
+# The words a row of a place's capacity bucket that its host value
+# stream holds (``ValueStream``: 524,288 words, 2 MB, at 32,768 rows); a
+# place whose values need more keeps its rows
+# (``compact.value_move.overrun``)
 STREAM_WORDS_PER_ROW = 16
 
 
@@ -118,9 +118,11 @@ class TpuCompactionService:
         kernel does (``value_path``). Riding: every lane stacked
         ``(group, N, ...)``. Index: ``val_words`` goes in and comes out
         as a TUPLE of per-shard ``(N, W)`` buffers, so that only real
-        shards' values cross the host-device seam; with ``stream`` a
-        tuple of per-shard word streams (``ValueStream``) in, and out as
-        ``val_stream`` (``gather_value_stream``)."""
+        shards' values cross the host-device seam; with ``stream`` no
+        value goes in or comes out (each place's values stay on the host
+        as one ``ValueStream``): the launch returns ``val_row``, the
+        merge order, beside the small lanes, and the sink reads each
+        output row's value from the input stream."""
         index = value_path(merge_kind, val_words) == "index"
         key = (merge_kind, drop_tombstones, num_words, uniform_klen, seq32,
                key_words, index, index and stream)
@@ -158,16 +160,9 @@ class TpuCompactionService:
                     for s, vw in enumerate(vws))
                 return out
 
-            def one_shard_stream(kwbe, klen, shi, slo, vt, streams, vl,
-                                 valid):
-                out = jax.vmap(one_shard_rows)(
+            def one_shard_stream(kwbe, klen, shi, slo, vt, vl, valid):
+                return jax.vmap(one_shard_rows)(
                     kwbe, klen, shi, slo, vt, vl, valid)
-                moved = jax.vmap(gather_value_stream)(
-                    jax.numpy.stack(streams), vl, out.pop("val_row"),
-                    out["val_len"], out["count"])
-                out["val_stream"] = tuple(moved[s]
-                                          for s in range(len(streams)))
-                return out
 
             # the XLA module's name (jit_<name>), by which a device
             # trace's reader finds the pipeline: fixed here, not left to
@@ -256,9 +251,11 @@ class TpuCompactionService:
         path = value_path(merge_kind, val_words)
         index = path == "index"
         vmixed = any(_values_mixed(b) for b in batches)
-        # the shape of one place's values on the device
-        per_place = ((STREAM_WORDS_PER_ROW * capacity,) if stream
-                     else (capacity, val_words))
+        # the shape of one place's values on the device (a stream
+        # place's stay on the host: the launch takes no value lane)
+        per_place = (capacity, val_words)
+        lanes = tuple(n for n in _GROUP_LANES
+                      if not (stream and n == "val_words"))
         span = current_span()  # tpu.compact_stream / tpu.compact_batch
         if span is not None:
             # key_widths: ``mixed`` where some place's keys differ in
@@ -268,8 +265,8 @@ class TpuCompactionService:
             # in use); value_widths ``mixed`` where some place's live
             # values differ in width; value_bytes the places' real value
             # bytes; value_move ``stream`` (each place's values one word
-            # stream) or ``rows``; value_bytes_padded the bytes the
-            # launch moves for values: a stream's capacity a place, or
+            # stream, on the host) or ``rows``; value_bytes_padded the
+            # bytes the launch moves for values: none for streams, else
             # the places' rows at the launch's width
             span.annotate(
                 val_words=val_words, value_path=path,
@@ -281,9 +278,8 @@ class TpuCompactionService:
                 value_widths="mixed" if vmixed else "uniform",
                 value_bytes=sum(int(b.val_len.sum()) for b in batches),
                 value_move="stream" if stream else "rows",
-                value_bytes_padded=4 * (
-                    per_place[0] * len(batches) if stream
-                    else val_words * sum(b.capacity for b in batches)))
+                value_bytes_padded=0 if stream else 4 * val_words * sum(
+                    b.capacity for b in batches))
         Stats.get().incr("compact.value_path." + path, len(batches))
         fn = self._pipeline(merge_kind, drop_tombstones, num_words,
                             uniform_klen, seq32, key_words, val_words,
@@ -291,17 +287,18 @@ class TpuCompactionService:
 
         def stage(lo: int) -> Dict[str, object]:
             """Stack one group on host and issue its async H2D. On the
-            index path a shard's values go up as a buffer of their own,
-            never stacked: the one its batch brought (``val_words_dev``:
-            the caller's thread put it up already) where that is of this
-            group's capacity bucket, else the host values (rows or word
-            stream) padded and put here; an empty place takes the one
-            zero buffer the device already holds."""
+            index path a shard's value rows go up as a buffer of their
+            own, never stacked: the one its batch brought
+            (``val_words_dev``: the caller's thread put it up already)
+            where that is of this group's capacity bucket, else the host
+            rows padded and put here; an empty place takes the one zero
+            buffer the device already holds. A stream group puts no
+            value up."""
             group = list(batches[lo:lo + group_size])
             pad_shards = group_size - len(group)
             stacked = {}
             with start_span("tpu.h2d", shards=len(group)) as sp:
-                for name in _GROUP_LANES:
+                for name in lanes:
                     if index and name == "val_words":
                         ups, prestaged, restaged = [], 0, 0
                         for b in group:
@@ -310,10 +307,8 @@ class TpuCompactionService:
                                 prestaged += 1
                             else:  # none came, or one of another bucket
                                 restaged += dev is not None
-                                dev = jax.device_put(_pad_to(
-                                    b.val_stream, per_place[0]) if stream
-                                    else _pad_to(_pad_cols(
-                                        b.val_words, val_words), capacity))
+                                dev = jax.device_put(_pad_to(_pad_cols(
+                                    b.val_words, val_words), capacity))
                             ups.append(dev)
                         Stats.get().incr("seam.values.prestaged", prestaged)
                         Stats.get().incr("seam.values.restaged", restaged)
@@ -338,7 +333,7 @@ class TpuCompactionService:
         dev = stage(groups[0])
         for gi, lo in enumerate(groups):
             with start_span("tpu.dispatch"):  # the async launch alone
-                out = fn(*(dev[name] for name in _GROUP_LANES))
+                out = fn(*(dev[name] for name in lanes))
             if gi + 1 < len(groups):
                 dev = stage(groups[gi + 1])  # H2D overlaps the kernel
             pending.append((lo, out))
@@ -355,9 +350,9 @@ class TpuCompactionService:
         return results
 
     def _zeros(self, key: tuple):
-        """The device's one all-zero buffer of this shape (``(capacity,
-        val_words)`` rows, or ``(words,)`` of a stream): what an empty
-        place of an index-path group reads."""
+        """The device's one all-zero buffer of this ``(capacity,
+        val_words)`` shape: what an empty place of an index-path group
+        reads."""
         if key not in self._zero_rows:
             self._zero_rows[key] = jax.device_put(
                 np.zeros(key, dtype=np.uint32))
@@ -366,12 +361,12 @@ class TpuCompactionService:
     def _drain(self, lo: int, out, batches, merge_kind, drop_tombstones,
                num_words, return_arrays=False) -> List[dict]:
         """Readback + unpack one group's device outputs. The index
-        path's values come back per shard (``out["val_words"]``, or
-        ``out["val_stream"]``, is a tuple), the real shards' only, each as
-        the padded block or stream: here,
+        path's values come back per shard (``out["val_words"]`` is a
+        tuple), the real shards' only, each as the padded block: here,
         or, for a shard whose batch brought its values up as a device
         buffer, wherever the caller reads the device buffer its result
-        carries (the copy is started here, behind the small lanes)."""
+        carries (the copy is started here, behind the small lanes). A
+        stream group's ``val_row`` comes back with the small lanes."""
         group = batches[lo:lo + out["count"].shape[0]]
         with start_span("tpu.readback"):  # blocked on the device, and D2H
             host = {k: np.asarray(v) for k, v in out.items()
@@ -395,8 +390,12 @@ class TpuCompactionService:
                         group[s], merge_kind, drop_tombstones, num_words,
                         return_arrays=return_arrays))
                     continue
-                results.append(_shard_result(
-                    host, s, int(host["count"][s]), return_arrays))
+                res = _shard_result(
+                    host, s, int(host["count"][s]), return_arrays)
+                if "val_row" in host:  # the values the merge order reads
+                    res["arrays"].update(val_stream=group[s].val_stream,
+                                         val_src_len=group[s].val_len)
+                results.append(res)
         return results
 
     def _cpu_recompute(self, batch: KVBatch, merge_kind: MergeKind,
@@ -412,6 +411,8 @@ class TpuCompactionService:
 
         record_host_fallback(
             "kernel_overflow", f"{batch.capacity}-entry shard")
+        if getattr(batch, "val_stream", None) is not None:
+            batch = _rows_batch(batch)  # the resolve takes value rows
         arrays, count = cpu_merge_resolve(
             batch, uint64_add=merge_kind is MergeKind.UINT64_ADD,
             drop_tombstones=drop_tombstones,
@@ -492,23 +493,26 @@ _LANES = (
 def _shard_result(host: Dict[str, np.ndarray], s: int, count: int,
                   return_arrays: bool) -> dict:
     """One shard's result from stacked device outputs: lane views (no
-    per-entry work) or unpacked tuples. A shard whose values came back
-    as a word stream (``compact_dbs_batched``'s, lane views) has
-    ``val_stream`` (whole) in ``val_words``' place."""
-    lanes = _LANES
-    if "val_stream" in host:
-        lanes = tuple(f for f in _LANES if f != "val_words") + (
-            "val_stream",)
+    per-entry work) or unpacked tuples. A place whose values stayed on
+    the host as a word stream (``compact_dbs_batched``'s, lane views)
+    has the merge order (``val_row``: each output row's input row) in
+    ``val_words``' place; ``_drain`` adds its batch's input stream
+    (``val_stream``) and the input rows' ``val_len`` (``val_src_len``),
+    from which ``_write_arrays`` finds each output value's words."""
+    if "val_row" in host:
+        lanes = {f: host[f][s][:count] for f in _LANES if f != "val_words"}
+        lanes["val_row"] = host["val_row"][s][:count]
+        return {"arrays": lanes, "bloom_words": host["bloom"][s],
+                "count": count}
     if return_arrays:
         def rows(f):
             a = host[f][s]
             # a value block left on the device stays whole: the thread
             # that reads it back slices it (``_write_arrays``)
-            return (a[:count] if isinstance(a, np.ndarray)
-                    and f != "val_stream" else a)
+            return a[:count] if isinstance(a, np.ndarray) else a
 
         return {
-            "arrays": {f: rows(f) for f in lanes},
+            "arrays": {f: rows(f) for f in _LANES},
             "bloom_words": host["bloom"][s],
             "count": count,
         }
@@ -631,6 +635,17 @@ def device_shard_rows_max(merge_operator) -> int:
     return MAX_BATCHED_DB_ENTRIES
 
 
+def _rows_batch(batch) -> "_LaneBatch":
+    """A stream place's batch with its values as zero-tailed rows (for
+    the host resolve, which takes rows)."""
+    lanes = {f: getattr(batch, f) for f in _GROUP_LANES[:-1]}
+    lanes["val_words"] = stream_rows(
+        {"val_stream": batch.val_stream, "val_len": batch.val_len,
+         "vtype": batch.vtype, "val_off": stream_offsets(batch.val_len)},
+        batch.capacity)
+    return _LaneBatch(lanes)
+
+
 class _LaneBatch:
     """Duck-typed KVBatch over pre-read lane arrays — the arrays-native
     input to compact_shard_batch/stream (no per-entry pack loop): the
@@ -640,10 +655,10 @@ class _LaneBatch:
     capacity), where the thread that decoded the shard put them up
     itself (an index-path shard of the served door); such a shard's
     resolved values come back as a device buffer too. ``val_stream``:
-    the place's values as one word stream (``ValueStream``'s buffer;
-    ``val_words`` is then None and ``val_words_dev`` the stream on the
-    device), ``val_cols`` the value bucket of its widest value, as its
-    rows would have had."""
+    the place's values as one word stream (``ValueStream``'s buffer,
+    which stays on the host; ``val_words`` is then None),
+    ``val_cols`` the value bucket of its widest value, as its rows would
+    have had."""
 
     __slots__ = _GROUP_LANES + ("val_words_dev", "val_stream", "val_cols")
 
@@ -682,18 +697,18 @@ def _write_arrays(db, res: dict, tctx: Optional[dict],
     arrays, count = res["arrays"], int(res["count"])
     if count == 0:
         return {"entries": []}
-    stream = "val_stream" in arrays
-    key = "val_stream" if stream else "val_words"
-    vals = arrays[key]
-    if not isinstance(vals, np.ndarray):
-        # the launch left this shard's padded block (or word stream) on
-        # the device (``_drain``): down on THIS thread, eight at once
+    if "val_row" in arrays:
+        # a stream place: each output row's value is its input row's
+        # words, where the input rows' lengths put them in the stream
+        arrays["val_off"] = stream_offsets(arrays.pop("val_src_len"))[
+            arrays.pop("val_row")]
+    elif not isinstance(arrays["val_words"], np.ndarray):
+        # the launch left this shard's padded block on the device
+        # (``_drain``): down on THIS thread, eight at once
+        vals = arrays["val_words"]
         with start_span("tpu.readback.values", remote=tctx,
                         bytes=vals.nbytes):
-            vals = np.asarray(vals)
-        arrays[key] = vals if stream else vals[:count]
-    if stream:  # each row's words from the rows before it
-        arrays["val_off"] = stream_offsets(arrays["val_len"][:count])
+            arrays["val_words"] = np.asarray(vals)[:count]
     opts = db.options
     build_bloom = (
         _launch_bloom(res["bloom_words"], count, opts.bits_per_key)
@@ -765,7 +780,8 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
     is centralized. An index-path shard's value block crosses the
     host-device seam on those per-db threads too (``tpu.h2d.values``
     after its decode, ``tpu.readback.values`` before its write): the
-    launching thread carries the small lanes alone.
+    launching thread carries the small lanes alone. A stream place's
+    values (below) do not cross it at all.
 
     A launch is ALWAYS the fixed ``(group_size, capacity)`` shape with
     ``capacity`` at most ``PLACE_ROWS_MAX``. A shard of more rows into
@@ -800,11 +816,13 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
     ``value_widths`` / ``value_bytes`` / ``value_bytes_padded`` on
     ``tpu.compact_stream`` say what that costs; an uncut shard whose runs
     say its values differ in width (a run's own do, or two runs' widest
-    differ) moves them as ONE word
-    stream instead (``ValueStream``: decoded into it, put up, gathered by
-    the merge order inside the same module, read back and written from
-    it, ``STREAM_WORDS_PER_ROW`` words a row of its capacity bucket;
-    ``value_move`` = ``stream`` on ``tpu.compact_stream``; such shards
+    differ) keeps them on the host as ONE word stream instead
+    (``ValueStream``: decoded into it, ``STREAM_WORDS_PER_ROW`` words a
+    row of its capacity bucket; the launch takes no value and returns
+    the merge order, ``val_row``, with the small lanes, and the sink
+    writes each output row's value from the input stream at its source
+    row's words; ``value_move`` = ``stream`` on ``tpu.compact_stream``,
+    whose ``value_bytes_padded`` is then 0; such shards
     launch as groups of their own; ``Stats``
     ``compact.value_move.stream`` / ``.rows`` count index-path shards by
     move, ``.overrun`` the places whose values overran the stream and
@@ -986,11 +1004,10 @@ def compact_dbs_batched(dbs, group_size: int = 8, pool=None):
             places = (_range_cut(name, parts, lanes, total, index, tctx)
                       if cut else [lanes])
             devs = []
-            for place in places if index else ():
+            for place in places if index and not stream else ():
                 # up from THIS thread, eight shards at once, and not one
                 # after another on the leader's (its ``tpu.h2d``)
-                padded = (place["val_stream"] if stream
-                          else place["val_words"].base)
+                padded = place["val_words"].base
                 with start_span("tpu.h2d.values", remote=tctx,
                                 bytes=padded.nbytes):
                     devs.append(

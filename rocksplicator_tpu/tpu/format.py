@@ -112,13 +112,21 @@ def stream_offsets(val_len: np.ndarray) -> np.ndarray:
 
 def stream_rows(arrays: Dict[str, np.ndarray], count: int) -> np.ndarray:
     """The first ``count`` rows' values of stream lanes (``val_stream``,
-    ``val_off``) as zero-tailed ``(count, W)`` rows, W the widest value's
-    words (2 at least): what a codec without a stream input takes."""
-    off = arrays["val_off"][:count + 1].astype(np.int64)
-    nw = np.diff(off)
+    and ``val_off``, the word at which each row's value starts) as
+    zero-tailed ``(count, W)`` rows, W the widest value's words (2 at
+    least): what a codec without a stream input takes. Row r's value is
+    ``ceil(val_len / 4)`` words from ``val_off[r]`` on, a DELETE none:
+    the offsets need not follow one another (a compaction's output rows
+    read their values where its input rows left them)."""
+    nw = np.where(arrays["vtype"][:count] == 2, 0,
+                  (arrays["val_len"][:count].astype(np.int64) + 3) // 4)
     rows = np.zeros((count, max(2, int(nw.max(initial=0)))), np.uint32)
     taken = np.arange(rows.shape[1]) < nw[:, None]
-    rows[taken] = arrays["val_stream"][off[0]:off[-1]]
+    # each taken word's source: its row's start, plus its place in the row
+    starts = np.cumsum(nw) - nw
+    src = np.arange(int(nw.sum())) + np.repeat(
+        arrays["val_off"][:count].astype(np.int64) - starts, nw)
+    rows[taken] = arrays["val_stream"][src]
     return rows
 
 
@@ -757,8 +765,8 @@ def write_sst_from_arrays(
     in kernel lane order — smaller files and no byte interleaving on
     either side; keys of 1 to 48 bytes, of one length or mixed, values
     of one width or mixed (``planar_widths``), as rows or as a
-    ``ValueStream``'s words (``val_stream`` and the ``count + 1`` word
-    offsets ``val_off`` in ``val_words``' place: the same file). ``device_words`` optionally carries the device planar
+    ``ValueStream``'s words (``val_stream`` and each row's word offset
+    ``val_off`` in ``val_words``' place: the same file). ``device_words`` optionally carries the device planar
     encoder's (nblocks, words) matrix for full blocks (the tail block is
     host-packed: its plane lengths differ from the fixed device shape)."""
     if planar:

@@ -9,9 +9,12 @@ row's word offset follows from the ``val_len`` of the rows before it.
   does; a stream that would overrun raises and takes nothing;
 - the PLANAR sink takes the stream with its offsets and writes the
   bytes it writes from rows, native and Python, over several files;
-- the device's move (``gather_value_stream``) against a plain gather;
+  and a compaction's output rows, each at its source row's words in
+  the input stream (the merge order taken at the input's offsets),
+  against the same rows gathered;
 - the served door (``compact_dbs_batched``): a MixGraph-shaped place
-  moves its values as a stream and writes the files the row path
+  keeps its values on the host as a stream (no value span crosses the
+  seam) and writes the files the row path
   writes, byte for byte, on places of duplicate keys across a plain
   writer's bulk file and a PLANAR flush, values of 10 and of 1,024
   bytes, empty values, a place of one row, tombstones kept; a place
@@ -23,11 +26,9 @@ XLA-CPU under conftest.py's explicit JAX_PLATFORMS=cpu.
 
 import random
 
-import jax
 import numpy as np
 import pytest
 
-from rocksplicator_tpu.ops.compaction_kernel import gather_value_stream
 from rocksplicator_tpu.storage import DB, DBOptions, OpType
 from rocksplicator_tpu.storage import native_compaction as nc
 from rocksplicator_tpu.storage.compaction import host_fallback_counts
@@ -38,7 +39,8 @@ from rocksplicator_tpu.tpu import format as fmt
 from rocksplicator_tpu.utils.stats import Stats
 from test_mixgraph_records import (D, P, check_db, compact_traced, db_files,
                                    lanes_of, mix_key, mix_value,
-                                   mixgraph_rows, python_codecs)
+                                   mixgraph_rows, plain_reference,
+                                   python_codecs)
 
 MOVES = ("compact.value_move.stream", "compact.value_move.rows",
          "compact.value_move.overrun")
@@ -213,38 +215,101 @@ def test_the_sink_writes_from_the_stream_the_file_it_writes_from_rows(
 
 
 # ---------------------------------------------------------------------------
-# the device's move
+# the merge order's move, in the sink
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("codec", ["native", "python"])
 @pytest.mark.parametrize("case", ["mixgraph", "one_row", "ten_and_1024",
                                   "nothing"])
-def test_the_device_gathers_the_stream_as_a_plain_gather(case):
+def test_the_sink_writes_at_the_source_words_what_it_writes_from_gathered_rows(
+        case, codec, tmp_path, monkeypatch):
+    """A compaction's output: ``count`` rows in key order, row i keeping
+    input row ``val_row[i]``'s value (the merge order). Written from the
+    input stream at each source row's words and from the same values
+    gathered as rows: the same file."""
     rng = np.random.default_rng(7)
-    n, size = 64, 16 * 64
+    n = 64
     in_len = rng.integers(10, 120, n).astype(np.uint32)
     in_len[::5] = 0  # DELETEs: no word
     if case == "ten_and_1024":
-        in_len[:] = np.where(np.arange(n) % 2, 10, 1024)[:n]
+        in_len[:] = np.where(np.arange(n) % 2, 10, 1024)
         in_len[3] = 0
-        size = 16 * 2048
+    in_type = np.where(in_len == 0, D, P).astype(np.uint32)
+    in_type[::10] = P  # and an empty PUT now and then
     count = {"mixgraph": 40, "one_row": 1, "ten_and_1024": 20,
              "nothing": 0}[case]
-    val_row = rng.permutation(n).astype(np.int32)  # the merge order
-    val_row[count:] = 0
-    out_len = np.where(np.arange(n) < count, in_len[val_row], 0).astype(
-        np.uint32)
+    val_row = rng.permutation(n)[:count].astype(np.int32)
     rows = np.zeros((n, 256), np.uint32)
     for i, w in enumerate((in_len + 3) // 4):
         rows[i, :w] = rng.integers(1, 2**32, w, dtype=np.uint32)
-    stream = np.zeros(size, np.uint32)
-    want = stream_of(rows, in_len)
-    stream[:len(want)] = want
-    got = np.asarray(jax.jit(gather_value_stream)(
-        stream, in_len, val_row, out_len, count))
-    moved = stream_of(rows[val_row[:count]], out_len[:count])
-    assert got.shape == (size,) and got.dtype == np.uint32
-    assert np.array_equal(got[:len(moved)], moved)
-    assert not got[len(moved):].any()
+        tail = int(in_len[i]) % 4
+        if tail:  # the bytes past a value's length are zero
+            rows[i, w - 1] &= (1 << 8 * tail) - 1
+    stream = np.concatenate([stream_of(rows, in_len),
+                             np.full(64, 0xA5A5A5A5, np.uint32)])
+    out = [(mix_key(3 * i + 1), 100 + i, int(in_type[r]), b"")
+           for i, r in enumerate(val_row)]
+    gathered = lanes_of(out or [(mix_key(1), 1, P, b"")])
+    gathered = {f: a[:count] for f, a in gathered.items()}
+    gathered["vtype"] = in_type[val_row]
+    gathered["val_len"] = in_len[val_row]
+    gathered["val_words"] = rows[val_row]
+    at_source = {f: a for f, a in gathered.items() if f != "val_words"}
+    at_source["val_stream"] = stream
+    at_source["val_off"] = fmt.stream_offsets(in_len)[val_row]
+    if codec == "python":
+        python_codecs(monkeypatch)
+    # the codecs without a stream input read the same rows off it
+    widest = max(2, int(((gathered["val_len"] + 3) // 4).max(initial=0)))
+    assert np.array_equal(fmt.stream_rows(at_source, count),
+                          gathered["val_words"][:, :widest])
+    a, b = str(tmp_path / "rows.tsst"), str(tmp_path / "stream.tsst")
+    props = fmt.write_sst_from_arrays(gathered, count, a, block_entries=16,
+                                      compression=1, planar=True)
+    assert fmt.write_sst_from_arrays(at_source, count, b, block_entries=16,
+                                     compression=1, planar=True) == props
+    assert (props is None) == (count == 0)
+    if count:
+        assert open(a, "rb").read() == open(b, "rb").read()
+        reader = SSTReader(b)
+        assert [(k, v) for k, _s, _t, v in reader.iterate()] == [
+            (mix_key(3 * i + 1), rows[r].astype("<u4").tobytes()[
+                :int(in_len[r])] if in_type[r] != D else b"")
+            for i, r in enumerate(val_row)]
+        reader.close()
+
+
+def test_a_flagged_stream_place_recomputes_on_the_host(tmp_path):
+    """A place the kernel flags is recomputed on the host from the batch
+    it was launched from; a stream place's batch carries no value rows,
+    and its recompute writes the file its rows' recompute writes."""
+    from rocksplicator_tpu.ops.compaction_kernel import MergeKind
+    from rocksplicator_tpu.storage.bloom import num_words_for
+
+    rows = mixgraph_rows(300, 45)
+    rng = random.Random(45)
+    rows += [(k, s + 1000, P, mix_value(rng)) for k, s, _t, _v in rows[::3]]
+    lanes = lanes_of(rows)
+    stream = fmt.ValueStream(cs.STREAM_WORDS_PER_ROW * 1024)
+    stream.append_rows(lanes["val_words"], lanes["val_len"])
+    streamed = {f: a for f, a in lanes.items() if f != "val_words"}
+    streamed["val_stream"] = stream.finish()
+    svc = cs.TpuCompactionService()
+    files = []
+    for side in (lanes, streamed):
+        res = svc._cpu_recompute(
+            cs._LaneBatch(side), MergeKind.NONE, True,
+            num_words_for(len(rows), 10), return_arrays=True)
+        files.append(str(tmp_path / f"{len(files)}.tsst"))
+        assert fmt.write_sst_from_arrays(
+            res["arrays"], res["count"], files[-1], block_entries=64,
+            planar=True) is not None
+    a, b = (open(f, "rb").read() for f in files)
+    assert a == b
+    reader = SSTReader(files[1])
+    assert [(k, int(t), v) for k, _s, t, v in reader.iterate()] == \
+        plain_reference(rows)
+    reader.close()
 
 
 # ---------------------------------------------------------------------------
@@ -339,10 +404,15 @@ def test_the_stream_path_writes_the_row_paths_files(case, tmp_path,
         assert launch["value_move"] == move and launch["value_path"] == "index"
         assert gained == ([2, 0, 0] if move == "stream" else [0, 2, 0])
         assert all(s["value_move"] == move for s in spans["tpu.lanes.decode"])
-        padded = (4 * cs.STREAM_WORDS_PER_ROW * launch["capacity"] * 2
-                  if move == "stream" else None)
-        if padded is not None:
-            assert launch["value_bytes_padded"] == padded
+        # a stream place's values never cross the seam: no span moves
+        # them, and the launch moves none
+        crossed = [n for n in ("tpu.h2d.values", "tpu.readback.values")
+                   if spans.get(n)]
+        if move == "stream":
+            assert launch["value_bytes_padded"] == 0 and not crossed
+        else:
+            assert launch["value_bytes_padded"] > 0
+            assert crossed == ["tpu.h2d.values", "tpu.readback.values"]
         for db, entries in made:
             check_db(db, kw["keys"], entries, drop=not kw.get("keep"))
     # the same launch width, the same value bytes, and the same files

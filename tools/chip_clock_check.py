@@ -48,10 +48,12 @@ the run's own and the last):
   ``tpu.h2d.values`` / ``tpu.readback.values`` spans and the leader's
   ``tpu.h2d`` / ``tpu.readback``: how many and their mean; and how they
   crossed: ``compact.value_move.stream`` / ``.rows`` (index-path shards
-  whose values moved as one word stream / as zero-tailed rows) and
-  ``.overrun`` (places whose values would not fit the stream and kept
-  their rows), beside the places the window's ``tpu.compact_stream``
-  spans launched by their ``value_move`` annotation.
+  whose values stayed on the host as one word stream, the launch taking
+  only the merge order's lanes, so that a stream place opens neither
+  value span / that moved as zero-tailed rows) and ``.overrun`` (places
+  whose values would not fit the stream and kept their rows), beside
+  the places the window's ``tpu.compact_stream`` spans launched by their
+  ``value_move`` annotation.
   ``--trace 0`` too.
 - **which shards were cut by key range**: the process's
   ``compact.range_cut.shards`` / ``.places`` counters (shards of more
